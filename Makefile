@@ -7,9 +7,9 @@ PY ?= python
 .PHONY: lint lint-fast lint-ci lint-baseline lint-update-baseline test \
 	knobs signatures determinism sanitizers chaos bench-hetero \
 	bench-charrnn bench-dpshard bench-elastic bench-serve \
-	bench-serve-scale
+	bench-serve-scale smoke-rehearse
 
-LINT_PATHS = deeplearning4j_tpu tools bench.py examples
+LINT_PATHS = deeplearning4j_tpu tools bench.py chip_smoke.py examples
 
 # Whole-package interprocedural + flow-sensitive JAX hot-path and
 # concurrency lint (rules G001-G018, docs/STATIC_ANALYSIS.md).
@@ -63,6 +63,14 @@ chaos:
 		tests/test_siglint.py tests/test_detlint.py \
 		tests/test_serving.py tests/test_serving_resilience.py \
 		tests/test_elastic.py -q
+
+# CPU rehearsal of chip_smoke.py — every phase (tier-1 runs only the two
+# LM phases) at tiny size with Pallas in interpret mode, then the 4-device
+# data-parallel phase on virtual CPU devices. Run before sending
+# `python chip_smoke.py` to the chip; its last line names the CPU.
+smoke-rehearse:
+	$(PY) chip_smoke.py --rehearse
+	$(PY) chip_smoke.py --rehearse --chips 4
 
 # shape-heterogeneous fused-grouping A/B: adaptive (per-bucket K +
 # trailing-only padding) vs the always-pad contract on a 2-shape

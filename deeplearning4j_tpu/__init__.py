@@ -20,38 +20,25 @@ __version__ = "0.1.0"
 
 
 def _init_compile_cache():
-    """Point jax at a persistent XLA compilation cache when
-    DL4J_TPU_COMPILE_CACHE_DIR is set — restarted runs (and the serving
-    tier the ROADMAP plans) skip cold-start compiles. Applied at package
-    import, before any program builds; the threshold knobs are
-    best-effort (names vary across jax versions) but the cache dir
-    itself failing to apply is surfaced."""
+    """Persistent XLA compilation cache — restarted runs and servers skip
+    cold-start compiles. Its place is decided from outside: where
+    ``JAX_COMPILATION_CACHE_DIR`` is set (or the caller configured a
+    directory already) JAX has read it itself and no directory is set
+    here. Otherwise it is the fixed ``<checkout>/.jax_cache`` — the path
+    is part of the cache key's neighbourhood, so never a temporary name."""
     import os as _os
-    import warnings as _warnings
 
-    from deeplearning4j_tpu.config import env_str as _env_str
-
-    cache_dir = _env_str("DL4J_TPU_COMPILE_CACHE_DIR")
-    if not cache_dir:
-        return
     import jax as _jax
-    try:
-        _jax.config.update("jax_compilation_cache_dir",
-                           _os.path.expanduser(cache_dir))
-    except Exception as exc:  # old jax without the option
-        _warnings.warn(
-            f"DL4J_TPU_COMPILE_CACHE_DIR={cache_dir!r} could not be "
-            f"applied (jax_compilation_cache_dir unsupported?): {exc!r}")
-        return
-    # cache even fast/small compiles: the knob exists to make restarts
-    # cheap, and the default 1s/min-size thresholds would skip most of
-    # this framework's per-signature programs
-    for opt, val in (("jax_persistent_cache_min_compile_time_secs", 0.0),
-                     ("jax_persistent_cache_min_entry_size_bytes", -1)):
-        try:
-            _jax.config.update(opt, val)
-        except Exception:  # graftlint: disable=G005 -- best-effort tuning thresholds; absent on older jax and the cache works without them
-            pass
+
+    if _jax.config.jax_compilation_cache_dir is None:
+        _jax.config.update(
+            "jax_compilation_cache_dir",
+            _os.path.join(_os.path.dirname(_os.path.dirname(
+                _os.path.abspath(__file__))), ".jax_cache"))
+    # cache even fast/small compiles: the default 1s/min-size thresholds
+    # would skip most of this framework's per-signature programs
+    _jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    _jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
 
 
 _init_compile_cache()

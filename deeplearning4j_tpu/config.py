@@ -81,9 +81,6 @@ _declare("DL4J_TPU_AB_SMOKE", "flag", False,
 _declare("DL4J_TPU_ALLOW_DOWNLOAD", "flag", False,
          "Enable the MNIST/LFW/CIFAR-10/Iris/trained-model download paths; "
          "off by default (air-gapped environments place files manually).")
-_declare("DL4J_TPU_BENCH_DEGRADED", "flag", False,
-         "Tooling: bench.py ran (or should run) at degraded sizing — "
-         "recorded in benchmark provenance.")
 _declare("DL4J_TPU_CKPT_EVERY", "int", 0,
          "Default periodic-checkpoint cadence (parameter updates between "
          "training checkpoints) for fit(checkpoint_dir=...); 0 disables "
@@ -100,10 +97,6 @@ _declare("DL4J_TPU_COLLECTIVE_TIMEOUT", "float", 300.0,
          "Per-round deadline (seconds) for coordinator collectives: a round "
          "not completed within it fails on EVERY waiter with "
          "CollectiveTimeoutError instead of hanging.")
-_declare("DL4J_TPU_COMPILE_CACHE_DIR", "str", "",
-         "Persistent XLA compilation cache directory "
-         "(jax_compilation_cache_dir), applied at package import: restarted "
-         "runs/servers skip cold-start compiles; empty (default) disables.")
 _declare("DL4J_TPU_CONNECT_RETRIES", "int", 3,
          "Extra connection attempts (exponential backoff) a collective "
          "client makes before giving up on the coordinator.")
@@ -491,3 +484,17 @@ if __name__ == "__main__":
     print("rule — see `docs/STATIC_ANALYSIS.md`.")
     print()
     print(knob_table_md())
+    print()
+    print("## JAX's own variables")
+    print()
+    print("Two variables belong to JAX, not to this registry, and the")
+    print("package follows them instead of wrapping them:")
+    print()
+    print("- `JAX_PLATFORMS` — `cpu` is the test and rehearsal lane; on a")
+    print("  machine with a chip leave it unset. Nothing in the package")
+    print("  switches platform on its own.")
+    print("- `JAX_COMPILATION_CACHE_DIR` — the persistent XLA compilation")
+    print("  cache. Where it is set JAX reads it itself and the package")
+    print("  sets no directory; where it is not, the package uses the fixed")
+    print("  `<checkout>/.jax_cache` (never a temporary name: a cache that")
+    print("  moves never hits).")

@@ -7,8 +7,7 @@ threshold and an absolute floor for tiny gradients.
 
 Per SURVEY §7 hard-part 6, checks run in float64 on the CPU backend (TPUs are
 poor at f64); tests set JAX_PLATFORMS=cpu and this module enables x64 locally
-via the ``enable_x64`` context (top-level on new JAX, experimental on old —
-see the compat shim in ``deeplearning4j_tpu.utils``).
+via the ``jax.enable_x64`` context.
 """
 
 from __future__ import annotations
@@ -16,8 +15,9 @@ from __future__ import annotations
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax import enable_x64
 
-from deeplearning4j_tpu.utils import enable_x64, flat_params
+from deeplearning4j_tpu.utils import flat_params
 
 
 def check_gradients(net, x, y, fmask=None, lmask=None, *, epsilon=1e-6,

@@ -3,11 +3,34 @@
 Single source for the MFU basis so bench.py and tools/ can never diverge.
 """
 
-# TPU v5e single-chip peak, bf16 matmul (the MFU denominator everywhere)
-TPU_V5E_BF16_PEAK_FLOPS = 197e12
+# Peak dense bf16 matmul FLOP/s of ONE chip, keyed by the ``device_kind``
+# JAX reports for it — the MFU denominator. A device that is not listed
+# has no peak here: ``peak_bf16_flops`` raises rather than assume one.
+PEAK_BF16_FLOPS = {
+    # Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16 per chip
+    # (16 GB HBM at 819 GB/s)
+    "TPU v5 lite": 197e12,
+}
 
 # MFU numerator convention: train step FLOPs = 3x forward (fwd + ~2x bwd)
 TRAIN_FLOPS_MULTIPLIER = 3
+
+
+def peak_bf16_flops(device_kind=None):
+    """The table's peak for ``device_kind`` (default: the first device JAX
+    reports). Unknown kinds are an error — an MFU against a guessed peak
+    is not a measurement."""
+    if device_kind is None:
+        import jax
+        device_kind = jax.devices()[0].device_kind
+    try:
+        return PEAK_BF16_FLOPS[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no peak FLOP/s recorded for device_kind {device_kind!r} "
+            f"(known: {sorted(PEAK_BF16_FLOPS)}); add it to "
+            "deeplearning4j_tpu/hw.py with its source before reporting "
+            "MFU on it") from None
 
 
 def transformer_fwd_flops_per_token(T, d_model, n_layers, d_ff, vocab):

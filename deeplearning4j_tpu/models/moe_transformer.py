@@ -142,7 +142,7 @@ class MoETransformerLM(TransformerLM):
                 return y
 
             out = _block_apply(c, bp, xx, drop=self._drop, rng=rr,
-                               ffn=moe_ffn)
+                               ffn=moe_ffn, plan=self._shard_plan)
             return out, cell["aux"]
 
         def apply(i, bp, x):

@@ -20,6 +20,7 @@ rather than as layer-zoo glue:
 
 from __future__ import annotations
 
+import functools
 import math
 from dataclasses import dataclass
 from typing import Optional
@@ -61,23 +62,34 @@ def _full_heads(c, k, v):
     return k, v
 
 
-def _blockwise_route(c, q, k, v):
+def _blockwise_route(c, q, k, v, plan=None):
     """Route the block_size attention: the pallas flash kernel (fused fwd
     + FlashAttention-2 bwd, ops/pallas_kernels.py) when the platform
     supports it, else the mathematically identical lax.scan recurrence.
     DL4J_TPU_LM_ATTN forces {pallas, scan}; read at TRACE time (the step
     jits once), so set it before the first fit_batch. A sliding window
     (c.window) rides the pallas route — the scan has no window support,
-    so that combination falls back to masked dense attention."""
+    so that combination falls back to masked dense attention.
+
+    ``plan`` is the model's ``ShardingCore`` when it was ``shard()``-ed:
+    GSPMD refuses to partition a Mosaic kernel ("wrap the call in a
+    shard_map"), so under a data-parallel plan the kernel runs per batch
+    shard inside one — attention never mixes batch rows."""
     mode = env_str("DL4J_TPU_LM_ATTN")
     if mode in ("auto", "pallas"):
         from deeplearning4j_tpu.ops.pallas_kernels import (flash_attention,
                                                            pallas_supported)
         if mode == "pallas" or pallas_supported():
             # GQA rides the kernel's index map — no repeat materialized
-            return flash_attention(q, k, v, causal=True,
-                                   block_q=c.block_size,
-                                   block_k=c.block_size, window=c.window)
+            attend = functools.partial(
+                flash_attention, causal=True, block_q=c.block_size,
+                block_k=c.block_size, window=c.window)
+            if plan is not None and plan.batch_axis:
+                spec = plan.batch_spec()
+                attend = jax.shard_map(
+                    attend, mesh=plan.mesh, in_specs=(spec, spec, spec),
+                    out_specs=spec, check_vma=False)
+            return attend(q, k, v)
     k, v = _full_heads(c, k, v)   # the JAX fallbacks want full heads
     if c.window is not None:
         return dense_attention(q, k, v, causal=True, window=c.window)
@@ -164,7 +176,8 @@ def _layer_norm(x, g, b, eps=1e-5):
     return (x - m) / jnp.sqrt(v + eps) * g + b
 
 
-def _block_apply(c, bp, x, drop=None, rng=None, attend=None, ffn=None):
+def _block_apply(c, bp, x, drop=None, rng=None, attend=None, ffn=None,
+                 plan=None):
     """One pre-LN block from its param dict — THE canonical block math,
     shared by TransformerLM (which threads its residual-branch dropout in
     via ``drop``), the dropout-free PP trainer, the SP trainer (which
@@ -172,7 +185,8 @@ def _block_apply(c, bp, x, drop=None, rng=None, attend=None, ffn=None):
     (which swaps the dense FFN for expert routing via ``ffn``). Any fix
     here reaches every consumer; only the TP trainer re-derives it (its
     weights are partitioned, so the matmuls are structurally
-    different)."""
+    different). ``plan`` (the GSPMD ``ShardingCore`` of a ``shard()``-ed
+    model) only reaches the flash-kernel route."""
     B, T, d = x.shape
     hd = d // c.n_heads
     r1 = r2 = None
@@ -192,7 +206,7 @@ def _block_apply(c, bp, x, drop=None, rng=None, attend=None, ffn=None):
         k, v = _full_heads(c, k, v)   # custom attends (ring SP) assume MHA
         o = attend(q, k, v)
     elif c.block_size:
-        o = _blockwise_route(c, q, k, v)
+        o = _blockwise_route(c, q, k, v, plan)
     else:
         k, v = _full_heads(c, k, v)
         o = dense_attention(q, k, v, causal=True, window=c.window)
@@ -284,6 +298,7 @@ class TransformerLM:
         self._jit_gen = {}      # blessed _gen_signature -> compiled sampler
         self._jit_decode = {}   # blessed _decode/_admit_signature -> program
         self._data_sharding = None
+        self._shard_plan = None   # ShardingCore, set by shard()
         self.listeners = []
 
     def set_listeners(self, *listeners):
@@ -418,7 +433,8 @@ class TransformerLM:
         return jnp.where(keep, x / (1.0 - rate), 0.0).astype(x.dtype)
 
     def _block(self, bp, x, rng=None):
-        return _block_apply(self.conf, bp, x, drop=self._drop, rng=rng)
+        return _block_apply(self.conf, bp, x, drop=self._drop, rng=rng,
+                            plan=self._shard_plan)
 
     def _logits(self, params, tokens, rng=None):
         c = self.conf
@@ -456,7 +472,7 @@ class TransformerLM:
         # shard(): level >= 2 reduce-scatters grads before the adamw
         # math, level 3 gathers the 1/N param shards just-in-time for
         # the forward; None (unsharded model) traces the plain step
-        plan = getattr(self, "_shard_plan", None)
+        plan = self._shard_plan
 
         def step(params, opt, it, rng, tokens, targets, mask):
             rng, sub = jax.random.split(rng)

@@ -6,10 +6,12 @@ implementation at construction (``ConvolutionLayer.java:69-76`` does
 the helper declines (``if helper != null && dtype != HALF`` —
 ``ConvolutionLayer.java:158,265,309``). Here the registry maps layer class
 names to helper objects; a helper's ``supports(layer, **ctx)`` gates each
-call and any helper exception falls back to the layer's built-in JAX path —
-the same graceful-degradation contract.
+call, and declining there is the whole fallback contract: the layer then
+takes its built-in JAX path. A helper that accepted a call and then raises
+is a defect and the exception propagates — on the chip a swallowed kernel
+failure would silently become the slower route.
 
-Shipped tenants (all user-facing layers exercise register/supports/fallback):
+Shipped tenants (all user-facing layers exercise register/supports/decline):
 - ``AcceleratedLSTMHelper`` — the SURVEY §2.8 accelerated LSTM (the role a
   later ``CudnnLSTMHelper`` plays): the same recurrence compiled with an
   unrolled ``lax.scan`` body, amortizing XLA while-loop per-step overhead.

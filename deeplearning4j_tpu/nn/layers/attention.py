@@ -87,17 +87,15 @@ class SelfAttentionLayer(BaseLayer):
 
     def _attend(self, q, k, v, mask):
         """Single-chip attention with the accelerated-helper seam: probe the
-        registry, gate per call, fall back to the built-in JAX path on
-        decline or error (ConvolutionLayer.java:158's helper pattern)."""
+        registry, gate per call, take the built-in JAX path when the helper
+        declines (ConvolutionLayer.java:158's helper pattern). A helper
+        that accepted and then raises propagates."""
         from deeplearning4j_tpu.nn import helpers
         from deeplearning4j_tpu.parallel import sequence_parallel as sp
         helper = helpers.get_helper(self)
         if helper is not None and helper.supports(self, mask=mask):
-            try:
-                return helper.attention(q, k, v, causal=self.causal,
-                                        block_size=self.block_size)
-            except Exception:  # graftlint: disable=G005 -- helper seam contract: fall back to the built-in path
-                pass  # helper declined at runtime — built-in path below
+            return helper.attention(q, k, v, causal=self.causal,
+                                    block_size=self.block_size)
         if self.block_size is not None:
             return sp.blockwise_attention(q, k, v, causal=self.causal,
                                           block_size=self.block_size, mask=mask)

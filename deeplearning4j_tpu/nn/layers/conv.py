@@ -92,14 +92,11 @@ class ConvolutionLayer(BaseLayer):
     def pre_output(self, params, x):
         # accelerated-helper probe (the CudnnConvolutionHelper seam,
         # ConvolutionLayer.java:69-76,158): helper algorithm when supported,
-        # built-in direct conv otherwise / on helper failure
+        # built-in direct conv when it declines
         from deeplearning4j_tpu.nn import helpers as _helpers
         helper = _helpers.get_helper(self)
         if helper is not None and helper.supports(self):
-            try:
-                return helper.pre_output(self, params, x)
-            except Exception:  # graftlint: disable=G005 -- helper seam contract: any helper failure falls back to the built-in path
-                pass
+            return helper.pre_output(self, params, x)
         return self._pre_output_builtin(params, x)
 
     def _pre_output_builtin(self, params, x):

@@ -106,7 +106,7 @@ class LSTM(BaseLayer):
         # trace-time knob): the fused Pallas cell — then the accelerated-
         # helper probe (ConvolutionLayer.java:69-76 role; SURVEY §2.8
         # accelerated LSTM): use the registered helper when it claims
-        # support, fall back to the built-in scan on any helper failure
+        # support, the built-in scan when it declines
         from deeplearning4j_tpu.config import env_str
         if env_str("DL4J_TPU_LSTM_KERNEL") == "pallas":
             from deeplearning4j_tpu.ops import pallas_kernels
@@ -117,10 +117,7 @@ class LSTM(BaseLayer):
         helper = _helpers.get_helper(self)
         if helper is not None and helper.supports(self, mask=mask,
                                                   seq_len=x.shape[1]):
-            try:
-                return helper.scan(self, params, x, h0, c0, mask, reverse)
-            except Exception:  # graftlint: disable=G005 -- helper seam contract: fall back to the built-in path
-                pass   # graceful per-call fallback to the built-in path
+            return helper.scan(self, params, x, h0, c0, mask, reverse)
         return self._scan_builtin(params, x, h0, c0, mask, reverse)
 
     def _scan_pallas(self, params, x, h0, c0, mask, reverse=False):

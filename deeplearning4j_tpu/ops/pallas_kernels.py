@@ -14,9 +14,10 @@ identical lax.scan implementation
 (``parallel/sequence_parallel.blockwise_attention``) via the same
 custom_vjp seam (the previous default, kept as an escape hatch).
 
-On non-TPU platforms the kernels run in interpreter mode if forced
-(tests set ``DL4J_TPU_PALLAS_INTERPRET=1``); otherwise callers fall back to
-the pure-JAX path through the helper seam (``nn/helpers.py``).
+Off the TPU the kernels run only in interpreter mode, a test setting
+(``DL4J_TPU_PALLAS_INTERPRET=1``); without it callers decline up front
+(``pallas_supported``) and take the pure-JAX path. On a TPU the setting is
+an error, so an interpreted kernel can never stand in for a compiled one.
 """
 
 from __future__ import annotations
@@ -31,30 +32,36 @@ from deeplearning4j_tpu.config import env_flag, env_str
 
 NEG_INF = -1e30
 
+# Per-query-row scalars (running max/sum, logsumexp, delta) cross the
+# kernel boundary lane-replicated as [..., rows, _LANES]: Mosaic wants the
+# last two dims of every block divisible by (8, 128) or equal to the
+# array's, which a [1, block_q] block of an [n, T] array is not.
+_LANES = 128
+
 
 def _interpret_mode():
-    return env_flag("DL4J_TPU_PALLAS_INTERPRET")
+    interpret = env_flag("DL4J_TPU_PALLAS_INTERPRET")
+    if interpret and jax.default_backend() == "tpu":
+        raise RuntimeError(
+            "DL4J_TPU_PALLAS_INTERPRET is set on a TPU: interpreter mode is "
+            "a CPU test setting and would replace the compiled kernels; "
+            "unset it")
+    return interpret
 
 
 def pallas_supported():
-    """True when the pallas path can run: on TPU, or interpreter forced."""
-    if _interpret_mode():
-        return True
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
+    """True when the pallas path can run: on TPU, or interpreter forced.
+    A backend that fails to initialise raises here, as it would anywhere."""
+    return _interpret_mode() or jax.default_backend() == "tpu"
 
 
-def _causal_mask(s, qi, kb, block_q, block_k, q_axis, window=None):
-    """Mask entries with q_pos < k_pos (and, with ``window``, entries more
-    than window-1 positions in the past) to NEG_INF. ``q_axis`` is the axis
-    of ``s`` that walks query positions (0 for [bq, bk] scores, 1 for the
-    transposed [bk, bq] scores of the dK/dV kernel)."""
+def _causal_mask(s, qi, kb, block_q, block_k, window=None):
+    """Mask entries of the [bq, bk] scores with q_pos < k_pos (and, with
+    ``window``, entries more than window-1 positions in the past) to
+    NEG_INF."""
     shape = s.shape
-    q_pos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, shape, q_axis)
-    k_pos = kb * block_k + jax.lax.broadcasted_iota(jnp.int32, shape,
-                                                    1 - q_axis)
+    q_pos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    k_pos = kb * block_k + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
     keep = q_pos >= k_pos
     if window is not None:
         keep &= q_pos - k_pos < window
@@ -78,8 +85,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
     persist across it — only one K/V block is VMEM-resident at a time, which
     is what keeps T unbounded (the full-K/V variant OOMs VMEM at T≈8k).
 
-    m/l are stored lane-replicated as [block_q, 128] (TPU tiling wants the
-    last dim ≥ one lane tile)."""
+    m/l are stored lane-replicated as [block_q, _LANES]."""
     from jax.experimental import pallas as pl
 
     qi = pl.program_id(1)
@@ -100,8 +106,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
             q, k_blk, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)    # [block_q, block_k]
         if causal:
-            s = _causal_mask(s, qi, kb, block_q, block_k, q_axis=0,
-                             window=window)
+            s = _causal_mask(s, qi, kb, block_q, block_k, window=window)
         m_prev = m_scr[...]                        # [block_q, 128], lanes equal
         l_prev = l_scr[...]
         m_cur = s.max(axis=-1, keepdims=True)      # [block_q, 1]
@@ -129,10 +134,11 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
     def _finalize():
         o_ref[0] = (acc_scr[...]
                     / jnp.maximum(l_scr[...][:, :1], 1e-30)).astype(o_ref.dtype)
-        m_fin = m_scr[...][:, 0]                   # lanes equal; take one
-        l_fin = l_scr[...][:, 0]
-        # logsumexp residual for the backward's P recomputation. A fully
-        # masked row (l == 0; only padded rows can hit this) gets +LARGE so
+        m_fin = m_scr[...]                         # [block_q, 128]
+        l_fin = l_scr[...]
+        # logsumexp residual for the backward's P recomputation, written
+        # lane-replicated like m/l (see _LANES). A fully masked row
+        # (l == 0; only padded rows can hit this) gets +LARGE so
         # exp(s - lse) underflows to an exact 0 instead of NaN.
         lse_ref[0] = jnp.where(l_fin > 0.0,
                                m_fin + jnp.log(jnp.maximum(l_fin, 1e-30)),
@@ -157,7 +163,7 @@ def _flash_forward(q, k, v, *, causal, block_q, block_k, window=None,
     return pl.pallas_call(
         kernel,
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
-                   jax.ShapeDtypeStruct((n, t), jnp.float32)],   # lse
+                   jax.ShapeDtypeStruct((n, t, _LANES), jnp.float32)],  # lse
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0),
@@ -170,12 +176,12 @@ def _flash_forward(q, k, v, *, causal, block_q, block_k, window=None,
         out_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_q), lambda b, i, j: (b, i),
+            pl.BlockSpec((1, block_q, _LANES), lambda b, i, j: (b, i, 0),
                          memory_space=pltpu.VMEM),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, 128), jnp.float32),   # running max
-            pltpu.VMEM((block_q, 128), jnp.float32),   # running sum
+            pltpu.VMEM((block_q, _LANES), jnp.float32),   # running max
+            pltpu.VMEM((block_q, _LANES), jnp.float32),   # running sum
             pltpu.VMEM((block_q, d), jnp.float32),     # unnormalized out
         ],
         interpret=_interpret_mode(),
@@ -203,19 +209,18 @@ def _flash_dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, dq_ref,
         k_blk = k_ref[0]                           # [bk, d]
         v_blk = v_ref[0]
         g = g_ref[0].astype(jnp.float32)           # [bq, d] dO
-        lse = lse_ref[0]                           # [bq]
-        delta = delta_ref[0]                       # [bq] rowsum(dO*O)
+        lse = lse_ref[0][:, :1]                    # [bq, 1]
+        delta = delta_ref[0][:, :1]                # [bq, 1] rowsum(dO*O)
         s = jax.lax.dot_general(
             q, k_blk, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
         if causal:
-            s = _causal_mask(s, qi, kb, block_q, block_k, q_axis=0,
-                             window=window)
-        p = jnp.exp(s - lse[:, None])              # [bq, bk]
+            s = _causal_mask(s, qi, kb, block_q, block_k, window=window)
+        p = jnp.exp(s - lse)                       # [bq, bk]
         dp = jax.lax.dot_general(
             g, v_blk, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)    # [bq, bk]
-        ds = p * (dp - delta[:, None]) * scale
+        ds = p * (dp - delta) * scale
         dq_scr[...] += jax.lax.dot_general(
             ds, k_blk, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -253,26 +258,26 @@ def _flash_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
         k_blk = k_ref[0]                           # [bk, d]
         v_blk = v_ref[0]
         g = g_ref[0].astype(jnp.float32)           # [bq, d]
-        lse = lse_ref[0]                           # [bq]
-        delta = delta_ref[0]
-        # transposed scores: [bk, bq]
-        st = jax.lax.dot_general(
-            k_blk, q, (((1,), (1,)), ((), ())),
+        lse = lse_ref[0][:, :1]                    # [bq, 1]
+        delta = delta_ref[0][:, :1]
+        s = jax.lax.dot_general(
+            q, k_blk, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
         if causal:
-            st = _causal_mask(st, qi, kb, block_q, block_k, q_axis=1,
-                              window=window)
-        pt = jnp.exp(st - lse[None, :])            # [bk, bq]
+            s = _causal_mask(s, qi, kb, block_q, block_k, window=window)
+        p = jnp.exp(s - lse)                       # [bq, bk]
+        # Pᵀ dO and dSᵀ Q contract the query axis (dim 0 of both operands):
+        # the per-row lse/delta broadcast along lanes as in the dQ kernel
         dv_scr[...] += jax.lax.dot_general(
-            pt, g, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dpt = jax.lax.dot_general(
-            v_blk, g, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)    # [bk, bq]
-        dst = pt * (dpt - delta[None, :]) * scale
+            p, g, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)    # [bk, d]
+        dp = jax.lax.dot_general(
+            g, v_blk, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)    # [bq, bk]
+        ds = p * (dp - delta) * scale
         dk_scr[...] += jax.lax.dot_general(
-            dst, q, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+            ds, q, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)    # [bk, d]
 
     if causal:
         # a Q block with no in-mask entry for this K block contributes
@@ -302,7 +307,8 @@ def _flash_fwd(q, k, v, causal, block_q, block_k, window=None, kv_group=1):
     out, lse = _flash_forward(q, k, v, causal=causal, block_q=block_q,
                               block_k=block_k, window=window,
                               kv_group=kv_group)
-    return out, (q, k, v, out, lse)
+    # keep one lane: the saved residual is [n, T], not 128x that
+    return out, (q, k, v, out, lse[..., 0])
 
 
 def _flash_bwd(causal, block_q, block_k, window, kv_group, residuals, g):
@@ -338,6 +344,8 @@ def _flash_bwd(causal, block_q, block_k, window, kv_group, residuals, g):
     # delta_i = Σ_d dO ⊙ O — a cheap fused elementwise+reduce; XLA keeps it
     # out of the kernels' VMEM budget
     delta = (g.astype(jnp.float32) * out.astype(jnp.float32)).sum(-1)
+    lse, delta = (jnp.broadcast_to(x[..., None], (n, t, _LANES))
+                  for x in (lse, delta))
 
     gk = kv_group
     qkvg_specs = [
@@ -349,9 +357,9 @@ def _flash_bwd(causal, block_q, block_k, window, kv_group, residuals, g):
                      memory_space=pltpu.VMEM),
         pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0),
                      memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, block_q), lambda b, i, j: (b, i),
+        pl.BlockSpec((1, block_q, _LANES), lambda b, i, j: (b, i, 0),
                      memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, block_q), lambda b, i, j: (b, i),
+        pl.BlockSpec((1, block_q, _LANES), lambda b, i, j: (b, i, 0),
                      memory_space=pltpu.VMEM),
     ]
     dq = pl.pallas_call(
@@ -380,9 +388,9 @@ def _flash_bwd(causal, block_q, block_k, window, kv_group, residuals, g):
                      memory_space=pltpu.VMEM),
         pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0),
                      memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, block_q), lambda b, j, i: (b, i),
+        pl.BlockSpec((1, block_q, _LANES), lambda b, j, i: (b, i, 0),
                      memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, block_q), lambda b, j, i: (b, i),
+        pl.BlockSpec((1, block_q, _LANES), lambda b, j, i: (b, i, 0),
                      memory_space=pltpu.VMEM),
     ]
     dk, dv = pl.pallas_call(

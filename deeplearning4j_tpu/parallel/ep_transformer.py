@@ -29,6 +29,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from deeplearning4j_tpu.models.moe_transformer import (MoETransformerConfig,
@@ -38,7 +39,6 @@ from deeplearning4j_tpu.models.transformer import (_adamw_apply,
                                                    _forward_tokens, _lr_at)
 from deeplearning4j_tpu.parallel.expert_parallel import (
     switch_dispatch_apply, topk_dispatch_apply)
-from deeplearning4j_tpu.utils import shard_map
 
 __all__ = ["EPTransformerLM"]
 

@@ -29,9 +29,9 @@ import math
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from deeplearning4j_tpu.parallel.sharding_core import pad_to_multiple
-from deeplearning4j_tpu.utils import shard_map
 
 __all__ = ["FSDPMLP", "FSDPTrainer"]
 

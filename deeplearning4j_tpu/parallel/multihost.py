@@ -37,17 +37,8 @@ def initialize(coordinator_address: str, num_processes: int, process_id: int,
     if _INITIALIZED:
         return
     if local_devices is not None:
-        try:
-            jax.config.update("jax_num_cpu_devices", int(local_devices))
-        except AttributeError:
-            # older JAX: no such knob — callers set
-            # XLA_FLAGS=--xla_force_host_platform_device_count=N before the
-            # first jax import instead (multihost_worker.py does)
-            pass
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:  # graftlint: disable=G005 -- optional jax config knob; absent on older jax
-        pass   # config absent (older jax) or non-CPU-only build
+        jax.config.update("jax_num_cpu_devices", int(local_devices))
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
     jax.distributed.initialize(coordinator_address,
                                num_processes=num_processes,
                                process_id=process_id)

@@ -34,8 +34,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from deeplearning4j_tpu.utils import shard_map
 
 __all__ = ["pp_mesh", "PipelineParallelNet"]
 

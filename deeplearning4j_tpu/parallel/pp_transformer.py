@@ -30,6 +30,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from deeplearning4j_tpu.models.transformer import (TransformerConfig,
@@ -37,7 +38,6 @@ from deeplearning4j_tpu.models.transformer import (TransformerConfig,
                                                    _adamw_apply,
                                                    _block_apply, _layer_norm,
                                                    _lr_at)
-from deeplearning4j_tpu.utils import shard_map
 
 __all__ = ["PPTransformerLM"]
 

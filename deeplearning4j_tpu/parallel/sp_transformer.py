@@ -28,6 +28,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from deeplearning4j_tpu.models.transformer import (TransformerConfig,
@@ -37,7 +38,6 @@ from deeplearning4j_tpu.models.transformer import (TransformerConfig,
                                                    _lr_at)
 from deeplearning4j_tpu.parallel.sequence_parallel import ring_attention
 from deeplearning4j_tpu.parallel.sharding_core import ShardingCore
-from deeplearning4j_tpu.utils import shard_map
 
 __all__ = ["SPTransformerLM"]
 

@@ -29,6 +29,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from deeplearning4j_tpu.models.transformer import (TransformerConfig,
@@ -38,7 +39,6 @@ from deeplearning4j_tpu.models.transformer import (TransformerConfig,
 from deeplearning4j_tpu.parallel.sequence_parallel import dense_attention
 from deeplearning4j_tpu.parallel.tensor_parallel import (
     _allreduce_identity_bwd, _identity_allreduce_bwd)
-from deeplearning4j_tpu.utils import shard_map
 
 __all__ = ["TPTransformerLM"]
 

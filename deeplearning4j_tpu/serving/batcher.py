@@ -105,9 +105,10 @@ class InferenceServer(ServingFrontEnd):
     def warm_start(self, row_shapes, dtype=None):
         """Pre-compile the blessed output signatures for every
         (bucket, row shape) pair by dispatching zeros through
-        ``model.output`` — with ``DL4J_TPU_COMPILE_CACHE_DIR`` set, a
-        server RESTART replays these compiles from the persistent XLA
-        cache and cold-start is ~free (docs/SERVING.md). ``dtype``
+        ``model.output`` — a server RESTART replays these compiles from
+        the persistent XLA cache (``JAX_COMPILATION_CACHE_DIR``, else
+        ``<checkout>/.jax_cache``) and cold-start is ~free
+        (docs/SERVING.md). ``dtype``
         defaults per model family — int32 token rows for the LM family
         (marked by the blessed ``_gen_signature`` builder), float32
         features otherwise — so the warmed signatures are the ones real

@@ -388,7 +388,7 @@ class ContinuousLM(ServingFrontEnd):
         no-op dispatch (all rows inactive / zero valid tokens, so the
         pool stays logically pristine) because ``jax.jit`` compiles on
         first CALL, not construction. The first request then pays no
-        compile, and a RESTART under ``DL4J_TPU_COMPILE_CACHE_DIR``
+        compile, and a RESTART over the persistent compilation cache
         compiles nothing. The slot pool is scheduler-owned once the
         loop thread runs, so warming a live server is refused instead
         of racing it."""
@@ -559,7 +559,7 @@ class ContinuousLM(ServingFrontEnd):
         the autotuner cache (and on a restart, adopt the persisted
         ladders when nothing pins them explicitly): a restarted server
         re-arms the SAME compiled-program inventory, so a warm boot over
-        ``DL4J_TPU_COMPILE_CACHE_DIR`` compiles nothing. RECORDING is
+        the persistent compilation cache compiles nothing. RECORDING is
         gated on the same ``DL4J_TPU_SERVE_AUTOTUNE`` arm flag as the
         slot probe — an unarmed server must never write the shared tune
         cache (explicit ctor ladders are per-server choices until the

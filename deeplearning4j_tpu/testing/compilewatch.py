@@ -163,8 +163,8 @@ def install():
 
 
 def uninstall():
-    """Stop recording. The listener stays registered (old JAX has no
-    unregister) but drops every event while inactive."""
+    """Stop recording. The listener stays registered (one per process)
+    but drops every event while inactive."""
     global _active
     with _state:
         _active = False

@@ -1,22 +1,1 @@
-"""Shared utilities + small cross-version compatibility shims."""
-
-import jax as _jax
-
-try:
-    # newer JAX re-exports the x64 context at top level
-    enable_x64 = _jax.enable_x64
-except AttributeError:
-    # older JAX (≤0.4.x): experimental home of the same context manager
-    from jax.experimental import enable_x64  # noqa: F401
-
-try:
-    shard_map = _jax.shard_map
-except AttributeError:
-    from jax.experimental.shard_map import shard_map as _shard_map_exp
-
-    def shard_map(f, mesh, in_specs, out_specs, **kw):
-        # old JAX spells the replication check ``check_rep``
-        if "check_vma" in kw:
-            kw["check_rep"] = kw.pop("check_vma")
-        return _shard_map_exp(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, **kw)
+"""Shared utilities."""

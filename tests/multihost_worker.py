@@ -16,8 +16,6 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
 
 import jax  # noqa: E402
 
-jax.config.update("jax_platforms", "cpu")
-
 
 def main():
     pid = int(sys.argv[1])

@@ -1,0 +1,213 @@
+"""What only the chip's compiler can say, asked without the chip.
+
+The Pallas kernels pass every interpret-mode test (tests/test_pallas.py) and
+can still be refused by Mosaic — as the flash kernels were, at every shape,
+for a block layout the interpreter never checks. The TPU compiler is
+installed here and compiles for a chip that is described, not attached
+(``jax.experimental.topologies``), so the kernels of the main path are
+compiled for the v5e at their real widths in tier-1. A compile that passes is
+not a chip run; ``chip_smoke.py`` is.
+
+Named to sort early: tier-1 is cut by its clock and late files never run.
+"""
+
+import json
+import os
+import subprocess
+import sys
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from deeplearning4j_tpu.ops import pallas_kernels as pk
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+SMOKE = os.path.join(REPO, "chip_smoke.py")
+
+
+@pytest.fixture(scope="module")
+def v5e_devices():
+    """The four chips of a described v5e 2x2 host. The persistent
+    compilation cache is off around these compiles: an entry written for a
+    described chip cannot be read back without one, and the next run would
+    warn on every test."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 -- no TPU compiler in this install
+        pytest.skip(f"the v5e topology cannot be described here: {e!r}")
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield topo.devices
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def v5e(v5e_devices):
+    """One described chip, as the sharding of a ShapeDtypeStruct."""
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(v5e_devices[0])
+
+
+def _compile(fn, *shapes):
+    """AOT-compile ``fn`` for the described chip; returns the number of
+    Mosaic kernels in the compiled program."""
+    compiled = jax.jit(fn).lower(*shapes).compile()
+    assert compiled.memory_analysis() is not None
+    return compiled.as_text().count("tpu_custom_call")
+
+
+# (q shape, kv shape, block): the chip_smoke train_lm shape (GPT-2 small,
+# batch 8 x 1024, d64) and one GQA 16/4 shape at head dim 128, T 2048
+FLASH_SHAPES = {
+    "train_lm": ((8, 12, 1024, 64), (8, 12, 1024, 64), 512),
+    "gqa_d128": ((2, 16, 2048, 128), (2, 4, 2048, 128), 512),
+}
+
+
+@pytest.mark.parametrize("shape", sorted(FLASH_SHAPES))
+@pytest.mark.parametrize("wrt", ["fwd", "dq", "dkv"])
+def test_flash_kernels_compile_for_v5e(v5e, shape, wrt):
+    q_shape, kv_shape, block = FLASH_SHAPES[shape]
+    q = jax.ShapeDtypeStruct(q_shape, jnp.bfloat16, sharding=v5e)
+    kv = jax.ShapeDtypeStruct(kv_shape, jnp.bfloat16, sharding=v5e)
+
+    def attend(q, k, v):
+        return pk.flash_attention(q, k, v, causal=True, block_q=block,
+                                  block_k=block)
+
+    def loss(q, k, v):
+        return attend(q, k, v).astype(jnp.float32).sum()
+
+    # grad wrt q keeps the dQ kernel and drops dK/dV (dead code), and the
+    # other way round; both keep the forward for its residuals
+    fn, kernels = {"fwd": (attend, 1),
+                   "dq": (jax.grad(loss, argnums=0), 2),
+                   "dkv": (jax.grad(loss, argnums=(1, 2)), 2)}[wrt]
+    assert _compile(fn, q, kv, kv) == kernels
+
+
+@pytest.mark.parametrize("peephole", [True, False])
+@pytest.mark.parametrize("wrt", ["fwd", "bwd"])
+def test_lstm_cell_compiles_for_v5e(v5e, wrt, peephole):
+    """The char-RNN bench width (bench.py: GravesLSTM, batch 32, hidden
+    200 — not a multiple of the 128-lane tile)."""
+    b, h = 32, 200
+    sds = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32, sharding=v5e)
+    args = [sds(b, 4 * h), sds(b, h), sds(b, h), sds(h, 4 * h)]
+    if peephole:
+        args.append(sds(3, h))
+
+    def loss(*a):
+        hh, cc = pk.lstm_cell(*a)
+        return hh.sum() + cc.sum()
+
+    fn = pk.lstm_cell if wrt == "fwd" else jax.grad(
+        loss, argnums=tuple(range(len(args))))
+    assert _compile(fn, *args) == 1
+
+
+def test_sharded_lm_step_compiles_for_2x2_mesh(v5e_devices, monkeypatch):
+    """``TransformerLM.shard(mesh)`` with the kernel route: GSPMD refuses
+    to partition a Mosaic kernel, so the step compiles for four chips only
+    because the route wraps the kernel in a shard_map over the batch axis.
+    Shapes only — nothing can be placed on a described device — so the
+    plan is attached the way ``shard()`` does, minus the device_put."""
+    from jax.sharding import PartitionSpec as P
+
+    from deeplearning4j_tpu.models.transformer import (TransformerConfig,
+                                                       TransformerLM)
+    from deeplearning4j_tpu.parallel.sharding_core import (ShardingCore,
+                                                           build_mesh)
+
+    # default_backend() is the CPU here: force the route the TPU takes
+    monkeypatch.setenv("DL4J_TPU_LM_ATTN", "pallas")
+    mesh = build_mesh(devices=v5e_devices)
+    lm = TransformerLM(TransformerConfig(
+        vocab_size=1024, max_len=256, d_model=256, n_heads=4, n_layers=1,
+        d_ff=512, compute_dtype="bfloat16", block_size=128))
+    params, opt = jax.eval_shape(lambda: (lm.init().params, lm.opt_state))
+    lm.params = lm.opt_state = None
+    core = lm._shard_plan = ShardingCore(mesh, level=3)
+
+    def sds(a, spec):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                    sharding=core.sharding(spec))
+
+    tokens = jax.ShapeDtypeStruct((8, 256), jnp.int32,
+                                  sharding=core.data_sharding())
+    compiled = lm._build_step().lower(
+        jax.tree.map(lambda a: sds(a, core.param_spec(a)), params),
+        jax.tree.map(lambda a: sds(a, core.updater_spec(a)), opt),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=core.sharding(P())),
+        jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=core.sharding(P())),
+        tokens, tokens, None).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 3
+    assert "all-gather" in text   # level 3: params gathered just in time
+
+
+def _smoke(*args, env=None):
+    return subprocess.run([sys.executable, SMOKE, *args], cwd=REPO,
+                          env=env, capture_output=True, text=True,
+                          timeout=600)
+
+
+def test_chip_smoke_rehearsal_passes_on_cpu():
+    """The two LM phases at tiny size on the CPU (the ResNet rehearsal is
+    `make smoke-rehearse`): every check passes and the last line names the
+    CPU — it can never be read as a chip result."""
+    r = _smoke("--rehearse", "--phases", "train_lm,serve_lm")
+    assert r.returncode == 0, r.stdout[-3000:] + r.stderr[-3000:]
+    lines = [json.loads(l) for l in r.stdout.splitlines()
+             if l.startswith("{")]
+    assert [l["phase"] for l in lines[:-1]] == ["setup", "train_lm",
+                                                "serve_lm"]
+    assert all(l["ok"] for l in lines)
+    assert lines[-1] == {"ok": True, "device": {
+        "platform": "cpu", "kind": "cpu", "count": 1}}
+
+
+def test_chip_smoke_refuses_to_run_without_a_tpu():
+    """No --rehearse, no TPU: non-zero at once, nothing on stdout — a CPU
+    run can never stand in for the chip, with or without JAX_PLATFORMS."""
+    for platforms in ("cpu", None):
+        env = dict(os.environ)
+        env.pop("JAX_PLATFORMS", None)
+        if platforms:
+            env["JAX_PLATFORMS"] = platforms
+        r = _smoke(env=env)
+        assert r.returncode != 0
+        assert r.stdout.strip() == ""
+        assert "no TPU" in r.stderr
+
+
+# ---------------------------------------------------------------------------
+# no path that quietly runs on the CPU, or against a guessed peak
+# ---------------------------------------------------------------------------
+
+def test_bench_fails_without_a_tpu_unless_cpu_was_asked_for():
+    """bench.py with no TPU and no JAX_PLATFORMS=cpu: the config fails, no
+    CPU number is printed, and the exit code says so."""
+    env = dict(os.environ)
+    env.pop("JAX_PLATFORMS", None)
+    r = subprocess.run(
+        [sys.executable, os.path.join(REPO, "bench.py"), "lenet_step"],
+        cwd=REPO, env=env, capture_output=True, text=True, timeout=300)
+    assert r.returncode != 0
+    line = json.loads(r.stdout.strip().splitlines()[-1])
+    assert line["unit"] == "error" and "no TPU" in line["error"]
+
+
+def test_unknown_device_kind_has_no_peak():
+    from deeplearning4j_tpu import hw
+    assert hw.peak_bf16_flops("TPU v5 lite") == 197e12
+    with pytest.raises(KeyError, match="no peak FLOP/s recorded"):
+        hw.peak_bf16_flops("TPU v9000")
+    with pytest.raises(KeyError, match="'cpu'"):
+        hw.peak_bf16_flops()   # this test's own backend

@@ -177,7 +177,8 @@ class TestLiveTree:
         os.chdir(REPO)
         try:
             return lint_paths(
-                ["deeplearning4j_tpu", "tools", "bench.py", "examples"],
+                ["deeplearning4j_tpu", "tools", "bench.py", "chip_smoke.py",
+                 "examples"],
                 cache_dir=".graftlint_cache")
         finally:
             os.chdir(cwd)
@@ -188,6 +189,7 @@ class TestLiveTree:
 
     def test_det_report_covers_the_model_zoo(self, live):
         r = det_report([PKG, TOOLS, os.path.join(REPO, "bench.py"),
+                        os.path.join(REPO, "chip_smoke.py"),
                         os.path.join(REPO, "examples")])
         assert r["version"] == 7
         for name in ("MultiLayerNetwork", "ComputationGraph",

@@ -193,12 +193,13 @@ class TestProbeAndDecide:
         assert small[0][3] == 2
 
 
-class TestCompileCacheKnob:
-    def test_compile_cache_dir_applies_and_populates(self, tmp_path):
-        """ISSUE 9 satellite: DL4J_TPU_COMPILE_CACHE_DIR points jax at a
-        persistent XLA compilation cache at package import (a restarted
-        run skips cold-start compiles). Subprocess: the knob is consulted
-        at import time, which already happened in this process."""
+class TestCompileCachePlacement:
+    def test_compile_cache_dir_placed_from_outside(self, tmp_path):
+        """The persistent XLA compilation cache is placed from outside:
+        with JAX_COMPILATION_CACHE_DIR set JAX reads it itself and the
+        package sets no directory; unset, the package falls back to the
+        fixed ``<checkout>/.jax_cache``. Subprocesses: the rule runs at
+        import time, which already happened in this process."""
         import subprocess
         import sys
 
@@ -206,18 +207,29 @@ class TestCompileCacheKnob:
             "import os, sys\n"
             "os.environ['JAX_PLATFORMS'] = 'cpu'\n"
             "import deeplearning4j_tpu, jax, jax.numpy as jnp\n"
-            "assert jax.config.jax_compilation_cache_dir == "
-            "os.environ['DL4J_TPU_COMPILE_CACHE_DIR']\n"
-            "jax.jit(lambda x: x * 2 + 1)(jnp.ones((32, 32)))"
+            "d = jax.config.jax_compilation_cache_dir\n"
+            "print(d)\n"
+            "if os.environ.get('JAX_COMPILATION_CACHE_DIR'):\n"
+            "    jax.jit(lambda x: x * 2 + 1)(jnp.ones((32, 32)))"
             ".block_until_ready()\n"
-            "print(len(os.listdir(os.environ['DL4J_TPU_COMPILE_CACHE_DIR'])))"
+            "    print(len(os.listdir(d)))"
         )
-        env = dict(os.environ)
-        env["DL4J_TPU_COMPILE_CACHE_DIR"] = str(tmp_path)
-        out = subprocess.run([sys.executable, "-c", code], env=env,
-                             capture_output=True, text=True, timeout=240)
-        assert out.returncode == 0, out.stderr[-2000:]
-        assert int(out.stdout.strip().splitlines()[-1]) > 0   # cache wrote
+
+        def run(cache_dir):
+            env = dict(os.environ)
+            env.pop("JAX_COMPILATION_CACHE_DIR", None)
+            if cache_dir:
+                env["JAX_COMPILATION_CACHE_DIR"] = cache_dir
+            out = subprocess.run([sys.executable, "-c", code], env=env,
+                                 capture_output=True, text=True, timeout=240)
+            assert out.returncode == 0, out.stderr[-2000:]
+            return out.stdout.strip().splitlines()
+
+        placed, n_entries = run(str(tmp_path))[-2:]
+        assert placed == str(tmp_path)
+        assert int(n_entries) > 0   # cache wrote
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert run(None)[-1] == os.path.join(repo, ".jax_cache")
 
 
 class TestUnfusedBucketing:

@@ -913,6 +913,7 @@ def test_committed_baseline_matches_the_tree():
     r = lint_live([os.path.join(REPO, "deeplearning4j_tpu"),
                    os.path.join(REPO, "tools"),
                    os.path.join(REPO, "bench.py"),
+                   os.path.join(REPO, "chip_smoke.py"),
                    os.path.join(REPO, "examples")])
     regressions, _ = ratchet_compare(counts_by_rule(r), baseline)
     assert regressions == [], regressions
@@ -967,7 +968,7 @@ def test_cli_exit_codes_and_json(tmp_path):
 # ---------------------------------------------------------------------------
 def test_package_gate_zero_unsuppressed_findings():
     """The whole-package gate (same scope as `make lint`): zero findings
-    across deeplearning4j_tpu + tools + bench.py + examples,
+    across deeplearning4j_tpu + tools + bench.py + chip_smoke.py + examples,
     interprocedural graph AND the shared dataflow fixpoint included,
     within the tier-1 budget on the 2-core box. One lint pass builds the
     parsed-AST/symbol-table/dataflow caches once and shares them across
@@ -976,6 +977,7 @@ def test_package_gate_zero_unsuppressed_findings():
     r = lint_paths([os.path.join(REPO, "deeplearning4j_tpu"),
                     os.path.join(REPO, "tools"),
                     os.path.join(REPO, "bench.py"),
+                    os.path.join(REPO, "chip_smoke.py"),
                     os.path.join(REPO, "examples")])
     elapsed = time.monotonic() - t0
     assert r.errors == []
@@ -2111,7 +2113,7 @@ def test_g018_flowed_axis_rank_and_arity_checks():
             return params, x
 
         def wrap(mesh):
-            from deeplearning4j_tpu.utils import shard_map
+            from jax import shard_map
             return shard_map(step, mesh=mesh,
                              in_specs=(P(), P("data")),     # 2 != 3 args
                              out_specs=(P(), P()))
@@ -2144,7 +2146,7 @@ def test_g018_correct_specs_through_helpers_stay_quiet():
             return params, x
 
         def wrap(mesh):
-            from deeplearning4j_tpu.utils import shard_map
+            from jax import shard_map
             return shard_map(step, mesh=mesh,
                              in_specs=(P(), P("data"), P("data")),
                              out_specs=(P(), P()))
@@ -2546,7 +2548,7 @@ def test_g018_arity_accepts_defaulted_params():
             return params, x
 
         def wrap(mesh):
-            from deeplearning4j_tpu.utils import shard_map
+            from jax import shard_map
             return shard_map(step, mesh=mesh,
                              in_specs=(P(), P("data")),
                              out_specs=(P(), P()))

@@ -4,12 +4,13 @@ helper path and gradient-checks it; ``TestConvolution.java:118`` asserts
 helper-vs-builtin output equality).
 
 Covers the SURVEY §2.8 accelerated LSTM and the conv tenant: register /
-supports / per-call fallback are exercised by user-facing layers.
+supports / decline are exercised by user-facing layers.
 """
 
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from deeplearning4j_tpu import NeuralNetConfiguration
@@ -17,7 +18,8 @@ from deeplearning4j_tpu.models.multi_layer_network import MultiLayerNetwork
 from deeplearning4j_tpu.nn import helpers
 from deeplearning4j_tpu.nn.conf.input_type import InputType
 from deeplearning4j_tpu.nn.layers import (ConvolutionLayer, DenseLayer, LSTM,
-                                          OutputLayer, RnnOutputLayer)
+                                          OutputLayer, RnnOutputLayer,
+                                          SelfAttentionLayer)
 from deeplearning4j_tpu.nn.layers.conv import ConvolutionLayer as ConvCls
 
 
@@ -29,6 +31,56 @@ def conv_layer_and_input(rng):
               "b": jnp.asarray(rng.normal(size=(4,)), jnp.float32)}
     x = jnp.asarray(rng.normal(size=(2, 8, 8, 3)), jnp.float32)
     return layer, params, x
+
+
+class _Broken(helpers.LayerHelper):
+    """Accepts every call, then fails in whichever entry point the layer
+    uses: a defect, which the seam must not turn into the built-in path."""
+
+    def supports(self, layer, **ctx):
+        return True
+
+    def _explode(self, *a, **kw):
+        raise RuntimeError("helper exploded")
+
+    pre_output = attention = scan = _explode
+
+
+def _call_conv():
+    layer = ConvolutionLayer(n_in=3, n_out=4, kernel_size=(3, 3))
+    params = layer.init_params(jax.random.PRNGKey(0))
+    layer.pre_output(params, jnp.zeros((2, 8, 8, 3), jnp.float32))
+
+
+def _call_attention():
+    layer = SelfAttentionLayer(n_in=8, n_out=8, n_heads=2)
+    q = jnp.zeros((2, 2, 4, 4), jnp.float32)
+    layer._attend(q, q, q, None)
+
+
+def _call_lstm():
+    layer = LSTM(n_in=4, n_out=6)
+    params = layer.init_params(jax.random.PRNGKey(0))
+    z = jnp.zeros((2, 6), jnp.float32)
+    layer._scan(params, jnp.zeros((2, 12, 4), jnp.float32), z, z, None)
+
+
+@pytest.mark.parametrize("layer_name,call", [
+    ("ConvolutionLayer", _call_conv),
+    ("SelfAttentionLayer", _call_attention),
+    ("LSTM", _call_lstm),
+])
+def test_failing_helper_propagates(layer_name, call):
+    """``supports()`` declining is the fallback contract; a helper that
+    accepted and then raised reaches the caller (it used to be swallowed,
+    which on the chip hides a kernel the compiler refused)."""
+    old = helpers._REGISTRY.get(layer_name)
+    helpers.register_helper(layer_name, _Broken())
+    try:
+        with pytest.raises(RuntimeError, match="helper exploded"):
+            call()
+    finally:
+        helpers.register_helper(layer_name, old)
 
 
 class TestConvHelperSeam:
@@ -88,27 +140,6 @@ class TestConvHelperSeam:
         assert h.supports(small)
         assert not h.supports(large)      # kernel too big
         assert not h.supports(deep)       # channels too deep for im2col win
-
-    def test_failing_helper_falls_back(self, conv_layer_and_input):
-        """Per-call graceful fallback (ConvolutionLayer.java:158 contract)."""
-        layer, params, x = conv_layer_and_input
-
-        class Broken(helpers.LayerHelper):
-            def supports(self, layer, **ctx):
-                return True
-
-            def pre_output(self, *a, **kw):
-                raise RuntimeError("helper exploded")
-
-        old = helpers._REGISTRY.get("ConvolutionLayer")
-        helpers.register_helper("ConvolutionLayer", Broken())
-        try:
-            out = layer.pre_output(params, x)   # no raise: builtin fallback
-            np.testing.assert_allclose(
-                np.asarray(out),
-                np.asarray(layer._pre_output_builtin(params, x)), atol=1e-5)
-        finally:
-            helpers.register_helper("ConvolutionLayer", old)
 
     def test_forced_helper_gradient_check(self, rng):
         """CuDNNGradientChecks.java:66 pattern: numeric-vs-analytic gradients

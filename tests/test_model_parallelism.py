@@ -13,11 +13,11 @@ holding 1/N of the parameters per device at rest.
 import jax
 import numpy as np
 import pytest
+from jax import shard_map
 
 from deeplearning4j_tpu.parallel.expert_parallel import ExpertParallelMoE, ep_mesh
 from deeplearning4j_tpu.parallel.pipeline_parallel import (
     PipelineParallelNet, pp_mesh)
-from deeplearning4j_tpu.utils import shard_map
 
 
 class TestPipelineParallel:

@@ -6,6 +6,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 import pytest
+from jax import enable_x64
 
 from deeplearning4j_tpu import NeuralNetConfiguration
 from deeplearning4j_tpu.datasets.dataset import ArrayDataSetIterator, DataSet
@@ -13,7 +14,6 @@ from deeplearning4j_tpu.models.multi_layer_network import MultiLayerNetwork
 from deeplearning4j_tpu.nn.layers import (
     AutoEncoder, DenseLayer, OutputLayer, RBM, VariationalAutoencoder,
 )
-from deeplearning4j_tpu.utils import enable_x64
 
 
 def binary_data(n=64, d=12, seed=0):

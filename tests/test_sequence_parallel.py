@@ -7,11 +7,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import shard_map
 
 from deeplearning4j_tpu.parallel.sequence_parallel import (
     blockwise_attention, dense_attention, ring_attention,
     sequence_parallel_attention)
-from deeplearning4j_tpu.utils import shard_map
 
 
 class TestBlockwiseAttention:

@@ -817,12 +817,12 @@ print("HITS", cc.hits, "MISSES", cc.misses)
 class TestWarmStart:
     def test_second_boot_compiles_nothing(self, tmp_path):
         """Serving startup pre-compiles the blessed inference signatures;
-        with DL4J_TPU_COMPILE_CACHE_DIR (PR 9) the SECOND boot serves
+        with a persistent cache (JAX_COMPILATION_CACHE_DIR) the SECOND boot serves
         every compile request from the persistent cache — zero misses
         (backend_compile events still fire on hits on current jax, so
         the cache counter, not CompileCounter, is the oracle)."""
         env = dict(os.environ)
-        env["DL4J_TPU_COMPILE_CACHE_DIR"] = str(tmp_path)
+        env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path)
         env.pop("DL4J_TPU_FAULT_SPEC", None)
 
         def boot():

@@ -8,7 +8,7 @@ makes them a hard number tests and ``bench.py`` can gate on.
 Counts ``/jax/core/compile/backend_compile_duration`` events from
 ``jax.monitoring`` — one per actual XLA ``backend_compile`` (jit cache hits
 emit nothing). The listener is registered once per process and toggled by the
-context manager, because old JAX versions expose no public unregister.
+context manager, so nested counters each see every event.
 
 Usage::
 
@@ -35,6 +35,7 @@ def _listener(event, duration, **kwargs):  # noqa: ARG001 — monitoring API
         with _lock:
             for c in _active:
                 c.count += 1
+                c.seconds += duration
 
 
 def _ensure_registered():
@@ -47,15 +48,18 @@ def _ensure_registered():
 
 
 class CompileCounter:
-    """Context manager counting XLA backend compilations in its body."""
+    """Context manager counting XLA backend compilations in its body
+    (``count``) and the wall seconds they took (``seconds``)."""
 
     def __init__(self):
         self.count = 0
+        self.seconds = 0.0
 
     def __enter__(self):
         _ensure_registered()
         with _lock:
             self.count = 0
+            self.seconds = 0.0
             _active.append(self)
         return self
 
@@ -96,8 +100,8 @@ def _ensure_cache_registered():
 
 
 class CompileCacheCounter:
-    """Counts persistent-XLA-cache (``DL4J_TPU_COMPILE_CACHE_DIR``) hits
-    and misses in its body. ``misses == 0 and hits > 0`` is THE
+    """Counts persistent-XLA-cache (``JAX_COMPILATION_CACHE_DIR``, else
+    ``<repo>/.jax_cache``) hits and misses in its body. ``misses == 0 and hits > 0`` is THE
     "warm restart compiles nothing" assertion for server warm-start:
     current jax versions emit ``backend_compile_duration`` even when the
     executable is served from the persistent cache (the event times the

@@ -16,8 +16,8 @@ import jax.numpy as jnp
 
 import _bootstrap  # noqa: F401  (repo root onto sys.path)
 
-from deeplearning4j_tpu.hw import (TPU_V5E_BF16_PEAK_FLOPS as PEAK,
-                                   TRAIN_FLOPS_MULTIPLIER,
+from deeplearning4j_tpu.hw import (TRAIN_FLOPS_MULTIPLIER,
+                                   peak_bf16_flops,
                                    transformer_fwd_flops_per_token)
 from deeplearning4j_tpu.models.transformer import (TransformerConfig,
                                                    TransformerLM)
@@ -25,6 +25,8 @@ from deeplearning4j_tpu.models.transformer import (TransformerConfig,
 PLATFORM = jax.devices()[0].platform
 if PLATFORM == "cpu":
     print("WARNING: running on CPU — numbers are NOT chip results")
+# MFU only against a recorded peak: off the TPU the column reads nan
+PEAK = peak_bf16_flops() if PLATFORM == "tpu" else float("nan")
 
 D, L, H, FF, V = 512, 8, 8, 2048, 32_768
 
