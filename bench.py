@@ -284,8 +284,7 @@ def bench_fused():
     PERIODIC CHECKPOINTING enabled (checkpoint_every=CKPT_EVERY below):
     the durability layer's acceptance bar is that the numpy-only atomic
     checkpoint path keeps 0 in-fit compiles while committing real
-    checkpoints. The whole A/B also runs with the obs layer FULLY ON
-    (metrics recording + span tracing into a temp DL4J_TPU_TRACE_DIR) —
+    checkpoints. The whole A/B also runs with metrics recording on —
     the observability acceptance bar is that instrumentation adds no
     recompiles or hot-path syncs — and the fused run's metrics summary
     is embedded in the JSON line so a perf regression in a BENCH_r*.json
@@ -331,7 +330,6 @@ def bench_fused():
         probes = obs.metrics.value("fuse.autotune_probes_total")
         best = 0.0
         obs.reset_metrics()               # summary covers the timed fits only
-        obs.tracing.reset_trace()         # so does the trace_events count
         with CompileCounter() as cc, tempfile.TemporaryDirectory() as ckdir:
             for _ in range(2):            # best-of-2: shared-host noise
                 it = MnistDataSetIterator(BATCH, train=True, num_examples=N)
@@ -350,14 +348,11 @@ def bench_fused():
                 obs.metrics_summary(), probes, selected, det_fp)
 
     with _restore_env("DL4J_TPU_FUSE_STEPS", "DL4J_TPU_FUSE_AUTOTUNE",
-                      "DL4J_TPU_TUNE_CACHE_DIR", "DL4J_TPU_TRACE_DIR"), \
-            tempfile.TemporaryDirectory() as trace_dir, \
+                      "DL4J_TPU_TUNE_CACHE_DIR"), \
             tempfile.TemporaryDirectory() as tune_dir:
-        os.environ["DL4J_TPU_TRACE_DIR"] = trace_dir
         os.environ["DL4J_TPU_TUNE_CACHE_DIR"] = tune_dir
         (v_fused, c_fused, sig_fused, stats_fused, metrics_fused,
          probes, selected, fp_fused) = run("autotune")
-        trace_events = obs.tracing.event_count()
         (v_unfused, c_unfused, sig_unfused, _, _, _, _,
          fp_unfused) = run(1)
     return {
@@ -390,10 +385,9 @@ def bench_fused():
         # of the same commit is a determinism regression in that arm
         # (docs/DETERMINISM.md)
         "determinism": {"fused": fp_fused, "unfused": fp_unfused},
-        # obs-layer summary of the FUSED timed fits (metrics + tracing were
-        # fully on for the whole A/B): the self-diagnosis payload
+        # obs-layer summary of the FUSED timed fits (metrics were on for
+        # the whole A/B): the self-diagnosis payload
         "metrics": metrics_fused,
-        "trace_events": trace_events,
     }
 
 

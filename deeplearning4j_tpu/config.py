@@ -354,10 +354,6 @@ _declare("DL4J_TPU_SLOW", "flag", False,
 _declare("DL4J_TPU_TEST_PLATFORM", "str", "cpu",
          "Platform the test suite forces before jax import; read raw in "
          "tests/conftest.py — see module docstring.")
-_declare("DL4J_TPU_TRACE_DIR", "str", "",
-         "Directory for Chrome trace-event span files (obs/tracing.py, "
-         "Perfetto-loadable, one trace_<pid>.json per process); empty "
-         "(default) disables span recording.")
 _declare("DL4J_TPU_TUNE_CACHE_DIR", "str", "~/.dl4j_tpu/tune",
          "Directory the fusion autotuner persists its (model, bucket shape, "
          "backend) -> K decisions into (atomic_io tmp+fsync+rename commits): "

@@ -532,7 +532,9 @@ class AsyncDataSetIterator(DataSetIterator):
             nb = (sum(self._nbytes(d) for d in group)
                   if self._device_stage else 0)
             if len(group) > 1 and full:
-                emit([_Staged(concat=self._host_concat(group))], nb)
+                with obs.span("prefetch.host_concat", batches=len(group)):
+                    concat = self._host_concat(group)
+                emit([_Staged(concat=concat)], nb)
                 return
             # PARTIAL stage groups (trailing batches, shape-change flushes)
             # go per-batch: a partial concat would mint a novel super-batch
@@ -884,30 +886,30 @@ class AsyncDataSetIterator(DataSetIterator):
         t0 = time.perf_counter()
 
         def got(item):
-            dt = time.perf_counter() - t0
-            _OBS_CONSUMER_WAIT.record(dt)
-            obs.add_span("prefetch.wait", t0, dt)
+            _OBS_CONSUMER_WAIT.record(time.perf_counter() - t0)
             return item
 
-        while True:
-            try:
-                return got(q.get(timeout=_LIVENESS_POLL_S))
-            except queue.Empty:
-                pass
-            if thread is not None and thread.is_alive():
-                continue
-            # dead worker: drain the race where the sentinel/batch landed
-            # between the get timeout and the liveness check
-            try:
-                return got(q.get_nowait())
-            except queue.Empty:
-                if self._error:
-                    raise self._error[0]
-                name = "<unstarted>" if thread is None else thread.name
-                raise PrefetchWorkerDiedError(
-                    f"prefetch worker thread {name!r} died without emitting "
-                    "its end-of-stream sentinel (hard crash?); the stream "
-                    "is broken — reset() the iterator to restart it")
+        with obs.span("prefetch.wait"):
+            while True:
+                try:
+                    return got(q.get(timeout=_LIVENESS_POLL_S))
+                except queue.Empty:
+                    pass
+                if thread is not None and thread.is_alive():
+                    continue
+                # dead worker: drain the race where the sentinel/batch landed
+                # between the get timeout and the liveness check
+                try:
+                    return got(q.get_nowait())
+                except queue.Empty:
+                    if self._error:
+                        raise self._error[0]
+                    name = "<unstarted>" if thread is None else thread.name
+                    raise PrefetchWorkerDiedError(
+                        f"prefetch worker thread {name!r} died without "
+                        "emitting its end-of-stream sentinel (hard crash?); "
+                        "the stream is broken — reset() the iterator to "
+                        "restart it")
 
     def __next__(self):
         if self._queue is None:
@@ -921,7 +923,8 @@ class AsyncDataSetIterator(DataSetIterator):
             raise StopIteration
         if isinstance(item, _Staged):
             # device transfer happens HERE, on the consumer thread
-            self._ready = self._stage_group(item)
+            with obs.span("prefetch.device_put"):
+                self._ready = self._stage_group(item)
             return self._ready.pop(0)
         return item
 

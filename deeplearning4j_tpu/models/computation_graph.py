@@ -153,33 +153,36 @@ class ComputationGraph(DeviceStateMixin):
                     x = v.preprocessor.pre_process(x, m)
                     m = v.preprocessor.feed_forward_mask(m)
                 rng_i = None if rngs is None else rngs[name]
-                if name in out_set and isinstance(layer, BaseOutputLayer):
-                    x_in = layer.apply_dropout(x, train=train, rng=rng_i)
-                    pre = layer.pre_output(params_map[name], x_in)
-                    preouts[name] = pre
-                    acts[name] = layer.activation_fn()(pre)
-                    new_states[name] = states_map[name]
-                elif name in out_set and isinstance(layer, LossLayer):
-                    preouts[name] = x
-                    acts[name], s = layer.forward(params_map[name], x, states_map[name],
-                                                  train=train, rng=rng_i, mask=m)
-                    new_states[name] = s
-                elif (carries is not None and isinstance(layer, LSTM)
-                      and not isinstance(layer, GravesBidirectionalLSTM)):
-                    x_in = layer.apply_dropout(x, train=train, rng=rng_i)
-                    carry = new_carries.get(name)
-                    if carry is None:
-                        carry = layer.initial_carry(x_in.shape[0], x_in.dtype)
-                    h0, c0 = carry
-                    out, (hf, cf) = layer._scan(params_map[name], x_in, h0, c0, m)
-                    new_carries[name] = (hf, cf)
-                    acts[name] = out
-                    new_states[name] = states_map[name]
-                else:
-                    acts[name], s = maybe_remat(
-                        layer, train, getattr(self.conf, "remat", False))(
-                        params_map[name], x, states_map[name], m, rng_i)
-                    new_states[name] = s
+                # the layer's class is its scope in a profiler trace: no vertex
+                # name, so that the copies of one op sum into one row
+                with jax.named_scope(type(layer).__name__):
+                    if name in out_set and isinstance(layer, BaseOutputLayer):
+                        x_in = layer.apply_dropout(x, train=train, rng=rng_i)
+                        pre = layer.pre_output(params_map[name], x_in)
+                        preouts[name] = pre
+                        acts[name] = layer.activation_fn()(pre)
+                        new_states[name] = states_map[name]
+                    elif name in out_set and isinstance(layer, LossLayer):
+                        preouts[name] = x
+                        acts[name], s = layer.forward(params_map[name], x, states_map[name],
+                                                      train=train, rng=rng_i, mask=m)
+                        new_states[name] = s
+                    elif (carries is not None and isinstance(layer, LSTM)
+                          and not isinstance(layer, GravesBidirectionalLSTM)):
+                        x_in = layer.apply_dropout(x, train=train, rng=rng_i)
+                        carry = new_carries.get(name)
+                        if carry is None:
+                            carry = layer.initial_carry(x_in.shape[0], x_in.dtype)
+                        h0, c0 = carry
+                        out, (hf, cf) = layer._scan(params_map[name], x_in, h0, c0, m)
+                        new_carries[name] = (hf, cf)
+                        acts[name] = out
+                        new_states[name] = states_map[name]
+                    else:
+                        acts[name], s = maybe_remat(
+                            layer, train, getattr(self.conf, "remat", False))(
+                            params_map[name], x, states_map[name], m, rng_i)
+                        new_states[name] = s
                 masks[name] = layer.feed_forward_mask(m)
             else:
                 # parameter-free vertex; rnn vertices may consult named inputs
@@ -194,7 +197,8 @@ class ComputationGraph(DeviceStateMixin):
                     # the named network input (DuplicateToTimeSeriesVertex.java)
                     xs = xs + [acts[v.ts_input_name]]
                     ms = ms + [masks.get(v.ts_input_name)]
-                acts[name] = v.forward(xs, ms)
+                with jax.named_scope(type(v).__name__):
+                    acts[name] = v.forward(xs, ms)
                 masks[name] = v.feed_forward_mask(ms)
         return acts, preouts, new_states, masks, new_carries
 
@@ -305,9 +309,11 @@ class ComputationGraph(DeviceStateMixin):
                     new_params[n] = p
                     new_upd[n] = s
                     continue
-                upd, s2 = updaters_mod.compute_updates(updater_confs[n], g, s, iteration, params=p)
-                new_params[n] = {k: p[k] - upd[k] for k in p}
-                new_upd[n] = s2
+                with jax.named_scope("updater"):
+                    upd, s2 = updaters_mod.compute_updates(
+                        updater_confs[n], g, s, iteration, params=p)
+                    new_params[n] = {k: p[k] - upd[k] for k in p}
+                    new_upd[n] = s2
             if tbptt:
                 # detach the carry between segments (truncation semantics,
                 # ComputationGraph doTruncatedBPTT)
@@ -451,10 +457,11 @@ class ComputationGraph(DeviceStateMixin):
                     new_params[n] = p
                     new_upd[n] = s
                     continue
-                upd, s2 = updaters_mod.compute_updates(updater_confs[n], g, s,
-                                                       iteration, params=p)
-                new_params[n] = {k: p[k] - upd[k] for k in p}
-                new_upd[n] = s2
+                with jax.named_scope("updater"):
+                    upd, s2 = updaters_mod.compute_updates(
+                        updater_confs[n], g, s, iteration, params=p)
+                    new_params[n] = {k: p[k] - upd[k] for k in p}
+                    new_upd[n] = s2
             keep = real
             if guard:
                 ok = step_all_finite(score, grads)
@@ -514,10 +521,11 @@ class ComputationGraph(DeviceStateMixin):
                         new_params[n] = p
                         new_upd[n] = s
                         continue
-                    upd, s2 = updaters_mod.compute_updates(
-                        updater_confs[n], g, s, iteration, params=p)
-                    new_params[n] = {k: p[k] - upd[k] for k in p}
-                    new_upd[n] = s2
+                    with jax.named_scope("updater"):
+                        upd, s2 = updaters_mod.compute_updates(
+                            updater_confs[n], g, s, iteration, params=p)
+                        new_params[n] = {k: p[k] - upd[k] for k in p}
+                        new_upd[n] = s2
                 # truncation semantics: detach the carry between windows
                 new_carries = jax.tree.map(jax.lax.stop_gradient, new_carries)
                 keep = real
@@ -651,30 +659,32 @@ class ComputationGraph(DeviceStateMixin):
         windows-per-batch updates per real step, like the host loop)."""
         t0 = time.perf_counter()
         plan = self._tbptt_window_plan(xs)
-        sig = self._fused_signature(xs, ys, guard)
-        if sig not in self._jit_train:
-            self._jit_train[sig] = self._build_fused_train_step(guard, plan)
-        (self.params_map, self.states_map, self.updater_states, self._rng,
-         self._iter_dev, skipped, self._last_gradients, scores) = \
-            self._jit_train[sig](
+        # every window is one parameter update (n_windows == 1 untruncated)
+        n_w = 1 if plan is None else (plan[1] + (1 if plan[2] else 0))
+        ku = k * n_w
+        with obs.span("fit.dispatch_group", steps=ku):
+            sig = self._fused_signature(xs, ys, guard)
+            if sig not in self._jit_train:
+                self._jit_train[sig] = self._build_fused_train_step(guard,
+                                                                    plan)
+            (self.params_map, self.states_map, self.updater_states,
+             self._rng, self._iter_dev, skipped, self._last_gradients,
+             scores) = self._jit_train[sig](
                 self.params_map, self.states_map, self.updater_states,
                 self._rng, self._device_iteration(), xs, ys, ews,
                 self._nan_skipped_arg())
-        if guard:
-            self._nanguard_record(skipped)
+            if guard:
+                self._nanguard_record(skipped)
         dt = time.perf_counter() - t0
         # scores: [K] standard, [K, n_windows] tBPTT — flatten to the
         # per-update stream (padding steps trail the real ones); flatten
         # even for n_windows == 1, where a raw scores[i] would hand
         # listeners/score_ a shape-(1,) array instead of a scalar
-        n_w = 1 if plan is None else (plan[1] + (1 if plan[2] else 0))
         if plan is not None:
             scores = scores.reshape((-1,))
-        ku = k * n_w
         _OBS_GROUP_SECONDS.record(dt)
         _OBS_GROUPS.inc()
         _OBS_STEPS.inc(ku)
-        obs.add_span("fit.dispatch_group", t0, dt, steps=ku)
         it0 = self.iteration
         self.iteration = it0 + ku
         self._iter_dev_py = self.iteration
@@ -748,21 +758,22 @@ class ComputationGraph(DeviceStateMixin):
                  ew=None):
         guard = nanguard_enabled()
         t0 = time.perf_counter()
-        sig = self._cache_signature("train", inputs, labels, fmasks, lmasks) \
-            + (tbptt, guard, ew is None)
-        if sig not in self._jit_train:
-            self._jit_train[sig] = self._build_train_step(tbptt, guard)
-        (self.params_map, self.states_map, self.updater_states, self._rng,
-         self._iter_dev, skipped, score, grads, new_carries) = self._jit_train[sig](
-            self.params_map, self.states_map, self.updater_states, self._rng,
-            self._device_iteration(), inputs, labels, fmasks, lmasks, ew,
-            carries, self._nan_skipped_arg())
-        if guard:
-            self._nanguard_record(skipped)
+        with obs.span("fit.step"):
+            sig = self._cache_signature("train", inputs, labels, fmasks,
+                                        lmasks) + (tbptt, guard, ew is None)
+            if sig not in self._jit_train:
+                self._jit_train[sig] = self._build_train_step(tbptt, guard)
+            (self.params_map, self.states_map, self.updater_states,
+             self._rng, self._iter_dev, skipped, score, grads,
+             new_carries) = self._jit_train[sig](
+                self.params_map, self.states_map, self.updater_states,
+                self._rng, self._device_iteration(), inputs, labels, fmasks,
+                lmasks, ew, carries, self._nan_skipped_arg())
+            if guard:
+                self._nanguard_record(skipped)
         dt = time.perf_counter() - t0
         _OBS_STEP_SECONDS.record(dt)
         _OBS_STEPS.inc()
-        obs.add_span("fit.step", t0, dt)
         self.score_ = score  # device array; synced lazily on read
         self._last_gradients = grads
         self._last_batch_size = int(inputs[0].shape[0])
@@ -1040,10 +1051,6 @@ class ComputationGraph(DeviceStateMixin):
                     close = getattr(lst, "close", None)
                     if callable(close):
                         close(self)
-                # fit boundary: persist buffered spans (no-op unless
-                # DL4J_TPU_TRACE_DIR is set)
-                if obs.tracing.enabled():
-                    obs.flush_trace()
             return self
         raise ValueError(f"Cannot fit on {type(data)}")
 

@@ -121,32 +121,35 @@ class MultiLayerNetwork(DeviceStateMixin):
                 mask = pre.feed_forward_mask(mask)
             rng_i = None if rngs is None else rngs[i]
             is_last = i == n - 1
-            if is_last and isinstance(layer, (BaseOutputLayer,)):
-                x_in = layer.apply_dropout(x, train=train, rng=rng_i)
-                preout = layer.pre_output(params_list[i], x_in)
-                x = layer.activation_fn()(preout)
-                new_states.append(states_list[i])
-            elif is_last and isinstance(layer, LossLayer):
-                preout = x
-                x, s = layer.forward(params_list[i], x, states_list[i],
-                                     train=train, rng=rng_i, mask=mask)
-                new_states.append(s)
-            elif (carries is not None and isinstance(layer, LSTM)
-                  and not isinstance(layer, GravesBidirectionalLSTM)):
-                x_in = layer.apply_dropout(x, train=train, rng=rng_i)
-                carry = new_carries[i]
-                if carry is None:
-                    carry = layer.initial_carry(x_in.shape[0], x_in.dtype)
-                h0, c0 = carry
-                out, (hf, cf) = layer._scan(params_list[i], x_in, h0, c0, mask)
-                new_carries[i] = (hf, cf)
-                x = out
-                new_states.append(states_list[i])
-            else:
-                x, s = maybe_remat(
-                    layer, train, getattr(self.conf, "remat", False))(
-                    params_list[i], x, states_list[i], mask, rng_i)
-                new_states.append(s)
+            # the layer's class is its scope in a profiler trace: no index, so
+            # that the copies of one op sum into one row (PERF.md section 3)
+            with jax.named_scope(type(layer).__name__):
+                if is_last and isinstance(layer, (BaseOutputLayer,)):
+                    x_in = layer.apply_dropout(x, train=train, rng=rng_i)
+                    preout = layer.pre_output(params_list[i], x_in)
+                    x = layer.activation_fn()(preout)
+                    new_states.append(states_list[i])
+                elif is_last and isinstance(layer, LossLayer):
+                    preout = x
+                    x, s = layer.forward(params_list[i], x, states_list[i],
+                                         train=train, rng=rng_i, mask=mask)
+                    new_states.append(s)
+                elif (carries is not None and isinstance(layer, LSTM)
+                      and not isinstance(layer, GravesBidirectionalLSTM)):
+                    x_in = layer.apply_dropout(x, train=train, rng=rng_i)
+                    carry = new_carries[i]
+                    if carry is None:
+                        carry = layer.initial_carry(x_in.shape[0], x_in.dtype)
+                    h0, c0 = carry
+                    out, (hf, cf) = layer._scan(params_list[i], x_in, h0, c0, mask)
+                    new_carries[i] = (hf, cf)
+                    x = out
+                    new_states.append(states_list[i])
+                else:
+                    x, s = maybe_remat(
+                        layer, train, getattr(self.conf, "remat", False))(
+                        params_list[i], x, states_list[i], mask, rng_i)
+                    new_states.append(s)
             mask = layer.feed_forward_mask(mask)
             acts.append(x)
         return acts, preout, new_states, mask, new_carries
@@ -236,9 +239,11 @@ class MultiLayerNetwork(DeviceStateMixin):
                     new_params.append(p)
                     new_upd.append(s)
                     continue
-                upd, s2 = updaters_mod.compute_updates(conf_u, g, s, iteration, params=p)
-                new_params.append({k: p[k] - upd[k] for k in p})
-                new_upd.append(s2)
+                with jax.named_scope("updater"):
+                    upd, s2 = updaters_mod.compute_updates(
+                        conf_u, g, s, iteration, params=p)
+                    new_params.append({k: p[k] - upd[k] for k in p})
+                    new_upd.append(s2)
             if tbptt:
                 new_carries = jax.tree.map(jax.lax.stop_gradient, new_carries)
             it2 = iteration + 1
@@ -325,20 +330,21 @@ class MultiLayerNetwork(DeviceStateMixin):
             return self._fit_batch_solver(x, y, fmask, lmask)
         guard = nanguard_enabled()
         t0 = time.perf_counter()
-        sig = self._train_signature(x, y, fmask, lmask, False, guard, ew)
-        if sig not in self._jit_train:
-            self._jit_train[sig] = self._build_train_step(False, guard)
-        (self.params_list, self.states_list, self.updater_states, self._rng,
-         self._iter_dev, skipped, score, grads, _) = self._jit_train[sig](
-            self.params_list, self.states_list, self.updater_states, self._rng,
-            self._device_iteration(), x, y, fmask, lmask, ew, None,
-            self._nan_skipped_arg())
-        if guard:
-            self._nanguard_record(skipped)
+        with obs.span("fit.step"):
+            sig = self._train_signature(x, y, fmask, lmask, False, guard, ew)
+            if sig not in self._jit_train:
+                self._jit_train[sig] = self._build_train_step(False, guard)
+            (self.params_list, self.states_list, self.updater_states,
+             self._rng, self._iter_dev, skipped, score, grads, _) = \
+                self._jit_train[sig](
+                    self.params_list, self.states_list, self.updater_states,
+                    self._rng, self._device_iteration(), x, y, fmask, lmask,
+                    ew, None, self._nan_skipped_arg())
+            if guard:
+                self._nanguard_record(skipped)
         dt = time.perf_counter() - t0
         _OBS_STEP_SECONDS.record(dt)
         _OBS_STEPS.inc()
-        obs.add_span("fit.step", t0, dt)
         self.score_ = score  # device array; synced lazily on read
         self._last_gradients = grads
         self._last_batch_size = int(x.shape[0])
@@ -417,9 +423,11 @@ class MultiLayerNetwork(DeviceStateMixin):
                     new_params.append(p)
                     new_upd.append(s)
                     continue
-                upd, s2 = updaters_mod.compute_updates(conf_u, g, s, iteration, params=p)
-                new_params.append({k: p[k] - upd[k] for k in p})
-                new_upd.append(s2)
+                with jax.named_scope("updater"):
+                    upd, s2 = updaters_mod.compute_updates(
+                        conf_u, g, s, iteration, params=p)
+                    new_params.append({k: p[k] - upd[k] for k in p})
+                    new_upd.append(s2)
             keep = real
             if guard:
                 ok = step_all_finite(score, grads)
@@ -479,10 +487,11 @@ class MultiLayerNetwork(DeviceStateMixin):
                         new_params.append(p)
                         new_upd.append(s)
                         continue
-                    upd, s2 = updaters_mod.compute_updates(conf_u, g, s,
-                                                           iteration, params=p)
-                    new_params.append({k: p[k] - upd[k] for k in p})
-                    new_upd.append(s2)
+                    with jax.named_scope("updater"):
+                        upd, s2 = updaters_mod.compute_updates(
+                            conf_u, g, s, iteration, params=p)
+                        new_params.append({k: p[k] - upd[k] for k in p})
+                        new_upd.append(s2)
                 # truncation semantics: detach the carry between windows
                 new_carries = jax.tree.map(jax.lax.stop_gradient, new_carries)
                 keep = real
@@ -620,31 +629,33 @@ class MultiLayerNetwork(DeviceStateMixin):
         window is one parameter update, exactly as in the host loop)."""
         t0 = time.perf_counter()
         plan = self._tbptt_window_plan(xs)
-        sig = self._fused_signature(xs, ys, guard)
-        if sig not in self._jit_train:
-            self._jit_train[sig] = self._build_fused_train_step(guard, plan)
-        (self.params_list, self.states_list, self.updater_states, self._rng,
-         self._iter_dev, skipped, self._last_gradients, scores) = \
-            self._jit_train[sig](
+        # every window is one parameter update (n_windows == 1 untruncated)
+        n_w = 1 if plan is None else (plan[1] + (1 if plan[2] else 0))
+        ku = k * n_w
+        with obs.span("fit.dispatch_group", steps=ku):
+            sig = self._fused_signature(xs, ys, guard)
+            if sig not in self._jit_train:
+                self._jit_train[sig] = self._build_fused_train_step(guard,
+                                                                    plan)
+            (self.params_list, self.states_list, self.updater_states,
+             self._rng, self._iter_dev, skipped, self._last_gradients,
+             scores) = self._jit_train[sig](
                 self.params_list, self.states_list, self.updater_states,
                 self._rng, self._device_iteration(), xs, ys, ews,
                 self._nan_skipped_arg())
-        if guard:
-            self._nanguard_record(skipped)
+            if guard:
+                self._nanguard_record(skipped)
         dt = time.perf_counter() - t0
         # scores: [K] standard, [K, n_windows] tBPTT — flatten to the
         # per-update stream (padding steps trail, so the first ku entries
         # are exactly the real updates); flatten even for n_windows == 1,
         # where scores is still rank-2 and a raw scores[i] would hand
         # listeners/score_ a shape-(1,) array instead of a scalar
-        n_w = 1 if plan is None else (plan[1] + (1 if plan[2] else 0))
         if plan is not None:
             scores = scores.reshape((-1,))
-        ku = k * n_w
         _OBS_GROUP_SECONDS.record(dt)
         _OBS_GROUPS.inc()
         _OBS_STEPS.inc(ku)
-        obs.add_span("fit.dispatch_group", t0, dt, steps=ku)
         it0 = self.iteration
         self.iteration = it0 + ku
         self._iter_dev_py = self.iteration
@@ -736,27 +747,28 @@ class MultiLayerNetwork(DeviceStateMixin):
             fm = None if fmask is None else fmask[:, start:start + seg]
             lm = None if lmask is None else lmask[:, start:start + seg]
             t0 = time.perf_counter()
-            sig = self._train_signature(xs, ys, fm, lm, True, guard, ew)
-            if sig not in self._jit_train:
-                self._jit_train[sig] = self._build_train_step(True, guard)
-            # materialise initial carries so the jit signature is stable
-            if not carries_init:
-                carries = [l.initial_carry(xs.shape[0], xs.dtype)
-                           if (isinstance(l, LSTM) and not isinstance(l, GravesBidirectionalLSTM))
-                           else None
-                           for l in self.layers]
-                carries_init = True
-            (self.params_list, self.states_list, self.updater_states, self._rng,
-             self._iter_dev, skipped, score, grads, carries) = self._jit_train[sig](
-                self.params_list, self.states_list, self.updater_states, self._rng,
-                self._device_iteration(), xs, ys, fm, lm, ew, carries,
-                self._nan_skipped_arg())
-            if guard:
-                self._nanguard_record(skipped)
+            with obs.span("fit.step"):
+                sig = self._train_signature(xs, ys, fm, lm, True, guard, ew)
+                if sig not in self._jit_train:
+                    self._jit_train[sig] = self._build_train_step(True, guard)
+                # materialise initial carries so the jit signature is stable
+                if not carries_init:
+                    carries = [l.initial_carry(xs.shape[0], xs.dtype)
+                               if (isinstance(l, LSTM) and not isinstance(l, GravesBidirectionalLSTM))
+                               else None
+                               for l in self.layers]
+                    carries_init = True
+                (self.params_list, self.states_list, self.updater_states,
+                 self._rng, self._iter_dev, skipped, score, grads,
+                 carries) = self._jit_train[sig](
+                    self.params_list, self.states_list, self.updater_states,
+                    self._rng, self._device_iteration(), xs, ys, fm, lm, ew,
+                    carries, self._nan_skipped_arg())
+                if guard:
+                    self._nanguard_record(skipped)
             dt = time.perf_counter() - t0
             _OBS_STEP_SECONDS.record(dt)
             _OBS_STEPS.inc()
-            obs.add_span("fit.step", t0, dt)
             last_score = score
             self._last_gradients = grads
             self._last_batch_size = int(xs.shape[0])
@@ -975,10 +987,6 @@ class MultiLayerNetwork(DeviceStateMixin):
                     close = getattr(lst, "close", None)
                     if callable(close):
                         close(self)
-                # fit boundary: persist buffered spans (no-op unless
-                # DL4J_TPU_TRACE_DIR is set)
-                if obs.tracing.enabled():
-                    obs.flush_trace()
             return self
         raise ValueError(f"Cannot fit on {type(data)}")
 

@@ -29,6 +29,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from deeplearning4j_tpu import obs
 from deeplearning4j_tpu.config import env_int, env_str
 
 from deeplearning4j_tpu.parallel.sequence_parallel import (
@@ -96,7 +97,30 @@ def _blockwise_route(c, q, k, v, plan=None):
     return blockwise_attention(q, k, v, causal=True,
                                block_size=c.block_size)
 
-__all__ = ["TransformerConfig", "TransformerLM"]
+__all__ = ["TransformerConfig", "TransformerLM", "SCOPES"]
+
+# The fixed vocabulary of ``jax.named_scope``s the LM step enters: what the
+# profiler's trace names a device op by (the stat ``tf_op`` of its event,
+# ``jit(step)/transpose(jvp(block.ln1))/mul``) and what the benchmark's
+# per-layer metrics read, token by token (``benchmark/scope_reduce.py``,
+# PERF.md section 3). Rules, each held by tests/test_scopes.py:
+# * no layer index (``block``, never ``b7``): the trace's reduction sums the
+#   unrolled layers' copies of one op into one row by its instruction's name;
+# * no name that is also a JAX primitive or transform (``transpose``, ``mul``);
+# * entered INSIDE the differentiated function, never around
+#   ``jax.value_and_grad``, and ONE scope element an op, the block's written
+#   ``block.attn`` (not ``attn`` inside ``block``, and no ``/``). XLA names a
+#   custom call after the piece of its name stack between the last two ``/``,
+#   and a transform wraps only the scope element next to it:
+#   ``jvp(block.attn)/pallas_call`` is the instruction ``%jvp_block.attn_``,
+#   where ``jvp(block)/attn/pallas_call`` would be ``%attn``. The benchmark's
+#   accepted ``flash_*_roofline`` readers tell the forward kernel from the
+#   backward ones by ``%jvp`` / ``%transpose`` at the start of that name; for
+#   the same reason the flash ``pallas_call``s take no ``name=`` (it enters a
+#   scope of its own). tests/test_aot_compile.py holds this on the program
+#   compiled for the chip.
+SCOPES = ("embed", "block", "ln1", "qkv", "attn", "proj", "ln2", "mlp",
+          "final_ln", "logits_loss", "grad_clip", "optimizer")
 
 
 @dataclass
@@ -192,34 +216,43 @@ def _block_apply(c, bp, x, drop=None, rng=None, attend=None, ffn=None,
     r1 = r2 = None
     if rng is not None:
         r1, r2 = jax.random.split(rng)
-    hloc = _layer_norm(x, bp["ln1_g"], bp["ln1_b"])
-    qkv = hloc @ bp["qkv"] + bp["qkv_b"]
-    kvd = c.kv_heads * hd
-    q, k, v = jnp.split(qkv, [d, d + kvd], axis=-1)
-    split = lambda a, H: a.reshape(B, T, H, hd).transpose(0, 2, 1, 3)
-    q = split(q, c.n_heads)
-    k, v = split(k, c.kv_heads), split(v, c.kv_heads)
-    if c.pos_embed == "rope":
-        cos, sin = _rope_cos_sin(c, hd, jnp.arange(T))
-        q, k = _apply_rope(q, cos, sin), _apply_rope(k, cos, sin)
-    if attend is not None:
-        k, v = _full_heads(c, k, v)   # custom attends (ring SP) assume MHA
-        o = attend(q, k, v)
-    elif c.block_size:
-        o = _blockwise_route(c, q, k, v, plan)
-    else:
-        k, v = _full_heads(c, k, v)
-        o = dense_attention(q, k, v, causal=True, window=c.window)
-    o = o.transpose(0, 2, 1, 3).reshape(B, T, d)
-    a = o @ bp["proj"] + bp["proj_b"]
-    x = x + (drop(a, r1) if drop else a)
-    hloc = _layer_norm(x, bp["ln2_g"], bp["ln2_b"])
-    if ffn is not None:
-        m = ffn(bp, hloc)
-    else:
-        m = jax.nn.gelu(hloc @ bp["fc"] + bp["fc_b"]) @ bp["out"] \
-            + bp["out_b"]
-    return x + (drop(m, r2) if drop else m)
+    # ONE scope element an op ("block.ln1", never "ln1" inside "block"): see
+    # the note at SCOPES for what the flash kernels' instruction names need
+    scope = jax.named_scope
+    with scope("block.ln1"):
+        hloc = _layer_norm(x, bp["ln1_g"], bp["ln1_b"])
+    with scope("block.qkv"):
+        qkv = hloc @ bp["qkv"] + bp["qkv_b"]
+    with scope("block.attn"):
+        kvd = c.kv_heads * hd
+        q, k, v = jnp.split(qkv, [d, d + kvd], axis=-1)
+        split = lambda a, H: a.reshape(B, T, H, hd).transpose(0, 2, 1, 3)
+        q = split(q, c.n_heads)
+        k, v = split(k, c.kv_heads), split(v, c.kv_heads)
+        if c.pos_embed == "rope":
+            cos, sin = _rope_cos_sin(c, hd, jnp.arange(T))
+            q, k = _apply_rope(q, cos, sin), _apply_rope(k, cos, sin)
+        if attend is not None:
+            k, v = _full_heads(c, k, v)   # custom attends (ring SP): MHA
+            o = attend(q, k, v)
+        elif c.block_size:
+            o = _blockwise_route(c, q, k, v, plan)
+        else:
+            k, v = _full_heads(c, k, v)
+            o = dense_attention(q, k, v, causal=True, window=c.window)
+        o = o.transpose(0, 2, 1, 3).reshape(B, T, d)
+    with scope("block.proj"):
+        a = o @ bp["proj"] + bp["proj_b"]
+        x = x + (drop(a, r1) if drop else a)
+    with scope("block.ln2"):
+        hloc = _layer_norm(x, bp["ln2_g"], bp["ln2_b"])
+    with scope("block.mlp"):
+        if ffn is not None:
+            m = ffn(bp, hloc)
+        else:
+            m = jax.nn.gelu(hloc @ bp["fc"] + bp["fc_b"]) @ bp["out"] \
+                + bp["out_b"]
+        return x + (drop(m, r2) if drop else m)
 
 
 def _forward_tokens(c, params, tokens, apply_block):
@@ -228,19 +261,23 @@ def _forward_tokens(c, params, tokens, apply_block):
     Shared by TransformerLM, the MoE family, and the EP trainer so the
     cast/loop/head logic exists once."""
     T = tokens.shape[1]
-    x = params["wte"][tokens]
-    if "wpe" in params:            # absent under rope (rotary in-block)
-        x = x + params["wpe"][:T]
     cd = c.compute_dtype
+    with jax.named_scope("embed"):
+        x = params["wte"][tokens]
+        if "wpe" in params:            # absent under rope (rotary in-block)
+            x = x + params["wpe"][:T]
+        if cd:
+            x = x.astype(cd)
     if cd:
-        x = x.astype(cd)
         params = jax.tree.map(
             lambda a: a.astype(cd) if jnp.issubdtype(a.dtype, jnp.floating)
             else a, params)
     for i in range(c.n_layers):
         x = apply_block(i, params[f"b{i}"], x)
-    x = _layer_norm(x, params["lnf_g"], params["lnf_b"])
-    return (x @ params["wte"].T).astype(jnp.float32)   # tied embeddings
+    with jax.named_scope("final_ln"):
+        x = _layer_norm(x, params["lnf_g"], params["lnf_b"])
+    with jax.named_scope("logits_loss"):
+        return (x @ params["wte"].T).astype(jnp.float32)   # tied embeddings
 
 
 def _lr_at(c, t):
@@ -450,20 +487,22 @@ class TransformerLM:
     def _loss(self, params, tokens, targets, mask, rng=None):
         c = self.conf
         logits = self._logits(params, tokens, rng)
-        logp = jax.nn.log_softmax(logits, axis=-1)
-        nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
-        if c.label_smoothing > 0.0:
-            # smoothed CE: (1-a)*nll + a*mean over the vocabulary
-            a = c.label_smoothing
-            nll = (1.0 - a) * nll - a * logp.mean(-1)
-        m = jnp.ones_like(nll) if mask is None else mask.astype(nll.dtype)
-        denom = jnp.maximum(m.sum(), 1.0)
-        loss = (nll * m).sum() / denom
-        if c.z_loss > 0.0:
-            # PaLM z-loss: pulls log Z toward 0, stabilizing bf16 logits
-            z = jax.nn.logsumexp(logits, axis=-1)
-            loss = loss + c.z_loss * ((z ** 2) * m).sum() / denom
-        return loss
+        with jax.named_scope("logits_loss"):
+            logp = jax.nn.log_softmax(logits, axis=-1)
+            nll = -jnp.take_along_axis(logp, targets[..., None],
+                                       axis=-1)[..., 0]
+            if c.label_smoothing > 0.0:
+                # smoothed CE: (1-a)*nll + a*mean over the vocabulary
+                a = c.label_smoothing
+                nll = (1.0 - a) * nll - a * logp.mean(-1)
+            m = jnp.ones_like(nll) if mask is None else mask.astype(nll.dtype)
+            denom = jnp.maximum(m.sum(), 1.0)
+            loss = (nll * m).sum() / denom
+            if c.z_loss > 0.0:
+                # PaLM z-loss: pulls log Z toward 0, stabilizing bf16 logits
+                z = jax.nn.logsumexp(logits, axis=-1)
+                loss = loss + c.z_loss * ((z ** 2) * m).sum() / denom
+            return loss
 
     # ---- training ------------------------------------------------------
     def _build_step(self):
@@ -485,18 +524,20 @@ class TransformerLM:
             if c.grad_clip_norm is not None:
                 # global-norm clipping (the reference's ClipL2PerParamType
                 # role for this family, applied across the whole tree)
-                gn = jnp.sqrt(sum(jnp.sum(jnp.square(g))
-                                  for g in jax.tree.leaves(grads)))
-                scale = jnp.minimum(1.0, c.grad_clip_norm
-                                    / jnp.maximum(gn, 1e-12))
-                grads = jax.tree.map(lambda g: g * scale, grads)
-            t = it + 1
-            new_p, new_opt = _adamw_apply(c, params, grads, opt, t,
-                                          _lr_at(c, t))
-            if c.ema_decay is not None:
-                d = c.ema_decay
-                new_opt["ema"] = jax.tree.map(
-                    lambda e, p: d * e + (1.0 - d) * p, opt["ema"], new_p)
+                with jax.named_scope("grad_clip"):
+                    gn = jnp.sqrt(sum(jnp.sum(jnp.square(g))
+                                      for g in jax.tree.leaves(grads)))
+                    scale = jnp.minimum(1.0, c.grad_clip_norm
+                                        / jnp.maximum(gn, 1e-12))
+                    grads = jax.tree.map(lambda g: g * scale, grads)
+            with jax.named_scope("optimizer"):
+                t = it + 1
+                new_p, new_opt = _adamw_apply(c, params, grads, opt, t,
+                                              _lr_at(c, t))
+                if c.ema_decay is not None:
+                    d = c.ema_decay
+                    new_opt["ema"] = jax.tree.map(
+                        lambda e, p: d * e + (1.0 - d) * p, opt["ema"], new_p)
             if plan is not None:
                 # pin updated state to its at-rest placement: level <= 2
                 # all-gathers the sharded delta onto the replicated
@@ -512,16 +553,18 @@ class TransformerLM:
         (inputs = tokens[:, :-1], targets = tokens[:, 1:])."""
         if self.params is None:
             self.init()
-        tokens = jnp.asarray(tokens, jnp.int32)
-        if targets is None:
-            tokens, targets = tokens[:, :-1], tokens[:, 1:]
-        else:
-            targets = jnp.asarray(targets, jnp.int32)
-        if self._data_sharding is not None:
-            tokens = jax.device_put(tokens, self._data_sharding)
-            targets = jax.device_put(targets, self._data_sharding)
-            if mask is not None:
-                mask = jax.device_put(jnp.asarray(mask), self._data_sharding)
+        with obs.span("lm.h2d"):
+            tokens = jnp.asarray(tokens, jnp.int32)
+            if targets is None:
+                tokens, targets = tokens[:, :-1], tokens[:, 1:]
+            else:
+                targets = jnp.asarray(targets, jnp.int32)
+            if self._data_sharding is not None:
+                tokens = jax.device_put(tokens, self._data_sharding)
+                targets = jax.device_put(targets, self._data_sharding)
+                if mask is not None:
+                    mask = jax.device_put(jnp.asarray(mask),
+                                          self._data_sharding)
         if self._step is None:
             self._step = self._build_step()
         if getattr(self, "_rng", None) is None:
@@ -530,15 +573,18 @@ class TransformerLM:
             # host-side mirror of the (device-carried) step counter so the
             # per-step listener callback never forces a device->host fetch
             self._it_host = int(self.iteration)  # graftlint: disable=G001 -- one-time adoption sync, not per-step
-        (self.params, self.opt_state, self.iteration, self._rng,
-         loss) = self._step(self.params, self.opt_state, self.iteration,
-                            self._rng, tokens, targets, mask)
+        with obs.span("lm.step_call"):
+            (self.params, self.opt_state, self.iteration, self._rng,
+             loss) = self._step(self.params, self.opt_state, self.iteration,
+                                self._rng, tokens, targets, mask)
         # device scalar, synced lazily on read (the MLN discipline): the
         # host loop must not block on a device->host fetch every step
         self.score_ = loss
         self._it_host += 1
-        for lst in self.listeners:
-            lst.iteration_done(self, self._it_host)
+        if self.listeners:
+            with obs.span("lm.listeners"):
+                for lst in self.listeners:
+                    lst.iteration_done(self, self._it_host)
         return self.score_
 
     def fit(self, data, *, epochs=1):

@@ -1,179 +1,39 @@
-"""Host-side trace spans exported as Chrome trace-event JSON.
+"""Host-side spans, written into the profiler's own trace.
 
-``with span("fit.dispatch_group"):`` records a complete ("ph": "X") event
-with microsecond monotonic timestamps and the OS thread id, so
-prefetch-worker, trainer, and coordinator spans interleave correctly on
-separate tracks when the file is opened in Perfetto (ui.perfetto.dev) or
-``chrome://tracing``. The span file loads SIDE-BY-SIDE with a
-``jax.profiler`` capture (ProfilerListener): the XLA trace names where a
-slow step spends device time, the span file names which host phase
-(prefetch wait, dispatch, nanguard sync, checkpoint commit) the step loop
-spent wall-clock in — docs/OBSERVABILITY.md shows the overlay workflow.
+``with span("fit.dispatch_group"):`` is a ``jax.profiler.TraceAnnotation``
+named ``dl4j:fit.dispatch_group``. Whenever anyone traces the process
+(``ProfilerListener``, ``jax.profiler.trace``, the benchmark's ``--trace 1``)
+the host plane of the SAME ``.xplane.pb`` that holds the device ops then holds
+the program's spans, on the line of the thread that ran them and on the device
+events' clock: prefetch-worker and trainer spans interleave with the XLA ops
+they caused, and an idle gap of the device can be laid to what the host was
+doing in it (``python3 -m benchmark.scope_reduce <file.xplane.pb>``,
+docs/OBSERVABILITY.md).
 
-Enablement is ``DL4J_TPU_TRACE_DIR``: empty (the default) makes
-``span()`` return a shared no-op context manager (near-zero overhead —
-one env read + branch); set, events accumulate in a bounded in-process
-buffer and :func:`flush` rewrites ``<dir>/trace_<pid>.json`` with the
-full buffer (the models' ``fit()`` flushes at its boundary, and an atexit
-hook catches runs that never reach one). The buffer is bounded
-(``_MAX_EVENTS``); overflow drops new events and counts them in the
-``trace.dropped_events_total`` metric rather than growing without limit.
+There is no switch and no file of its own: outside a profiler session an
+annotation is one small object and a flag test, and records nothing.
 
-Like ``obs.metrics``, nothing here touches jax and every value recorded
-is host data — a span can never force a device sync (the G001 carve-out
-contract, docs/STATIC_ANALYSIS.md).
+Like ``obs.metrics``, a span takes host scalars only, never a device array,
+and never syncs (the G001 carve-out contract, docs/STATIC_ANALYSIS.md).
+``jax.profiler`` is imported on the first span, not with the package: a
+process that only reads metrics never loads it.
 """
 
 from __future__ import annotations
 
-import atexit
-import json
-import os
-import threading
-import time
+__all__ = ["span", "PREFIX"]
 
-__all__ = ["span", "add_span", "enabled", "trace_dir", "flush",
-           "reset_trace", "event_count"]
+PREFIX = "dl4j:"
 
-_MAX_EVENTS = 200_000
-
-_EVENTS = []
-_EVENTS_LOCK = threading.Lock()
-_SEEN_TIDS = set()          # tids that already emitted thread metadata
-_PID = os.getpid()
-
-
-def trace_dir():
-    """The span output directory (``DL4J_TPU_TRACE_DIR``; empty = off).
-    Read at call time, so tests/tools may set it after import."""
-    from deeplearning4j_tpu.config import env_str
-    return env_str("DL4J_TPU_TRACE_DIR")
-
-
-def enabled():
-    return bool(trace_dir())
-
-
-def _now_us():
-    # monotonic microseconds; Perfetto needs only a consistent epoch
-    return time.perf_counter_ns() // 1_000
-
-
-def _append(event, tname):
-    tid = event["tid"]
-    with _EVENTS_LOCK:
-        if len(_EVENTS) >= _MAX_EVENTS:
-            from deeplearning4j_tpu.obs import metrics
-            metrics.counter(
-                "trace.dropped_events_total",
-                "Span events dropped because the trace buffer is full").inc()
-            return
-        if tid not in _SEEN_TIDS:
-            _SEEN_TIDS.add(tid)
-            _EVENTS.append({"ph": "M", "name": "thread_name", "pid": _PID,
-                            "tid": tid, "args": {"name": tname}})
-        _EVENTS.append(event)
-
-
-class _NullSpan:
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-_NULL_SPAN = _NullSpan()
-
-
-class _Span:
-    __slots__ = ("name", "args", "_t0")
-
-    def __init__(self, name, args):
-        self.name = name
-        self.args = args
-
-    def __enter__(self):
-        self._t0 = _now_us()
-        return self
-
-    def __exit__(self, *exc):
-        t1 = _now_us()
-        th = threading.current_thread()
-        event = {"ph": "X", "name": self.name, "cat": self.name.split(".")[0],
-                 "ts": self._t0, "dur": t1 - self._t0,
-                 "pid": _PID, "tid": th.native_id}
-        if self.args:
-            event["args"] = self.args
-        _append(event, th.name)
-        return False
+_annotation = None
 
 
 def span(name, **args):
-    """Context manager recording its body as one complete trace event
-    (no-op singleton when tracing is off). ``args`` become the event's
-    ``args`` payload — keep them small, JSON-able host values."""
-    if not enabled():
-        return _NULL_SPAN
-    return _Span(name, args)
-
-
-def add_span(name, start, duration, tid=None, **args):
-    """Record an externally timed span: ``start`` is a
-    ``time.perf_counter()`` reading, ``duration`` seconds. For code that
-    measures a window itself (coordinator rounds) instead of wrapping a
-    block."""
-    if not enabled():
-        return
-    th = threading.current_thread()
-    event = {"ph": "X", "name": name, "cat": name.split(".")[0],
-             "ts": int(start * 1e6), "dur": int(duration * 1e6),
-             "pid": _PID, "tid": th.native_id if tid is None else tid}
-    if args:
-        event["args"] = args
-    _append(event, th.name)
-
-
-def event_count():
-    with _EVENTS_LOCK:
-        return len(_EVENTS)
-
-
-def reset_trace():
-    """Drop every buffered event (test boundary helper)."""
-    with _EVENTS_LOCK:
-        _EVENTS.clear()
-        _SEEN_TIDS.clear()
-
-
-def flush(path=None):
-    """Rewrite the trace file with the FULL buffer (events accumulate
-    across fits, so one Perfetto-loadable file covers the whole run).
-    Returns the path written, or None when tracing is off and no explicit
-    ``path`` was given."""
-    if path is None:
-        d = trace_dir()
-        if not d:
-            return None
-        os.makedirs(d, exist_ok=True)
-        path = os.path.join(d, f"trace_{_PID}.json")
-    with _EVENTS_LOCK:
-        events = list(_EVENTS)
-    payload = {"traceEvents": events, "displayTimeUnit": "ms"}
-    tmp = path + ".tmp"
-    with open(tmp, "w") as f:
-        json.dump(payload, f)
-    os.replace(tmp, path)   # readers never see a half-written trace
-    return path
-
-
-@atexit.register
-def _flush_at_exit():
-    # a run that dies before a fit boundary still gets its spans
-    if enabled() and event_count():
-        try:
-            flush()
-        except OSError:
-            pass
+    """Context manager marking its body as one span of the calling thread in
+    the profiler's trace. ``args`` become the event's arguments — keep them
+    small host values."""
+    global _annotation
+    if _annotation is None:
+        from jax.profiler import TraceAnnotation
+        _annotation = TraceAnnotation
+    return _annotation(PREFIX + name, **args)

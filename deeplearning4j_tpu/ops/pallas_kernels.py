@@ -615,6 +615,7 @@ def _lstm_cell_call(zx, h_prev, c_prev, rw, peep):
     vmem = lambda: pl.BlockSpec(memory_space=pltpu.VMEM)
     h, c = pl.pallas_call(
         kernel,
+        name="lstm_fwd",
         out_shape=[jax.ShapeDtypeStruct(h_prev.shape, h_prev.dtype),
                    jax.ShapeDtypeStruct(c_prev.shape, c_prev.dtype)],
         in_specs=[vmem() for _ in range(5)],
@@ -636,6 +637,7 @@ def _lstm_cell_bwd_call(zx, h_prev, c_prev, rw, peep, dh, dc):
     vmem = lambda: pl.BlockSpec(memory_space=pltpu.VMEM)
     dzx, dhp, dcp, drw, dp = pl.pallas_call(
         kernel,
+        name="lstm_bwd",
         out_shape=[jax.ShapeDtypeStruct(zx.shape, zx.dtype),
                    jax.ShapeDtypeStruct(h_prev.shape, h_prev.dtype),
                    jax.ShapeDtypeStruct(c_prev.shape, c_prev.dtype),

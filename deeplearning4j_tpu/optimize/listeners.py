@@ -65,9 +65,16 @@ class ProfilerListener(IterationListener):
     ``jax.profiler`` trace over a window of training iterations).
 
     Captures iterations [start_iteration, start_iteration + num_iterations)
-    into ``log_dir`` as a TensorBoard-loadable trace (``.trace.json.gz``
-    under ``<log_dir>/plugins/profile/*``) — op-level device timelines, the
-    data that names where a slow step actually spends its time.
+    into ``log_dir``: one ``<log_dir>/plugins/profile/<time>/*.xplane.pb``
+    (TensorBoard- and Perfetto-loadable) that holds, on one clock, the device
+    ops under the names of the program's ``jax.named_scope``s and the host's
+    ``obs.span``s (``dl4j:<name>``) on the line of their thread.
+    ``python3 -m benchmark.scope_reduce <that file>`` prints device time by
+    scope, the spans by thread and the device's idle gaps by span.
+
+    The trace is taken without the Python tracer and at host tracer level 1,
+    which keeps the annotations: the defaults make the file ten times the
+    size and ``stop_trace`` minutes long (PERF.md section 6, PR 25).
 
     >>> net.set_listeners([ProfilerListener("/tmp/prof", start_iteration=10)])
     """
@@ -99,7 +106,10 @@ class ProfilerListener(IterationListener):
         if (not self._active and not self.captured
                 and iteration >= self.start_iteration):
             self._sync(model)
-            jax.profiler.start_trace(self.log_dir)
+            options = jax.profiler.ProfileOptions()
+            options.python_tracer_level = 0
+            options.host_tracer_level = 1
+            jax.profiler.start_trace(self.log_dir, profiler_options=options)
             self._active = True
             self._stop_at = iteration + self.num_iterations
             return

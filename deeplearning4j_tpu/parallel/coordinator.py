@@ -273,7 +273,6 @@ class PyCoordinator:
             _OBS_TIMEOUTS.inc()
         elif status == STATUS_PEER_DEAD:
             _OBS_DEAD_PEERS.inc()
-        obs.add_span("collective.round", e.t0, dur, status=status)
 
     def _fail_entry(self, tag, e, status, message):
         """Fail a round (caller holds the lock): every current waiter of

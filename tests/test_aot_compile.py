@@ -152,6 +152,55 @@ def test_sharded_lm_step_compiles_for_2x2_mesh(v5e_devices, monkeypatch):
     assert "all-gather" in text   # level 3: params gathered just in time
 
 
+def test_lm_step_names_reach_the_chips_program(v5e, monkeypatch):
+    """What the chip's compiler makes of the step's ``jax.named_scope``s
+    (``models/transformer.SCOPES``). XLA names a custom call after the last
+    piece of its name stack before the primitive, and the benchmark's
+    accepted readers (``benchmark/layer_metrics/flash_*_roofline.py``, which
+    a PR may not edit) tell the forward flash kernel from the backward ones
+    by ``%jvp`` / ``%transpose`` at the start of that name: a scope nested
+    inside ``block.attn``, a ``name=`` on the flash ``pallas_call``s or a
+    scope around ``value_and_grad`` would turn both metrics silent."""
+    import re
+
+    from benchmark import trace_reduce
+    from benchmark.layer_metrics import flash_bwd_roofline, flash_fwd_roofline
+    from deeplearning4j_tpu.models.transformer import (TransformerConfig,
+                                                       TransformerLM)
+
+    monkeypatch.setenv("DL4J_TPU_LM_ATTN", "pallas")
+    layers = 2
+    lm = TransformerLM(TransformerConfig(
+        vocab_size=512, max_len=128, d_model=128, n_heads=2, n_layers=layers,
+        d_ff=512, compute_dtype="bfloat16", block_size=128))
+    params, opt = jax.eval_shape(lambda: (lm.init().params, lm.opt_state))
+    lm.params = lm.opt_state = None
+    sds = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e)
+    tokens = jax.ShapeDtypeStruct((2, 128), jnp.int32, sharding=v5e)
+    text = lm._build_step().lower(
+        jax.tree.map(sds, params), jax.tree.map(sds, opt),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=v5e),
+        jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=v5e),
+        tokens, tokens, None).compile().as_text()
+    # a profiler's event is named by the instruction's text from its name on
+    kernels = [line.strip() for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    fwd = [k for k in kernels if flash_fwd_roofline.is_flash_fwd(k)]
+    bwd = [k for k in kernels if flash_bwd_roofline.is_flash_bwd(k)]
+    assert (len(kernels), len(fwd), len(bwd)) == (3 * layers, layers,
+                                                  2 * layers)
+    stacks = {re.search(r'op_name="([^"]*)"', k).group(1) for k in kernels}
+    assert stacks == {"jit(step)/jvp(block.attn)/pallas_call",
+                      "jit(step)/transpose(jvp(block.attn))/pallas_call"}
+    # the ledger's breakdown keeps one row for all layers' copies of a kernel
+    assert len({trace_reduce.label(k) for k in fwd}) == 1
+    assert len({trace_reduce.label(k) for k in bwd}) == 2   # dQ; dK, dV
+    # and the other scopes are in the compiled program's metadata
+    for scope in ("embed", "block.ln1", "block.mlp", "logits_loss",
+                  "optimizer"):
+        assert re.search(rf'op_name="jit\(step\)/[^"]*{scope}[)/]', text), scope
+
+
 def _smoke(*args, env=None):
     return subprocess.run([sys.executable, SMOKE, *args], cwd=REPO,
                           env=env, capture_output=True, text=True,

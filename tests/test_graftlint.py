@@ -2195,11 +2195,12 @@ def test_g016_guards_the_real_hot_path_against_flowed_sync():
     sources = _package_sources()
     mln = os.path.join(REPO, "deeplearning4j_tpu", "models",
                        "multi_layer_network.py")
-    anchor = "        if guard:\n            self._nanguard_record(skipped)"
+    anchor = ("            if guard:\n"
+              "                self._nanguard_record(skipped)")
     assert anchor in sources[mln]
-    seeded = ("        healthy = step_all_finite(score, grads)\n"
-              "        if healthy:\n"
-              "            self._streak = self._streak + 1\n" + anchor)
+    seeded = ("            healthy = step_all_finite(score, grads)\n"
+              "            if healthy:\n"
+              "                self._streak = self._streak + 1\n" + anchor)
     mln_src = sources[mln].replace(anchor, seeded, 1)
     alone = lint_sources({mln: mln_src})
     assert not any(f.rule_id == "G016" and f.line and "healthy"

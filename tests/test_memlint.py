@@ -864,13 +864,13 @@ class TestCrossMethodSelfAttr:
                            "multi_layer_network.py")
         with open(mln, encoding="utf-8") as fh:
             src = fh.read()
-        w_anchor = ("        if guard:\n"
-                    "            self._nanguard_record(skipped)")
+        w_anchor = ("            if guard:\n"
+                    "                self._nanguard_record(skipped)")
         r_anchor = "        sig = self._output_signature(x, fmask)"
         assert w_anchor in src and r_anchor in src
         seeded = src.replace(
             w_anchor,
-            "        self._last_finite = step_all_finite(score, grads)\n"
+            "            self._last_finite = step_all_finite(score, grads)\n"
             + w_anchor, 1)
         seeded = seeded.replace(
             r_anchor,

@@ -1,14 +1,16 @@
 """Unified observability layer (deeplearning4j_tpu/obs/): metric registry,
 trace spans, instrumentation through the training stack, export surfaces.
 
-The acceptance contract under test (ISSUE 6): with DL4J_TPU_METRICS=1 and
-tracing on, a fused fit still compiles 0 programs in-fit against 1 train
-signature (instrumentation adds no recompiles or hot-path syncs), the
-exported trace file parses as Chrome trace-event JSON with spans from >=2
-distinct threads, and the PR-3 fuse telemetry counts identically through
-its migrated registry mirror.
+The acceptance contract under test (ISSUE 6, re-stated by ISSUE 26): with
+DL4J_TPU_METRICS=1 and a profiler session running, a fused fit still compiles
+0 programs in-fit against 1 train signature (instrumentation adds no
+recompiles or hot-path syncs), the session's one ``.xplane.pb`` holds the
+program's spans (``dl4j:<name>``) from >=2 distinct threads, and the PR-3 fuse
+telemetry counts identically through its migrated registry mirror.
 """
 
+import contextlib
+import glob
 import json
 import os
 import threading
@@ -22,7 +24,6 @@ from deeplearning4j_tpu.datasets.dataset import ArrayDataSetIterator, DataSet
 from deeplearning4j_tpu.models.multi_layer_network import MultiLayerNetwork
 from deeplearning4j_tpu.nn.layers import DenseLayer, OutputLayer
 from deeplearning4j_tpu.obs import metrics as obs_metrics
-from deeplearning4j_tpu.obs import tracing as obs_tracing
 
 
 def make_data(n=120, d=4, c=3, seed=0):
@@ -43,10 +44,34 @@ def mlp(seed=1):
 @pytest.fixture(autouse=True)
 def _clean_registry():
     obs.reset_metrics()
-    obs_tracing.reset_trace()
     yield
     obs.reset_metrics()
-    obs_tracing.reset_trace()
+
+
+@contextlib.contextmanager
+def profiler_session(directory):
+    """A CPU profiler capture with the options ProfilerListener and the
+    benchmark use; yields a function that reads the program's spans back
+    out of the session's one file once the block has ended."""
+    import jax
+
+    from benchmark import scope_reduce
+
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 1
+    jax.profiler.start_trace(str(directory), profiler_options=options)
+
+    def spans():
+        found = glob.glob(os.path.join(str(directory), "**", "*.xplane.pb"),
+                          recursive=True)
+        assert len(found) == 1
+        return scope_reduce.load(found[0])[1]
+
+    try:
+        yield spans
+    finally:
+        jax.profiler.stop_trace()
 
 
 # ---------------------------------------------------------------------------
@@ -159,48 +184,58 @@ class TestRegistry:
 # trace spans
 # ---------------------------------------------------------------------------
 class TestTracing:
-    def test_disabled_by_default_records_nothing(self):
-        with obs.span("t.nothing"):
+    def test_outside_a_session_a_span_writes_nothing(self, tmp_path,
+                                                     monkeypatch):
+        """No switch, no buffer, no file of its own: with no profiler
+        session a span is a no-op that touches nothing on disk."""
+        monkeypatch.chdir(tmp_path)
+        monkeypatch.setenv("TMPDIR", str(tmp_path))
+        with obs.span("t.nothing", items=3):
             pass
-        assert obs_tracing.event_count() == 0
-        assert obs_tracing.flush() is None
+        assert os.listdir(tmp_path) == []
+        assert not hasattr(obs, "flush_trace")
+        assert not hasattr(obs, "add_span")
 
-    def test_spans_across_threads_export_chrome_trace_json(
-            self, tmp_path, monkeypatch):
-        monkeypatch.setenv("DL4J_TPU_TRACE_DIR", str(tmp_path))
-
-        def worker():
-            with obs.span("t.worker_phase", items=3):
-                pass
-
-        t = threading.Thread(target=worker, name="obs-test-worker")
-        t.start()
-        t.join()
-        with obs.span("t.main_phase"):
+    def test_spans_of_two_threads_lie_on_separate_lines_of_the_host_plane(
+            self, tmp_path):
+        with obs.span("t.before_the_session"):
             pass
-        obs.add_span("t.manual", 1.0, 0.25, status=0)
-        path = obs_tracing.flush()
-        doc = json.loads(open(path).read())
-        events = doc["traceEvents"]
-        spans = [e for e in events if e["ph"] == "X"]
-        assert {e["name"] for e in spans} == {"t.worker_phase", "t.main_phase",
-                                              "t.manual"}
-        for e in spans:   # chrome trace-event required fields
-            assert {"name", "ph", "ts", "dur", "pid", "tid"} <= set(e)
-        assert len({e["tid"] for e in spans}) >= 2
-        manual = next(e for e in spans if e["name"] == "t.manual")
-        assert manual["ts"] == 1_000_000 and manual["dur"] == 250_000
-        meta = [e for e in events if e["ph"] == "M"]
-        assert "obs-test-worker" in {e["args"]["name"] for e in meta}
+        with profiler_session(tmp_path) as spans:
+            def worker():
+                with obs.span("t.worker_phase", items=3):
+                    pass
 
-    def test_buffer_is_bounded(self, tmp_path, monkeypatch):
-        monkeypatch.setenv("DL4J_TPU_TRACE_DIR", str(tmp_path))
-        monkeypatch.setattr(obs_tracing, "_MAX_EVENTS", 10)
-        for _ in range(50):
-            with obs.span("t.flood"):
+            t = threading.Thread(target=worker, name="obs-test-worker")
+            t.start()
+            t.join()
+            with obs.span("t.main_phase"):
+                with obs.span("t.inner"):
+                    pass
+        got = spans()
+        by_name = {name: (thread, start, dur)
+                   for thread, name, start, dur in got}
+        # the annotation's arguments are no part of the span's name
+        assert set(by_name) == {"t.worker_phase", "t.main_phase", "t.inner"}
+        assert by_name["t.worker_phase"][0] != by_name["t.main_phase"][0]
+        assert by_name["t.inner"][0] == by_name["t.main_phase"][0]
+        _, start, dur = by_name["t.main_phase"]
+        _, inner_start, inner_dur = by_name["t.inner"]
+        assert start <= inner_start and inner_start + inner_dur <= start + dur
+
+    def test_the_raw_event_carries_the_prefix_and_the_arguments(
+            self, tmp_path):
+        from jax.profiler import ProfileData
+        with profiler_session(tmp_path) as spans:
+            with obs.span("t.group", steps=4):
                 pass
-        assert obs_tracing.event_count() <= 10
-        assert obs.metrics.value("trace.dropped_events_total") > 0
+        assert [n for _, n, _, _ in spans()] == ["t.group"]
+        path, = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                          recursive=True)
+        events = {e.name: dict(e.stats)
+                  for plane in ProfileData.from_file(path).planes
+                  if plane.name == "/host:CPU"
+                  for line in plane.lines for e in line.events}
+        assert events["dl4j:t.group"]["steps"] == 4
 
 
 # ---------------------------------------------------------------------------
@@ -209,45 +244,48 @@ class TestTracing:
 class TestInstrumentedFit:
     def test_fused_fit_records_and_adds_no_recompiles(
             self, tmp_path, monkeypatch):
-        """The tentpole acceptance: metrics on + tracing on + periodic
-        checkpointing; the instrumented fused fit keeps 0 in-fit compiles
-        and ONE train signature, the registry sees the groups/steps/commit,
-        and the trace has spans from the trainer AND prefetch threads."""
+        """The tentpole acceptance: metrics on + a profiler session running
+        + periodic checkpointing; the instrumented fused fit keeps 0 in-fit
+        compiles and ONE train signature, the registry sees the
+        groups/steps/commit, and the session's trace holds the program's
+        spans from the trainer AND prefetch threads."""
         from tools.compile_counter import CompileCounter
 
         monkeypatch.setenv("DL4J_TPU_METRICS", "1")
-        monkeypatch.setenv("DL4J_TPU_TRACE_DIR", str(tmp_path / "spans"))
         monkeypatch.setenv("DL4J_TPU_FUSE_STEPS", "4")
         ckdir = tmp_path / "ck"
         X, Y = make_data(120)    # 15 batches of 8 -> 4 groups (one short)
         net = mlp()
         it = ArrayDataSetIterator(X, Y, batch_size=8)
-        net.fit(it, checkpoint_every=8, checkpoint_dir=str(ckdir))
-        assert len(net._jit_train) == 1
-        assert obs.metrics.value("train.steps_total") == 15
-        assert obs.metrics.value("train.dispatch_groups_total") == 4
-        h = obs.histogram("train.dispatch_group_seconds")
-        assert h.count == 4 and h.sum > 0
-        assert obs.metrics.value("checkpoint.commits_total") >= 1
-        assert obs.metrics.value("checkpoint.bytes_written_total") > 0
-        assert obs.histogram("checkpoint.commit_seconds").count >= 1
-        assert obs.metrics.value("prefetch.fused_groups_total") == 4
-        assert obs.histogram("prefetch.consumer_wait_seconds").count > 0
-        # second fit, warm cache: instrumentation must not compile anything
-        with CompileCounter() as cc:
-            net.fit(ArrayDataSetIterator(X, Y, batch_size=8))
-        assert cc.count == 0
-        assert len(net._jit_train) == 1
-        # trace file: valid Chrome trace-event JSON, >=2 distinct threads
-        trace_path = tmp_path / "spans" / f"trace_{os.getpid()}.json"
-        events = json.loads(trace_path.read_text())["traceEvents"]
-        spans = [e for e in events if e["ph"] == "X"]
-        names = {e["name"] for e in spans}
+        with profiler_session(tmp_path / "prof") as spans:
+            net.fit(it, checkpoint_every=8, checkpoint_dir=str(ckdir))
+            assert len(net._jit_train) == 1
+            assert obs.metrics.value("train.steps_total") == 15
+            assert obs.metrics.value("train.dispatch_groups_total") == 4
+            h = obs.histogram("train.dispatch_group_seconds")
+            assert h.count == 4 and h.sum > 0
+            assert obs.metrics.value("checkpoint.commits_total") >= 1
+            assert obs.metrics.value("checkpoint.bytes_written_total") > 0
+            assert obs.histogram("checkpoint.commit_seconds").count >= 1
+            assert obs.metrics.value("prefetch.fused_groups_total") == 4
+            assert obs.histogram("prefetch.consumer_wait_seconds").count > 0
+            # second fit, warm cache, the session still running:
+            # instrumentation must not compile anything
+            with CompileCounter() as cc:
+                net.fit(ArrayDataSetIterator(X, Y, batch_size=8))
+            assert cc.count == 0
+            assert len(net._jit_train) == 1
+        got = spans()
+        names = {name for _, name, _, _ in got}
         assert {"fit.dispatch_group", "fit.nanguard_sync", "prefetch.pull",
+                "prefetch.stack_group", "prefetch.wait",
                 "fit.checkpoint_commit", "checkpoint.write"} <= names
-        assert len({e["tid"] for e in spans}) >= 2
-        group_spans = [e for e in spans if e["name"] == "fit.dispatch_group"]
-        assert sum(e["args"]["steps"] for e in group_spans[:4]) == 15
+        threads = {name: {t for t, n, _, _ in got if n == name}
+                   for name in names}
+        assert threads["fit.dispatch_group"] == threads["prefetch.wait"]
+        assert not threads["fit.dispatch_group"] & threads["prefetch.pull"]
+        # two fits of 4 groups each
+        assert sum(n == "fit.dispatch_group" for _, n, _, _ in got) == 8
 
     def test_unfused_fit_records_step_histogram(self, monkeypatch):
         monkeypatch.setenv("DL4J_TPU_FUSE_STEPS", "1")
@@ -348,6 +386,40 @@ class TestProfilerListenerHardening:
         lst.close()            # and stays idempotent
         assert not lst.captured
 
+    def test_capture_is_light_and_holds_the_programs_spans(
+            self, tmp_path, monkeypatch):
+        """The listener traces without the Python tracer, at host tracer
+        level 1 (what keeps the annotations), and its one file is what
+        ``python3 -m benchmark.scope_reduce`` reads."""
+        import jax
+
+        from benchmark import scope_reduce
+        from deeplearning4j_tpu.optimize.listeners import ProfilerListener
+
+        seen = {}
+        start = jax.profiler.start_trace
+
+        def spy(directory, **kwargs):
+            seen.update(kwargs)
+            return start(directory, **kwargs)
+
+        monkeypatch.setattr(jax.profiler, "start_trace", spy)
+        monkeypatch.setenv("DL4J_TPU_FUSE_STEPS", "1")
+        X, Y = make_data(64)
+        net = mlp()
+        lst = ProfilerListener(str(tmp_path), start_iteration=2,
+                               num_iterations=3, log_fn=lambda *a: None)
+        net.set_listeners([lst])
+        net.fit(ArrayDataSetIterator(X, Y, batch_size=8))
+        assert lst.captured
+        options = seen["profiler_options"]
+        assert (options.python_tracer_level,
+                options.host_tracer_level) == (0, 1)
+        path, = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                          recursive=True)
+        names = [n for _, n, _, _ in scope_reduce.load(path)[1]]
+        assert names.count("fit.step") == 3
+
     def test_double_stop_and_stop_without_start_are_no_ops(
             self, tmp_path, monkeypatch):
         """Even if jax raises on stop (no trace running / already
@@ -362,7 +434,8 @@ class TestProfilerListenerHardening:
             calls.append(1)
             raise RuntimeError("No profile started")
 
-        monkeypatch.setattr(jax.profiler, "start_trace", lambda d: None)
+        monkeypatch.setattr(jax.profiler, "start_trace",
+                            lambda d, **options: None)
         monkeypatch.setattr(jax.profiler, "stop_trace", fake_stop)
         lst = ProfilerListener(str(tmp_path), start_iteration=0,
                                num_iterations=1, log_fn=lambda *a: None)
@@ -388,7 +461,8 @@ class TestProfilerListenerHardening:
         import jax
         from deeplearning4j_tpu.optimize.listeners import ProfilerListener
         stops = []
-        monkeypatch.setattr(jax.profiler, "start_trace", lambda d: None)
+        monkeypatch.setattr(jax.profiler, "start_trace",
+                            lambda d, **options: None)
         monkeypatch.setattr(jax.profiler, "stop_trace",
                             lambda: stops.append(1))
         lst = ProfilerListener(str(tmp_path), start_iteration=0,
@@ -415,7 +489,8 @@ class TestProfilerListenerHardening:
             self, tmp_path, monkeypatch):
         import jax
         from deeplearning4j_tpu.optimize.listeners import ProfilerListener
-        monkeypatch.setattr(jax.profiler, "start_trace", lambda d: None)
+        monkeypatch.setattr(jax.profiler, "start_trace",
+                            lambda d, **options: None)
         monkeypatch.setattr(jax.profiler, "stop_trace", lambda: None)
         logged = []
         lst = ProfilerListener(str(tmp_path), start_iteration=0,

@@ -270,9 +270,11 @@ def _is_obs_module(path):
     closure pulls their bodies in — where the ``float(v)`` coercions and
     clock reads that ARE the implementation would spray G001/G004 false
     positives at every instrumented seam. The contract that makes the
-    carve-out sound (docs/OBSERVABILITY.md): obs never imports jax and
-    records HOST scalars only — a caller handing it a device value performs
-    that sync itself, at its own call site, where G001 still bites."""
+    carve-out sound (docs/OBSERVABILITY.md): obs records HOST scalars only,
+    never takes a device array and never syncs (its one lazy import of jax
+    is ``jax.profiler``'s annotation, which touches no device) — a caller
+    handing it a device value performs that sync itself, at its own call
+    site, where G001 still bites."""
     p = path.replace("\\", "/")
     return "deeplearning4j_tpu/obs/" in p
 
