@@ -7,7 +7,11 @@ grid over (batch·heads, query blocks), K/V streamed block-by-block with the
 running-max/sum recurrence — no O(T²) score materialization in HBM) and the
 matching FlashAttention-2-style backward (a dQ kernel streaming K/V blocks
 and a dK/dV kernel streaming Q/dO blocks, both recomputing P from the
-forward's saved logsumexp — nothing O(T²) is ever stored).
+forward's saved logsumexp — nothing O(T²) is ever stored). Inside a block
+the three share one step: ``flash_plan`` derives heads a grid step and
+compute tiles from the shapes, ``_branches`` says which blocks need a mask
+and which block of a row writes the accumulators, ``_tiles`` which tiles of
+a block hold anything to compute.
 
 ``DL4J_TPU_FLASH_BWD=scan`` falls the backward to the mathematically
 identical lax.scan implementation
@@ -23,6 +27,7 @@ an error, so an interpreted kernel can never stand in for a compiled one.
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -37,6 +42,7 @@ NEG_INF = -1e30
 # last two dims of every block divisible by (8, 128) or equal to the
 # array's, which a [1, block_q] block of an [n, T] array is not.
 _LANES = 128
+_SUBLANES = 8
 
 
 def _interpret_mode():
@@ -55,17 +61,81 @@ def pallas_supported():
     return _interpret_mode() or jax.default_backend() == "tpu"
 
 
-def _causal_mask(s, qi, kb, block_q, block_k, window=None):
-    """Mask entries of the [bq, bk] scores with q_pos < k_pos (and, with
-    ``window``, entries more than window-1 positions in the past) to
-    NEG_INF."""
-    shape = s.shape
-    q_pos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
-    k_pos = kb * block_k + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
-    keep = q_pos >= k_pos
+class FlashPlan(NamedTuple):
+    """What one grid step of the flash kernels works on, beyond the
+    ``(block_q, block_k)`` block the DMA moves: ``heads`` rows of n
+    (batch·heads) a step, and the ``(tile_q, tile_k)`` tile of scores the
+    products and the softmax are computed on inside the block."""
+    heads: int
+    tile_q: int
+    tile_k: int
+
+
+# what the hungriest kernel's blocks and scratch may take of a core's VMEM
+# (16 MiB scoped by default on a v5e; a raised limit read slower there), the
+# tiles' temporaries left aside
+_VMEM_BUDGET = 8 * 2 ** 20
+# the rows of a step are unrolled: each is a copy of the step's code; and a
+# step's rows lie in one group of _SUBLANES rows of n (_stat_rows)
+_MAX_HEADS = 8
+
+
+def _flash_vmem_bytes(heads, block_q, block_k, d, itemsize):
+    """VMEM of the hungriest of the three kernels, the dQ one: every input
+    and output block twice (the pipeline double-buffers them), the
+    lane-replicated lse and delta blocks among them, and the float32
+    accumulator."""
+    q_like = block_q * d * itemsize            # q, dO, dQ
+    k_like = block_k * d * itemsize            # k, v
+    stats = block_q * _LANES * 4               # lse, delta
+    return heads * (2 * (3 * q_like + 2 * k_like + 2 * stats)
+                    + block_q * d * 4)
+
+
+def _tile(block):
+    return 256 if block % 256 == 0 else block
+
+
+def flash_plan(n, t, d, itemsize, block_q, block_k, kv_group=1):
+    """The ``FlashPlan`` of a call, from its shapes alone (every reading:
+    PERF.md, PR 27, a v5e).
+
+    Tiles: 256 on a side of the block that divides by it, else the whole
+    side -- the step the kernels took before they had tiles. A [512, 512]
+    float32 tile of scores is four register files that live in VMEM
+    between every two vector passes, and a causal block on the diagonal
+    leaves out the tiles above it; 128-tiles leave out more and read
+    slower (more, smaller products), and Mosaic refuses a 128-wide piece
+    of a row of the dK/dV kernel's statistics at a row it only knows when
+    it runs.
+
+    Heads: a grid step has a fixed cost, and the rows of a step fill each
+    other's waits, so where a row of n is one or two blocks several rows
+    share a step: the largest power of two that divides n, up to
+    ``_MAX_HEADS``, whose blocks stay inside ``_VMEM_BUDGET``.
+    Grouped-query attention keeps one row a step: its K/V index map
+    serves one K/V head a step."""
+    heads = 1
+    if kv_group == 1 and t // block_q <= 2 and t // block_k <= 2:
+        while (n % (2 * heads) == 0 and 2 * heads <= _MAX_HEADS
+               and _flash_vmem_bytes(2 * heads, block_q, block_k, d,
+                                     itemsize) <= _VMEM_BUDGET):
+            heads *= 2
+    return FlashPlan(heads, _tile(block_q), _tile(block_k))
+
+
+def _keep(offset, shape, window, transposed=False):
+    """The in-mask entries of a tile of scores: q_pos >= k_pos, and with
+    ``window`` q_pos - k_pos < window. ``offset`` is q_pos - k_pos of the
+    tile's first entry (an int, or a traced scalar); queries run along dim
+    0, along dim 1 where ``transposed``."""
+    q_dim, k_dim = (1, 0) if transposed else (0, 1)
+    dist = (offset + jax.lax.broadcasted_iota(jnp.int32, shape, q_dim)
+            - jax.lax.broadcasted_iota(jnp.int32, shape, k_dim))
+    keep = dist >= 0
     if window is not None:
-        keep &= q_pos - k_pos < window
-    return jnp.where(keep, s, NEG_INF)
+        keep &= dist < window
+    return keep
 
 
 def _block_live(qi, kb, block_q, block_k, window):
@@ -77,76 +147,278 @@ def _block_live(qi, kb, block_q, block_k, window):
     return live
 
 
-def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
-                  acc_scr, *, block_q, block_k, causal, scale, window=None):
-    """One (batch·head, q-block, k-block) grid step. The innermost grid
-    dimension walks K/V blocks sequentially on the same core, so the VMEM
-    scratch accumulators (running max m, running sum l, unnormalized output)
-    persist across it — only one K/V block is VMEM-resident at a time, which
-    is what keeps T unbounded (the full-K/V variant OOMs VMEM at T≈8k).
+def _branches(qi, kb, *, causal, block_q, block_k, window, n_qb, n_kb,
+              inner):
+    """The ``(kind, first, condition)`` branches a grid step chooses from
+    (a condition of None: always). A block with no in-mask entry takes
+    none. ``inner`` names the blocks the innermost grid dimension walks,
+    "k" or "q"; ``first`` is whether the block is the first live one of
+    that walk, which writes the accumulators where the others add to them
+    (so nothing zero-fills them).
 
-    m/l are stored lane-replicated as [block_q, _LANES]."""
+    full  every pair of the block is in the mask: no mask is built
+    diag  the block on the diagonal of square blocks without a window: the
+          mask is the same in every such block, and tiles above the
+          diagonal are left out
+    edge  any other block that the diagonal or the window's edge crosses:
+          every tile masked by its positions in the sequence"""
+    aligned = causal and window is None and block_q == block_k
+    if not causal:
+        kinds = [("full", None)]
+    elif aligned:
+        kinds = ([("diag", None)] if n_qb == 1 else
+                 [("diag", kb == qi), ("full", kb < qi)])
+    else:
+        full = (kb + 1) * block_k - 1 <= qi * block_q
+        if window is not None:
+            full &= (qi + 1) * block_q - 1 - kb * block_k < window
+        live = _block_live(qi, kb, block_q, block_k, window)
+        kinds = [("full", full), ("edge", live & jnp.logical_not(full))]
+    if inner == "k":
+        i, n_inner = kb, n_kb
+        lowest = _clamp_to_live_k(qi, 0, block_q, block_k, causal, window)
+    else:
+        i, n_inner = qi, n_qb
+        lowest = _clamp_to_live_q(0, kb, block_q, block_k, causal, window,
+                                  n_qb)
+    firsts = ([(True, None)] if n_inner == 1 else
+              [(True, i == lowest), (False, i != lowest)])
+
+    def possible(kind, first):
+        if not aligned:
+            return True
+        if inner == "q":    # a K block meets its diagonal block first
+            return first == (kind == "diag")
+        # K block 0 is first; a full block after it takes 3 blocks a row
+        return first or kind == "diag" or n_qb > 2
+
+    def both(a, b):
+        return b if a is None else a if b is None else a & b
+
+    return [(kind, first, both(c, when)) for kind, c in kinds
+            for first, when in firsts if possible(kind, first)]
+
+
+def _tiles(kind, block_q, block_k, plan):
+    """``[(q0, k0, masked)]``: the tiles of a block of ``kind`` that hold an
+    in-mask entry, by their first row and column inside the block."""
+    out = []
+    for q0 in range(0, block_q, plan.tile_q):
+        for k0 in range(0, block_k, plan.tile_k):
+            if kind == "diag":
+                if k0 > q0 + plan.tile_q - 1:
+                    continue
+                masked = k0 + plan.tile_k - 1 > q0
+            else:
+                masked = kind == "edge"
+            out.append((q0, k0, masked))
+    return out
+
+
+def _strips(tiles, by):
+    """``tiles`` grouped by their first row (``by`` 0) or column (1):
+    ``[(start, [(other start, masked)])]``."""
+    strips = {}
+    for tile in tiles:
+        strips.setdefault(tile[by], []).append((tile[1 - by], tile[2]))
+    return sorted(strips.items())
+
+
+def _offset(kind, qi, kb, block_q, block_k, q0, k0):
+    """q_pos - k_pos of a tile's first entry: inside a diagonal block the
+    block's own position cancels."""
+    local = q0 - k0
+    return local if kind == "diag" else qi * block_q - kb * block_k + local
+
+
+def _run_branches(branches, heads, fn):
+    """``fn(h, kind, first)`` for each of the step's rows of n, under the
+    branch that holds. The rows are unrolled, not looped over: they share
+    nothing, and in one basic block the scheduler fills one row's waits (an
+    MXU result, a lane reduction) with the next row's work."""
+    from jax.experimental import pallas as pl
+
+    def step(kind, first):
+        for h in range(heads):
+            fn(h, kind, first)
+
+    for kind, first, condition in branches:
+        if condition is None:
+            step(kind, first)
+        else:
+            pl.when(condition)(functools.partial(step, kind, first))
+
+
+def _lanes(x, width):
+    """A per-row statistic held lane-replicated as [rows, _LANES], as
+    [rows, width] (or [rows, 1] to broadcast, where width is no multiple of
+    the lanes)."""
+    reps, rem = divmod(width, _LANES)
+    if rem:
+        return x[:, :1]
+    return x if reps == 1 else jnp.tile(x, (1, reps))
+
+
+_NT = (((1,), (1,)), ((), ()))     # a @ bᵀ
+_NN = (((1,), (0,)), ((), ()))     # a @ b
+
+
+def _dot(a, b, dims):
+    """Operands as they come (bfloat16 tiles go to the MXU as bfloat16),
+    float32 accumulation."""
+    return jax.lax.dot_general(a, b, dims,
+                               preferred_element_type=jnp.float32)
+
+
+def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch, plan,
+                  block_q, block_k, n_qb, n_kb, causal, scale, window=None):
+    """One (``plan.heads`` rows of batch·head, q-block, k-block) grid step.
+    The innermost grid dimension walks K/V blocks sequentially on the same
+    core, so the VMEM scratch accumulators (running max m, running sum l,
+    unnormalized output) persist across it — only one K/V block is
+    VMEM-resident at a time, which is what keeps T unbounded (the full-K/V
+    variant OOMs VMEM at T≈8k). With one K/V block a row (``n_kb`` 1)
+    there is nothing to carry and no scratch.
+
+    Inside the block a strip of ``plan.tile_q`` query rows takes ONE
+    online-softmax update over all its live tiles. Scores, m, l and the
+    accumulator are float32; P meets V in V's dtype.
+
+    m/l are stored lane-replicated as [heads, block_q, _LANES]."""
     from jax.experimental import pallas as pl
 
     qi = pl.program_id(1)
     kb = pl.program_id(2)
-    n_kb = pl.num_programs(2)
+    tq, tk = plan.tile_q, plan.tile_k
+    single = n_kb == 1
+    if not single:
+        m_scr, l_scr, acc_scr = scratch
 
-    @pl.when(kb == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+    def _compute(h, kind, first):
+        for q0, row in _strips(_tiles(kind, block_q, block_k, plan), 0):
+            rows = pl.ds(q0, tq)
+            q = q_ref[h, rows, :] * scale              # [tq, d]
+            s, v = [], []
+            for k0, masked in row:
+                s_t = _dot(q, k_ref[h, pl.ds(k0, tk), :], _NT)   # [tq, tk]
+                if masked:
+                    s_t = jnp.where(
+                        _keep(_offset(kind, qi, kb, block_q, block_k, q0,
+                                      k0), s_t.shape, window), s_t, NEG_INF)
+                s.append(s_t)
+                v.append(v_ref[h, pl.ds(k0, tk), :])
+            m_cur = functools.reduce(jnp.maximum, s).max(
+                axis=-1, keepdims=True)                # [tq, 1]
+            if first:
+                m_new = m_tile = m_cur
+            else:
+                m_prev = m_scr[h, rows, :]             # [tq, 128], lanes equal
+                m_new = jnp.maximum(m_prev, m_cur)     # broadcast over lanes
+                m_tile = _lanes(m_new, tk)
+                correction = jnp.exp(jnp.where(m_prev == NEG_INF, NEG_INF,
+                                               m_prev - m_new))
+            p = [jnp.exp(s_t - m_tile) for s_t in s]
+            l_cur = functools.reduce(jnp.add, p).sum(axis=-1, keepdims=True)
+            pv = functools.reduce(jnp.add, [
+                _dot(p_t.astype(v_t.dtype), v_t, _NN)
+                for p_t, v_t in zip(p, v)])            # [tq, d]
+            if single:
+                o_ref[h, rows, :] = (
+                    pv / jnp.maximum(l_cur, 1e-30)).astype(o_ref.dtype)
+                lse_ref[h, rows, :] = jnp.broadcast_to(
+                    _lse(m_new, l_cur), (tq, _LANES))
+            elif first:
+                m_scr[h, rows, :] = jnp.broadcast_to(m_new, (tq, _LANES))
+                l_scr[h, rows, :] = jnp.broadcast_to(l_cur, (tq, _LANES))
+                acc_scr[h, rows, :] = pv
+            else:
+                m_scr[h, rows, :] = m_new
+                l_scr[h, rows, :] = l_scr[h, rows, :] * correction + l_cur
+                acc_scr[h, rows, :] = (acc_scr[h, rows, :]
+                                       * correction[:, :1] + pv)
 
-    def _compute():
-        q = q_ref[0] * scale                       # [block_q, d]
-        k_blk = k_ref[0]                           # [block_k, d]
-        v_blk = v_ref[0]
-        s = jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)    # [block_q, block_k]
-        if causal:
-            s = _causal_mask(s, qi, kb, block_q, block_k, window=window)
-        m_prev = m_scr[...]                        # [block_q, 128], lanes equal
-        l_prev = l_scr[...]
-        m_cur = s.max(axis=-1, keepdims=True)      # [block_q, 1]
-        m_new = jnp.maximum(m_prev, m_cur)         # broadcast over lanes
-        correction = jnp.exp(jnp.where(m_prev == NEG_INF, NEG_INF,
-                                       m_prev - m_new))
-        p = jnp.exp(s - m_new[:, :1])
-        l_new = l_prev * correction + p.sum(axis=-1, keepdims=True)
-        m_scr[...] = m_new
-        l_scr[...] = l_new
-        acc_scr[...] = acc_scr[...] * correction[:, :1] + jax.lax.dot_general(
-            p, v_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    # blocks with no in-mask entry (above the diagonal, or entirely beyond
+    # the sliding window) take no branch: they contribute nothing
+    _run_branches(_branches(qi, kb, causal=causal, block_q=block_q,
+                            block_k=block_k, window=window, n_qb=n_qb,
+                            n_kb=n_kb, inner="k"),
+                  plan.heads, _compute)
 
-    if causal:
-        # blocks with no in-mask entry (above the diagonal, or entirely
-        # beyond the sliding window) contribute nothing — skip them
-        @pl.when(_block_live(qi, kb, block_q, block_k, window))
-        def _():
-            _compute()
-    else:
-        _compute()
-
-    @pl.when(kb == n_kb - 1)
-    def _finalize():
-        o_ref[0] = (acc_scr[...]
-                    / jnp.maximum(l_scr[...][:, :1], 1e-30)).astype(o_ref.dtype)
-        m_fin = m_scr[...]                         # [block_q, 128]
-        l_fin = l_scr[...]
-        # logsumexp residual for the backward's P recomputation, written
-        # lane-replicated like m/l (see _LANES). A fully masked row
-        # (l == 0; only padded rows can hit this) gets +LARGE so
-        # exp(s - lse) underflows to an exact 0 instead of NaN.
-        lse_ref[0] = jnp.where(l_fin > 0.0,
-                               m_fin + jnp.log(jnp.maximum(l_fin, 1e-30)),
-                               -NEG_INF)
+    if not single:
+        @pl.when(kb == n_kb - 1)
+        def _finalize():
+            for h in range(plan.heads):
+                l_fin = l_scr[h]                       # [block_q, 128]
+                o_ref[h] = (acc_scr[h] / jnp.maximum(l_fin[:, :1], 1e-30)
+                            ).astype(o_ref.dtype)
+                lse_ref[h] = _lse(m_scr[h], l_fin)
 
 
-def _flash_forward(q, k, v, *, causal, block_q, block_k, window=None,
-                   kv_group=1):
+def _lse(m, l):
+    """The logsumexp residual for the backward's P recomputation, written
+    lane-replicated like m/l (see _LANES). A fully masked row (l == 0; only
+    padded rows can hit this) gets +LARGE so exp(s - lse) underflows to an
+    exact 0 instead of NaN."""
+    return jnp.where(l > 0.0, m + jnp.log(jnp.maximum(l, 1e-30)), -NEG_INF)
+
+
+def _clamp_to_live_k(i, j, block_q, block_k, causal, window):
+    """The K/V block to hold at grid step (q-block i, k-block j): j itself
+    where the pair is live, else the nearest live one of the row, so a
+    dead step re-uses the block it has and fetches nothing."""
+    if not causal:
+        return j
+    j = jnp.minimum(j, ((i + 1) * block_q - 1) // block_k)
+    if window is not None:
+        j = jnp.maximum(j, jnp.maximum(i * block_q - window + 1, 0)
+                        // block_k)
+    return j
+
+
+def _clamp_to_live_q(i, j, block_q, block_k, causal, window, n_qb):
+    """The same for the dK/dV kernel, which walks the Q blocks i of a K
+    block j."""
+    if not causal:
+        return i
+    i = jnp.maximum(i, (j * block_k) // block_q)
+    if window is not None:
+        i = jnp.minimum(i, jnp.minimum(
+            ((j + 1) * block_k + window - 2) // block_q, n_qb - 1))
+    return i
+
+
+def _vmem(shape, index):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    return pl.BlockSpec(shape, index, memory_space=pltpu.VMEM)
+
+
+def _statics(q, causal, block_q, block_k, window, kv_group):
+    """What the kernels are built from besides the arrays, read where the
+    call is made: ``_flash_forward`` and ``_flash_backward`` are traced once
+    for each value of it."""
+    n, t, d = q.shape
+    return dict(causal=causal, block_q=block_q, block_k=block_k,
+                window=window, kv_group=kv_group, interpret=_interpret_mode(),
+                plan=flash_plan(n, t, d, q.dtype.itemsize, block_q, block_k,
+                                kv_group))
+
+
+# Traced once for each shape and inlined where it is called (``inline``: no
+# call, no name of its own in the name stack): the unrolled layers of a
+# model then share one trace of the kernels, and JAX lowers equations with
+# the same parameters once for all of them. Without it a step of 24 layers
+# traced and lowered the three kernels 24 times, 77 s of a run's set-up.
+_trace_once = functools.partial(
+    jax.jit, inline=True,
+    static_argnames=("causal", "block_q", "block_k", "window", "kv_group",
+                     "plan", "interpret"))
+
+
+@_trace_once
+def _flash_forward(q, k, v, *, causal, block_q, block_k, window, kv_group,
+                   plan, interpret):
     """q: [n, T, d]; k/v: [n // kv_group, T, d] (n = batch·q-heads).
     ``kv_group`` > 1 is grouped-query attention: consecutive runs of
     kv_group query heads share one K/V head, mapped by the BlockSpec
@@ -155,158 +427,203 @@ def _flash_forward(q, k, v, *, causal, block_q, block_k, window=None,
     from jax.experimental.pallas import tpu as pltpu
 
     n, t, d = q.shape
-    scale = 1.0 / (d ** 0.5)
-    kernel = functools.partial(_flash_kernel, block_q=block_q, block_k=block_k,
-                               causal=causal, scale=scale, window=window)
-    grid = (n, t // block_q, t // block_k)
+    hb = plan.heads
+    n_qb, n_kb = t // block_q, t // block_k
+    kernel = functools.partial(
+        _flash_kernel, plan=plan, block_q=block_q, block_k=block_k,
+        n_qb=n_qb, n_kb=n_kb, causal=causal, scale=1.0 / (d ** 0.5),
+        window=window)
     g = kv_group
+
+    def kv_index(b, i, j):
+        return (b // g,
+                _clamp_to_live_k(i, j, block_q, block_k, causal, window), 0)
+
+    q_index = lambda b, i, j: (b, i, 0)
+    scratch = [] if n_kb == 1 else [
+        pltpu.VMEM((hb, block_q, _LANES), jnp.float32),   # running max
+        pltpu.VMEM((hb, block_q, _LANES), jnp.float32),   # running sum
+        pltpu.VMEM((hb, block_q, d), jnp.float32),        # unnormalized out
+    ]
     return pl.pallas_call(
         kernel,
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
                    jax.ShapeDtypeStruct((n, t, _LANES), jnp.float32)],  # lse
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b // g, j, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b // g, j, 0),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_q, _LANES), lambda b, i, j: (b, i, 0),
-                         memory_space=pltpu.VMEM),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, _LANES), jnp.float32),   # running max
-            pltpu.VMEM((block_q, _LANES), jnp.float32),   # running sum
-            pltpu.VMEM((block_q, d), jnp.float32),     # unnormalized out
-        ],
-        interpret=_interpret_mode(),
+        grid=(n // hb, n_qb, n_kb),
+        in_specs=[_vmem((hb, block_q, d), q_index),
+                  _vmem((hb, block_k, d), kv_index),
+                  _vmem((hb, block_k, d), kv_index)],
+        out_specs=[_vmem((hb, block_q, d), q_index),
+                   _vmem((hb, block_q, _LANES), q_index)],
+        scratch_shapes=scratch,
+        interpret=interpret,
     )(q, k, v)
 
 
 def _flash_dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, dq_ref,
-                     dq_scr, *, block_q, block_k, causal, scale,
-                     window=None):
+                     *scratch, plan, block_q, block_k, n_qb, n_kb, causal,
+                     scale, window=None):
     """dQ pass: for a fixed Q block, stream K/V blocks (innermost grid dim)
     and accumulate dQ = Σ_kb dS @ K, with P recomputed from the saved
-    logsumexp (FlashAttention-2 eq. 12-16)."""
+    logsumexp (FlashAttention-2 eq. 12-16), tile by tile. The softmax scale
+    rides in q for the scores, as in the forward, and meets dQ once, as it
+    is written."""
     from jax.experimental import pallas as pl
 
     qi = pl.program_id(1)
     kb = pl.program_id(2)
-    n_kb = pl.num_programs(2)
+    tq, tk = plan.tile_q, plan.tile_k
+    single = n_kb == 1
+    if not single:
+        dq_scr, = scratch
 
-    @pl.when(kb == 0)
-    def _init():
-        dq_scr[...] = jnp.zeros_like(dq_scr)
+    def _compute(h, kind, first):
+        for q0, row in _strips(_tiles(kind, block_q, block_k, plan), 0):
+            rows = pl.ds(q0, tq)
+            q = q_ref[h, rows, :] * scale              # [tq, d]
+            g = g_ref[h, rows, :]                      # [tq, d] dO
+            lse = _lanes(lse_ref[h, rows, :], tk)      # [tq, tk]
+            delta = _lanes(delta_ref[h, rows, :], tk)  # rowsum(dO*O)
+            dq = None
+            for k0, masked in row:
+                k_t = k_ref[h, pl.ds(k0, tk), :]       # [tk, d]
+                s = _dot(q, k_t, _NT)                  # [tq, tk]
+                if masked:
+                    s = jnp.where(
+                        _keep(_offset(kind, qi, kb, block_q, block_k, q0,
+                                      k0), s.shape, window), s, NEG_INF)
+                p = jnp.exp(s - lse)
+                dp = _dot(g, v_ref[h, pl.ds(k0, tk), :], _NT)
+                ds = p * (dp - delta)
+                part = _dot(ds.astype(k_t.dtype), k_t, _NN)      # [tq, d]
+                dq = part if dq is None else dq + part
+            if single:
+                dq_ref[h, rows, :] = (dq * scale).astype(dq_ref.dtype)
+            elif first:
+                dq_scr[h, rows, :] = dq
+            else:
+                dq_scr[h, rows, :] += dq
 
-    def _compute():
-        q = q_ref[0]                               # [bq, d]
-        k_blk = k_ref[0]                           # [bk, d]
-        v_blk = v_ref[0]
-        g = g_ref[0].astype(jnp.float32)           # [bq, d] dO
-        lse = lse_ref[0][:, :1]                    # [bq, 1]
-        delta = delta_ref[0][:, :1]                # [bq, 1] rowsum(dO*O)
-        s = jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        if causal:
-            s = _causal_mask(s, qi, kb, block_q, block_k, window=window)
-        p = jnp.exp(s - lse)                       # [bq, bk]
-        dp = jax.lax.dot_general(
-            g, v_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)    # [bq, bk]
-        ds = p * (dp - delta) * scale
-        dq_scr[...] += jax.lax.dot_general(
-            ds, k_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    _run_branches(_branches(qi, kb, causal=causal, block_q=block_q,
+                            block_k=block_k, window=window, n_qb=n_qb,
+                            n_kb=n_kb, inner="k"),
+                  plan.heads, _compute)
 
-    if causal:
-        @pl.when(_block_live(qi, kb, block_q, block_k, window))
-        def _():
-            _compute()
-    else:
-        _compute()
+    if not single:
+        @pl.when(kb == n_kb - 1)
+        def _finalize():
+            dq_ref[...] = (dq_scr[...] * scale).astype(dq_ref.dtype)
 
-    @pl.when(kb == n_kb - 1)
-    def _finalize():
-        dq_ref[0] = dq_scr[...].astype(dq_ref.dtype)
+
+def _stat_rows(x, heads, block_q):
+    """A per-row statistic ``x`` [n, T] as the dK/dV kernel takes it, with
+    its block and the block's index from (first row of n, Q block).
+
+    Where the block is lane-aligned, the [n, T] array itself in groups of
+    the 8 rows of n that share a tile of its layout, a block holding the
+    group of this step's ``heads`` (a power of two up to 8): nothing is
+    copied. Else (small blocks) one row a block, [n, T / block_q, 1,
+    block_q]: a block shape Mosaic takes at any size, and a relayout XLA
+    has to make."""
+    n, t = x.shape
+    if block_q % _LANES == 0 or block_q == t:
+        x = jnp.pad(x, ((0, -n % _SUBLANES), (0, 0)))
+        return (x.reshape(-1, _SUBLANES, t), (1, _SUBLANES, block_q),
+                lambda b, i: (b * heads // _SUBLANES, 0, i))
+    return (x.reshape(n, t // block_q, 1, block_q), (heads, 1, 1, block_q),
+            lambda b, i: (b, i, 0, 0))
+
+
+def _stat_row(ref, h, row0, q0, tq):
+    """The [1, tq] piece from column ``q0`` of the step's head ``h`` in a
+    block of ``_stat_rows``; the step's heads start at row ``row0`` of
+    their group of 8."""
+    from jax.experimental import pallas as pl
+
+    if len(ref.shape) == 4:
+        return ref[h, 0, :, pl.ds(q0, tq)]
+    return ref[0, pl.ds(row0 + h, 1), pl.ds(q0, tq)]
 
 
 def _flash_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
-                      dk_ref, dv_ref, dk_scr, dv_scr, *, block_q, block_k,
-                      causal, scale, window=None):
+                      dk_ref, dv_ref, *scratch, plan, block_q, block_k,
+                      n_qb, n_kb, causal, scale, window=None):
     """dK/dV pass: for a fixed K/V block, stream Q/dO blocks (innermost
-    grid dim); dV = Σ_qb Pᵀ dO, dK = Σ_qb dSᵀ Q."""
+    grid dim); dV = Σ_qb Pᵀ dO, dK = Σ_qb dSᵀ Q. The tiles are computed
+    transposed, keys along dim 0 (Sᵀ = K Qᵀ, dPᵀ = V dOᵀ), so that both
+    sums are plain products with nothing to turn; lse and delta come as
+    rows to broadcast over the keys (``_stat_rows``)."""
     from jax.experimental import pallas as pl
 
     kb = pl.program_id(1)
     qi = pl.program_id(2)
-    n_qb = pl.num_programs(2)
+    tq, tk = plan.tile_q, plan.tile_k
+    single = n_qb == 1
+    # where this step's heads start in their group of 8 rows (_stat_rows)
+    row0 = (0 if plan.heads == _SUBLANES
+            else pl.program_id(0) * plan.heads % _SUBLANES)
+    if not single:
+        dk_scr, dv_scr = scratch
 
-    @pl.when(qi == 0)
-    def _init():
-        dk_scr[...] = jnp.zeros_like(dk_scr)
-        dv_scr[...] = jnp.zeros_like(dv_scr)
+    def _compute(h, kind, first):
+        for k0, col in _strips(_tiles(kind, block_q, block_k, plan), 1):
+            rows = pl.ds(k0, tk)
+            k_t = k_ref[h, rows, :]                    # [tk, d]
+            v_t = v_ref[h, rows, :]
+            dk = dv = None
+            for q0, masked in col:
+                q = q_ref[h, pl.ds(q0, tq), :] * scale           # [tq, d]
+                g = g_ref[h, pl.ds(q0, tq), :]
+                lse = _stat_row(lse_ref, h, row0, q0, tq)        # [1, tq]
+                delta = _stat_row(delta_ref, h, row0, q0, tq)
+                s = _dot(k_t, q, _NT)                  # [tk, tq]
+                if masked:
+                    s = jnp.where(
+                        _keep(_offset(kind, qi, kb, block_q, block_k, q0,
+                                      k0), s.shape, window, transposed=True),
+                        s, NEG_INF)
+                p = jnp.exp(s - lse)
+                dp = _dot(v_t, g, _NT)                 # [tk, tq]
+                ds = p * (dp - delta)
+                dv_part = _dot(p.astype(g.dtype), g, _NN)        # [tk, d]
+                dk_part = _dot(ds.astype(q.dtype), q, _NN)       # q carries scale
+                dv = dv_part if dv is None else dv + dv_part
+                dk = dk_part if dk is None else dk + dk_part
+            if single:
+                dk_ref[h, rows, :] = dk.astype(dk_ref.dtype)
+                dv_ref[h, rows, :] = dv.astype(dv_ref.dtype)
+            elif first:
+                dk_scr[h, rows, :] = dk
+                dv_scr[h, rows, :] = dv
+            else:
+                dk_scr[h, rows, :] += dk
+                dv_scr[h, rows, :] += dv
 
-    def _compute():
-        q = q_ref[0]                               # [bq, d]
-        k_blk = k_ref[0]                           # [bk, d]
-        v_blk = v_ref[0]
-        g = g_ref[0].astype(jnp.float32)           # [bq, d]
-        lse = lse_ref[0][:, :1]                    # [bq, 1]
-        delta = delta_ref[0][:, :1]
-        s = jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        if causal:
-            s = _causal_mask(s, qi, kb, block_q, block_k, window=window)
-        p = jnp.exp(s - lse)                       # [bq, bk]
-        # Pᵀ dO and dSᵀ Q contract the query axis (dim 0 of both operands):
-        # the per-row lse/delta broadcast along lanes as in the dQ kernel
-        dv_scr[...] += jax.lax.dot_general(
-            p, g, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)    # [bk, d]
-        dp = jax.lax.dot_general(
-            g, v_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)    # [bq, bk]
-        ds = p * (dp - delta) * scale
-        dk_scr[...] += jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)    # [bk, d]
+    # a Q block with no in-mask entry for this K block contributes nothing
+    # here (above the diagonal / beyond the window)
+    _run_branches(_branches(qi, kb, causal=causal, block_q=block_q,
+                            block_k=block_k, window=window, n_qb=n_qb,
+                            n_kb=n_kb, inner="q"),
+                  plan.heads, _compute)
 
-    if causal:
-        # a Q block with no in-mask entry for this K block contributes
-        # nothing here (above the diagonal / beyond the window)
-        @pl.when(_block_live(qi, kb, block_q, block_k, window))
-        def _():
-            _compute()
-    else:
-        _compute()
-
-    @pl.when(qi == n_qb - 1)
-    def _finalize():
-        dk_ref[0] = dk_scr[...].astype(dk_ref.dtype)
-        dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
+    if not single:
+        @pl.when(qi == n_qb - 1)
+        def _finalize():
+            dk_ref[...] = dk_scr[...].astype(dk_ref.dtype)
+            dv_ref[...] = dv_scr[...].astype(dv_ref.dtype)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def _flash_attention_3d(q, k, v, causal, block_q, block_k, window=None,
                         kv_group=1):
-    out, _lse = _flash_forward(q, k, v, causal=causal, block_q=block_q,
-                               block_k=block_k, window=window,
-                               kv_group=kv_group)
+    out, _lse = _flash_forward(
+        q, k, v, **_statics(q, causal, block_q, block_k, window, kv_group))
     return out
 
 
 def _flash_fwd(q, k, v, causal, block_q, block_k, window=None, kv_group=1):
-    out, lse = _flash_forward(q, k, v, causal=causal, block_q=block_q,
-                              block_k=block_k, window=window,
-                              kv_group=kv_group)
+    out, lse = _flash_forward(
+        q, k, v, **_statics(q, causal, block_q, block_k, window, kv_group))
     # keep one lane: the saved residual is [n, T], not 128x that
     return out, (q, k, v, out, lse[..., 0])
 
@@ -334,83 +651,87 @@ def _flash_bwd(causal, block_q, block_k, window, kv_group, residuals, g):
                                                     block_size=block_k),
                 q, k, v)
         return vjp(g)
+    return _flash_backward(
+        *residuals, g,
+        **_statics(residuals[0], causal, block_q, block_k, window, kv_group))
 
+
+@_trace_once
+def _flash_backward(q, k, v, out, lse, g, *, causal, block_q, block_k, window,
+                    kv_group, plan, interpret):
+    """dQ, dK, dV of ``_flash_forward`` from its residuals and dO."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    q, k, v, out, lse = residuals
     n, t, d = q.shape
-    scale = 1.0 / (d ** 0.5)
+    hb = plan.heads
+    n_qb, n_kb = t // block_q, t // block_k
+    static = dict(plan=plan, block_q=block_q, block_k=block_k, n_qb=n_qb,
+                  n_kb=n_kb, causal=causal, scale=1.0 / (d ** 0.5),
+                  window=window)
     # delta_i = Σ_d dO ⊙ O — a cheap fused elementwise+reduce; XLA keeps it
     # out of the kernels' VMEM budget
     delta = (g.astype(jnp.float32) * out.astype(jnp.float32)).sum(-1)
-    lse, delta = (jnp.broadcast_to(x[..., None], (n, t, _LANES))
-                  for x in (lse, delta))
+    # the dQ kernel broadcasts them over the keys of a [q, k] tile and takes
+    # them lane-replicated (see _LANES); the dK/dV kernel, whose tiles are
+    # [k, q], takes them as rows
+    lse_cols, delta_cols = (jnp.broadcast_to(x[..., None], (n, t, _LANES))
+                            for x in (lse, delta))
+    (lse_rows, row_block, row_index), (delta_rows, _, _) = (
+        _stat_rows(x, hb, block_q) for x in (lse, delta))
 
     gk = kv_group
-    qkvg_specs = [
-        pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0),
-                     memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, block_k, d), lambda b, i, j: (b // gk, j, 0),
-                     memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, block_k, d), lambda b, i, j: (b // gk, j, 0),
-                     memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0),
-                     memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, block_q, _LANES), lambda b, i, j: (b, i, 0),
-                     memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, block_q, _LANES), lambda b, i, j: (b, i, 0),
-                     memory_space=pltpu.VMEM),
-    ]
+
+    def kv_index(b, i, j):
+        return (b // gk,
+                _clamp_to_live_k(i, j, block_q, block_k, causal, window), 0)
+
+    q_index = lambda b, i, j: (b, i, 0)
     dq = pl.pallas_call(
-        functools.partial(_flash_dq_kernel, block_q=block_q,
-                          block_k=block_k, causal=causal, scale=scale,
-                          window=window),
+        functools.partial(_flash_dq_kernel, **static),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        grid=(n, t // block_q, t // block_k),
-        in_specs=qkvg_specs,
-        out_specs=pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0),
-                               memory_space=pltpu.VMEM),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        interpret=_interpret_mode(),
-    )(q, k, v, g, lse, delta)
+        grid=(n // hb, n_qb, n_kb),
+        in_specs=[_vmem((hb, block_q, d), q_index),
+                  _vmem((hb, block_k, d), kv_index),
+                  _vmem((hb, block_k, d), kv_index),
+                  _vmem((hb, block_q, d), q_index),
+                  _vmem((hb, block_q, _LANES), q_index),
+                  _vmem((hb, block_q, _LANES), q_index)],
+        out_specs=_vmem((hb, block_q, d), q_index),
+        scratch_shapes=[] if n_kb == 1 else [
+            pltpu.VMEM((hb, block_q, d), jnp.float32)],
+        interpret=interpret,
+    )(q, k, v, g, lse_cols, delta_cols)
 
     # dk/dv grid: (n, K blocks, Q blocks) — the index maps swap i/j roles.
     # With GQA the kernel accumulates PER Q-HEAD (output shaped like q);
     # the group-sum down to the kv heads happens outside — revisiting one
     # output block from different outer-grid steps would race.
-    dkv_specs = [
-        pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0),
-                     memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, block_k, d), lambda b, j, i: (b // gk, j, 0),
-                     memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, block_k, d), lambda b, j, i: (b // gk, j, 0),
-                     memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0),
-                     memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, block_q, _LANES), lambda b, j, i: (b, i, 0),
-                     memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, block_q, _LANES), lambda b, j, i: (b, i, 0),
-                     memory_space=pltpu.VMEM),
-    ]
+    def live_q(j, i):
+        return _clamp_to_live_q(i, j, block_q, block_k, causal, window, n_qb)
+
+    q_of_k = lambda b, j, i: (b, live_q(j, i), 0)
+    row_of_k = lambda b, j, i: row_index(b, live_q(j, i))
+    kv_of_k = lambda b, j, i: (b // gk, j, 0)
+    out_of_k = lambda b, j, i: (b, j, 0)
     dk, dv = pl.pallas_call(
-        functools.partial(_flash_dkv_kernel, block_q=block_q,
-                          block_k=block_k, causal=causal, scale=scale,
-                          window=window),
+        functools.partial(_flash_dkv_kernel, **static),
         out_shape=[jax.ShapeDtypeStruct((n, t, d), k.dtype),
                    jax.ShapeDtypeStruct((n, t, d), v.dtype)],
-        grid=(n, t // block_k, t // block_q),
-        in_specs=dkv_specs,
-        out_specs=[
-            pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0),
-                         memory_space=pltpu.VMEM),
-        ],
-        scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
-                        pltpu.VMEM((block_k, d), jnp.float32)],
-        interpret=_interpret_mode(),
-    )(q, k, v, g, lse, delta)
+        grid=(n // hb, n_kb, n_qb),
+        in_specs=[_vmem((hb, block_q, d), q_of_k),
+                  _vmem((hb, block_k, d), kv_of_k),
+                  _vmem((hb, block_k, d), kv_of_k),
+                  _vmem((hb, block_q, d), q_of_k),
+                  _vmem(row_block, row_of_k),
+                  _vmem(row_block, row_of_k)],
+        out_specs=[_vmem((hb, block_k, d), out_of_k),
+                   _vmem((hb, block_k, d), out_of_k)],
+        scratch_shapes=[] if n_qb == 1 else [
+            pltpu.VMEM((hb, block_k, d), jnp.float32),
+            pltpu.VMEM((hb, block_k, d), jnp.float32)],
+        interpret=interpret,
+    )(q, k, v, g, lse_rows, delta_rows)
     if kv_group > 1:
         dk = dk.astype(jnp.float32).reshape(
             n // kv_group, kv_group, t, d).sum(1).astype(k.dtype)
@@ -437,9 +758,18 @@ def flash_attention(q, k, v, *, causal=False, block_q=512, block_k=512,
     for the rematerializing fallback). ``window`` (requires causal) limits
     each query to the last ``window`` positions — sliding-window attention;
     fully out-of-window blocks are skipped in BOTH directions, so compute
-    scales O(T·window) instead of O(T²/2). Block defaults of 512 measured
-    fastest on v5e at T=8k (≈10% over the lax.scan path; 128-blocks are ~35%
-    slower from grid overhead).
+    scales O(T·window) instead of O(T²/2).
+
+    The blocks are what the DMA moves and the grid walks; what a grid step
+    does inside them (heads a step, compute tiles, which blocks build a
+    mask) follows from the shapes: ``flash_plan``. Products take their
+    operands in the inputs' dtype (bfloat16 in, bfloat16 operands) and
+    accumulate in float32; the softmax statistics are float32. Measured on
+    a v5e (PERF.md, PR 27; bfloat16, d 64, causal, forward + dQ + dK/dV
+    device ms a call): [128, 1024, 64] at block 512 1.44 (2.38 before PR
+    27), [512, 256, 64] at block 256 0.64 (1.56), [64, 1024, 64] at block
+    128 3.59, five times block 512's time a row: small blocks pay per grid
+    step. No route through the lax.scan path has been timed on a chip.
     """
     if window is not None:
         if not causal:

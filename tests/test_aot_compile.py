@@ -63,10 +63,14 @@ def _compile(fn, *shapes):
 
 
 # (q shape, kv shape, block): the chip_smoke train_lm shape (GPT-2 small,
-# batch 8 x 1024, d64) and one GQA 16/4 shape at head dim 128, T 2048
+# batch 8 x 1024, d64), one GQA 16/4 shape at head dim 128, T 2048, and the
+# two cells of the benchmark (GPT-2 medium: two blocks a row with the
+# diagonal and the dead block; one block a row, several heads a grid step)
 FLASH_SHAPES = {
     "train_lm": ((8, 12, 1024, 64), (8, 12, 1024, 64), 512),
     "gqa_d128": ((2, 16, 2048, 128), (2, 4, 2048, 128), 512),
+    "gpt2m_t1024": ((8, 16, 1024, 64), (8, 16, 1024, 64), 512),
+    "gpt2m_t256": ((32, 16, 256, 64), (32, 16, 256, 64), 256),
 }
 
 

@@ -151,6 +151,198 @@ class TestFlashBackwardKernels:
                                        atol=0.15, rtol=0.1)
 
 
+def _pallas_calls(jaxpr, found=None):
+    """``{kernel function's name: inner jaxpr}`` of every pallas_call under
+    ``jaxpr`` (the flash calls take no ``name=``: tests/test_aot_compile)."""
+    found = {} if found is None else found
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            inner = eqn.params["jaxpr"]
+            found[inner.debug_info.func_name] = inner
+            continue
+        for sub in _sub_jaxprs(eqn):
+            _pallas_calls(sub, found)
+    return found
+
+
+def _sub_jaxprs(eqn):
+    for value in eqn.params.values():
+        for item in (value if isinstance(value, (list, tuple)) else [value]):
+            item = getattr(item, "jaxpr", item)
+            if hasattr(item, "eqns"):
+                yield item
+
+
+def _dots(jaxpr):
+    """Every dot_general of a kernel's jaxpr, through its branches (pl.when)
+    and loops (the heads of a step)."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            yield eqn
+        for sub in _sub_jaxprs(eqn):
+            yield from _dots(sub)
+
+
+class TestFlashGridStep:
+    """What one grid step of the three kernels hands to the MXU, and that
+    the step's branches and tiles (``flash_plan``) compute the attention
+    they stand for."""
+
+    # products a tile: forward QKᵀ, PV; dQ QKᵀ, dO Vᵀ, dS K; dK/dV K Qᵀ,
+    # V dOᵀ, Pᵀ dO, dSᵀ Q
+    PRODUCTS = {"_flash_kernel": 2, "_flash_dq_kernel": 3,
+                "_flash_dkv_kernel": 4}
+
+    @pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+    @pytest.mark.parametrize("kernel", sorted(PRODUCTS))
+    def test_every_product_takes_the_inputs_dtype(self, interpret_pallas,
+                                                  kernel, dtype):
+        """bfloat16 inputs: every product of the step meets the MXU with two
+        bfloat16 operands and accumulates in float32 (P and dS are cast to
+        the operand they meet, dO is not upcast); float32 callers keep
+        float32 products."""
+        import jax
+        from deeplearning4j_tpu.ops.pallas_kernels import flash_attention
+        x = jnp.zeros((2, 512, 32), dtype)
+
+        def loss(q, k, v):
+            return flash_attention(q, k, v, causal=True, block_q=256,
+                                   block_k=256).astype(jnp.float32).sum()
+        calls = _pallas_calls(
+            jax.make_jaxpr(jax.grad(loss, (0, 1, 2)))(x, x, x).jaxpr)
+        assert sorted(calls) == sorted(self.PRODUCTS)
+        dots = list(_dots(calls[kernel]))
+        # some whole number of tiles, each with all of its products
+        assert dots and len(dots) % self.PRODUCTS[kernel] == 0
+        for eqn in dots:
+            assert [v.aval.dtype for v in eqn.invars] == [jnp.dtype(dtype)] * 2
+            assert eqn.params["preferred_element_type"] == jnp.float32
+            assert eqn.outvars[0].aval.dtype == jnp.float32
+
+    # bfloat16 inputs, so the float32 reference sees the same numbers; on
+    # the way to a gradient entry four values are rounded to bfloat16 once
+    # each (the output O that delta is made of, P, dS, the gradient as it
+    # is returned), 2**-9 relative each; twice that for what a row of up to
+    # 512 such terms accumulates
+    BF16_TOL = 8 * 2.0 ** -9
+
+    # T of 3-4 blocks: a row of blocks holds a wholly live block, one the
+    # diagonal (or the window's edge) crosses and a dead one at once
+    CASES = {
+        "square": dict(q=(2, 512, 32), bq=128, bk=128),
+        "tiles_in_the_diagonal_block": dict(q=(1, 1536, 32), bq=512,
+                                            bk=512),
+        "window": dict(q=(2, 512, 32), bq=128, bk=128, window=200),
+        "window_inside_a_block": dict(q=(1, 768, 32), bq=256, bk=256,
+                                      window=100),
+        "rect_q_wider": dict(q=(1, 512, 32), bq=256, bk=128),
+        "rect_k_wider": dict(q=(1, 512, 32), bq=128, bk=256),
+        "gqa": dict(q=(1, 4, 384, 32), kv=(1, 2, 384, 32), bq=128, bk=128),
+        "padded": dict(q=(2, 450, 32), bq=128, bk=128),
+        "heads_share_a_step": dict(q=(8, 256, 32), bq=256, bk=256),
+        "non_causal": dict(q=(2, 384, 32), bq=128, bk=128, causal=False),
+    }
+
+    @pytest.mark.parametrize("case", sorted(CASES))
+    def test_bf16_forward_and_grads_match_dense(self, interpret_pallas, case):
+        import jax
+        from deeplearning4j_tpu.ops.pallas_kernels import flash_attention
+        c = dict(self.CASES[case])
+        q_shape = c.pop("q")
+        kv_shape = c.pop("kv", q_shape)
+        bq, bk = c.pop("bq"), c.pop("bk")
+        causal = c.pop("causal", True)
+        window = c.pop("window", None)
+        rng = np.random.RandomState(7)
+        q = jnp.asarray(rng.randn(*q_shape), jnp.bfloat16)
+        k = jnp.asarray(rng.randn(*kv_shape), jnp.bfloat16)
+        v = jnp.asarray(rng.randn(*kv_shape), jnp.bfloat16)
+        cot = jnp.asarray(rng.randn(*q_shape), jnp.float32)
+        group = q_shape[-3] // kv_shape[-3]
+
+        def flash(a, b, c):
+            return flash_attention(a, b, c, causal=causal, block_q=bq,
+                                   block_k=bk, window=window)
+
+        def dense(a, b, c):
+            a, b, c = (x.astype(jnp.float32) for x in (a, b, c))
+            if group > 1:
+                b, c = (jnp.repeat(x, group, axis=-3) for x in (b, c))
+            return dense_attention(a, b, c, causal=causal, window=window)
+
+        def grads(fn):
+            return jax.grad(lambda a, b, c: (fn(a, b, c).astype(jnp.float32)
+                                             * cot).sum(), (0, 1, 2))(q, k, v)
+        got = (flash(q, k, v),) + grads(flash)
+        want = (dense(q, k, v),) + grads(dense)
+        for g, w, name in zip(got, want, ("out", "dq", "dk", "dv")):
+            assert g.dtype == jnp.bfloat16
+            w = np.asarray(w, np.float32)
+            np.testing.assert_allclose(
+                np.asarray(g, np.float32), w, rtol=0,
+                atol=self.BF16_TOL * np.abs(w).max(), err_msg=name)
+
+
+class TestFlashPlan:
+    """``flash_plan``: heads a grid step and the compute tile, from shapes
+    alone (no knob); and the tiles a block's kind leaves to compute."""
+
+    # (n, t, d, itemsize, block_q, block_k, kv_group) -> (heads, tile_q, tile_k)
+    CASES = {
+        # the benchmark's cells: 8 x 16 heads at T 1024, 32 x 16 at T 256
+        "gpt2m_t1024": ((128, 1024, 64, 2, 512, 512, 1), (4, 256, 256)),
+        "gpt2m_t256": ((512, 256, 64, 2, 256, 256, 1), (8, 256, 256)),
+        # chip_smoke's GPT-2 small: 96 rows
+        "gpt2s_t1024": ((96, 1024, 64, 2, 512, 512, 1), (4, 256, 256)),
+        # float32 blocks are twice the bytes: half the heads
+        "float32": ((64, 1024, 64, 4, 512, 512, 1), (2, 256, 256)),
+        "d128": ((128, 1024, 128, 2, 512, 512, 1), (2, 256, 256)),
+        # grouped-query attention and long rows keep one row a step
+        "gqa": ((32, 2048, 128, 2, 512, 512, 4), (1, 256, 256)),
+        "long_row": ((32, 4096, 64, 2, 512, 512, 1), (1, 256, 256)),
+        # heads divide n
+        "odd_n": ((3, 256, 64, 2, 256, 256, 1), (1, 256, 256)),
+        "n_6": ((6, 32, 8, 4, 16, 16, 1), (2, 16, 16)),
+        # blocks that 256 does not divide are one tile
+        "block_128": ((64, 1024, 64, 2, 128, 128, 1), (1, 128, 128)),
+        "block_384": ((4, 384, 32, 2, 384, 384, 1), (4, 384, 384)),
+        "rect": ((2, 512, 32, 2, 256, 128, 1), (1, 256, 128)),
+        "small_block": ((2, 12, 8, 4, 12, 12, 1), (2, 12, 12)),
+    }
+
+    @pytest.mark.parametrize("case", sorted(CASES))
+    def test_plan_from_shapes(self, case):
+        from deeplearning4j_tpu.ops import pallas_kernels as pk
+        args, want = self.CASES[case]
+        plan = pk.flash_plan(*args)
+        assert tuple(plan) == want
+        n, t, d, itemsize, bq, bk, _ = args
+        assert n % plan.heads == 0
+        assert bq % plan.tile_q == 0 and bk % plan.tile_k == 0
+        assert pk._flash_vmem_bytes(plan.heads, bq, bk, d,
+                                    itemsize) <= pk._VMEM_BUDGET
+
+    @pytest.mark.parametrize("kind,tile,want", [
+        # a 512-block in 256-tiles: the diagonal block leaves out the tile
+        # above the diagonal and masks the two on it
+        ("diag", 256, [(0, 0, True), (256, 0, False), (256, 256, True)]),
+        ("full", 256, [(0, 0, False), (0, 256, False), (256, 0, False),
+                       (256, 256, False)]),
+        ("edge", 256, [(0, 0, True), (0, 256, True), (256, 0, True),
+                       (256, 256, True)]),
+        # one tile a block: the step the kernels took before the tiles
+        ("diag", 512, [(0, 0, True)]),
+        ("full", 512, [(0, 0, False)]),
+    ])
+    def test_tiles_of_a_block(self, kind, tile, want):
+        from deeplearning4j_tpu.ops import pallas_kernels as pk
+        plan = pk.FlashPlan(1, tile, tile)
+        assert pk._tiles(kind, 512, 512, plan) == want
+        # 128-tiles of the diagonal block: 10 of 16, 4 of them masked
+        fine = pk._tiles("diag", 512, 512, pk.FlashPlan(1, 128, 128))
+        assert (len(fine), sum(m for _, _, m in fine)) == (10, 4)
+
+
 class TestSlidingWindow:
     """Causal sliding-window attention: the kernels mask entries more than
     window-1 positions in the past and skip fully out-of-window blocks."""
@@ -778,7 +970,12 @@ class TestHelperSeam:
         out_plain = np.asarray(net_plain.output(x))
         np.testing.assert_allclose(out_helper, out_plain, atol=1e-5)
 
-    def test_broken_helper_falls_back(self, rng):
+    def test_broken_helper_propagates(self, rng):
+        """Declining in ``supports()`` is the way back to the built-in
+        path; a helper that accepted the call and then raised reaches the
+        caller through the layer's whole forward (PR 21 took the swallowing
+        fall-back out of the seam: on the chip it hid a kernel the compiler
+        had refused; tests/test_helpers_seam.py holds the seam itself)."""
         from deeplearning4j_tpu.nn import helpers
         from deeplearning4j_tpu.nn.layers import SelfAttentionLayer
 
@@ -795,8 +992,8 @@ class TestHelperSeam:
             import jax
             params = layer.init_params(jax.random.PRNGKey(0))
             x = jnp.asarray(rng.randn(1, 8, 4), jnp.float32)
-            out, _ = layer.forward(params, x, {})
-            assert np.isfinite(np.asarray(out)).all()
+            with pytest.raises(RuntimeError, match="boom"):
+                layer.forward(params, x, {})
         finally:
             helpers.register_helper("SelfAttentionLayer",
                                     helpers.FlashAttentionHelper())
