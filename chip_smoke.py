@@ -13,6 +13,12 @@ on ONE TPU chip, in ONE process:
                   models/_device_state.fuse_allowed)
   train_lm        TransformerLM at GPT-2-small widths, block_size=512: the
                   Pallas flash-attention kernels inside the donated step
+  train_mixed_lm  TransformerLM with a per-layer list in the Laguna-XS.2 cut's
+                  pattern (full + dense, then window, window, window, full
+                  with experts; heads of 128, 48 / 64 query heads over 8
+                  key/value heads, window 512, 8 of 16 experts held, remat)
+                  at a small width: three steps, and the loss against dense
+                  attention and a looped expert sum on the same weights
   serve_lm        the same model behind ContinuousLM + ServingIngress, a few
                   /v1/generate requests over HTTP admitted mid-decode
 
@@ -43,7 +49,8 @@ import time
 import traceback
 import urllib.request
 
-PHASES = ("train_lenet", "train_resnet50", "train_lm", "serve_lm")
+PHASES = ("train_lenet", "train_resnet50", "train_lm", "train_mixed_lm",
+          "serve_lm")
 
 # TransformerLM widths: GPT-2 small (Radford et al. 2019: 50257 BPE tokens,
 # 1024 positions, d_model 768, 12 heads, 12 layers, d_ff 3072).
@@ -51,6 +58,16 @@ _GPT2_SMALL = dict(vocab_size=50257, max_len=1024, d_model=768, n_heads=12,
                    n_layers=12, d_ff=3072, block_size=512)
 _TINY_LM = dict(vocab_size=512, max_len=64, d_model=32, n_heads=4,
                 n_layers=2, d_ff=64, block_size=32)
+
+# the mixed model: the kernels' shapes are the published ones (head 128, groups
+# of 6 and 8 query heads, window and block 512, rows of 4 blocks), the widths
+# around them small; the rehearsal's are what the interpreter does in seconds
+_MIXED_LM = dict(vocab_size=4096, seq=2048, d_model=256, d_ff=512, head_dim=128,
+                 n_kv_heads=8, heads_full=48, heads_window=64, window=512,
+                 block_size=512, n_experts=16, held=8, top_k=2, d_expert=128)
+_TINY_MIXED_LM = dict(vocab_size=96, seq=32, d_model=64, d_ff=128, head_dim=16,
+                      n_kv_heads=2, heads_full=4, heads_window=6, window=8,
+                      block_size=8, n_experts=16, held=8, top_k=2, d_expert=32)
 
 # relative bar for "the same numbers up to bf16": 8 mantissa bits leave
 # ~0.4% per rounding; the repo's cross-backend parity gate uses the same 2e-2
@@ -71,6 +88,7 @@ def _sizes(rehearse):
                                   stages=(1, 1, 1, 1)),
                         batch=4, batches_per_fit=4),
             lm=dict(conf=_TINY_LM, batch=2),
+            mixed=dict(conf=_TINY_MIXED_LM, batch=2),
             # (prompt length, n_new): the first streams and stays decoding
             # while the others are admitted
             serve=dict(conf=_TINY_LM,
@@ -81,6 +99,7 @@ def _sizes(rehearse):
         resnet=dict(conf=dict(n_classes=1000, height=224, width=224),
                     batch=128, batches_per_fit=9),
         lm=dict(conf=_GPT2_SMALL, batch=8),
+        mixed=dict(conf=_MIXED_LM, batch=2),
         serve=dict(conf=_GPT2_SMALL,
                    requests=((5, 160), (37, 16), (130, 40), (300, 8))),
         dp=dict(conf=_GPT2_SMALL, batch=8))
@@ -237,6 +256,109 @@ def phase_train_lm(sz, seed, rehearse):
     return {"checks": checks, "run_s": run_s, "steps": len(losses),
             "tokens_per_step": int(toks[:, 1:].size), "losses": losses,
             "logits_rel_err_vs_dense": rel, **facts}
+
+
+def _mixed_config(m, seed):
+    """The per-layer list of the Laguna-XS.2 cut at the sizes ``m``."""
+    from deeplearning4j_tpu.models.transformer import (Experts, LayerSpec,
+                                                       Rope,
+                                                       TransformerConfig)
+    full = Rope(base=500000.0, share=0.5, yarn_factor=64.0,
+                yarn_original_len=m["seq"] // 2, yarn_beta_fast=64.0)
+    F = LayerSpec(None, m["heads_full"], full, "experts")
+    W = LayerSpec(m["window"], m["heads_window"], Rope(), "experts")
+    return TransformerConfig(
+        vocab_size=m["vocab_size"], max_len=m["seq"], d_model=m["d_model"],
+        n_heads=m["heads_full"], n_kv_heads=m["n_kv_heads"],
+        head_dim=m["head_dim"], n_layers=5, d_ff=m["d_ff"],
+        block_size=m["block_size"], pos_embed="rope", rope_layout="half",
+        norm="rmsnorm", norm_eps=1e-6, bias=False, ffn="swiglu",
+        tie_embeddings=False, attn_gate=True, remat=True,
+        layers=(LayerSpec(None, m["heads_full"], full, "dense"), W, W, W, F),
+        experts=Experts(n_experts=m["n_experts"], top_k=m["top_k"],
+                        d_expert=m["d_expert"], held=(0, m["held"]),
+                        scale=2.5, d_shared=m["d_expert"]),
+        compute_dtype="bfloat16", seed=seed)
+
+
+def _plain_mixed_loss(c, params, tokens):
+    """The mixed model's loss with dense masked attention and the held
+    experts one after another, each on every token under a weight that is 0
+    where the token did not choose it: no kernel, no grouped product."""
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+    from deeplearning4j_tpu.models import expert_layer
+    from deeplearning4j_tpu.models.transformer import (_block_apply,
+                                                       _forward_tokens)
+    c = dataclasses.replace(c, block_size=None, remat=False)
+    ex = c.experts
+    first, count = ex.held_range
+
+    def looped(bp, h):
+        flat = h.reshape(-1, h.shape[-1])
+        w, chosen = expert_layer.route(ex, flat, bp["router"])
+        y = expert_layer.swiglu(flat, bp["sh_gate"], bp["sh_up"],
+                                bp["sh_down"])
+        for e in range(count):
+            w_e = jnp.where(chosen == first + e, w, 0.0).sum(-1)
+            y = y + w_e[:, None].astype(h.dtype) * expert_layer.swiglu(
+                flat, bp["W_gate"][e], bp["W_up"][e], bp["W_down"][e])
+        return y.reshape(h.shape)
+
+    def apply(i, bp, x):
+        spec = c.layer_spec(i)
+        return _block_apply(c, bp, x, spec=spec,
+                            ffn=looped if spec.ffn == "experts" else None)
+
+    @jax.jit
+    def loss(params, tokens):
+        logp = jax.nn.log_softmax(
+            _forward_tokens(c, params, tokens[:, :-1], apply), axis=-1)
+        return -jnp.take_along_axis(logp, tokens[:, 1:, None], -1).mean()
+
+    return float(loss(params, jnp.asarray(tokens, jnp.int32)))
+
+
+def phase_train_mixed_lm(sz, seed, rehearse):
+    import numpy as np
+    from deeplearning4j_tpu.models.transformer import TransformerLM
+
+    m = sz["conf"]
+    lm = TransformerLM(_mixed_config(m, seed)).init()
+    c = lm.conf
+    rng = np.random.default_rng(seed)
+    toks = rng.integers(0, c.vocab_size, (sz["batch"], m["seq"] + 1))
+    steps = 3
+    losses, run_s = _lm_steps(lm, toks, steps)
+    counters = lm.moe_counters()
+    # eight of sixteen experts, two a token: one assignment a token on average
+    balanced = steps * 4 * toks[:, 1:].size
+    checks = {"loss_falls": _falls(losses),
+              "no_row_over_buffer": counters["moe.rows_over_buffer"] == 0,
+              "rows_near_balance":
+                  0.5 * balanced < counters["moe.local_rows"] < 2 * balanced}
+    facts = {}
+    if not rehearse:   # interpret mode lowers to plain HLO, not Mosaic
+        text = lm._step.lower(
+            lm.params, lm.opt_state, lm.iteration, lm._rng,
+            toks[:, :-1].astype(np.int32), toks[:, 1:].astype(np.int32),
+            None).as_text()
+        facts["tpu_custom_calls"] = text.count("tpu_custom_call")
+        facts["ragged_dots"] = text.count("ragged_dot")
+        # forward, the forward again under remat, dQ and dK/dV in each layer
+        checks["flash_kernels_in_step"] = \
+            facts["tpu_custom_calls"] == 4 * c.n_layers
+        checks["grouped_products_in_step"] = facts["ragged_dots"] > 0
+    got = lm.eval_loss(toks)
+    want = _plain_mixed_loss(c, lm.params, toks)
+    rel = abs(got - want) / abs(want)
+    checks["loss_matches_plain"] = rel <= _BF16_REL
+    return {"checks": checks, "run_s": run_s, "steps": steps,
+            "tokens_per_step": int(toks[:, 1:].size), "losses": losses,
+            "loss_rel_err_vs_plain": rel, "loss_kernels": got,
+            "loss_plain": want, "counters": counters, **facts}
 
 
 def _post(port, body, first_chunk=None):
@@ -507,6 +629,7 @@ def main(argv=None):
         table = {"train_lenet": (phase_train_lenet, sz["lenet"]),
                  "train_resnet50": (phase_train_resnet50, sz["resnet"]),
                  "train_lm": (phase_train_lm, sz["lm"]),
+                 "train_mixed_lm": (phase_train_mixed_lm, sz["mixed"]),
                  "serve_lm": (phase_serve_lm, sz["serve"])}
         plan = [(p, *table[p]) for p in PHASES if p in phases]
     ok = native

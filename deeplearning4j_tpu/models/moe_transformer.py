@@ -20,6 +20,7 @@ schedule, and the fit/listener surface are all inherited from
 
 from __future__ import annotations
 
+import functools
 import math
 from dataclasses import dataclass
 
@@ -44,6 +45,9 @@ class MoETransformerConfig(TransformerConfig):
 
     def __post_init__(self):
         super().__post_init__()
+        # the Switch family's layers are told apart by ``moe_every`` alone
+        self.one_block("the Switch-routed MoE family (MoETransformerLM, "
+                       "EPTransformerLM)")
         if self.n_experts < 2:
             raise ValueError("need at least 2 experts")
         if self.moe_every < 1:
@@ -128,6 +132,7 @@ class MoETransformerLM(TransformerLM):
         rngs = (jax.random.split(rng, c.n_layers)
                 if rng is not None and c.dropout > 0 else [None] * c.n_layers)
         auxes = []
+        spec = c.one_block("MoETransformerLM")
 
         def moe_block(bp, xx, rr):
             """Block returning (x, aux) so the aux crosses the
@@ -141,7 +146,7 @@ class MoETransformerLM(TransformerLM):
                 cell["aux"] = aux
                 return y
 
-            out = _block_apply(c, bp, xx, drop=self._drop, rng=rr,
+            out = _block_apply(c, bp, xx, spec, drop=self._drop, rng=rr,
                                ffn=moe_ffn, plan=self._shard_plan)
             return out, cell["aux"]
 
@@ -151,8 +156,8 @@ class MoETransformerLM(TransformerLM):
                 x, aux = blk(bp, x, rngs[i])
                 auxes.append(aux)   # appended OUTSIDE the checkpoint
                 return x
-            blk = jax.checkpoint(self._block) if c.remat else self._block
-            return blk(bp, x, rngs[i])
+            blk = functools.partial(self._block, spec)
+            return (jax.checkpoint(blk) if c.remat else blk)(bp, x, rngs[i])
 
         logits = _forward_tokens(c, params, tokens, apply)
         return logits, sum(auxes, jnp.float32(0.0))

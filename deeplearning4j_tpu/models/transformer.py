@@ -22,8 +22,8 @@ from __future__ import annotations
 
 import functools
 import math
-from dataclasses import dataclass
-from typing import Optional
+from dataclasses import dataclass, replace
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -32,45 +32,71 @@ import numpy as np
 from deeplearning4j_tpu import obs
 from deeplearning4j_tpu.config import env_int, env_str
 
+from deeplearning4j_tpu.models.expert_layer import (STATS, Experts,
+                                                    expert_ffn, swiglu)
 from deeplearning4j_tpu.parallel.sequence_parallel import (
     blockwise_attention, dense_attention)
 
 
-def _rope_cos_sin(c, hd, positions):
+def _rope_cos_sin(rope, hd, positions):
     """cos/sin tables for rotary embeddings at ``positions`` (any shape),
-    returned shaped positions.shape + [hd/2], in f32."""
-    inv = c.rope_base ** (-jnp.arange(0, hd, 2, dtype=jnp.float32) / hd)
-    ang = positions.astype(jnp.float32)[..., None] * inv
-    return jnp.cos(ang), jnp.sin(ang)
+    returned shaped positions.shape + [rot/2], in f32; ``rot`` = the rotated
+    share of the head's ``hd`` dims."""
+    rot = rope.rotated(hd)
+    if rope.yarn_factor is None:
+        inv = rope.base ** (-jnp.arange(0, rot, 2, dtype=jnp.float32) / rot)
+        ang = positions.astype(jnp.float32)[..., None] * inv
+        return jnp.cos(ang), jnp.sin(ang)
+    inv, factor = rope.yarn_frequencies(rot)
+    ang = positions.astype(jnp.float32)[..., None] \
+        * jnp.asarray(inv, jnp.float32)
+    return jnp.cos(ang) * factor, jnp.sin(ang) * factor
 
 
-def _apply_rope(x, cos, sin):
-    """Rotate interleaved pairs of the head dim. x: [..., T, hd];
-    cos/sin: [T, hd/2] (broadcast over the leading dims)."""
-    x1, x2 = x[..., 0::2], x[..., 1::2]
-    r1 = x1 * cos - x2 * sin
-    r2 = x1 * sin + x2 * cos
-    return jnp.stack([r1, r2], axis=-1).reshape(x.shape).astype(x.dtype)
+def _apply_rope(x, cos, sin, layout="interleaved", rot=None):
+    """Rotate pairs of the head dim. x: [..., T, hd]; cos/sin: [T, rot/2]
+    (broadcast over the leading dims). ``rot`` (None: the whole head) is how
+    many leading dims are rotated; the rest pass as they are.
+    ``interleaved``: pairs (2i, 2i+1); ``half``: pairs (i, i + rot/2),
+    Hugging Face's ``rotate_half``."""
+    xr = x if rot is None else x[..., :rot]
+    if layout == "half":
+        # The two halves apart, joined afterwards: on the v5e the compiler
+        # makes of this one fusion with two outputs, rooted in a tuple that
+        # carries no name stack, so its time reads under no scope. Written
+        # as ONE expression (x cos + rotate_half(x) sin) it reads under the
+        # layer's scope and costs 10 ms more a step of the 8k cell (the
+        # halves then cross lanes): PERF.md section 6, PR 28.
+        x1, x2 = jnp.split(xr, 2, axis=-1)
+        out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
+    else:
+        x1, x2 = xr[..., 0::2], xr[..., 1::2]
+        out = jnp.stack([x1 * cos - x2 * sin, x1 * sin + x2 * cos],
+                        axis=-1).reshape(xr.shape)
+    if rot is not None:
+        out = jnp.concatenate([out, x[..., rot:]], axis=-1)
+    return out.astype(x.dtype)
 
 
-def _full_heads(c, k, v):
+def _full_heads(kv_group, k, v):
     """Expand GQA K/V to full query heads for routes that assume MHA.
     The grouping convention (consecutive query heads share a kv head)
     must match the pallas kernels' b // kv_group index map."""
-    if c.kv_group > 1:
-        k = jnp.repeat(k, c.kv_group, axis=1)
-        v = jnp.repeat(v, c.kv_group, axis=1)
+    if kv_group > 1:
+        k = jnp.repeat(k, kv_group, axis=1)
+        v = jnp.repeat(v, kv_group, axis=1)
     return k, v
 
 
-def _blockwise_route(c, q, k, v, plan=None):
+def _blockwise_route(c, q, k, v, plan=None, window=None):
     """Route the block_size attention: the pallas flash kernel (fused fwd
     + FlashAttention-2 bwd, ops/pallas_kernels.py) when the platform
     supports it, else the mathematically identical lax.scan recurrence.
     DL4J_TPU_LM_ATTN forces {pallas, scan}; read at TRACE time (the step
     jits once), so set it before the first fit_batch. A sliding window
-    (c.window) rides the pallas route — the scan has no window support,
-    so that combination falls back to masked dense attention.
+    (``window``, the layer's) rides the pallas route — the scan has no
+    window support, so that combination falls back to masked dense
+    attention. Head count, group and head size are the arrays' own.
 
     ``plan`` is the model's ``ShardingCore`` when it was ``shard()``-ed:
     GSPMD refuses to partition a Mosaic kernel ("wrap the call in a
@@ -84,20 +110,22 @@ def _blockwise_route(c, q, k, v, plan=None):
             # GQA rides the kernel's index map — no repeat materialized
             attend = functools.partial(
                 flash_attention, causal=True, block_q=c.block_size,
-                block_k=c.block_size, window=c.window)
+                block_k=c.block_size, window=window)
             if plan is not None and plan.batch_axis:
                 spec = plan.batch_spec()
                 attend = jax.shard_map(
                     attend, mesh=plan.mesh, in_specs=(spec, spec, spec),
                     out_specs=spec, check_vma=False)
             return attend(q, k, v)
-    k, v = _full_heads(c, k, v)   # the JAX fallbacks want full heads
-    if c.window is not None:
-        return dense_attention(q, k, v, causal=True, window=c.window)
+    # the JAX fallbacks want full heads
+    k, v = _full_heads(q.shape[1] // k.shape[1], k, v)
+    if window is not None:
+        return dense_attention(q, k, v, causal=True, window=window)
     return blockwise_attention(q, k, v, causal=True,
                                block_size=c.block_size)
 
-__all__ = ["TransformerConfig", "TransformerLM", "SCOPES"]
+__all__ = ["TransformerConfig", "TransformerLM", "LayerSpec", "Rope",
+           "Experts", "SCOPES"]
 
 # The fixed vocabulary of ``jax.named_scope``s the LM step enters: what the
 # profiler's trace names a device op by (the stat ``tf_op`` of its event,
@@ -120,7 +148,71 @@ __all__ = ["TransformerConfig", "TransformerLM", "SCOPES"]
 #   scope of its own). tests/test_aot_compile.py holds this on the program
 #   compiled for the chip.
 SCOPES = ("embed", "block", "ln1", "qkv", "attn", "proj", "ln2", "mlp",
-          "final_ln", "logits_loss", "grad_clip", "optimizer")
+          "final_ln", "logits_loss", "grad_clip", "optimizer",
+          # a model with a per-layer list (``TransformerConfig.layers``):
+          # attention by the layer's kind in ``attn``'s place, the gate on
+          # its output, and the expert layer's parts in ``mlp``'s place
+          "attn_full", "attn_window", "attn_gate", "router", "moe_dispatch",
+          "experts", "shared_expert")
+
+
+@dataclass(frozen=True)
+class Rope:
+    """One layer's rotary embedding. ``share``: the leading share of a
+    head's dims that is rotated (``partial_rotary_factor``). YaRN where
+    ``yarn_factor`` is set; ``attention_factor`` None takes YaRN's own
+    ``0.1 ln(factor) + 1``, on cos and sin."""
+    base: float = 10000.0
+    share: float = 1.0
+    yarn_factor: Optional[float] = None
+    yarn_original_len: int = 4096
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    attention_factor: Optional[float] = None
+
+    def rotated(self, hd):
+        """How many of a head's ``hd`` dims are rotated."""
+        return math.floor(hd * self.share)
+
+    def yarn_frequencies(self, rot):
+        """``(frequencies [rot/2], the factor on cos and sin)`` under YaRN
+        (Peng et al. 2023, as Hugging Face's ``_compute_yarn_parameters``
+        reads a config): dims that turn more than ``yarn_beta_fast`` times
+        over the original context keep ``base ** (-2i / rot)``, those that
+        turn less than ``yarn_beta_slow`` times take it divided by
+        ``yarn_factor``, a linear ramp between."""
+        def dim_of(turns):   # the dim that turns ``turns`` times
+            return rot * math.log(self.yarn_original_len
+                                  / (turns * 2 * math.pi)) \
+                / (2 * math.log(self.base))
+
+        low = max(math.floor(dim_of(self.yarn_beta_fast)), 0)
+        high = min(math.ceil(dim_of(self.yarn_beta_slow)), rot - 1)
+        if low == high:
+            high += 0.001
+        inv = []
+        for i in range(rot // 2):
+            ramp = min(max((i - low) / (high - low), 0.0), 1.0)
+            plain = self.base ** (-2 * i / rot)
+            inv.append(plain / self.yarn_factor * ramp + plain * (1 - ramp))
+        factor = self.attention_factor
+        if factor is None:
+            factor = 0.1 * math.log(self.yarn_factor) + 1.0
+        return inv, factor
+
+
+@dataclass(frozen=True)
+class LayerSpec:
+    """What one layer of a per-layer list states (``TransformerConfig
+    .layers``); None takes the model's own setting."""
+    window: Optional[int] = None      # None = full causal attention
+    n_heads: Optional[int] = None     # query heads (the K/V heads are the model's)
+    rope: Optional[Rope] = None
+    ffn: str = "dense"                # "dense" | "experts"
+
+    @property
+    def attn_scope(self):
+        return "block.attn_window" if self.window else "block.attn_full"
 
 
 @dataclass
@@ -152,25 +244,63 @@ class TransformerConfig:
     z_loss: float = 0.0                   # PaLM logit-normalizer penalty
     ema_decay: Optional[float] = None     # Polyak weight averaging
     seed: int = 0
+    # ---- the layer's vocabulary; the defaults are the GPT-2 block --------
+    norm: str = "layernorm"               # "layernorm" (gain + bias) | "rmsnorm"
+    norm_eps: float = 1e-5
+    bias: bool = True                     # biases on every projection
+    ffn: str = "gelu"                     # "gelu" | "swiglu"
+    tie_embeddings: bool = True           # False: an output head of its own
+    head_dim: Optional[int] = None        # None = d_model // n_heads
+    rope_layout: str = "interleaved"      # "interleaved" | "half" (rotate_half)
+    attn_gate: bool = False               # per-head sigmoid gate on attention's output
+    experts: Optional[Experts] = None     # the expert layers' settings
+    # per-layer list, one LayerSpec a layer: attention kind and window,
+    # query heads, rope, FFN kind. None: every layer the model's settings.
+    layers: Optional[Tuple[LayerSpec, ...]] = None
 
     def __post_init__(self):
-        if self.d_model % self.n_heads:
+        if self.head_dim is None and self.d_model % self.n_heads:
             raise ValueError(
                 f"d_model {self.d_model} not divisible by n_heads "
                 f"{self.n_heads}")
         if self.window is not None and self.window < 1:
             raise ValueError(f"window must be >= 1, got {self.window}")
-        if self.n_kv_heads is not None and self.n_heads % self.n_kv_heads:
-            raise ValueError(
-                f"n_heads {self.n_heads} not divisible by n_kv_heads "
-                f"{self.n_kv_heads}")
         if self.pos_embed not in ("learned", "rope"):
             raise ValueError(f"unknown pos_embed {self.pos_embed!r}")
-        if self.pos_embed == "rope" and (self.d_model // self.n_heads) % 2:
-            raise ValueError("rope needs an even head dim")
         if self.ema_decay is not None and not 0.0 < self.ema_decay < 1.0:
             raise ValueError(f"ema_decay must be in (0, 1), "
                              f"got {self.ema_decay}")
+        for name, value, known in (
+                ("norm", self.norm, ("layernorm", "rmsnorm")),
+                ("ffn", self.ffn, ("gelu", "swiglu")),
+                ("rope_layout", self.rope_layout, ("interleaved", "half"))):
+            if value not in known:
+                raise ValueError(f"unknown {name} {value!r}")
+        if self.layers is not None:
+            self.layers = tuple(self.layers)
+            if len(self.layers) != self.n_layers:
+                raise ValueError(f"{len(self.layers)} layers listed for "
+                                 f"n_layers {self.n_layers}")
+        for i in range(self.n_layers):
+            spec = self.layer_spec(i)
+            if spec.n_heads % self.kv_heads:
+                raise ValueError(
+                    f"layer {i}: n_heads {spec.n_heads} not divisible by "
+                    f"n_kv_heads {self.kv_heads}")
+            if spec.window is not None and spec.window < 1:
+                raise ValueError(f"layer {i}: window must be >= 1")
+            if spec.ffn not in ("dense", "experts"):
+                raise ValueError(f"layer {i}: unknown ffn {spec.ffn!r}")
+            if spec.ffn == "experts" and self.experts is None:
+                raise ValueError(f"layer {i} has experts and the "
+                                 "configuration's `experts` is None")
+            if self.pos_embed == "rope" and spec.rope.rotated(self.hd) % 2:
+                raise ValueError("rope needs an even rotated head dim")
+
+    @property
+    def hd(self):
+        """A head's size, the same in every layer."""
+        return self.head_dim or self.d_model // self.n_heads
 
     @property
     def kv_heads(self):
@@ -179,6 +309,53 @@ class TransformerConfig:
     @property
     def kv_group(self):
         return self.n_heads // self.kv_heads
+
+    def layer_spec(self, i):
+        """Layer ``i``'s ``LayerSpec`` with nothing left None but a full
+        layer's ``window``."""
+        spec = (self.layers[i] if self.layers is not None
+                else LayerSpec(window=self.window))
+        return replace(spec, n_heads=spec.n_heads or self.n_heads,
+                       rope=spec.rope or Rope(base=self.rope_base))
+
+    def one_block(self, what):
+        """The ``LayerSpec`` every layer shares, for ``what``: a trainer that
+        builds ONE block program and runs it for every layer. It refuses a
+        per-layer list or experts by name, never trains every layer as
+        layer 0."""
+        if self.layers is not None or self.experts is not None:
+            raise NotImplementedError(
+                f"{what} runs one block program for every layer and is not "
+                "implemented for a configuration with a per-layer list "
+                "(`layers`) or `experts`; train it through "
+                "TransformerLM.fit_batch")
+        return self.layer_spec(0)
+
+    @property
+    def has_experts(self):
+        return any(self.layer_spec(i).ffn == "experts"
+                   for i in range(self.n_layers))
+
+    def served_as_gpt2(self, what):
+        """``generate``, beam search and the continuous-batching decode
+        programs are written for the GPT-2 block with rope and one window;
+        they refuse any other setting by its name, never run without it."""
+        found = [name for name, differs in (
+            ("a per-layer list (`layers`)", self.layers is not None),
+            ("experts", self.experts is not None),
+            ("attn_gate", self.attn_gate),
+            (f"norm {self.norm!r}", self.norm != "layernorm"),
+            ("bias False", not self.bias),
+            (f"ffn {self.ffn!r}", self.ffn != "gelu"),
+            ("an untied head", not self.tie_embeddings),
+            ("head_dim", self.head_dim is not None),
+            (f"rope_layout {self.rope_layout!r}",
+             self.rope_layout != "interleaved")) if differs]
+        if found:
+            raise NotImplementedError(
+                f"{what} is not implemented for a configuration with "
+                + ", ".join(found) + "; train and score it through "
+                "fit_batch / eval_loss / output")
 
 
 def _decay_mask(params):
@@ -200,7 +377,22 @@ def _layer_norm(x, g, b, eps=1e-5):
     return (x - m) / jnp.sqrt(v + eps) * g + b
 
 
-def _block_apply(c, bp, x, drop=None, rng=None, attend=None, ffn=None,
+def _norm(c, p, name, x):
+    """The configuration's norm with the parameters ``p[name + "_g"]`` (and
+    ``"_b"``): LayerNorm, or RMSNorm (a gain, no mean, no bias)."""
+    if c.norm == "rmsnorm":
+        ms = jnp.mean(jnp.square(x.astype(jnp.float32)), -1, keepdims=True)
+        return (x * jax.lax.rsqrt(ms + c.norm_eps).astype(x.dtype)) \
+            * p[name + "_g"]
+    return _layer_norm(x, p[name + "_g"], p[name + "_b"], c.norm_eps)
+
+
+def _linear(c, bp, name, x):
+    y = x @ bp[name]
+    return y + bp[name + "_b"] if c.bias else y
+
+
+def _block_apply(c, bp, x, spec, drop=None, rng=None, attend=None, ffn=None,
                  plan=None):
     """One pre-LN block from its param dict — THE canonical block math,
     shared by TransformerLM (which threads its residual-branch dropout in
@@ -210,9 +402,14 @@ def _block_apply(c, bp, x, drop=None, rng=None, attend=None, ffn=None,
     here reaches every consumer; only the TP trainer re-derives it (its
     weights are partitioned, so the matmuls are structurally
     different). ``plan`` (the GSPMD ``ShardingCore`` of a ``shard()``-ed
-    model) only reaches the flash-kernel route."""
+    model) only reaches the flash-kernel route.
+
+    ``spec`` is the layer's resolved ``LayerSpec``: ``c.layer_spec(i)``, or
+    ``c.one_block(who)`` in a trainer that runs one block for every layer.
+    Every choice it and ``c`` make is made at trace time. A layer with
+    experts returns ``(x, statistics)``."""
     B, T, d = x.shape
-    hd = d // c.n_heads
+    hd, H = c.hd, spec.n_heads
     r1 = r2 = None
     if rng is not None:
         r1, r2 = jax.random.split(rng)
@@ -220,35 +417,48 @@ def _block_apply(c, bp, x, drop=None, rng=None, attend=None, ffn=None,
     # the note at SCOPES for what the flash kernels' instruction names need
     scope = jax.named_scope
     with scope("block.ln1"):
-        hloc = _layer_norm(x, bp["ln1_g"], bp["ln1_b"])
+        hloc = _norm(c, bp, "ln1", x)
     with scope("block.qkv"):
-        qkv = hloc @ bp["qkv"] + bp["qkv_b"]
-    with scope("block.attn"):
-        kvd = c.kv_heads * hd
-        q, k, v = jnp.split(qkv, [d, d + kvd], axis=-1)
-        split = lambda a, H: a.reshape(B, T, H, hd).transpose(0, 2, 1, 3)
-        q = split(q, c.n_heads)
+        qkv = _linear(c, bp, "qkv", hloc)
+    with scope(spec.attn_scope if c.layers is not None else "block.attn"):
+        qd, kvd = H * hd, c.kv_heads * hd
+        q, k, v = jnp.split(qkv, [qd, qd + kvd], axis=-1)
+        split = lambda a, n: a.reshape(B, T, n, hd).transpose(0, 2, 1, 3)
+        q = split(q, H)
         k, v = split(k, c.kv_heads), split(v, c.kv_heads)
         if c.pos_embed == "rope":
-            cos, sin = _rope_cos_sin(c, hd, jnp.arange(T))
-            q, k = _apply_rope(q, cos, sin), _apply_rope(k, cos, sin)
+            cos, sin = _rope_cos_sin(spec.rope, hd, jnp.arange(T))
+            rot = spec.rope.rotated(hd) if spec.rope.share < 1.0 else None
+            q = _apply_rope(q, cos, sin, c.rope_layout, rot)
+            k = _apply_rope(k, cos, sin, c.rope_layout, rot)
         if attend is not None:
-            k, v = _full_heads(c, k, v)   # custom attends (ring SP): MHA
+            # custom attends (ring SP): MHA
+            k, v = _full_heads(H // c.kv_heads, k, v)
             o = attend(q, k, v)
         elif c.block_size:
-            o = _blockwise_route(c, q, k, v, plan)
+            o = _blockwise_route(c, q, k, v, plan, spec.window)
         else:
-            k, v = _full_heads(c, k, v)
-            o = dense_attention(q, k, v, causal=True, window=c.window)
-        o = o.transpose(0, 2, 1, 3).reshape(B, T, d)
+            k, v = _full_heads(H // c.kv_heads, k, v)
+            o = dense_attention(q, k, v, causal=True, window=spec.window)
+        o = o.transpose(0, 2, 1, 3).reshape(B, T, qd)
+    if c.attn_gate:
+        with scope("block.attn_gate"):
+            gate = jax.nn.sigmoid(hloc @ bp["attn_gate"])      # [B, T, H]
+            o = (o.reshape(B, T, H, hd)
+                 * gate[..., None].astype(o.dtype)).reshape(B, T, qd)
     with scope("block.proj"):
-        a = o @ bp["proj"] + bp["proj_b"]
+        a = _linear(c, bp, "proj", o)
         x = x + (drop(a, r1) if drop else a)
     with scope("block.ln2"):
-        hloc = _layer_norm(x, bp["ln2_g"], bp["ln2_b"])
+        hloc = _norm(c, bp, "ln2", x)
+    if spec.ffn == "experts" and ffn is None:
+        m, stats = expert_ffn(c.experts, bp, hloc)
+        return x + (drop(m, r2) if drop else m), stats
     with scope("block.mlp"):
         if ffn is not None:
             m = ffn(bp, hloc)
+        elif c.ffn == "swiglu":
+            m = swiglu(hloc, bp["fc_gate"], bp["fc"], bp["out"])
         else:
             m = jax.nn.gelu(hloc @ bp["fc"] + bp["fc_b"]) @ bp["out"] \
                 + bp["out_b"]
@@ -257,7 +467,8 @@ def _block_apply(c, bp, x, drop=None, rng=None, attend=None, ffn=None,
 
 def _forward_tokens(c, params, tokens, apply_block):
     """THE canonical token forward: embed + compute_dtype cast + per-layer
-    ``apply_block(i, block_params, x)`` + final LN + tied logits in f32.
+    ``apply_block(i, block_params, x)`` + final norm + logits in f32 (the
+    embedding again where it is tied, else the head).
     Shared by TransformerLM, the MoE family, and the EP trainer so the
     cast/loop/head logic exists once."""
     T = tokens.shape[1]
@@ -275,8 +486,10 @@ def _forward_tokens(c, params, tokens, apply_block):
     for i in range(c.n_layers):
         x = apply_block(i, params[f"b{i}"], x)
     with jax.named_scope("final_ln"):
-        x = _layer_norm(x, params["lnf_g"], params["lnf_b"])
+        x = _norm(c, params, "lnf", x)
     with jax.named_scope("logits_loss"):
+        if not c.tie_embeddings:
+            return (x @ params["head"]).astype(jnp.float32)
         return (x @ params["wte"].T).astype(jnp.float32)   # tied embeddings
 
 
@@ -322,8 +535,30 @@ def _adamw_apply(c, params, grads, opt, t, lr_t, mask=None):
     return new_p, {"m": new_m, "v": new_v}
 
 
+_COUNT_LOW = 1 << 30
+_MOE_DOCS = {
+    "moe.local_rows": "Expert assignments that met an expert held here, "
+                      "since init (read by TransformerLM.moe_counters)",
+    "moe.rows_computed": "Rows the grouped expert products ran over, "
+                         "padding included, since init",
+    "moe.rows_over_buffer": "Expert assignments left out because the static "
+                            "row buffer was full, since init (0 when sound)",
+}
+
+
+def _count(total, n):
+    """``total`` (int32 [2]: multiples of 2**30, and the rest) plus ``n``: a
+    count carried on the device through the steps, read rarely, that a
+    32-bit integer alone would not hold for long."""
+    low = total[1] + n
+    return jnp.stack([total[0] + low // _COUNT_LOW, low % _COUNT_LOW])
+
+
 class TransformerLM:
-    """Pre-LN decoder-only LM with tied input/output embeddings."""
+    """Pre-LN decoder-only LM. The defaults of ``TransformerConfig`` are the
+    GPT-2 block with tied input/output embeddings; its other settings (norm,
+    biases, FFN kind, head, rope, gate, the per-layer list, experts) are one
+    block and one step, branched at trace time."""
 
     def __init__(self, config: TransformerConfig):
         self.conf = config
@@ -418,34 +653,67 @@ class TransformerLM:
     def init(self):
         c = self.conf
         ks = jax.random.split(jax.random.PRNGKey(c.seed), 4 + 8 * c.n_layers)
-        d, h = c.d_model, c.d_ff
+        d, h, hd = c.d_model, c.d_ff, c.hd
         std = 0.02
-        p = {
-            "wte": std * jax.random.normal(ks[0], (c.vocab_size, d)),
-            "lnf_g": jnp.ones((d,)), "lnf_b": jnp.zeros((d,)),
-        }
+        normal = lambda key, shape, s=std: s * jax.random.normal(key, shape)
+        # residual-branch output projections scaled 1/sqrt(2L) (GPT-2)
+        rs = std / math.sqrt(2 * c.n_layers)
+
+        def norm(p, name):
+            p[name + "_g"] = jnp.ones((d,))
+            if c.norm == "layernorm":
+                p[name + "_b"] = jnp.zeros((d,))
+
+        def linear(p, name, key, shape, s=std):
+            p[name] = normal(key, shape, s)
+            if c.bias:
+                p[name + "_b"] = jnp.zeros(shape[-1:])
+
+        p = {"wte": normal(ks[0], (c.vocab_size, d))}
+        norm(p, "lnf")
         if c.pos_embed == "learned":   # rope needs no position table
-            p["wpe"] = std * jax.random.normal(ks[1], (c.max_len, d))
-        # GQA shrinks the K/V projections: q keeps d columns, k/v carry
-        # kv_heads*hd each (== d for MHA)
-        qkv_cols = d + 2 * c.kv_heads * (d // c.n_heads)
+            p["wpe"] = normal(ks[1], (c.max_len, d))
+        if not c.tie_embeddings:
+            p["head"] = normal(ks[2], (d, c.vocab_size))
         for i in range(c.n_layers):
             k = ks[4 + 8 * i:4 + 8 * (i + 1)]
-            # residual-branch output projections scaled 1/sqrt(2L) (GPT-2)
-            rs = std / math.sqrt(2 * c.n_layers)
-            p[f"b{i}"] = {
-                "ln1_g": jnp.ones((d,)), "ln1_b": jnp.zeros((d,)),
-                "qkv": std * jax.random.normal(k[0], (d, qkv_cols)),
-                "qkv_b": jnp.zeros((qkv_cols,)),
-                "proj": rs * jax.random.normal(k[1], (d, d)),
-                "proj_b": jnp.zeros((d,)),
-                "ln2_g": jnp.ones((d,)), "ln2_b": jnp.zeros((d,)),
-                "fc": std * jax.random.normal(k[2], (d, h)),
-                "fc_b": jnp.zeros((h,)),
-                "out": rs * jax.random.normal(k[3], (h, d)),
-                "out_b": jnp.zeros((d,)),
-            }
+            spec = c.layer_spec(i)
+            qd = spec.n_heads * hd
+            bp = p[f"b{i}"] = {}
+            norm(bp, "ln1")
+            # GQA shrinks the K/V projections: q keeps n_heads*hd columns,
+            # k/v carry kv_heads*hd each (all three d for MHA)
+            linear(bp, "qkv", k[0], (d, qd + 2 * c.kv_heads * hd))
+            linear(bp, "proj", k[1], (qd, d), rs)
+            norm(bp, "ln2")
+            if c.attn_gate:
+                bp["attn_gate"] = normal(k[4], (d, spec.n_heads))
+            if spec.ffn == "experts":
+                ex = c.experts
+                count, f = ex.held_range[1], ex.d_expert
+                bp["router"] = normal(k[2], (d, ex.n_experts))
+                bp["W_gate"] = normal(k[3], (count, d, f))
+                bp["W_up"] = normal(k[5], (count, d, f))
+                bp["W_down"] = normal(k[6], (count, f, d), rs)
+                if ex.d_shared:
+                    k7 = jax.random.split(k[7], 3)
+                    bp["sh_gate"] = normal(k7[0], (d, ex.d_shared))
+                    bp["sh_up"] = normal(k7[1], (d, ex.d_shared))
+                    bp["sh_down"] = normal(k7[2], (ex.d_shared, d), rs)
+                continue
+            linear(bp, "fc", k[2], (d, h))
+            linear(bp, "out", k[3], (h, d), rs)
+            if c.ffn == "swiglu":
+                bp["fc_gate"] = normal(k[5], (d, h))
         self.params = jax.tree.map(lambda a: jnp.asarray(a, jnp.float32), p)
+        self._init_opt_state()
+        return self
+
+    def _init_opt_state(self):
+        """AdamW's moments at zero beside ``self.params`` (and the Polyak
+        shadow, and the expert layers' counters, where the configuration has
+        them)."""
+        c = self.conf
         self.opt_state = {
             "m": jax.tree.map(jnp.zeros_like, self.params),
             "v": jax.tree.map(jnp.zeros_like, self.params),
@@ -453,11 +721,32 @@ class TransformerLM:
         if c.ema_decay is not None:   # Polyak shadow starts at the init
             self.opt_state["ema"] = jax.tree.map(lambda a: a + 0,
                                                  self.params)
-        return self
+        if c.has_experts:
+            self.opt_state["moe"] = {k: jnp.zeros((2,), jnp.int32)
+                                     for k in STATS}
 
     def num_params(self):
         return sum(int(np.prod(a.shape))
                    for a in jax.tree.leaves(self.params))
+
+    def moe_counters(self):
+        """The expert layers' counts since ``init``, summed over layers and
+        steps: ``moe.local_rows`` (assignments that met a held expert),
+        ``moe.rows_computed`` (rows the grouped products ran over, padding
+        and all) and ``moe.rows_over_buffer`` (assignments left out because
+        the row buffer was full: 0 in a sound run). They are carried on the
+        device beside the optimizer's state and fetched HERE, one sync, so
+        never call it inside a timed loop; the ``obs.metrics`` gauges of the
+        same names take what was read. ``{}`` for a model without experts."""
+        moe = (self.opt_state or {}).get("moe")
+        if moe is None:
+            return {}
+        host = jax.device_get(moe)
+        out = {f"moe.{k}": int(v[0]) * _COUNT_LOW + int(v[1])
+               for k, v in host.items()}
+        for name, n in out.items():
+            obs.metrics.gauge(name, _MOE_DOCS[name]).set(n)
+        return out
 
     # ---- forward -------------------------------------------------------
     def _drop(self, x, rng):
@@ -469,24 +758,32 @@ class TransformerLM:
         keep = jax.random.bernoulli(rng, 1.0 - rate, x.shape)
         return jnp.where(keep, x / (1.0 - rate), 0.0).astype(x.dtype)
 
-    def _block(self, bp, x, rng=None):
-        return _block_apply(self.conf, bp, x, drop=self._drop, rng=rng,
+    def _block(self, spec, bp, x, rng=None):
+        return _block_apply(self.conf, bp, x, spec, drop=self._drop, rng=rng,
                             plan=self._shard_plan)
 
-    def _logits(self, params, tokens, rng=None):
+    def _logits(self, params, tokens, rng=None, stats=None):
+        """``stats``: a list that takes each expert layer's statistics (they
+        leave a rematerialised block as outputs of it); None drops them."""
         c = self.conf
         rngs = (jax.random.split(rng, c.n_layers)
                 if rng is not None and c.dropout > 0 else [None] * c.n_layers)
 
         def apply(i, bp, x):
-            blk = (jax.checkpoint(self._block) if c.remat else self._block)
-            return blk(bp, x, rngs[i])
+            spec = c.layer_spec(i)
+            blk = functools.partial(self._block, spec)
+            out = (jax.checkpoint(blk) if c.remat else blk)(bp, x, rngs[i])
+            if spec.ffn != "experts":
+                return out
+            if stats is not None:
+                stats.append(out[1])
+            return out[0]
 
         return _forward_tokens(c, params, tokens, apply)
 
-    def _loss(self, params, tokens, targets, mask, rng=None):
+    def _loss(self, params, tokens, targets, mask, rng=None, stats=None):
         c = self.conf
-        logits = self._logits(params, tokens, rng)
+        logits = self._logits(params, tokens, rng, stats)
         with jax.named_scope("logits_loss"):
             logp = jax.nn.log_softmax(logits, axis=-1)
             nll = -jnp.take_along_axis(logp, targets[..., None],
@@ -516,9 +813,17 @@ class TransformerLM:
         def step(params, opt, it, rng, tokens, targets, mask):
             rng, sub = jax.random.split(rng)
             fwd_params = params if plan is None else plan.gather_params(params)
-            loss, grads = jax.value_and_grad(self._loss)(
-                fwd_params, tokens, targets, mask,
-                sub if c.dropout > 0 else None)
+            sub = sub if c.dropout > 0 else None
+            if c.has_experts:
+                def loss_and_stats(p):
+                    found = []
+                    loss = self._loss(p, tokens, targets, mask, sub, found)
+                    return loss, {k: sum(f[k] for f in found) for k in STATS}
+                (loss, stats), grads = jax.value_and_grad(
+                    loss_and_stats, has_aux=True)(fwd_params)
+            else:
+                loss, grads = jax.value_and_grad(self._loss)(
+                    fwd_params, tokens, targets, mask, sub)
             if plan is not None:
                 grads = plan.constrain_grads(grads)
             if c.grad_clip_norm is not None:
@@ -538,6 +843,9 @@ class TransformerLM:
                     d = c.ema_decay
                     new_opt["ema"] = jax.tree.map(
                         lambda e, p: d * e + (1.0 - d) * p, opt["ema"], new_p)
+            if c.has_experts:
+                new_opt["moe"] = {k: _count(opt["moe"][k], stats[k])
+                                  for k in STATS}
             if plan is not None:
                 # pin updated state to its at-rest placement: level <= 2
                 # all-gathers the sharded delta onto the replicated
@@ -765,6 +1073,7 @@ class TransformerLM:
         scheduler (serving/decode.py); the device copies here are the
         traced truth."""
         c = self.conf
+        c.served_as_gpt2("the continuous-batching KV slot pool (ContinuousLM)")
         hd = c.d_model // c.n_heads
         total = c.max_len
         cdt = self._cache_dtype()
@@ -977,6 +1286,7 @@ class TransformerLM:
         pages ``[L, kv_heads, W, hd]`` so the scheduler can memoise
         them."""
         c = self.conf
+        c.served_as_gpt2("chunked prefill (ContinuousLM)")
         d = c.d_model
         hd = d // c.n_heads
         L = c.n_layers
@@ -1014,7 +1324,8 @@ class TransformerLM:
             if c.window is not None:   # sliding-window attention rides
                 keep &= tpos[None, :] > (pos_w[:, None] - c.window)
             if c.pos_embed == "rope":
-                cos, sin = _rope_cos_sin(c, hd, pos_w)       # [W, hd/2]
+                cos, sin = _rope_cos_sin(c.layer_spec(0).rope, hd,
+                                         pos_w)              # [W, hd/2]
             new_k, new_v, pk, pv = [], [], [], []
             for i in range(L):
                 bp = params[f"b{i}"]
@@ -1108,6 +1419,8 @@ class TransformerLM:
         compute dtype with f32 logits; one fix here reaches every decode
         consumer."""
         c = self.conf
+        c.served_as_gpt2("KV-cache decoding (generate, beam_search, "
+                         "ContinuousLM)")
         d = c.d_model
         hd = d // c.n_heads
         L = c.n_layers
@@ -1127,10 +1440,11 @@ class TransformerLM:
             k, v = sh(k, c.kv_heads), sh(v, c.kv_heads)
             if c.pos_embed == "rope":   # cache stores ROTATED keys
                 if vector_pos:          # per-row rotation angle
-                    cos, sin = _rope_cos_sin(c, hd, pos)
+                    cos, sin = _rope_cos_sin(c.layer_spec(0).rope, hd, pos)
                     cos, sin = cos[:, None, None, :], sin[:, None, None, :]
                 else:
-                    cos, sin = _rope_cos_sin(c, hd, jnp.asarray(pos)[None])
+                    cos, sin = _rope_cos_sin(c.layer_spec(0).rope, hd,
+                                             jnp.asarray(pos)[None])
                 q, k = _apply_rope(q, cos, sin), _apply_rope(k, cos, sin)
             if vector_pos:
                 # per-row scatter at pos: rows past the cache end (a

@@ -120,6 +120,7 @@ class EPTransformerLM:
     def _local_loss(self, params, tokens, targets, capacity):
         c = self.conf
         auxes = []
+        spec = c.one_block("EPTransformerLM")
 
         def moe_block(bp, xx):
             cell = {}
@@ -130,11 +131,11 @@ class EPTransformerLM:
                 cell["aux"] = aux
                 return y
 
-            out = _block_apply(c, bp, xx, ffn=ffn)
+            out = _block_apply(c, bp, xx, spec, ffn=ffn)
             return out, cell["aux"]
 
         def dense_block(bp, xx):
-            return _block_apply(c, bp, xx)
+            return _block_apply(c, bp, xx, spec)
 
         def apply(i, bp, x):
             if i in self._moe_layers:

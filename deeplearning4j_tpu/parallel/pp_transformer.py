@@ -52,6 +52,7 @@ class PPTransformerLM:
 
     def __init__(self, mesh: Mesh, config: TransformerConfig,
                  n_micro: int, axis: str = "pipe"):
+        self._spec = config.one_block("PPTransformerLM")
         if config.dropout:
             raise ValueError("PP trainer runs dropout-free (eval parity)")
         if config.pos_embed != "learned":
@@ -125,7 +126,7 @@ class PPTransformerLM:
         local_blocks = {k: params["blocks"][k][0]     # (bps, ...)
                         for k in self._block_keys}
 
-        blk = lambda bp, x: _block_apply(c, bp, x)
+        blk = lambda bp, x: _block_apply(c, bp, x, self._spec)
         if c.remat:
             blk = jax.checkpoint(blk)   # closure over config: only arrays
                                         # cross the checkpoint boundary

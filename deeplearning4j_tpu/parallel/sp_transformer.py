@@ -47,6 +47,7 @@ class SPTransformerLM:
 
     def __init__(self, mesh: Mesh, config: TransformerConfig,
                  axis: str = "seq"):
+        self._spec = config.one_block("SPTransformerLM")
         if config.dropout:
             raise ValueError("SP trainer runs dropout-free (eval parity)")
         if config.block_size:
@@ -86,7 +87,7 @@ class SPTransformerLM:
         and shards trivially)."""
         ring = lambda q, k, v: ring_attention(
             q, k, v, axis_name=self.axis, causal=True)
-        return _block_apply(self.conf, bp, x, attend=ring)
+        return _block_apply(self.conf, bp, x, self._spec, attend=ring)
 
     def _local_loss(self, params, tokens, targets):
         """tokens/targets: [B, T/N] local shards; returns the local nll

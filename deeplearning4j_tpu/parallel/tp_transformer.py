@@ -52,6 +52,7 @@ class TPTransformerLM:
         TP×DP: params sharded over ``model`` and replicated over ``data``
         (axes a spec doesn't name are replicated), batch sharded over
         ``data``, one gradient psum over ``data`` per step."""
+        config.one_block("TPTransformerLM")
         if config.dropout:
             raise ValueError("TP trainer runs dropout-free (eval parity)")
         if config.block_size:

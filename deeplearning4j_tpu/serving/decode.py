@@ -280,6 +280,9 @@ class ContinuousLM(ServingFrontEnd):
                  seed=0, kv_ladder=None, prefill_ladder=None,
                  prefix_cache_mb=None):
         super().__init__(queue_cap=queue_cap)
+        # the decode and prefill programs are the GPT-2 block's: refuse any
+        # other setting here, by its name, not at the first request
+        lm.conf.served_as_gpt2("continuous batching (ContinuousLM)")
         if lm.params is None:
             lm.init()
         self.lm = lm

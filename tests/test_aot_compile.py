@@ -71,19 +71,25 @@ FLASH_SHAPES = {
     "gqa_d128": ((2, 16, 2048, 128), (2, 4, 2048, 128), 512),
     "gpt2m_t1024": ((8, 16, 1024, 64), (8, 16, 1024, 64), 512),
     "gpt2m_t256": ((32, 16, 256, 64), (32, 16, 256, 64), 256),
+    # the third cell (Laguna-XS.2, 2 x 8192 tokens): heads of 128, rows of 16
+    # blocks, groups of 6 and 8 query heads a key/value head; the window
+    # layers see one block (a fourth entry is the window)
+    "laguna_full": ((2, 48, 8192, 128), (2, 8, 8192, 128), 512),
+    "laguna_window": ((2, 64, 8192, 128), (2, 8, 8192, 128), 512, 512),
 }
 
 
 @pytest.mark.parametrize("shape", sorted(FLASH_SHAPES))
 @pytest.mark.parametrize("wrt", ["fwd", "dq", "dkv"])
 def test_flash_kernels_compile_for_v5e(v5e, shape, wrt):
-    q_shape, kv_shape, block = FLASH_SHAPES[shape]
+    q_shape, kv_shape, block, *window = FLASH_SHAPES[shape]
+    window = window[0] if window else None
     q = jax.ShapeDtypeStruct(q_shape, jnp.bfloat16, sharding=v5e)
     kv = jax.ShapeDtypeStruct(kv_shape, jnp.bfloat16, sharding=v5e)
 
     def attend(q, k, v):
         return pk.flash_attention(q, k, v, causal=True, block_q=block,
-                                  block_k=block)
+                                  block_k=block, window=window)
 
     def loss(q, k, v):
         return attend(q, k, v).astype(jnp.float32).sum()
@@ -205,6 +211,42 @@ def test_lm_step_names_reach_the_chips_program(v5e, monkeypatch):
         assert re.search(rf'op_name="jit\(step\)/[^"]*{scope}[)/]', text), scope
 
 
+def test_mixed_lm_step_compiles_for_v5e(v5e, monkeypatch):
+    """A step with a per-layer list (full and window layers with their own
+    head counts, the gate, experts held 8 of 16, remat) compiles for the chip:
+    four flash kernels a layer (forward, forward again under remat, dQ,
+    dK/dV), the grouped products as the compiler's own kernels, and every
+    scope of the per-layer vocabulary in the program's names."""
+    import re
+
+    import chip_smoke
+    from deeplearning4j_tpu.models.transformer import TransformerLM
+    monkeypatch.setenv("DL4J_TPU_LM_ATTN", "pallas")
+    sizes = dict(chip_smoke._MIXED_LM, seq=1024, d_model=128, d_ff=256,
+                 vocab_size=512)
+    lm = TransformerLM(chip_smoke._mixed_config(sizes, 0))
+    params, opt = jax.eval_shape(lambda: (lm.init().params, lm.opt_state))
+    lm.params = lm.opt_state = None
+    sds = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e)
+    tokens = jax.ShapeDtypeStruct((2, sizes["seq"]), jnp.int32, sharding=v5e)
+    text = lm._build_step().lower(
+        jax.tree.map(sds, params), jax.tree.map(sds, opt),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=v5e),
+        jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=v5e),
+        tokens, tokens, None).compile().as_text()
+    kernels = re.findall(r'custom_call_target="tpu_custom_call"[^\n]*'
+                         r'op_name="([^"]*)"', text)
+    flash = [n for n in kernels if n.endswith("pallas_call")]
+    assert len(flash) == 4 * lm.conf.n_layers
+    assert sum("block.attn_window" in n for n in flash) == 4 * 3
+    assert sum("block.attn_full" in n for n in flash) == 4 * 2
+    assert "ragged-dot" in text
+    for scope in ("attn_gate", "router", "moe_dispatch", "experts",
+                  "shared_expert"):
+        assert re.search(rf'op_name="jit\(step\)/[^"]*block\.{scope}[)/]',
+                         text), scope
+
+
 def _smoke(*args, env=None):
     return subprocess.run([sys.executable, SMOKE, *args], cwd=REPO,
                           env=env, capture_output=True, text=True,
@@ -212,15 +254,15 @@ def _smoke(*args, env=None):
 
 
 def test_chip_smoke_rehearsal_passes_on_cpu():
-    """The two LM phases at tiny size on the CPU (the ResNet rehearsal is
+    """The three LM phases at tiny size on the CPU (the ResNet rehearsal is
     `make smoke-rehearse`): every check passes and the last line names the
     CPU — it can never be read as a chip result."""
-    r = _smoke("--rehearse", "--phases", "train_lm,serve_lm")
+    r = _smoke("--rehearse", "--phases", "train_lm,train_mixed_lm,serve_lm")
     assert r.returncode == 0, r.stdout[-3000:] + r.stderr[-3000:]
     lines = [json.loads(l) for l in r.stdout.splitlines()
              if l.startswith("{")]
-    assert [l["phase"] for l in lines[:-1]] == ["setup", "train_lm",
-                                                "serve_lm"]
+    assert [l["phase"] for l in lines[:-1]] == [
+        "setup", "train_lm", "train_mixed_lm", "serve_lm"]
     assert all(l["ok"] for l in lines)
     assert lines[-1] == {"ok": True, "device": {
         "platform": "cpu", "kind": "cpu", "count": 1}}

@@ -47,13 +47,66 @@ def lm_stacks():
         return name_stacks(lowered.as_text(debug_info=True))
 
 
+@pytest.fixture(scope="module")
+def mixed_stacks():
+    """The name stacks of a tiny step with a per-layer list: a full and a
+    window layer, the gate, experts and a shared expert, under remat."""
+    from deeplearning4j_tpu.models.transformer import Experts, LayerSpec
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("DL4J_TPU_PALLAS_INTERPRET", "1")
+        mp.setenv("DL4J_TPU_LM_ATTN", "pallas")
+        lm = transformer.TransformerLM(transformer.TransformerConfig(
+            vocab_size=64, max_len=32, d_model=32, n_heads=2, n_kv_heads=1,
+            head_dim=8, n_layers=2, d_ff=64, block_size=16, pos_embed="rope",
+            norm="rmsnorm", bias=False, ffn="swiglu", tie_embeddings=False,
+            attn_gate=True, remat=True, compute_dtype="bfloat16",
+            layers=(LayerSpec(), LayerSpec(window=8, n_heads=4,
+                                           ffn="experts")),
+            experts=Experts(n_experts=4, top_k=2, d_expert=16, held=(0, 2),
+                            d_shared=16))).init()
+        tokens = jnp.zeros((2, 32), jnp.int32)
+        lowered = lm._build_step().lower(
+            lm.params, lm.opt_state, jnp.int32(0), jax.random.PRNGKey(0),
+            tokens, tokens, None)
+        return name_stacks(lowered.as_text(debug_info=True))
+
+
+# what a model with a per-layer list enters in ``attn``'s and ``mlp``'s place
+PER_LAYER_SCOPES = ("attn_full", "attn_window", "attn_gate", "router",
+                    "moe_dispatch", "experts", "shared_expert")
+
+
 def test_the_benchmark_reads_the_programs_vocabulary():
-    assert scope_reduce.LM_SCOPES == transformer.SCOPES
+    """``scope_reduce.LM_SCOPES`` is the benchmark's accepted copy (its
+    ``unscoped_share`` finds ``block`` in every layer's scope either way);
+    what PR 28 added comes after it."""
+    assert transformer.SCOPES == scope_reduce.LM_SCOPES + PER_LAYER_SCOPES
 
 
 @pytest.mark.parametrize("scope", transformer.SCOPES)
-def test_lm_step_enters_every_scope_of_the_vocabulary(lm_stacks, scope):
-    assert any(scope in scope_reduce.tokens(s) for s in lm_stacks)
+def test_lm_step_enters_every_scope_of_the_vocabulary(lm_stacks, mixed_stacks,
+                                                      scope):
+    stacks = mixed_stacks if scope in PER_LAYER_SCOPES else lm_stacks
+    assert any(scope in scope_reduce.tokens(s) for s in stacks)
+
+
+def test_per_layer_scopes_name_the_kernels_of_their_layer_type(mixed_stacks):
+    """The flash kernels of a window layer sit under ``block.attn_window``,
+    forward and (rematerialised) backward, those of a full layer under
+    ``block.attn_full``; no layer with a per-layer list enters ``attn``."""
+    kernels = {s for s in mixed_stacks if s.endswith("/pallas_call")}
+    assert kernels
+    for s in kernels:
+        assert len({"attn_full", "attn_window"} & scope_reduce.tokens(s)) == 1
+    for scope in ("attn_full", "attn_window"):
+        mine = [s for s in kernels if scope in scope_reduce.tokens(s)]
+        assert any("transpose" in scope_reduce.tokens(s) for s in mine)
+        assert any("transpose" not in scope_reduce.tokens(s) for s in mine)
+    assert not any("attn" in scope_reduce.tokens(s) for s in mixed_stacks)
+    # the experts' grouped products, forward and backward
+    assert any(s.endswith("ragged_dot_general")
+               and "experts" in scope_reduce.tokens(s)
+               and "transpose" in scope_reduce.tokens(s) for s in mixed_stacks)
 
 
 def test_lm_scopes_carry_no_layer_index(lm_stacks):
