@@ -7,11 +7,16 @@ grid over (batch·heads, query blocks), K/V streamed block-by-block with the
 running-max/sum recurrence — no O(T²) score materialization in HBM) and the
 matching FlashAttention-2-style backward (a dQ kernel streaming K/V blocks
 and a dK/dV kernel streaming Q/dO blocks, both recomputing P from the
-forward's saved logsumexp — nothing O(T²) is ever stored). Inside a block
-the three share one step: ``flash_plan`` derives heads a grid step and
-compute tiles from the shapes, ``_branches`` says which blocks need a mask
-and which block of a row writes the accumulators, ``_tiles`` which tiles of
-a block hold anything to compute.
+forward's saved logsumexp — nothing O(T²) is ever stored). The grid of all
+three is ``(rows of batch·heads, steps)``: the steps are the live (Q block,
+K block) pairs of a row and no others (``flash_walk``; a block pair the
+causal mask or the window leaves empty is no grid step), read from an int32
+table that reaches the kernels and their index maps by scalar prefetch.
+Inside a block the three share one step: ``flash_plan`` derives heads a
+grid step and compute tiles from the shapes, ``_branches`` says which blocks
+need a mask, ``_tiles`` which tiles of a block hold anything to compute; the
+walk says which step of a row writes the accumulators and which one
+finalises.
 
 ``DL4J_TPU_FLASH_BWD=scan`` falls the backward to the mathematically
 identical lax.scan implementation
@@ -33,9 +38,23 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from deeplearning4j_tpu import obs
 from deeplearning4j_tpu.config import env_flag, env_str
 
 NEG_INF = -1e30
+
+# Grid steps of the flash kernels in the programs traced so far, summed over
+# the call sites (a layer's forward is one, its backward two: dQ and dK/dV),
+# each call's rows of n times the steps of its walk; and how many of them
+# hold an in-mask entry. They move where a program is traced, never in a
+# step. live / walked is 1 since the grid is the walk; under the rectangle
+# grid it was 136/256 on a causal row of 16 blocks, 31/256 under a window
+# of one block.
+_STEPS_WALKED = obs.gauge(
+    "flash.steps_walked", "Grid steps of the flash kernels traced so far")
+_STEPS_LIVE = obs.gauge(
+    "flash.steps_live", "Of flash.steps_walked, the steps on a block pair "
+    "with an in-mask entry")
 
 # Per-query-row scalars (running max/sum, logsumexp, delta) cross the
 # kernel boundary lane-replicated as [..., rows, _LANES]: Mosaic wants the
@@ -98,7 +117,8 @@ def _tile(block):
 
 def flash_plan(n, t, d, itemsize, block_q, block_k, kv_group=1):
     """The ``FlashPlan`` of a call, from its shapes alone (every reading:
-    PERF.md, PR 27, a v5e).
+    PERF.md, PR 27, a v5e). Which blocks a row's grid steps visit is the
+    other half of a call's statics: ``flash_walk``.
 
     Tiles: 256 on a side of the block that divides by it, else the whole
     side -- the step the kernels took before they had tiles. A [512, 512]
@@ -143,18 +163,95 @@ def _block_live(qi, kb, block_q, block_k, window):
     k ≤ q (causal), and with a window, some q − k < window."""
     live = kb * block_k < (qi + 1) * block_q
     if window is not None:
-        live &= qi * block_q - (kb + 1) * block_k + 1 < window
+        live &= qi * block_q + 1 - window < (kb + 1) * block_k
     return live
 
 
-def _branches(qi, kb, *, causal, block_q, block_k, window, n_qb, n_kb,
+class FlashWalk(NamedTuple):
+    """The grid steps of one row of n (batch·heads) in one of the flash
+    kernels: step ``s`` works on Q block ``q[s]`` and K block ``k[s]``;
+    bit 0 of ``flags[s]`` says that the step is the first of its outer
+    block (it writes the accumulators, the others add to them), bit 1 that
+    it is the last (it finalises). int32 arrays, one entry a step.
+    ``live`` is the number of pairs of the row's rectangle that hold an
+    in-mask entry: every one of them is walked, once, and nothing else, so
+    ``steps == live``."""
+    q: np.ndarray
+    k: np.ndarray
+    flags: np.ndarray
+    live: int
+
+    @property
+    def steps(self):
+        return len(self.q)
+
+    @property
+    def table(self):
+        """What the kernels are handed: ``q``, ``k`` and ``flags`` end to
+        end, so step ``s`` reads entries ``s``, ``steps + s`` and
+        ``2 * steps + s``."""
+        return np.concatenate([self.q, self.k, self.flags])
+
+    @property
+    def single(self):
+        """Every outer block is one step: nothing to carry between steps."""
+        return bool((self.flags == _FIRST | _LAST).all())
+
+
+_FIRST, _LAST = 1, 2
+# The table lives in SMEM for the whole call, 12 bytes a step: this many
+# steps are 768 KiB of the v5e's 1 MiB (tests/test_aot_compile.py compiles a
+# walk of this size for the chip). A causal row of 361 blocks (T 184,832 at
+# block 512) or a non-causal one of 256 fits; a window's walk grows with T
+# alone. Past it ``flash_walk`` raises and names the way out, larger blocks:
+# nothing walks dead blocks instead.
+MAX_WALK_STEPS = 2 ** 16
+
+
+@functools.lru_cache(maxsize=64)
+def flash_walk(causal, window, block_q, block_k, t, inner):
+    """The ``FlashWalk`` of a row of n, from the statics of a call alone.
+
+    ``inner`` "k" (forward, dQ): Q block by Q block, the live K blocks of
+    each ascending -- the order in which the rectangle's live steps came,
+    so the sums keep their order. ``inner`` "q" (dK/dV): K block by K block,
+    its live Q blocks ascending. Every outer block has a live pair (the one
+    on the diagonal), so every output block is written."""
+    n_qb, n_kb = t // block_q, t // block_k
+    live = (_block_live(np.arange(n_qb)[:, None], np.arange(n_kb)[None, :],
+                        block_q, block_k, window) if causal
+            else np.ones((n_qb, n_kb), bool))
+    if inner == "k":
+        q, k = np.nonzero(live)
+        outer = q
+    else:
+        k, q = np.nonzero(live.T)
+        outer = k
+    if len(q) > MAX_WALK_STEPS:
+        raise ValueError(
+            f"flash attention at T {t} in blocks of {block_q} x {block_k} "
+            f"walks {len(q)} block pairs a row, more than the "
+            f"{MAX_WALK_STEPS} its table holds: use larger blocks")
+    edge = np.flatnonzero(np.diff(outer)) + 1      # where a new outer starts
+    assert len(edge) + 1 == (n_qb if inner == "k" else n_kb)
+    flags = np.zeros(len(q), np.int32)
+    flags[np.r_[0, edge]] |= _FIRST
+    flags[np.r_[edge - 1, len(q) - 1]] |= _LAST
+    q, k = q.astype(np.int32), k.astype(np.int32)
+    for shared in (q, k, flags):       # one walk serves every call: cached
+        shared.setflags(write=False)
+    return FlashWalk(q, k, flags, np.count_nonzero(live))
+
+
+def _branches(qi, kb, first, *, causal, block_q, block_k, window, n_qb,
               inner):
     """The ``(kind, first, condition)`` branches a grid step chooses from
-    (a condition of None: always). A block with no in-mask entry takes
-    none. ``inner`` names the blocks the innermost grid dimension walks,
-    "k" or "q"; ``first`` is whether the block is the first live one of
-    that walk, which writes the accumulators where the others add to them
-    (so nothing zero-fills them).
+    (a condition of None: always); every step of the walk is a live block
+    and takes exactly one. ``first`` is whether the step is the first of
+    its outer block (``FlashWalk``), which writes the accumulators where
+    the others add to them (so nothing zero-fills them): a traced boolean,
+    or None where every outer block is one step. ``inner`` names the blocks
+    the steps of an outer block walk, "k" or "q".
 
     full  every pair of the block is in the mask: no mask is built
     diag  the block on the diagonal of square blocks without a window: the
@@ -172,31 +269,23 @@ def _branches(qi, kb, *, causal, block_q, block_k, window, n_qb, n_kb,
         full = (kb + 1) * block_k - 1 <= qi * block_q
         if window is not None:
             full &= (qi + 1) * block_q - 1 - kb * block_k < window
-        live = _block_live(qi, kb, block_q, block_k, window)
-        kinds = [("full", full), ("edge", live & jnp.logical_not(full))]
-    if inner == "k":
-        i, n_inner = kb, n_kb
-        lowest = _clamp_to_live_k(qi, 0, block_q, block_k, causal, window)
-    else:
-        i, n_inner = qi, n_qb
-        lowest = _clamp_to_live_q(0, kb, block_q, block_k, causal, window,
-                                  n_qb)
-    firsts = ([(True, None)] if n_inner == 1 else
-              [(True, i == lowest), (False, i != lowest)])
+        kinds = [("full", full), ("edge", jnp.logical_not(full))]
+    firsts = ([(True, None)] if first is None else
+              [(True, first), (False, jnp.logical_not(first))])
 
-    def possible(kind, first):
-        if not aligned:
+    def possible(kind, is_first):
+        if not aligned or first is None:
             return True
         if inner == "q":    # a K block meets its diagonal block first
-            return first == (kind == "diag")
+            return is_first == (kind == "diag")
         # K block 0 is first; a full block after it takes 3 blocks a row
-        return first or kind == "diag" or n_qb > 2
+        return is_first or kind == "diag" or n_qb > 2
 
     def both(a, b):
         return b if a is None else a if b is None else a & b
 
-    return [(kind, first, both(c, when)) for kind, c in kinds
-            for first, when in firsts if possible(kind, first)]
+    return [(kind, is_first, both(c, when)) for kind, c in kinds
+            for is_first, when in firsts if possible(kind, is_first)]
 
 
 def _tiles(kind, block_q, block_k, plan):
@@ -249,6 +338,20 @@ def _run_branches(branches, heads, fn):
             pl.when(condition)(functools.partial(step, kind, first))
 
 
+def _step(table, steps, single):
+    """``(qi, kb, first, last)`` of this grid step, read from the walk's
+    table in SMEM (``FlashWalk.table``); ``first`` and ``last`` are None
+    where every outer block is one step."""
+    from jax.experimental import pallas as pl
+
+    s = pl.program_id(1)
+    qi, kb = table[s], table[steps + s]
+    if single:
+        return qi, kb, None, None
+    flags = table[2 * steps + s]
+    return qi, kb, (flags & _FIRST) != 0, (flags & _LAST) != 0
+
+
 def _lanes(x, width):
     """A per-row statistic held lane-replicated as [rows, _LANES], as
     [rows, width] (or [rows, 1] to broadcast, where width is no multiple of
@@ -270,15 +373,17 @@ def _dot(a, b, dims):
                                preferred_element_type=jnp.float32)
 
 
-def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch, plan,
-                  block_q, block_k, n_qb, n_kb, causal, scale, window=None):
-    """One (``plan.heads`` rows of batch·head, q-block, k-block) grid step.
-    The innermost grid dimension walks K/V blocks sequentially on the same
-    core, so the VMEM scratch accumulators (running max m, running sum l,
-    unnormalized output) persist across it — only one K/V block is
-    VMEM-resident at a time, which is what keeps T unbounded (the full-K/V
-    variant OOMs VMEM at T≈8k). With one K/V block a row (``n_kb`` 1)
-    there is nothing to carry and no scratch.
+def _flash_kernel(table, q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch, plan,
+                  block_q, block_k, n_qb, steps, single, causal, scale,
+                  window=None):
+    """One (``plan.heads`` rows of batch·head, step of the walk) grid step.
+    The innermost grid dimension walks the live K/V blocks of a Q block
+    sequentially on the same core, so the VMEM scratch accumulators
+    (running max m, running sum l, unnormalized output) persist across it —
+    only one K/V block is VMEM-resident at a time, which is what keeps T
+    unbounded (the full-K/V variant OOMs VMEM at T≈8k). Where every Q block
+    has one live K/V block (``single``) there is nothing to carry and no
+    scratch.
 
     Inside the block a strip of ``plan.tile_q`` query rows takes ONE
     online-softmax update over all its live tiles. Scores, m, l and the
@@ -287,10 +392,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch, plan,
     m/l are stored lane-replicated as [heads, block_q, _LANES]."""
     from jax.experimental import pallas as pl
 
-    qi = pl.program_id(1)
-    kb = pl.program_id(2)
+    qi, kb, first, last = _step(table, steps, single)
     tq, tk = plan.tile_q, plan.tile_k
-    single = n_kb == 1
     if not single:
         m_scr, l_scr, acc_scr = scratch
 
@@ -337,15 +440,13 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch, plan,
                 acc_scr[h, rows, :] = (acc_scr[h, rows, :]
                                        * correction[:, :1] + pv)
 
-    # blocks with no in-mask entry (above the diagonal, or entirely beyond
-    # the sliding window) take no branch: they contribute nothing
-    _run_branches(_branches(qi, kb, causal=causal, block_q=block_q,
+    _run_branches(_branches(qi, kb, first, causal=causal, block_q=block_q,
                             block_k=block_k, window=window, n_qb=n_qb,
-                            n_kb=n_kb, inner="k"),
+                            inner="k"),
                   plan.heads, _compute)
 
     if not single:
-        @pl.when(kb == n_kb - 1)
+        @pl.when(last)
         def _finalize():
             for h in range(plan.heads):
                 l_fin = l_scr[h]                       # [block_q, 128]
@@ -362,47 +463,69 @@ def _lse(m, l):
     return jnp.where(l > 0.0, m + jnp.log(jnp.maximum(l, 1e-30)), -NEG_INF)
 
 
-def _clamp_to_live_k(i, j, block_q, block_k, causal, window):
-    """The K/V block to hold at grid step (q-block i, k-block j): j itself
-    where the pair is live, else the nearest live one of the row, so a
-    dead step re-uses the block it has and fetches nothing."""
-    if not causal:
-        return j
-    j = jnp.minimum(j, ((i + 1) * block_q - 1) // block_k)
-    if window is not None:
-        j = jnp.maximum(j, jnp.maximum(i * block_q - window + 1, 0)
-                        // block_k)
-    return j
-
-
-def _clamp_to_live_q(i, j, block_q, block_k, causal, window, n_qb):
-    """The same for the dK/dV kernel, which walks the Q blocks i of a K
-    block j."""
-    if not causal:
-        return i
-    i = jnp.maximum(i, (j * block_k) // block_q)
-    if window is not None:
-        i = jnp.minimum(i, jnp.minimum(
-            ((j + 1) * block_k + window - 2) // block_q, n_qb - 1))
-    return i
-
-
-def _vmem(shape, index):
+def _walk_call(kernel, walk, rows, *, in_specs, out_specs, out_shape,
+               scratch_shapes, interpret):
+    """The ``pallas_call`` of ``kernel`` over the grid ``(rows, steps of
+    the walk)``. The walk's table is prefetched into SMEM ahead of the
+    arrays (one operand: each costs a call a copy of its own): the kernel
+    takes it as its first ref with ``steps`` and ``single`` as statics
+    (``_step``). A spec is ``(block shape, index map)``, the map written on
+    ``(row, Q block, K block)`` and handed the step's two here; every block
+    lives in VMEM."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    return pl.BlockSpec(shape, index, memory_space=pltpu.VMEM)
+    steps = walk.steps
+
+    def spec(shape, index):
+        return pl.BlockSpec(
+            shape, lambda b, s, table: index(b, table[s], table[steps + s]),
+            memory_space=pltpu.VMEM)
+
+    call = pl.pallas_call(
+        functools.partial(kernel, steps=steps, single=walk.single),
+        out_shape=out_shape,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(rows, steps),
+            in_specs=[spec(*x) for x in in_specs],
+            out_specs=[spec(*x) for x in out_specs],
+            scratch_shapes=scratch_shapes),
+        interpret=interpret)
+    return functools.partial(call, walk.table)
 
 
-def _statics(q, causal, block_q, block_k, window, kv_group):
+def _q_block(b, qi, kb):
+    """Index map of a block of Q-like rows: the step's Q block."""
+    return b, qi, 0
+
+
+def _k_block(b, qi, kb):
+    return b, kb, 0
+
+
+def _kv_block(kv_group):
+    """Index map of a block of K or V: the step's K block of the K/V head
+    that the row's group of ``kv_group`` query heads shares."""
+    return lambda b, qi, kb: (b // kv_group, kb, 0)
+
+
+def _statics(q, causal, block_q, block_k, window, kv_group, inners):
     """What the kernels are built from besides the arrays, read where the
     call is made: ``_flash_forward`` and ``_flash_backward`` are traced once
-    for each value of it."""
+    for each value of it. ``inners`` names the walks of the kernels this
+    call site adds to the program ("k": forward or dQ, "q": dK/dV), for the
+    gauges: they count here, once a call site, and not inside the shared
+    trace."""
     n, t, d = q.shape
+    plan = flash_plan(n, t, d, q.dtype.itemsize, block_q, block_k, kv_group)
+    for inner in inners:
+        walk = flash_walk(causal, window, block_q, block_k, t, inner)
+        for gauge, steps in ((_STEPS_WALKED, walk.steps),
+                             (_STEPS_LIVE, walk.live)):
+            gauge.set(gauge.value + n // plan.heads * steps)
     return dict(causal=causal, block_q=block_q, block_k=block_k,
                 window=window, kv_group=kv_group, interpret=_interpret_mode(),
-                plan=flash_plan(n, t, d, q.dtype.itemsize, block_q, block_k,
-                                kv_group))
+                plan=plan)
 
 
 # Traced once for each shape and inlined where it is called (``inline``: no
@@ -423,57 +546,47 @@ def _flash_forward(q, k, v, *, causal, block_q, block_k, window, kv_group,
     ``kv_group`` > 1 is grouped-query attention: consecutive runs of
     kv_group query heads share one K/V head, mapped by the BlockSpec
     index (no materialized repeat). T must divide by the blocks."""
-    from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     n, t, d = q.shape
     hb = plan.heads
-    n_qb, n_kb = t // block_q, t // block_k
+    walk = flash_walk(causal, window, block_q, block_k, t, "k")
     kernel = functools.partial(
         _flash_kernel, plan=plan, block_q=block_q, block_k=block_k,
-        n_qb=n_qb, n_kb=n_kb, causal=causal, scale=1.0 / (d ** 0.5),
+        n_qb=t // block_q, causal=causal, scale=1.0 / (d ** 0.5),
         window=window)
-    g = kv_group
-
-    def kv_index(b, i, j):
-        return (b // g,
-                _clamp_to_live_k(i, j, block_q, block_k, causal, window), 0)
-
-    q_index = lambda b, i, j: (b, i, 0)
-    scratch = [] if n_kb == 1 else [
+    kv_index = _kv_block(kv_group)
+    scratch = [] if walk.single else [
         pltpu.VMEM((hb, block_q, _LANES), jnp.float32),   # running max
         pltpu.VMEM((hb, block_q, _LANES), jnp.float32),   # running sum
         pltpu.VMEM((hb, block_q, d), jnp.float32),        # unnormalized out
     ]
-    return pl.pallas_call(
-        kernel,
+    return _walk_call(
+        kernel, walk, n // hb,
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
                    jax.ShapeDtypeStruct((n, t, _LANES), jnp.float32)],  # lse
-        grid=(n // hb, n_qb, n_kb),
-        in_specs=[_vmem((hb, block_q, d), q_index),
-                  _vmem((hb, block_k, d), kv_index),
-                  _vmem((hb, block_k, d), kv_index)],
-        out_specs=[_vmem((hb, block_q, d), q_index),
-                   _vmem((hb, block_q, _LANES), q_index)],
+        in_specs=[((hb, block_q, d), _q_block),
+                  ((hb, block_k, d), kv_index),
+                  ((hb, block_k, d), kv_index)],
+        out_specs=[((hb, block_q, d), _q_block),
+                   ((hb, block_q, _LANES), _q_block)],
         scratch_shapes=scratch,
         interpret=interpret,
     )(q, k, v)
 
 
-def _flash_dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, dq_ref,
-                     *scratch, plan, block_q, block_k, n_qb, n_kb, causal,
-                     scale, window=None):
-    """dQ pass: for a fixed Q block, stream K/V blocks (innermost grid dim)
-    and accumulate dQ = Σ_kb dS @ K, with P recomputed from the saved
-    logsumexp (FlashAttention-2 eq. 12-16), tile by tile. The softmax scale
-    rides in q for the scores, as in the forward, and meets dQ once, as it
-    is written."""
+def _flash_dq_kernel(table, q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
+                     dq_ref, *scratch, plan, block_q, block_k, n_qb, steps,
+                     single, causal, scale, window=None):
+    """dQ pass: for a fixed Q block, stream its live K/V blocks (the steps
+    of the walk) and accumulate dQ = Σ_kb dS @ K, with P recomputed from
+    the saved logsumexp (FlashAttention-2 eq. 12-16), tile by tile. The
+    softmax scale rides in q for the scores, as in the forward, and meets
+    dQ once, as it is written."""
     from jax.experimental import pallas as pl
 
-    qi = pl.program_id(1)
-    kb = pl.program_id(2)
+    qi, kb, first, last = _step(table, steps, single)
     tq, tk = plan.tile_q, plan.tile_k
-    single = n_kb == 1
     if not single:
         dq_scr, = scratch
 
@@ -504,20 +617,21 @@ def _flash_dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, dq_ref,
             else:
                 dq_scr[h, rows, :] += dq
 
-    _run_branches(_branches(qi, kb, causal=causal, block_q=block_q,
+    _run_branches(_branches(qi, kb, first, causal=causal, block_q=block_q,
                             block_k=block_k, window=window, n_qb=n_qb,
-                            n_kb=n_kb, inner="k"),
+                            inner="k"),
                   plan.heads, _compute)
 
     if not single:
-        @pl.when(kb == n_kb - 1)
+        @pl.when(last)
         def _finalize():
             dq_ref[...] = (dq_scr[...] * scale).astype(dq_ref.dtype)
 
 
 def _stat_rows(x, heads, block_q):
     """A per-row statistic ``x`` [n, T] as the dK/dV kernel takes it, with
-    its block and the block's index from (first row of n, Q block).
+    its block and the block's index map (on ``(row, Q block, K block)``, as
+    ``_walk_call`` takes them).
 
     Where the block is lane-aligned, the [n, T] array itself in groups of
     the 8 rows of n that share a tile of its layout, a block holding the
@@ -529,9 +643,9 @@ def _stat_rows(x, heads, block_q):
     if block_q % _LANES == 0 or block_q == t:
         x = jnp.pad(x, ((0, -n % _SUBLANES), (0, 0)))
         return (x.reshape(-1, _SUBLANES, t), (1, _SUBLANES, block_q),
-                lambda b, i: (b * heads // _SUBLANES, 0, i))
+                lambda b, qi, kb: (b * heads // _SUBLANES, 0, qi))
     return (x.reshape(n, t // block_q, 1, block_q), (heads, 1, 1, block_q),
-            lambda b, i: (b, i, 0, 0))
+            lambda b, qi, kb: (b, qi, 0, 0))
 
 
 def _stat_row(ref, h, row0, q0, tq):
@@ -545,20 +659,18 @@ def _stat_row(ref, h, row0, q0, tq):
     return ref[0, pl.ds(row0 + h, 1), pl.ds(q0, tq)]
 
 
-def _flash_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
-                      dk_ref, dv_ref, *scratch, plan, block_q, block_k,
-                      n_qb, n_kb, causal, scale, window=None):
-    """dK/dV pass: for a fixed K/V block, stream Q/dO blocks (innermost
-    grid dim); dV = Σ_qb Pᵀ dO, dK = Σ_qb dSᵀ Q. The tiles are computed
-    transposed, keys along dim 0 (Sᵀ = K Qᵀ, dPᵀ = V dOᵀ), so that both
-    sums are plain products with nothing to turn; lse and delta come as
-    rows to broadcast over the keys (``_stat_rows``)."""
+def _flash_dkv_kernel(table, q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
+                      dk_ref, dv_ref, *scratch, plan, block_q, block_k, n_qb,
+                      steps, single, causal, scale, window=None):
+    """dK/dV pass: for a fixed K/V block, stream its live Q/dO blocks (the
+    steps of the walk); dV = Σ_qb Pᵀ dO, dK = Σ_qb dSᵀ Q. The tiles are
+    computed transposed, keys along dim 0 (Sᵀ = K Qᵀ, dPᵀ = V dOᵀ), so that
+    both sums are plain products with nothing to turn; lse and delta come
+    as rows to broadcast over the keys (``_stat_rows``)."""
     from jax.experimental import pallas as pl
 
-    kb = pl.program_id(1)
-    qi = pl.program_id(2)
+    qi, kb, first, last = _step(table, steps, single)
     tq, tk = plan.tile_q, plan.tile_k
-    single = n_qb == 1
     # where this step's heads start in their group of 8 rows (_stat_rows)
     row0 = (0 if plan.heads == _SUBLANES
             else pl.program_id(0) * plan.heads % _SUBLANES)
@@ -599,15 +711,13 @@ def _flash_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
                 dk_scr[h, rows, :] += dk
                 dv_scr[h, rows, :] += dv
 
-    # a Q block with no in-mask entry for this K block contributes nothing
-    # here (above the diagonal / beyond the window)
-    _run_branches(_branches(qi, kb, causal=causal, block_q=block_q,
+    _run_branches(_branches(qi, kb, first, causal=causal, block_q=block_q,
                             block_k=block_k, window=window, n_qb=n_qb,
-                            n_kb=n_kb, inner="q"),
+                            inner="q"),
                   plan.heads, _compute)
 
     if not single:
-        @pl.when(qi == n_qb - 1)
+        @pl.when(last)
         def _finalize():
             dk_ref[...] = dk_scr[...].astype(dk_ref.dtype)
             dv_ref[...] = dv_scr[...].astype(dv_ref.dtype)
@@ -617,13 +727,15 @@ def _flash_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
 def _flash_attention_3d(q, k, v, causal, block_q, block_k, window=None,
                         kv_group=1):
     out, _lse = _flash_forward(
-        q, k, v, **_statics(q, causal, block_q, block_k, window, kv_group))
+        q, k, v,
+        **_statics(q, causal, block_q, block_k, window, kv_group, "k"))
     return out
 
 
 def _flash_fwd(q, k, v, causal, block_q, block_k, window=None, kv_group=1):
     out, lse = _flash_forward(
-        q, k, v, **_statics(q, causal, block_q, block_k, window, kv_group))
+        q, k, v,
+        **_statics(q, causal, block_q, block_k, window, kv_group, "k"))
     # keep one lane: the saved residual is [n, T], not 128x that
     return out, (q, k, v, out, lse[..., 0])
 
@@ -653,21 +765,20 @@ def _flash_bwd(causal, block_q, block_k, window, kv_group, residuals, g):
         return vjp(g)
     return _flash_backward(
         *residuals, g,
-        **_statics(residuals[0], causal, block_q, block_k, window, kv_group))
+        **_statics(residuals[0], causal, block_q, block_k, window, kv_group,
+                   "kq"))
 
 
 @_trace_once
 def _flash_backward(q, k, v, out, lse, g, *, causal, block_q, block_k, window,
                     kv_group, plan, interpret):
     """dQ, dK, dV of ``_flash_forward`` from its residuals and dO."""
-    from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     n, t, d = q.shape
     hb = plan.heads
-    n_qb, n_kb = t // block_q, t // block_k
-    static = dict(plan=plan, block_q=block_q, block_k=block_k, n_qb=n_qb,
-                  n_kb=n_kb, causal=causal, scale=1.0 / (d ** 0.5),
+    static = dict(plan=plan, block_q=block_q, block_k=block_k,
+                  n_qb=t // block_q, causal=causal, scale=1.0 / (d ** 0.5),
                   window=window)
     # delta_i = Σ_d dO ⊙ O — a cheap fused elementwise+reduce; XLA keeps it
     # out of the kernels' VMEM budget
@@ -679,55 +790,42 @@ def _flash_backward(q, k, v, out, lse, g, *, causal, block_q, block_k, window,
                             for x in (lse, delta))
     (lse_rows, row_block, row_index), (delta_rows, _, _) = (
         _stat_rows(x, hb, block_q) for x in (lse, delta))
+    kv_index = _kv_block(kv_group)
 
-    gk = kv_group
-
-    def kv_index(b, i, j):
-        return (b // gk,
-                _clamp_to_live_k(i, j, block_q, block_k, causal, window), 0)
-
-    q_index = lambda b, i, j: (b, i, 0)
-    dq = pl.pallas_call(
-        functools.partial(_flash_dq_kernel, **static),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        grid=(n // hb, n_qb, n_kb),
-        in_specs=[_vmem((hb, block_q, d), q_index),
-                  _vmem((hb, block_k, d), kv_index),
-                  _vmem((hb, block_k, d), kv_index),
-                  _vmem((hb, block_q, d), q_index),
-                  _vmem((hb, block_q, _LANES), q_index),
-                  _vmem((hb, block_q, _LANES), q_index)],
-        out_specs=_vmem((hb, block_q, d), q_index),
-        scratch_shapes=[] if n_kb == 1 else [
+    walk = flash_walk(causal, window, block_q, block_k, t, "k")
+    dq, = _walk_call(
+        functools.partial(_flash_dq_kernel, **static), walk, n // hb,
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype)],
+        in_specs=[((hb, block_q, d), _q_block),
+                  ((hb, block_k, d), kv_index),
+                  ((hb, block_k, d), kv_index),
+                  ((hb, block_q, d), _q_block),
+                  ((hb, block_q, _LANES), _q_block),
+                  ((hb, block_q, _LANES), _q_block)],
+        out_specs=[((hb, block_q, d), _q_block)],
+        scratch_shapes=[] if walk.single else [
             pltpu.VMEM((hb, block_q, d), jnp.float32)],
         interpret=interpret,
     )(q, k, v, g, lse_cols, delta_cols)
 
-    # dk/dv grid: (n, K blocks, Q blocks) — the index maps swap i/j roles.
-    # With GQA the kernel accumulates PER Q-HEAD (output shaped like q);
-    # the group-sum down to the kv heads happens outside — revisiting one
-    # output block from different outer-grid steps would race.
-    def live_q(j, i):
-        return _clamp_to_live_q(i, j, block_q, block_k, causal, window, n_qb)
-
-    q_of_k = lambda b, j, i: (b, live_q(j, i), 0)
-    row_of_k = lambda b, j, i: row_index(b, live_q(j, i))
-    kv_of_k = lambda b, j, i: (b // gk, j, 0)
-    out_of_k = lambda b, j, i: (b, j, 0)
-    dk, dv = pl.pallas_call(
-        functools.partial(_flash_dkv_kernel, **static),
+    # dK/dV walk K block by K block. With GQA the kernel accumulates PER
+    # Q-HEAD (output shaped like q); the group-sum down to the kv heads
+    # happens outside — revisiting one output block from different
+    # outer-grid steps would race.
+    walk = flash_walk(causal, window, block_q, block_k, t, "q")
+    dk, dv = _walk_call(
+        functools.partial(_flash_dkv_kernel, **static), walk, n // hb,
         out_shape=[jax.ShapeDtypeStruct((n, t, d), k.dtype),
                    jax.ShapeDtypeStruct((n, t, d), v.dtype)],
-        grid=(n // hb, n_kb, n_qb),
-        in_specs=[_vmem((hb, block_q, d), q_of_k),
-                  _vmem((hb, block_k, d), kv_of_k),
-                  _vmem((hb, block_k, d), kv_of_k),
-                  _vmem((hb, block_q, d), q_of_k),
-                  _vmem(row_block, row_of_k),
-                  _vmem(row_block, row_of_k)],
-        out_specs=[_vmem((hb, block_k, d), out_of_k),
-                   _vmem((hb, block_k, d), out_of_k)],
-        scratch_shapes=[] if n_qb == 1 else [
+        in_specs=[((hb, block_q, d), _q_block),
+                  ((hb, block_k, d), kv_index),
+                  ((hb, block_k, d), kv_index),
+                  ((hb, block_q, d), _q_block),
+                  (row_block, row_index),
+                  (row_block, row_index)],
+        out_specs=[((hb, block_k, d), _k_block),
+                   ((hb, block_k, d), _k_block)],
+        scratch_shapes=[] if walk.single else [
             pltpu.VMEM((hb, block_k, d), jnp.float32),
             pltpu.VMEM((hb, block_k, d), jnp.float32)],
         interpret=interpret,
@@ -756,13 +854,18 @@ def flash_attention(q, k, v, *, causal=False, block_q=512, block_k=512,
     Pads T to the block size; leading dims are collapsed into the grid.
     Differentiable (pallas FlashAttention-2 backward; DL4J_TPU_FLASH_BWD=scan
     for the rematerializing fallback). ``window`` (requires causal) limits
-    each query to the last ``window`` positions — sliding-window attention;
-    fully out-of-window blocks are skipped in BOTH directions, so compute
-    scales O(T·window) instead of O(T²/2).
+    each query to the last ``window`` positions — sliding-window attention.
 
-    The blocks are what the DMA moves and the grid walks; what a grid step
-    does inside them (heads a step, compute tiles, which blocks build a
-    mask) follows from the shapes: ``flash_plan``. Products take their
+    The blocks are what the DMA moves. The grid walks the block pairs that
+    hold an in-mask entry and no others (``flash_walk``): a block above the
+    diagonal or wholly outside the window is no grid step in the forward,
+    the dQ or the dK/dV kernel, so compute and grid scale O(T·window)
+    instead of O(T²/2), and a causal row takes n(n+1)/2 steps of its n²
+    pairs. A row may walk up to ``MAX_WALK_STEPS`` pairs (a causal T of
+    184,832 at block 512); past it the call raises and asks for larger
+    blocks. What a grid step does inside a block (heads a step, compute
+    tiles, which blocks build a mask) follows from the shapes:
+    ``flash_plan``. Products take their
     operands in the inputs' dtype (bfloat16 in, bfloat16 operands) and
     accumulate in float32; the softmax statistics are float32. Measured on
     a v5e (PERF.md, PR 27; bfloat16, d 64, causal, forward + dQ + dK/dV

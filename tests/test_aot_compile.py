@@ -102,6 +102,28 @@ def test_flash_kernels_compile_for_v5e(v5e, shape, wrt):
     assert _compile(fn, q, kv, kv) == kernels
 
 
+def test_flash_walk_at_its_bound_compiles_for_v5e(v5e):
+    """The walk's table is a scalar-prefetch operand and lives in the 1 MiB
+    of SMEM for the whole call, 12 bytes a step: a row that walks exactly
+    ``MAX_WALK_STEPS`` pairs (non-causal, 256 x 256 blocks of 512: T
+    131,072) compiles, all three kernels; one pair more is refused by the
+    shapes, before anything is built (a causal row of 362 blocks)."""
+    t = 256 * 512
+    assert pk.flash_walk(False, None, 512, 512, t, "k").steps \
+        == pk.MAX_WALK_STEPS
+    x = jax.ShapeDtypeStruct((1, t, 128), jnp.bfloat16, sharding=v5e)
+
+    def loss(q, k, v):
+        return pk.flash_attention(q, k, v).astype(jnp.float32).sum()
+
+    assert _compile(jax.grad(loss, argnums=(0, 1, 2)), x, x, x) == 3
+    past = jax.ShapeDtypeStruct((1, 362 * 512, 128), jnp.bfloat16,
+                                sharding=v5e)
+    with pytest.raises(ValueError, match="larger blocks"):
+        jax.jit(lambda q: pk.flash_attention(q, q, q, causal=True)).lower(
+            past)
+
+
 @pytest.mark.parametrize("peephole", [True, False])
 @pytest.mark.parametrize("wrt", ["fwd", "bwd"])
 def test_lstm_cell_compiles_for_v5e(v5e, wrt, peephole):

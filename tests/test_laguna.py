@@ -389,11 +389,16 @@ def test_rope_against_the_formulas(name):
 
 # --- the flash kernels at a head of 16, in groups, under a window ------------
 
-@pytest.mark.parametrize("window,kv_group", [(8, 3), (None, 3), (8, 2),
-                                             (None, 2), (20, 3)])
-def test_flash_kernels_in_groups_under_a_window_match_dense(window, kv_group):
+# T 32 is a row of 4 blocks; the cell's rows are 16 blocks in groups of 8
+# (window layers) and 6 (full layers): T 128, where the grid walks 31 and
+# 136 of a row's 256 block pairs
+@pytest.mark.parametrize("window,kv_group,T", [
+    (8, 3, 32), (None, 3, 32), (8, 2, 32), (None, 2, 32), (20, 3, 32),
+    (8, 8, 128), (None, 6, 128), (12, 6, 64)])
+def test_flash_kernels_in_groups_under_a_window_match_dense(window, kv_group,
+                                                            T):
     from deeplearning4j_tpu.ops.pallas_kernels import flash_attention
-    kv, hd, T, block = 2, 16, 32, 8
+    kv, hd, block = 2, 16, 8
     keys = jax.random.split(jax.random.PRNGKey(7), 4)
     q = jax.random.normal(keys[0], (2, kv * kv_group, T, hd))
     k = jax.random.normal(keys[1], (2, kv, T, hd))

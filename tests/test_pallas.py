@@ -173,14 +173,52 @@ def _sub_jaxprs(eqn):
                 yield item
 
 
-def _dots(jaxpr):
-    """Every dot_general of a kernel's jaxpr, through its branches (pl.when)
-    and loops (the heads of a step)."""
+def _eqns(jaxpr):
+    """Every equation under ``jaxpr``, through calls, branches (pl.when) and
+    loops (the heads of a step)."""
     for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "dot_general":
-            yield eqn
+        yield eqn
         for sub in _sub_jaxprs(eqn):
-            yield from _dots(sub)
+            yield from _eqns(sub)
+
+
+def _dots(jaxpr):
+    """Every dot_general of a kernel's jaxpr."""
+    return (eqn for eqn in _eqns(jaxpr)
+            if eqn.primitive.name == "dot_general")
+
+
+def _assert_flash_matches_dense(q_shape, kv_shape, bq, bk, window, seed=11,
+                                atol=3e-4):
+    """Forward and all three gradients of the kernels (interpreter mode)
+    against ``dense_attention``, float32, K/V heads repeated for the
+    reference."""
+    import jax
+    from deeplearning4j_tpu.ops.pallas_kernels import flash_attention
+    rng = np.random.RandomState(seed)
+    q = jnp.asarray(rng.randn(*q_shape), jnp.float32)
+    k = jnp.asarray(rng.randn(*kv_shape), jnp.float32)
+    v = jnp.asarray(rng.randn(*kv_shape), jnp.float32)
+    cot = jnp.asarray(rng.randn(*q_shape), jnp.float32)
+    group = q_shape[-3] // kv_shape[-3] if len(q_shape) > 3 else 1
+
+    def flash(a, b, c):
+        return flash_attention(a, b, c, causal=True, block_q=bq, block_k=bk,
+                               window=window)
+
+    def dense(a, b, c):
+        if group > 1:
+            b, c = (jnp.repeat(x, group, axis=-3) for x in (b, c))
+        return dense_attention(a, b, c, causal=True, window=window)
+
+    got, got_vjp = jax.vjp(flash, q, k, v)
+    want, want_vjp = jax.vjp(dense, q, k, v)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5,
+                               err_msg="out")
+    for g, w, name in zip(got_vjp(cot), want_vjp(cot), "qkv"):
+        assert g.shape == w.shape
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), atol=atol,
+                                   err_msg=f"d{name}")
 
 
 class TestFlashGridStep:
@@ -343,6 +381,151 @@ class TestFlashPlan:
         assert (len(fine), sum(m for _, _, m in fine)) == (10, 4)
 
 
+class TestFlashWalk:
+    """``flash_walk``: the grid steps of a row of n are its live (Q block,
+    K block) pairs, each once, in the order the sums are made in, from the
+    statics of a call alone."""
+
+    # T, block_q, block_k, window, causal
+    CASES = {
+        # the third cell's two layer kinds: rows of 16 blocks
+        "laguna_full": (8192, 512, 512, None, True),
+        "laguna_window": (8192, 512, 512, 512, True),
+        "rows_of_8": (4096, 512, 512, None, True),
+        "window_one_and_a_half_blocks": (4096, 512, 512, 768, True),
+        "window_no_multiple_of_the_block": (1024, 128, 128, 200, True),
+        "window_inside_a_block": (768, 256, 256, 100, True),
+        "window_of_one": (64, 8, 8, 1, True),
+        "rect_q_wider": (2048, 256, 128, None, True),
+        "rect_k_wider_window": (2048, 128, 256, 300, True),
+        "odd_block_count": (640, 128, 128, None, True),
+        "non_causal": (1024, 128, 256, None, False),
+        # the GPT-2 cells: two blocks a row, one block a row
+        "gpt2m_t1024": (1024, 512, 512, None, True),
+        "gpt2m_t256": (256, 256, 256, None, True),
+    }
+
+    @staticmethod
+    def _live_by_position(t, bq, bk, window, causal):
+        """The live pairs from the mask entry by entry, not from
+        ``_block_live``."""
+        pos = np.arange(t)
+        dist = pos[:, None] - pos[None, :]
+        keep = np.ones((t, t), bool)
+        if causal:
+            keep = dist >= 0
+            if window is not None:
+                keep &= dist < window
+        blocks = keep.reshape(t // bq, bq, t // bk, bk).any(axis=(1, 3))
+        return {(int(i), int(j)) for i, j in zip(*np.nonzero(blocks))}
+
+    @pytest.mark.parametrize("inner", ["k", "q"])
+    @pytest.mark.parametrize("case", sorted(CASES))
+    def test_walks_the_live_pairs_once_in_order(self, case, inner):
+        from deeplearning4j_tpu.ops import pallas_kernels as pk
+        t, bq, bk, window, causal = self.CASES[case]
+        walk = pk.flash_walk(causal, window, bq, bk, t, inner)
+        pairs = list(zip(walk.q.tolist(), walk.k.tolist()))
+        want = self._live_by_position(t, bq, bk, window, causal)
+        assert len(pairs) == len(set(pairs)) == walk.steps == walk.live
+        assert set(pairs) == want
+        if causal:
+            assert all(bool(pk._block_live(i, j, bq, bk, window)) ==
+                       ((i, j) in want)
+                       for i in range(t // bq) for j in range(t // bk))
+        # outer block by outer block, the inner blocks ascending: the order
+        # of the rectangle's live steps
+        key = (lambda p: p) if inner == "k" else (lambda p: p[::-1])
+        assert pairs == sorted(pairs, key=key)
+        outer = walk.q if inner == "k" else walk.k
+        n_outer = t // (bq if inner == "k" else bk)
+        first = (walk.flags & pk._FIRST) != 0
+        last = (walk.flags & pk._LAST) != 0
+        # every outer block is there, with one first and one last step
+        assert outer[first].tolist() == outer[last].tolist() \
+            == list(range(n_outer))
+        for o in range(n_outer):
+            steps = np.flatnonzero(outer == o)
+            assert first[steps[0]] and last[steps[-1]]
+            assert (np.diff(steps) == 1).all()
+        assert walk.single == (walk.steps == n_outer)
+
+    def test_live_over_walked_at_the_cells_shapes(self):
+        """At 16 blocks a row the rectangle held 256 steps: 136 live under
+        the causal mask, 31 under a window of one block. The walk takes
+        those and nothing else, in all three kernels."""
+        from deeplearning4j_tpu.ops import pallas_kernels as pk
+        for case, live in (("laguna_full", 136), ("laguna_window", 31)):
+            t, bq, bk, window, causal = self.CASES[case]
+            for inner in "kq":
+                walk = pk.flash_walk(causal, window, bq, bk, t, inner)
+                assert (walk.steps, walk.live) == (live, live)
+        one = pk.flash_walk(True, None, 256, 256, 256, "k")
+        assert (one.steps, one.single) == (1, True)
+
+    @pytest.mark.parametrize("case,rows,steps", [
+        ("laguna_window", 128, 31), ("laguna_full", 96, 136),
+        ("gpt2m_t1024", 128 // 4, 3), ("gpt2m_t256", 512 // 8, 1)])
+    def test_the_grid_of_all_three_kernels_is_the_walk(self, case, rows,
+                                                       steps):
+        """At the cells' shapes (bfloat16; d 128 for the third cell's, 64
+        for GPT-2's): every ``pallas_call`` of a traced forward + backward
+        has the grid ``(rows of n / heads a step, steps of the walk)`` and
+        takes the walk's table ahead of its arrays."""
+        import jax
+        from deeplearning4j_tpu.ops import pallas_kernels as pk
+        t, bq, bk, window, causal = self.CASES[case]
+        d = 128 if case.startswith("laguna") else 64
+        n = {"laguna_window": 128, "laguna_full": 96, "gpt2m_t1024": 128,
+             "gpt2m_t256": 512}[case]
+        x = jax.ShapeDtypeStruct((n, t, d), jnp.bfloat16)
+
+        def loss(q, k, v):
+            return pk.flash_attention(
+                q, k, v, causal=causal, block_q=bq, block_k=bk,
+                window=window).astype(jnp.float32).sum()
+        jaxpr = jax.make_jaxpr(jax.grad(loss, (0, 1, 2)))(x, x, x).jaxpr
+        grids = [(eqn.params["grid_mapping"].grid,
+                  eqn.params["grid_mapping"].num_index_operands)
+                 for eqn in _eqns(jaxpr) if eqn.primitive.name == "pallas_call"]
+        assert grids == [((rows, steps), 1)] * 3
+
+    def test_a_row_past_the_tables_bound_is_refused(self):
+        """Decided by the shapes alone: past ``MAX_WALK_STEPS`` the call
+        raises before anything is built (the bound itself compiles for the
+        chip: tests/test_aot_compile.py)."""
+        from deeplearning4j_tpu.ops import pallas_kernels as pk
+        blocks = 362                       # 65,703 causal pairs
+        assert blocks * (blocks + 1) // 2 > pk.MAX_WALK_STEPS
+        with pytest.raises(ValueError, match="larger blocks"):
+            pk.flash_walk(True, None, 8, 8, 8 * blocks, "k")
+        assert pk.flash_walk(True, None, 8, 8, 8 * 361, "q").steps \
+            == 361 * 362 // 2 <= pk.MAX_WALK_STEPS
+
+    def test_gauges_count_the_steps_of_a_traced_call(self, interpret_pallas):
+        """``flash.steps_walked`` / ``flash.steps_live``: rows of n times the
+        walk's steps, summed over the kernels a traced program holds."""
+        import jax
+        from deeplearning4j_tpu import obs
+        from deeplearning4j_tpu.ops import pallas_kernels as pk
+        x = jnp.zeros((2, 64, 8), jnp.float32)
+
+        def loss(q, k, v, window):
+            return pk.flash_attention(q, k, v, causal=True, block_q=16,
+                                      block_k=16, window=window).sum()
+        obs.reset_metrics()
+        # forward alone: 2 rows x 10 of the 16 pairs of a causal row of 4
+        jax.make_jaxpr(lambda q: loss(q, q, q, None))(x)
+        assert (obs.metrics.value("flash.steps_walked"),
+                obs.metrics.value("flash.steps_live")) == (20, 20)
+        # a window of one block, forward + dQ + dK/dV: 3 x 2 rows x 7 pairs
+        obs.reset_metrics()
+        jax.make_jaxpr(jax.grad(lambda q: loss(q, q, q, 16)))(x)
+        assert (obs.metrics.value("flash.steps_walked"),
+                obs.metrics.value("flash.steps_live")) == (42, 42)
+        obs.reset_metrics()
+
+
 class TestSlidingWindow:
     """Causal sliding-window attention: the kernels mask entries more than
     window-1 positions in the past and skip fully out-of-window blocks."""
@@ -380,6 +563,36 @@ class TestSlidingWindow:
                 np.testing.assert_allclose(
                     np.asarray(g1), np.asarray(g2), atol=2e-4,
                     err_msg=f"d{name} window={w}")
+
+    # rows of 8 and 16 blocks (the third cell's are 16): a window of one
+    # block, of one and a half, and none; the last two with rectangular
+    # blocks. Every Q block but the last ends on a K block that is not the
+    # row's last one, and under a window none but the first starts on
+    # block 0: the accumulators are written and finalised where the walk
+    # says, not at the ends of the rectangle
+    @pytest.mark.parametrize("blocks", [8, 16])
+    @pytest.mark.parametrize("window,bq,bk", [
+        (8, 8, 8), (12, 8, 8), (None, 8, 8), (12, 16, 8), (20, 8, 16)])
+    def test_long_rows_match_dense(self, interpret_pallas, blocks, window,
+                                   bq, bk):
+        _assert_flash_matches_dense((2, 8 * blocks, 8), (2, 8 * blocks, 8),
+                                    bq, bk, window)
+
+    def test_finalises_on_the_last_live_block(self, interpret_pallas):
+        """Window 8 at block 8 over 8 blocks: Q block 3's K blocks are 2
+        and 3, so its last step is neither the row's last K block (7) nor
+        is its first K block 0; dK/dV's K block 3 walks Q blocks 3 and 4
+        of 8."""
+        from deeplearning4j_tpu.ops import pallas_kernels as pk
+        fwd = pk.flash_walk(True, 8, 8, 8, 64, "k")
+        mine = fwd.q == 3
+        assert fwd.k[mine].tolist() == [2, 3]
+        assert fwd.flags[mine].tolist() == [pk._FIRST, pk._LAST]
+        bwd = pk.flash_walk(True, 8, 8, 8, 64, "q")
+        mine = bwd.k == 3
+        assert bwd.q[mine].tolist() == [3, 4]
+        assert bwd.flags[mine].tolist() == [pk._FIRST, pk._LAST]
+        _assert_flash_matches_dense((1, 64, 8), (1, 64, 8), 8, 8, 8)
 
     def test_window_one_attends_self_only(self, rng, interpret_pallas):
         from deeplearning4j_tpu.ops.pallas_kernels import flash_attention
@@ -449,6 +662,15 @@ class TestGroupedQueryAttention:
         ref = self._ref(q, k, v, 2, causal=True, window=10)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=1e-5)
+
+    # the third cell's groups, 6 and 8 query heads a K/V head, on rows of
+    # 8 and 16 blocks, with and without a window of one block
+    @pytest.mark.parametrize("kv_group,blocks,window", [
+        (6, 16, 8), (8, 16, None), (8, 8, 8), (6, 8, None), (8, 8, 12)])
+    def test_long_rows_in_groups_match_dense(self, interpret_pallas,
+                                             kv_group, blocks, window):
+        _assert_flash_matches_dense((1, kv_group, 8 * blocks, 8),
+                                    (1, 1, 8 * blocks, 8), 8, 8, window)
 
     def test_scan_escape_hatch_gqa(self, rng, interpret_pallas, monkeypatch):
         import jax
