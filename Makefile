@@ -5,11 +5,9 @@
 PY ?= python
 
 .PHONY: lint lint-fast lint-ci lint-baseline lint-update-baseline test \
-	knobs signatures determinism sanitizers chaos bench-hetero \
-	bench-charrnn bench-dpshard bench-elastic bench-serve \
-	bench-serve-scale smoke-rehearse
+	knobs signatures determinism sanitizers chaos smoke-rehearse
 
-LINT_PATHS = deeplearning4j_tpu tools bench.py chip_smoke.py examples
+LINT_PATHS = deeplearning4j_tpu tools chip_smoke.py examples
 
 # Whole-package interprocedural + flow-sensitive JAX hot-path and
 # concurrency lint (rules G001-G018, docs/STATIC_ANALYSIS.md).
@@ -71,46 +69,6 @@ chaos:
 smoke-rehearse:
 	$(PY) chip_smoke.py --rehearse
 	$(PY) chip_smoke.py --rehearse --chips 4
-
-# shape-heterogeneous fused-grouping A/B: adaptive (per-bucket K +
-# trailing-only padding) vs the always-pad contract on a 2-shape
-# alternating stream (docs/FUSED_LOOP.md)
-bench-hetero:
-	$(PY) bench.py fused_hetero
-
-# sequence-workload fused A/B: GravesLSTM char-RNN tBPTT with the
-# scan-of-scans device window loop vs the host window loop
-# (docs/FUSED_LOOP.md "Sequence workloads")
-bench-charrnn:
-	$(PY) bench.py charrnn
-
-# serving-tier open-loop A/B: continuous batching (persistent KV slot
-# pool, serving/decode.py) vs naive per-request generate() — p50/p99 +
-# tokens/sec + compile counter embedded (docs/SERVING.md)
-bench-serve:
-	$(PY) bench.py serve
-
-# serving resilience acceptance on a 2-replica router: steady
-# multi-client load with zero steady-state compiles (replicas share ONE
-# blessed signature set), kill 1 of 2 under load (zero requests lost,
-# admitted work typed+retryable, zero recovery compiles), then overload
-# past the SLO gate — 429 sheds counted, admitted p99 reported
-# (docs/SERVING.md, docs/ROBUSTNESS.md §8)
-bench-serve-scale:
-	$(PY) bench.py serve_scale
-
-# ZeRO level A/B on the virtual 8-device CPU mesh: replicated DP vs
-# DL4J_TPU_DP_SHARD={1,2,3} through the unified sharding core, with the
-# memlint per-level replicated-state rows embedded (docs/PARALLELISM.md)
-bench-dpshard:
-	$(PY) bench.py dp_shard
-
-# elastic recovery A/B on the virtual 8-device CPU mesh: kill-peer
-# mid-fit -> checkpoint -> re-form -> re-shard -> continue; re-form
-# latency + post-re-form throughput vs pre-death, collective/elastic
-# obs counters embedded (docs/ROBUSTNESS.md §7)
-bench-elastic:
-	$(PY) bench.py elastic
 
 # regenerate the env-knob table from the typed registry
 # (deeplearning4j_tpu/config.py); tests/test_graftlint.py keeps it in sync

@@ -336,26 +336,18 @@ class TransformerConfig:
         return any(self.layer_spec(i).ffn == "experts"
                    for i in range(self.n_layers))
 
-    def served_as_gpt2(self, what):
-        """``generate``, beam search and the continuous-batching decode
-        programs are written for the GPT-2 block with rope and one window;
-        they refuse any other setting by its name, never run without it."""
-        found = [name for name, differs in (
-            ("a per-layer list (`layers`)", self.layers is not None),
-            ("experts", self.experts is not None),
-            ("attn_gate", self.attn_gate),
-            (f"norm {self.norm!r}", self.norm != "layernorm"),
-            ("bias False", not self.bias),
-            (f"ffn {self.ffn!r}", self.ffn != "gelu"),
-            ("an untied head", not self.tie_embeddings),
-            ("head_dim", self.head_dim is not None),
-            (f"rope_layout {self.rope_layout!r}",
-             self.rope_layout != "interleaved")) if differs]
-        if found:
+    def served_without_experts(self, what):
+        """``generate``, beam search and the continuous-batching programs call
+        the one ``_block_apply`` and serve every setting it takes but experts,
+        which they refuse by name: the expert layer's static row buffer is
+        exact only up to ``expert_row_buffer`` times the balanced load, and
+        its counters live beside the optimizer's state. Neither has a meaning
+        for one decoded token."""
+        if self.has_experts:
             raise NotImplementedError(
                 f"{what} is not implemented for a configuration with "
-                + ", ".join(found) + "; train and score it through "
-                "fit_batch / eval_loss / output")
+                "experts; train and score it through fit_batch / eval_loss / "
+                "output")
 
 
 def _decay_mask(params):
@@ -393,21 +385,26 @@ def _linear(c, bp, name, x):
 
 
 def _block_apply(c, bp, x, spec, drop=None, rng=None, attend=None, ffn=None,
-                 plan=None):
+                 plan=None, positions=None):
     """One pre-LN block from its param dict — THE canonical block math,
-    shared by TransformerLM (which threads its residual-branch dropout in
-    via ``drop``), the dropout-free PP trainer, the SP trainer (which
-    swaps the attention for the ring via ``attend``), and the MoE family
-    (which swaps the dense FFN for expert routing via ``ffn``). Any fix
-    here reaches every consumer; only the TP trainer re-derives it (its
-    weights are partitioned, so the matmuls are structurally
-    different). ``plan`` (the GSPMD ``ShardingCore`` of a ``shard()``-ed
-    model) only reaches the flash-kernel route.
+    shared by TransformerLM's training step (which threads its
+    residual-branch dropout in via ``drop``), its served programs (prefill
+    and one-token decode, which swap the attention for one over their KV
+    cache via ``attend`` and say where the tokens sit via ``positions``),
+    the dropout-free PP trainer, the SP trainer (``attend``: the ring), and
+    the MoE family (which swaps the dense FFN for expert routing via
+    ``ffn``). Any fix here reaches every consumer; only the TP trainer
+    re-derives it (its weights are partitioned, so the matmuls are
+    structurally different). ``plan`` (the GSPMD ``ShardingCore`` of a
+    ``shard()``-ed model) only reaches the flash-kernel route.
 
     ``spec`` is the layer's resolved ``LayerSpec``: ``c.layer_spec(i)``, or
     ``c.one_block(who)`` in a trainer that runs one block for every layer.
-    Every choice it and ``c`` make is made at trace time. A layer with
-    experts returns ``(x, statistics)``."""
+    Every choice it and ``c`` make is made at trace time. ``positions``
+    ([T], or [B, T] where the rows differ; None: ``arange(T)``) are what
+    rope turns by. ``attend(q, k, v)`` takes ``q`` [B, H, T, hd] and the
+    GROUPED ``k``, ``v`` [B, kv_heads, T, hd], rotated, and returns
+    [B, H, T, hd]. A layer with experts returns ``(x, statistics)``."""
     B, T, d = x.shape
     hd, H = c.hd, spec.n_heads
     r1 = r2 = None
@@ -427,13 +424,14 @@ def _block_apply(c, bp, x, spec, drop=None, rng=None, attend=None, ffn=None,
         q = split(q, H)
         k, v = split(k, c.kv_heads), split(v, c.kv_heads)
         if c.pos_embed == "rope":
-            cos, sin = _rope_cos_sin(spec.rope, hd, jnp.arange(T))
+            pos = jnp.arange(T) if positions is None else positions
+            cos, sin = _rope_cos_sin(spec.rope, hd, pos)
+            if cos.ndim == 3:      # [B, T, rot/2]: the same for every head
+                cos, sin = cos[:, None], sin[:, None]
             rot = spec.rope.rotated(hd) if spec.rope.share < 1.0 else None
             q = _apply_rope(q, cos, sin, c.rope_layout, rot)
             k = _apply_rope(k, cos, sin, c.rope_layout, rot)
         if attend is not None:
-            # custom attends (ring SP): MHA
-            k, v = _full_heads(H // c.kv_heads, k, v)
             o = attend(q, k, v)
         elif c.block_size:
             o = _blockwise_route(c, q, k, v, plan, spec.window)
@@ -465,32 +463,109 @@ def _block_apply(c, bp, x, spec, drop=None, rng=None, attend=None, ffn=None,
         return x + (drop(m, r2) if drop else m)
 
 
-def _forward_tokens(c, params, tokens, apply_block):
-    """THE canonical token forward: embed + compute_dtype cast + per-layer
-    ``apply_block(i, block_params, x)`` + final norm + logits in f32 (the
-    embedding again where it is tied, else the head).
-    Shared by TransformerLM, the MoE family, and the EP trainer so the
-    cast/loop/head logic exists once."""
-    T = tokens.shape[1]
-    cd = c.compute_dtype
+def _embed(c, params, tokens, positions=None):
+    """Tokens [B, T] to the first block's input: ``wte``, plus the learned
+    ``wpe`` at ``positions`` ([T] or [B, T]; None: the first T rows) where the
+    model has one (absent under rope: rotary in-block), in the compute dtype.
+    A served row coasting past the table's end reads its last row; nobody
+    reads what such a row computes."""
     with jax.named_scope("embed"):
         x = params["wte"][tokens]
-        if "wpe" in params:            # absent under rope (rotary in-block)
-            x = x + params["wpe"][:T]
-        if cd:
-            x = x.astype(cd)
-    if cd:
-        params = jax.tree.map(
-            lambda a: a.astype(cd) if jnp.issubdtype(a.dtype, jnp.floating)
-            else a, params)
-    for i in range(c.n_layers):
-        x = apply_block(i, params[f"b{i}"], x)
+        if "wpe" in params:
+            x = x + (params["wpe"][:tokens.shape[1]] if positions is None else
+                     params["wpe"][jnp.clip(positions, 0, c.max_len - 1)])
+        return x.astype(c.compute_dtype) if c.compute_dtype else x
+
+
+def _cast_params(c, params):
+    """The parameters as the blocks compute with them: the floating leaves in
+    the compute dtype where the model has one (f32 masters stay the
+    caller's)."""
+    cd = c.compute_dtype
+    if not cd:
+        return params
+    return jax.tree.map(
+        lambda a: a.astype(cd) if jnp.issubdtype(a.dtype, jnp.floating)
+        else a, params)
+
+
+def _head(c, params, x):
+    """Final norm, then the logits in f32: the embedding again where it is
+    tied, else the head."""
     with jax.named_scope("final_ln"):
         x = _norm(c, params, "lnf", x)
     with jax.named_scope("logits_loss"):
         if not c.tie_embeddings:
             return (x @ params["head"]).astype(jnp.float32)
         return (x @ params["wte"].T).astype(jnp.float32)   # tied embeddings
+
+
+def _forward_tokens(c, params, tokens, apply_block):
+    """THE canonical token forward: embed + compute_dtype cast + per-layer
+    ``apply_block(i, block_params, x)`` + final norm + logits in f32.
+    Shared by TransformerLM, the MoE family, and the EP trainer so the
+    cast/loop/head logic exists once; the served programs call the same
+    three pieces around their own layer loop."""
+    x = _embed(c, params, tokens)
+    params = _cast_params(c, params)
+    for i in range(c.n_layers):
+        x = apply_block(i, params[f"b{i}"], x)
+    return _head(c, params, x)
+
+
+# ---- attention over a KV cache: what the served programs hand _block_apply --
+def _cache_keep(positions, total, window):
+    """Which of a cache's ``total`` entries the query at each of ``positions``
+    ([T], or [B, T]) reads: itself and what came before, no further back than
+    the layer's ``window`` (a window layer's cache is masked, not
+    shortened). [1 | B, T, total] bool."""
+    pos = (positions if positions.ndim == 2 else positions[None])[..., None]
+    at = jnp.arange(total)
+    keep = at <= pos
+    if window is not None:
+        keep &= at > pos - window
+    return keep
+
+
+def _attend_cache(q, kc, vc, keep):
+    """THE attention of the served programs: ``q`` [B, H, T, hd] over a cache
+    ``kc``, ``vc`` [B, kv_heads, total, hd] that already holds the T new
+    entries, under ``keep`` (``_cache_keep``). Grouped scores: q is regrouped
+    onto its kv head, the cache is never repeated."""
+    B, H, T, hd = q.shape
+    kv = kc.shape[1]
+    qh = q.reshape(B, kv, H // kv, T, hd)
+    s = jnp.einsum("bkgqd,bktd->bkgqt", qh, kc) / math.sqrt(hd)
+    s = jnp.where(keep[:, None, None], s, -1e30)
+    o = jnp.einsum("bkgqt,bktd->bkgqd", jax.nn.softmax(s, axis=-1), vc)
+    return o.reshape(B, H, T, hd)
+
+
+# The three cache writers, one a mode. What each leaves in the cache is the
+# bit-parity contract between the served programs (tests/test_serving.py);
+# which is cheapest no cell can say yet (ROADMAP S6: count bytes first), so
+# they are neither merged nor chosen between here.
+def _write_at(cache, new, pos):
+    """Lock-step decode (``generate``, beam search): every row's one new entry
+    ``new`` [B, kv_heads, 1, hd] at the same scalar ``pos``."""
+    return jax.lax.dynamic_update_slice_in_dim(cache, new, pos, axis=2)
+
+
+def _write_rows(cache, new, hit):
+    """Continuous decode: row r's new entry where ``hit`` [B, total] is set:
+    its own position if the row is active; a finished row coasting past the
+    cache end matches nothing."""
+    return jnp.where(hit[:, None, :, None], new, cache)
+
+
+def _write_window(row, pages, hitf, wrote):
+    """Prefill: window ``pages`` [kv_heads, W, hd] into ONE slot's cache row
+    [kv_heads, total, hd] at the hit positions: a 0/1 einsum (exactly one
+    source per written position, so the write is bit-exact) — no
+    dynamic_update_slice, so a window running past ``max_len`` clips instead
+    of shifting."""
+    scat = jnp.einsum("wt,kwd->ktd", hitf, pages)
+    return jnp.where(wrote[None, :, None], scat, row)
 
 
 def _lr_at(c, t):
@@ -1073,8 +1148,9 @@ class TransformerLM:
         scheduler (serving/decode.py); the device copies here are the
         traced truth."""
         c = self.conf
-        c.served_as_gpt2("the continuous-batching KV slot pool (ContinuousLM)")
-        hd = c.d_model // c.n_heads
+        c.served_without_experts(
+            "the continuous-batching KV slot pool (ContinuousLM)")
+        hd = c.hd
         total = c.max_len
         cdt = self._cache_dtype()
         S = slots
@@ -1125,7 +1201,7 @@ class TransformerLM:
         c = self.conf
         total = c.max_len
         W = min(W, total)
-        row_step = self._make_token_step(S, W, vector_pos=True)
+        row_step = self._make_token_step(W, vector_pos=True)
         rows = jnp.arange(S)
 
         def chunk_run(params, state):
@@ -1157,8 +1233,8 @@ class TransformerLM:
                 # every sampler mix shares this ONE compiled signature.
                 # The filter's argsort is gated behind a traced cond —
                 # ONE program either way, but an all-greedy/unfiltered
-                # pool (the common serving case, and the bench.py serve
-                # lane) never pays the per-step sort
+                # pool (the common serving case) never pays the per-step
+                # sort
                 need = jnp.any((topk < c.vocab_size) | (topp < 1.0))
                 flt = jax.lax.cond(
                     need,
@@ -1286,78 +1362,46 @@ class TransformerLM:
         pages ``[L, kv_heads, W, hd]`` so the scheduler can memoise
         them."""
         c = self.conf
-        c.served_as_gpt2("chunked prefill (ContinuousLM)")
-        d = c.d_model
-        hd = d // c.n_heads
-        L = c.n_layers
+        c.served_without_experts("chunked prefill (ContinuousLM)")
+        hd = c.hd
         total = c.max_len
-        cd = c.compute_dtype
         cdt = self._cache_dtype()
         win = jnp.arange(W)
         tpos = jnp.arange(total)
 
-        def scatter(row, pages, hitf, wrote):
-            """Write window ``pages`` [kv_heads, W, hd] into cache row
-            [kv_heads, total, hd] at the hit positions: a 0/1 einsum
-            (exactly one source per written position, so the write is
-            bit-exact) — no dynamic_update_slice, so a window running
-            past ``max_len`` clips instead of shifting."""
-            scat = jnp.einsum("wt,kwd->ktd", hitf, pages)
-            return jnp.where(wrote[None, :, None], scat, row)
+        def hits(start, nvalid):
+            """The window's positions, and where its valid tokens land in a
+            cache row: ``hit`` [W, total] as 0/1 in the cache dtype, and which
+            of the row's entries are written."""
+            pos_w = start + win
+            hit = (tpos[None, :] == pos_w[:, None]) \
+                & (win < nvalid)[:, None]
+            return pos_w, hit.astype(cdt), hit.any(axis=0)
 
         def forward(params, toks, start, nvalid, krows, vrows):
-            pos_w = start + win
-            x = params["wte"][toks]                          # [W, d]
-            if c.pos_embed == "learned":
-                x = x + params["wpe"][jnp.clip(pos_w, 0, total - 1)]
-            if cd:   # mirror _make_token_step: compute-dtype body
-                x = x.astype(cd)
-                params = jax.tree.map(
-                    lambda a: (a.astype(cd)
-                               if jnp.issubdtype(a.dtype, jnp.floating)
-                               else a), params)
-            hit = (tpos[None, :] == pos_w[:, None]) \
-                & (win < nvalid)[:, None]                    # [W, total]
-            hitf = hit.astype(cdt)
-            wrote = hit.any(axis=0)
-            keep = tpos[None, :] <= pos_w[:, None]
-            if c.window is not None:   # sliding-window attention rides
-                keep &= tpos[None, :] > (pos_w[:, None] - c.window)
-            if c.pos_embed == "rope":
-                cos, sin = _rope_cos_sin(c.layer_spec(0).rope, hd,
-                                         pos_w)              # [W, hd/2]
+            pos_w, hitf, wrote = hits(start, nvalid)
+            x = _embed(c, params, toks[None], pos_w)         # [1, W, d]
+            params = _cast_params(c, params)
             new_k, new_v, pk, pv = [], [], [], []
-            for i in range(L):
-                bp = params[f"b{i}"]
-                hloc = _layer_norm(x, bp["ln1_g"], bp["ln1_b"])
-                qkv = hloc @ bp["qkv"] + bp["qkv_b"]
-                kvd = c.kv_heads * hd
-                q, k, v = jnp.split(qkv, [d, d + kvd], axis=-1)
-                q = q.reshape(W, c.n_heads, hd).transpose(1, 0, 2)
-                k = k.reshape(W, c.kv_heads, hd).transpose(1, 0, 2)
-                v = v.reshape(W, c.kv_heads, hd).transpose(1, 0, 2)
-                if c.pos_embed == "rope":   # cache stores ROTATED keys
-                    q = _apply_rope(q, cos, sin)
-                    k = _apply_rope(k, cos, sin)
-                # window K/V land in the cache row BEFORE attention, so
-                # within-window causality reads them back at cache dtype
-                # — exactly what the decode step's per-token writes see
-                kc = scatter(krows[i], k, hitf, wrote)
-                vc = scatter(vrows[i], v, hitf, wrote)
-                qh = q.reshape(c.kv_heads, c.kv_group, W, hd)
-                s = jnp.einsum("kgwd,ktd->kgwt", qh, kc) / math.sqrt(hd)
-                s = jnp.where(keep[None, None, :, :], s, -1e30)
-                o = jnp.einsum("kgwt,ktd->kgwd",
-                               jax.nn.softmax(s, axis=-1), vc)
-                o = o.transpose(2, 0, 1, 3).reshape(W, d)
-                x = x + o @ bp["proj"] + bp["proj_b"]
-                hloc = _layer_norm(x, bp["ln2_g"], bp["ln2_b"])
-                x = x + jax.nn.gelu(hloc @ bp["fc"] + bp["fc_b"]) \
-                    @ bp["out"] + bp["out_b"]
-                new_k.append(kc)
-                new_v.append(vc)
-                pk.append(k.astype(cdt))
-                pv.append(v.astype(cdt))
+            for i in range(c.n_layers):
+                spec = c.layer_spec(i)
+                keep = _cache_keep(pos_w, total, spec.window)
+
+                def attend(q, k, v, i=i, keep=keep):
+                    # window K/V land in the cache row BEFORE attention, so
+                    # within-window causality reads them back at cache dtype
+                    # — exactly what the decode step's per-token writes see
+                    # (the cache stores ROTATED keys)
+                    kc = _write_window(krows[i], k[0], hitf, wrote)
+                    vc = _write_window(vrows[i], v[0], hitf, wrote)
+                    new_k.append(kc)
+                    new_v.append(vc)
+                    pk.append(k[0].astype(cdt))
+                    pv.append(v[0].astype(cdt))
+                    return _attend_cache(q, kc[None], vc[None], keep)
+
+                x = _block_apply(c, params[f"b{i}"], x, spec, attend=attend,
+                                 positions=pos_w)
             return (tuple(new_k), tuple(new_v),
                     jnp.stack(pk), jnp.stack(pv))
 
@@ -1374,14 +1418,10 @@ class TransformerLM:
                 for b in state["v"]]
 
             def reuse(_):
-                pos_w = start + win
-                hit = (tpos[None, :] == pos_w[:, None]) \
-                    & (win < nvalid)[:, None]
-                hitf = hit.astype(cdt)
-                wrote = hit.any(axis=0)
-                ks = tuple(scatter(r, ik[i], hitf, wrote)
+                _, hitf, wrote = hits(start, nvalid)
+                ks = tuple(_write_window(r, ik[i], hitf, wrote)
                            for i, r in enumerate(krows))
-                vs = tuple(scatter(r, iv[i], hitf, wrote)
+                vs = tuple(_write_window(r, iv[i], hitf, wrote)
                            for i, r in enumerate(vrows))
                 return ks, vs, ik, iv
 
@@ -1407,109 +1447,59 @@ class TransformerLM:
 
         return jax.jit(prefill, donate_argnums=(1,))
 
-    def _make_token_step(self, B, total, *, vector_pos=False):
-        """One-token decode step closure over (rows B, cache length
-        total): THE canonical decode attention/FFN math, shared by the
-        sampling and beam-search builders (scalar ``pos`` — the whole
-        batch decodes in lock-step, cache writes via
-        ``dynamic_update_slice``) and, with ``vector_pos=True``, the
-        continuous-batching decode step (per-row ``pos[B]`` positions,
-        one-hot cache writes masked by the active-row ``write`` arg —
-        rows past the cache end match nothing). Runs in the model's
-        compute dtype with f32 logits; one fix here reaches every decode
-        consumer."""
+    def _make_token_step(self, total, *, vector_pos=False):
+        """One-token decode step over caches of length ``total``: the one
+        ``_block_apply`` with an attention that writes the token's K/V into
+        the layer's cache and attends over it. Shared by the sampling and
+        beam-search builders (scalar ``pos`` — the whole batch decodes in
+        lock-step, cache writes via ``dynamic_update_slice``) and, with
+        ``vector_pos=True``, the continuous-batching decode step (per-row
+        ``pos[B]`` positions, one-hot cache writes masked by the active-row
+        ``write`` arg — rows past the cache end match nothing). Runs in
+        the model's compute dtype with f32 logits."""
         c = self.conf
-        c.served_as_gpt2("KV-cache decoding (generate, beam_search, "
-                         "ContinuousLM)")
-        d = c.d_model
-        hd = d // c.n_heads
-        L = c.n_layers
-        cd = c.compute_dtype
-
-        def block_step(bp, x, kc, vc, pos, write):
-            """x: [B, 1, d]; kc/vc: [B, kv_heads, total, hd] caches (the
-            GQA cache is kv_group× smaller than MHA's); pos: scalar, or
-            [B] i32 with ``vector_pos``; write: [B] bool active-row mask
-            (vector_pos only)."""
-            hloc = _layer_norm(x, bp["ln1_g"], bp["ln1_b"])
-            qkv = hloc @ bp["qkv"] + bp["qkv_b"]
-            kvd = c.kv_heads * hd
-            q, k, v = jnp.split(qkv, [d, d + kvd], axis=-1)
-            sh = lambda a, H: a.reshape(B, 1, H, hd).transpose(0, 2, 1, 3)
-            q = sh(q, c.n_heads)
-            k, v = sh(k, c.kv_heads), sh(v, c.kv_heads)
-            if c.pos_embed == "rope":   # cache stores ROTATED keys
-                if vector_pos:          # per-row rotation angle
-                    cos, sin = _rope_cos_sin(c.layer_spec(0).rope, hd, pos)
-                    cos, sin = cos[:, None, None, :], sin[:, None, None, :]
-                else:
-                    cos, sin = _rope_cos_sin(c.layer_spec(0).rope, hd,
-                                             jnp.asarray(pos)[None])
-                q, k = _apply_rope(q, cos, sin), _apply_rope(k, cos, sin)
-            if vector_pos:
-                # per-row scatter at pos: rows past the cache end (a
-                # finished slot coasting until freed) match nothing
-                hit = (jnp.arange(total)[None, :] == pos[:, None]) \
-                    & write[:, None]
-                kc = jnp.where(hit[:, None, :, None], k, kc)
-                vc = jnp.where(hit[:, None, :, None], v, vc)
-                keep = jnp.arange(total)[None, :] <= pos[:, None]
-                if c.window is not None:
-                    keep &= jnp.arange(total)[None, :] > (pos[:, None]
-                                                          - c.window)
-                keep = keep[:, None, None, :]
-            else:
-                kc = jax.lax.dynamic_update_slice_in_dim(kc, k, pos, axis=2)
-                vc = jax.lax.dynamic_update_slice_in_dim(vc, v, pos, axis=2)
-                keep = jnp.arange(total) <= pos
-                if c.window is not None:   # sliding window: cache entries
-                    keep &= jnp.arange(total) > pos - c.window  # > W masked
-                keep = keep[None, None, None, :]
-            # grouped scores: q regrouped onto its kv head, no cache repeat
-            qh = q[:, :, 0].reshape(B, c.kv_heads, c.kv_group, hd)
-            s = jnp.einsum("bkgd,bktd->bkgt", qh, kc) / math.sqrt(hd)
-            s = jnp.where(keep, s, -1e30)
-            o = jnp.einsum("bkgt,bktd->bkgd", jax.nn.softmax(s, axis=-1), vc)
-            o = o.reshape(B, 1, d)
-            x = x + o @ bp["proj"] + bp["proj_b"]
-            hloc = _layer_norm(x, bp["ln2_g"], bp["ln2_b"])
-            x = x + jax.nn.gelu(hloc @ bp["fc"] + bp["fc_b"]) @ bp["out"] \
-                + bp["out_b"]
-            return x, kc, vc
+        c.served_without_experts("KV-cache decoding (generate, beam_search, "
+                                 "ContinuousLM)")
 
         def token_step(params, tok, pos, kcs, vcs, write=None):
-            x = params["wte"][tok][:, None, :]
-            if c.pos_embed == "learned":
-                if vector_pos:
-                    x = x + params["wpe"][jnp.clip(pos, 0, c.max_len - 1)][
-                        :, None, :]
-                else:
-                    x = x + params["wpe"][pos][None, None]
-            if cd:   # mirror _forward_tokens: compute-dtype body, f32 logits
-                x = x.astype(cd)
-                params = jax.tree.map(
-                    lambda a: (a.astype(cd)
-                               if jnp.issubdtype(a.dtype, jnp.floating)
-                               else a), params)
+            """tok: [B]; kcs/vcs: per layer [B, kv_heads, total, hd] caches
+            (the GQA cache is kv_group× smaller than MHA's) holding ROTATED
+            keys; pos: scalar, or [B] i32 with ``vector_pos``; write: [B]
+            bool active-row mask (vector_pos only)."""
+            if vector_pos:
+                positions = pos[:, None]                     # [B, 1]
+                hit = (jnp.arange(total)[None, :] == positions) \
+                    & write[:, None]
+                put = lambda cache, new: _write_rows(cache, new, hit)
+            else:
+                positions = jnp.asarray(pos)[None]           # [1]
+                put = lambda cache, new: _write_at(cache, new, pos)
+            x = _embed(c, params, tok[:, None], positions)   # [B, 1, d]
+            params = _cast_params(c, params)
             new_k, new_v = [], []
-            for i in range(L):
-                x, kc, vc = block_step(params[f"b{i}"], x, kcs[i], vcs[i],
-                                       pos, write)
-                new_k.append(kc)
-                new_v.append(vc)
-            x = _layer_norm(x, params["lnf_g"], params["lnf_b"])
-            logits = (x @ params["wte"].T).astype(jnp.float32)
-            return logits[:, 0], new_k, new_v
+            for i in range(c.n_layers):
+                spec = c.layer_spec(i)
+                keep = _cache_keep(positions, total, spec.window)
+
+                def attend(q, k, v, i=i, keep=keep):
+                    kc, vc = put(kcs[i], k), put(vcs[i], v)
+                    new_k.append(kc)
+                    new_v.append(vc)
+                    return _attend_cache(q, kc, vc, keep)
+
+                x = _block_apply(c, params[f"b{i}"], x, spec, attend=attend,
+                                 positions=positions)
+            return _head(c, params, x)[:, 0], new_k, new_v
 
         return token_step
 
     def _build_generate(self, B, P, n_new, temperature, top_k=None,
                         top_p=None, rep_penalty=None):
         c = self.conf
-        hd = c.d_model // c.n_heads
+        hd = c.hd
         L = c.n_layers
         total = P + n_new
-        token_step = self._make_token_step(B, total)
+        token_step = self._make_token_step(total)
 
         def run(params, prompt, rng):
             cdt = self._cache_dtype()
@@ -1599,11 +1589,10 @@ class TransformerLM:
 
     def _build_beam(self, B, P, n_new, W):
         c = self.conf
-        hd = c.d_model // c.n_heads
+        hd = c.hd
         L = c.n_layers
         total = P + n_new
-        prefill_step = self._make_token_step(B, total)
-        beam_step = self._make_token_step(B * W, total)
+        token_step = self._make_token_step(total)
 
         def run(params, prompt):
             cdt = self._cache_dtype()
@@ -1617,8 +1606,7 @@ class TransformerLM:
 
             def prefill(carry, i):
                 kcs, vcs, _ = carry
-                lg, kcs, vcs = prefill_step(params, prompt[:, i], i, kcs,
-                                            vcs)
+                lg, kcs, vcs = token_step(params, prompt[:, i], i, kcs, vcs)
                 return (kcs, vcs, lg), None
             (kcs, vcs, logits), _ = jax.lax.scan(
                 prefill, (kcs, vcs, logits), jnp.arange(P))
@@ -1647,8 +1635,8 @@ class TransformerLM:
                 rows = (jnp.arange(B)[:, None] * W + parent).reshape(-1)
                 kcs = [k[rows] for k in kcs]
                 vcs = [v[rows] for v in vcs]
-                lg, kcs, vcs = beam_step(params, tok.reshape(-1), P + i,
-                                         kcs, vcs)
+                lg, kcs, vcs = token_step(params, tok.reshape(-1), P + i,
+                                          kcs, vcs)
                 return (kcs, vcs, lg, top_s), (tok, parent)
 
             (_, _, _, scores), (toks_t, parents_t) = jax.lax.scan(

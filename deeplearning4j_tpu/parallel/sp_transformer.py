@@ -34,8 +34,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from deeplearning4j_tpu.models.transformer import (TransformerConfig,
                                                    TransformerLM,
                                                    _adamw_apply,
-                                                   _block_apply, _layer_norm,
-                                                   _lr_at)
+                                                   _block_apply, _full_heads,
+                                                   _layer_norm, _lr_at)
 from deeplearning4j_tpu.parallel.sequence_parallel import ring_attention
 from deeplearning4j_tpu.parallel.sharding_core import ShardingCore
 
@@ -85,8 +85,10 @@ class SPTransformerLM:
         """The canonical ``_block_apply`` math on a [B, T/N, d] shard with
         the attention swapped for the ring (everything else is per-token
         and shards trivially)."""
+        # the ring assumes MHA: the block hands over the grouped K/V
         ring = lambda q, k, v: ring_attention(
-            q, k, v, axis_name=self.axis, causal=True)
+            q, *_full_heads(q.shape[1] // k.shape[1], k, v),
+            axis_name=self.axis, causal=True)
         return _block_apply(self.conf, bp, x, self._spec, attend=ring)
 
     def _local_loss(self, params, tokens, targets):

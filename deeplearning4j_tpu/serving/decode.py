@@ -280,9 +280,9 @@ class ContinuousLM(ServingFrontEnd):
                  seed=0, kv_ladder=None, prefill_ladder=None,
                  prefix_cache_mb=None):
         super().__init__(queue_cap=queue_cap)
-        # the decode and prefill programs are the GPT-2 block's: refuse any
-        # other setting here, by its name, not at the first request
-        lm.conf.served_as_gpt2("continuous batching (ContinuousLM)")
+        # what the decode and prefill programs cannot serve is refused here,
+        # by its name, not at the first request
+        lm.conf.served_without_experts("continuous batching (ContinuousLM)")
         if lm.params is None:
             lm.init()
         self.lm = lm
@@ -505,10 +505,9 @@ class ContinuousLM(ServingFrontEnd):
         [1, 64]. Replaces the old hard-coded 4."""
         import jax
         c = self.lm.conf
-        hd = c.d_model // c.n_heads
         # host metadata reads only: sizes/dtypes, never values
         dsize = np.dtype(self.lm._cache_dtype()).itemsize
-        kv_slot = 2 * c.n_layers * c.kv_heads * c.max_len * hd * dsize
+        kv_slot = 2 * c.n_layers * c.kv_heads * c.max_len * c.hd * dsize
         params_b = sum(a.size * a.dtype.itemsize
                        for a in jax.tree.leaves(self.lm.params))
         budget = env_int("DL4J_TPU_MEM_BUDGET", minimum=1)
@@ -664,8 +663,7 @@ class ContinuousLM(ServingFrontEnd):
         if pages is None:
             import jax.numpy as jnp
             c = self.lm.conf
-            hd = c.d_model // c.n_heads
-            shape = (c.n_layers, c.kv_heads, W, hd)
+            shape = (c.n_layers, c.kv_heads, W, c.hd)
             z = jnp.zeros(shape, self.lm._cache_dtype())
             pages = self._zero_pages[W] = (z, z)
         return pages

@@ -127,8 +127,8 @@ def test_flash_walk_at_its_bound_compiles_for_v5e(v5e):
 @pytest.mark.parametrize("peephole", [True, False])
 @pytest.mark.parametrize("wrt", ["fwd", "bwd"])
 def test_lstm_cell_compiles_for_v5e(v5e, wrt, peephole):
-    """The char-RNN bench width (bench.py: GravesLSTM, batch 32, hidden
-    200 — not a multiple of the 128-lane tile)."""
+    """The char-RNN width (GravesLSTM, batch 32, hidden 200 — not a
+    multiple of the 128-lane tile)."""
     b, h = 32, 200
     sds = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32, sharding=v5e)
     args = [sds(b, 4 * h), sds(b, h), sds(b, h), sds(h, 4 * h)]
@@ -307,19 +307,6 @@ def test_chip_smoke_refuses_to_run_without_a_tpu():
 # ---------------------------------------------------------------------------
 # no path that quietly runs on the CPU, or against a guessed peak
 # ---------------------------------------------------------------------------
-
-def test_bench_fails_without_a_tpu_unless_cpu_was_asked_for():
-    """bench.py with no TPU and no JAX_PLATFORMS=cpu: the config fails, no
-    CPU number is printed, and the exit code says so."""
-    env = dict(os.environ)
-    env.pop("JAX_PLATFORMS", None)
-    r = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py"), "lenet_step"],
-        cwd=REPO, env=env, capture_output=True, text=True, timeout=300)
-    assert r.returncode != 0
-    line = json.loads(r.stdout.strip().splitlines()[-1])
-    assert line["unit"] == "error" and "no TPU" in line["error"]
-
 
 def test_unknown_device_kind_has_no_peak():
     from deeplearning4j_tpu import hw

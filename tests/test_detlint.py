@@ -177,8 +177,7 @@ class TestLiveTree:
         os.chdir(REPO)
         try:
             return lint_paths(
-                ["deeplearning4j_tpu", "tools", "bench.py", "chip_smoke.py",
-                 "examples"],
+                ["deeplearning4j_tpu", "tools", "chip_smoke.py", "examples"],
                 cache_dir=".graftlint_cache")
         finally:
             os.chdir(cwd)
@@ -188,8 +187,7 @@ class TestLiveTree:
         assert [s for s in live.suppressed if s.rule_id in RULES] == []
 
     def test_det_report_covers_the_model_zoo(self, live):
-        r = det_report([PKG, TOOLS, os.path.join(REPO, "bench.py"),
-                        os.path.join(REPO, "chip_smoke.py"),
+        r = det_report([PKG, TOOLS, os.path.join(REPO, "chip_smoke.py"),
                         os.path.join(REPO, "examples")])
         assert r["version"] == 7
         for name in ("MultiLayerNetwork", "ComputationGraph",

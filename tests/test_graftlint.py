@@ -912,7 +912,6 @@ def test_committed_baseline_matches_the_tree():
     assert baseline.get("findings", {}) == {}
     r = lint_live([os.path.join(REPO, "deeplearning4j_tpu"),
                    os.path.join(REPO, "tools"),
-                   os.path.join(REPO, "bench.py"),
                    os.path.join(REPO, "chip_smoke.py"),
                    os.path.join(REPO, "examples")])
     regressions, _ = ratchet_compare(counts_by_rule(r), baseline)
@@ -968,7 +967,7 @@ def test_cli_exit_codes_and_json(tmp_path):
 # ---------------------------------------------------------------------------
 def test_package_gate_zero_unsuppressed_findings():
     """The whole-package gate (same scope as `make lint`): zero findings
-    across deeplearning4j_tpu + tools + bench.py + chip_smoke.py + examples,
+    across deeplearning4j_tpu + tools + chip_smoke.py + examples,
     interprocedural graph AND the shared dataflow fixpoint included,
     within the tier-1 budget on the 2-core box. One lint pass builds the
     parsed-AST/symbol-table/dataflow caches once and shares them across
@@ -976,8 +975,7 @@ def test_package_gate_zero_unsuppressed_findings():
     t0 = time.monotonic()
     r = lint_paths([os.path.join(REPO, "deeplearning4j_tpu"),
                     os.path.join(REPO, "tools"),
-                    os.path.join(REPO, "bench.py"),
-                    os.path.join(REPO, "chip_smoke.py"),
+                     os.path.join(REPO, "chip_smoke.py"),
                     os.path.join(REPO, "examples")])
     elapsed = time.monotonic() - t0
     assert r.errors == []

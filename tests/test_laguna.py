@@ -476,12 +476,16 @@ def test_a_layer_with_experts_needs_the_experts_setting():
 
 @pytest.mark.parametrize("call", ["generate", "beam_search", "continuous"])
 def test_serving_refuses_the_new_settings_by_name(call):
-    """``generate``, ``beam_search`` and ``ContinuousLM`` are written for the
-    GPT-2 block: a per-layer list, a gate or experts are refused by name, never
-    run through another block's mathematics."""
-    lm = TransformerLM(mixed(attn_gate=True)).init()
+    """``generate``, ``beam_search`` and ``ContinuousLM`` call the one block and
+    serve what it takes, but experts: a row buffer and counters beside the
+    optimizer's state have no meaning for one decoded token, so they are
+    refused by name, never run."""
+    from deeplearning4j_tpu.models.transformer import LayerSpec
+    lm = TransformerLM(mixed(
+        experts=Experts(n_experts=4, top_k=2, d_expert=16),
+        layers=(LayerSpec(), LayerSpec(window=4, ffn="experts")))).init()
     prompt = np.zeros((1, 4), np.int32)
-    with pytest.raises(NotImplementedError, match="per-layer list.*attn_gate"):
+    with pytest.raises(NotImplementedError, match="with experts"):
         if call == "generate":
             lm.generate(prompt, 2)
         elif call == "beam_search":
@@ -489,6 +493,101 @@ def test_serving_refuses_the_new_settings_by_name(call):
         else:
             from deeplearning4j_tpu.serving.decode import ContinuousLM
             ContinuousLM(lm, slots=2)
+
+
+# --- the served programs against the training block ---------------------------
+# ``output()`` is ``_block_apply`` with its own attention and no cache. Every
+# setting the served programs take from the same block, one a case; the GPT-2
+# block and a LayerNorm epsilon far from the default (the served copies of the
+# block once normalised with 1e-5 whatever the configuration said) beside them.
+def tiny(**changes):
+    return TransformerConfig(vocab_size=64, max_len=32, d_model=32, n_heads=2,
+                             n_layers=2, d_ff=64, **changes)
+
+
+def half_partial_rope():
+    from deeplearning4j_tpu.models.transformer import LayerSpec
+    rope = Rope(base=100.0, share=0.5)
+    return tiny(pos_embed="rope", rope_layout="half",
+                layers=(LayerSpec(rope=rope), LayerSpec(rope=rope)))
+
+
+SERVED = {
+    "gpt2": tiny,
+    "gpt2_rope_gqa_window": lambda: tiny(pos_embed="rope", n_kv_heads=1,
+                                         window=4),
+    "norm_eps": lambda: tiny(norm_eps=1e-3),
+    "rmsnorm": lambda: tiny(norm="rmsnorm"),
+    "no_bias": lambda: tiny(bias=False, ffn="swiglu"),
+    "swiglu": lambda: tiny(ffn="swiglu"),
+    "untied_head": lambda: tiny(tie_embeddings=False),
+    "head_dim": lambda: tiny(head_dim=8),
+    "rope_half_partial": half_partial_rope,
+    "attn_gate": lambda: tiny(attn_gate=True),
+    "mixed_layers": lambda: mixed(rope_base=100.0),
+}
+PROMPT, NEW = 5, 7
+
+
+def served_model(name):
+    """A tiny float32 model of the named setting. The blocks' leaves are redrawn
+    at a size where each block moves the logits (at ``init()``'s 0.02 a tied
+    head answers the token it was given whatever the blocks do); the embeddings
+    keep ``init()``'s size, whose variance is of the order of ``norm_eps``
+    1e-3, so the first norm shows which epsilon it was given."""
+    lm = TransformerLM(SERVED[name]()).init()
+    blocks = {k: v for k, v in lm.params.items() if k.startswith("b")}
+    leaves, tree = jax.tree.flatten(blocks)
+    keys = jax.random.split(jax.random.PRNGKey(SEED), len(leaves))
+    lm.params.update(tree.unflatten([
+        a + jax.random.normal(k, a.shape) * (0.2 if a.ndim > 1 else 0.1)
+        for a, k in zip(leaves, keys)]))
+    return lm
+
+
+@pytest.mark.parametrize("call", ["generate", "continuous"])
+@pytest.mark.parametrize("name", sorted(SERVED))
+def test_every_greedy_token_is_the_training_blocks_argmax(name, call):
+    """Every token the served programs pick greedily is the argmax of
+    ``output()`` at the same prefix (one call over the whole row: attention is
+    causal, so position t reads exactly the prefix). A seed whose two best
+    logits lie within 1e-5 anywhere is to be replaced, not tolerated."""
+    lm = served_model(name)
+    prompts = np.random.default_rng(SEED).integers(0, 64, (2, PROMPT),
+                                                   dtype=np.int32)
+    if call == "generate":
+        rows = lm.generate(prompts, NEW, temperature=0.0)
+    else:
+        from deeplearning4j_tpu.serving.decode import ContinuousLM
+        served = ContinuousLM(lm, slots=2, chunk=3)
+        try:
+            rows = np.stack([f.result(120) for f in
+                             [served.submit(p, NEW) for p in prompts]])
+        finally:
+            served.stop()
+    assert np.array_equal(rows[:, :PROMPT], prompts)
+    logits = lm.output(rows[:, :-1])[:, PROMPT - 1:]
+    best = np.sort(logits, axis=-1)
+    assert (best[..., -1] - best[..., -2]).min() > 1e-5, "replace the seed"
+    assert np.array_equal(rows[:, PROMPT:], logits.argmax(-1))
+
+
+def test_a_custom_attend_receives_the_grouped_heads():
+    """``_block_apply`` hands ``attend`` K and V on ``kv_heads`` heads (and q
+    on the layer's own count); a caller that wants MHA repeats them itself."""
+    from deeplearning4j_tpu.models.transformer import _block_apply
+    lm = TransformerLM(mixed()).init()
+    c, seen = lm.conf, []
+
+    def attend(q, k, v):
+        seen.append((q.shape, k.shape, v.shape))
+        return jnp.zeros_like(q)
+
+    x = jnp.zeros((3, 6, c.d_model))
+    for i in range(c.n_layers):
+        _block_apply(c, lm.params[f"b{i}"], x, c.layer_spec(i), attend=attend)
+    assert seen == [((3, 2, 6, 8), (3, 1, 6, 8), (3, 1, 6, 8)),
+                    ((3, 4, 6, 8), (3, 1, 6, 8), (3, 1, 6, 8))]
 
 
 def test_num_params_of_the_cut_is_the_issues_count():

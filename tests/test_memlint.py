@@ -18,6 +18,7 @@ import textwrap
 import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+ZOO = os.path.join(REPO, "deeplearning4j_tpu", "models", "zoo.py")
 sys.path.insert(0, REPO) if REPO not in sys.path else None
 
 from tools.graftlint import (lint_file, lint_paths, lint_source,  # noqa: E402
@@ -434,8 +435,7 @@ class TestFootprint:
         assert "unresolved" in md and "looped" in md
 
     def test_model_mem_report_unknown_name(self):
-        zoo = os.path.join(REPO, "deeplearning4j_tpu", "models", "zoo.py")
-        got = model_mem_report(zoo, "nonesuch", batch=8, steps=4)
+        got = model_mem_report(ZOO, "nonesuch", batch=8, steps=4)
         assert got["rows"] == [] and "nonesuch" in got["unresolved"]
 
 
@@ -1027,46 +1027,24 @@ class TestFootprintAccuracy:
 
 
 # ---------------------------------------------------------------------------
-# bench embedding
+# the zoo's builders through the standalone entry
 # ---------------------------------------------------------------------------
-class TestBenchEmbedding:
-    def test_bench_helper_rows_and_unresolved(self):
-        import bench
-        got = bench._mem_report("lenet_mnist", batch=128)
+class TestZooReport:
+    def test_zoo_rows_and_unresolved(self):
+        got = model_mem_report(ZOO, "lenet_mnist", batch=128, steps=8)
         assert got["unresolved"] is None
         programs = [r["program"] for r in got["rows"]]
         assert "train[B=128]" in programs and any(
             p.startswith("fused[") for p in programs)
         # a control-flow builder carries its reason, never a silent miss
-        got = bench._mem_report("resnet50", batch=32)
+        got = model_mem_report(ZOO, "resnet50", batch=32, steps=8)
         assert got["rows"] == [] and "control flow" in got["unresolved"]
 
-    def test_bench_consts_override_matches_degraded_lane(self):
-        import bench
-        got = bench._mem_report(
-            "char_rnn", batch=8, steps=8, seq=200,
+    def test_consts_override_the_builders_defaults(self):
+        got = model_mem_report(
+            ZOO, "char_rnn", batch=8, steps=8, seq=200,
             consts={"vocab_size": 32, "hidden": 64, "tbptt_length": 25})
         assert got["unresolved"] is None
         train = got["rows"][0]
         assert train["n_params"] == 60320
         assert train["bytes"]["inputs"] == 2 * 8 * 200 * 32 * 4
-
-    def test_dpshard_state_rows_split_the_train_row_per_level(self):
-        """The dp_shard bench's per-level replicated-state rows: level N
-        counts sharded components 1/n — level 3 on DP-8 keeps 1/8 of
-        what level 0 replicates (the G020 footprint the sharding core
-        removes)."""
-        import bench
-        report = bench._mem_report("mlp_mnist", batch=512,
-                                   consts={"hidden": 2048})
-        rows = bench._dpshard_state_rows(report, n=8)
-        assert [r["level"] for r in rows] == [0, 1, 2, 3]
-        train = report["rows"][0]["bytes"]
-        full = train["params"] + train["grads"] + train["updater"]
-        assert rows[0]["replicated_state_bytes_per_device"] == full
-        assert rows[3]["replicated_state_bytes_per_device"] == full // 8
-        # monotone: each level replicates no more than the one below
-        reps = [r["replicated_state_bytes_per_device"] for r in rows]
-        assert reps == sorted(reps, reverse=True)
-        # an unresolved report degrades to no rows, never a crash
-        assert bench._dpshard_state_rows({"rows": []}, n=8) == []
