@@ -217,6 +217,18 @@ def phase_train_resnet50(sz, seed, rehearse):
     }
 
 
+def _flash_kernels_in_step(text, n_layers, facts):
+    """Whether the lowered step ``text`` holds the forward, dQ and dK/dV
+    flash kernels once a layer each and no other Mosaic call; the counts go
+    into ``facts``."""
+    names = ("_flash_kernel", "_flash_dq_kernel", "_flash_dkv_kernel")
+    found = {n: text.count(f'kernel_name = "{n}"') for n in names}
+    facts["tpu_custom_calls"] = text.count("tpu_custom_call")
+    facts["kernels_in_step"] = found
+    return (all(v == n_layers for v in found.values())
+            and facts["tpu_custom_calls"] == 3 * n_layers)
+
+
 def phase_train_lm(sz, seed, rehearse):
     import dataclasses
 
@@ -237,13 +249,8 @@ def phase_train_lm(sz, seed, rehearse):
             lm.params, lm.opt_state, lm.iteration, lm._rng,
             toks[:, :-1].astype(np.int32), toks[:, 1:].astype(np.int32),
             None).as_text()
-        names = ("_flash_kernel", "_flash_dq_kernel", "_flash_dkv_kernel")
-        found = {n: text.count(f'kernel_name = "{n}"') for n in names}
-        facts["tpu_custom_calls"] = text.count("tpu_custom_call")
-        facts["kernels_in_step"] = found
-        checks["flash_kernels_in_step"] = (
-            all(v == c.n_layers for v in found.values())
-            and facts["tpu_custom_calls"] == 3 * c.n_layers)
+        checks["flash_kernels_in_step"] = _flash_kernels_in_step(
+            text, c.n_layers, facts)
 
     # the kernel route against dense attention on the same weights
     rows = toks[:_CMP_ROWS, :c.max_len]
@@ -345,11 +352,11 @@ def phase_train_mixed_lm(sz, seed, rehearse):
             lm.params, lm.opt_state, lm.iteration, lm._rng,
             toks[:, :-1].astype(np.int32), toks[:, 1:].astype(np.int32),
             None).as_text()
-        facts["tpu_custom_calls"] = text.count("tpu_custom_call")
         facts["ragged_dots"] = text.count("ragged_dot")
-        # forward, the forward again under remat, dQ and dK/dV in each layer
-        checks["flash_kernels_in_step"] = \
-            facts["tpu_custom_calls"] == 4 * c.n_layers
+        # remat or not, one of each a layer: a rematerialised block keeps the
+        # forward kernel's output and logsumexp and does not run it again
+        checks["flash_kernels_in_step"] = _flash_kernels_in_step(
+            text, c.n_layers, facts)
         checks["grouped_products_in_step"] = facts["ragged_dots"] > 0
     got = lm.eval_loss(toks)
     want = _plain_mixed_loss(c, lm.params, toks)

@@ -13,7 +13,10 @@ rather than as layer-zoo glue:
   modules in ``parallel/`` share the same attention math);
 - ``compute_dtype='bfloat16'`` runs forward/backward in bf16 against f32
   masters (MXU-friendly), ``remat=True`` wraps each block in
-  ``jax.checkpoint`` to trade FLOPs for activation HBM;
+  ``jax.checkpoint`` to trade FLOPs for activation HBM: a block keeps its
+  input and, on the flash route, the kernel's attention output and
+  logsumexp (``B·T·H·hd`` compute-dtype elements + ``B·H·T`` float32 a
+  layer), so its backward recomputes everything but the forward kernel;
 - generation is a ``lax.scan`` over a preallocated KV cache — static
   shapes, one compiled program for the whole sampling loop.
 """
@@ -34,6 +37,9 @@ from deeplearning4j_tpu.config import env_int, env_str
 
 from deeplearning4j_tpu.models.expert_layer import (STATS, Experts,
                                                     expert_ffn, swiglu)
+from deeplearning4j_tpu.ops.pallas_kernels import (FLASH_RESIDUALS,
+                                                   flash_attention,
+                                                   pallas_supported)
 from deeplearning4j_tpu.parallel.sequence_parallel import (
     blockwise_attention, dense_attention)
 
@@ -104,8 +110,6 @@ def _blockwise_route(c, q, k, v, plan=None, window=None):
     shard inside one — attention never mixes batch rows."""
     mode = env_str("DL4J_TPU_LM_ATTN")
     if mode in ("auto", "pallas"):
-        from deeplearning4j_tpu.ops.pallas_kernels import (flash_attention,
-                                                           pallas_supported)
         if mode == "pallas" or pallas_supported():
             # GQA rides the kernel's index map — no repeat materialized
             attend = functools.partial(
@@ -233,6 +237,8 @@ class TransformerConfig:
     beta2: float = 0.999
     eps: float = 1e-8
     compute_dtype: Optional[str] = None   # e.g. "bfloat16"
+    # a rematerialised block keeps its input and, on the flash route, the
+    # kernel's attention output and logsumexp (see _remat for the bytes)
     remat: bool = False
     block_size: Optional[int] = None      # flash-attention block; None=dense
     window: Optional[int] = None          # causal sliding-window width
@@ -461,6 +467,18 @@ def _block_apply(c, bp, x, spec, drop=None, rng=None, attend=None, ffn=None,
             m = jax.nn.gelu(hloc @ bp["fc"] + bp["fc_b"]) @ bp["out"] \
                 + bp["out_b"]
         return x + (drop(m, r2) if drop else m)
+
+
+def _remat(blk):
+    """``blk`` rematerialised: its backward recomputes it from its input,
+    except the two results only the flash forward kernel can produce
+    (``FLASH_RESIDUALS``: the attention output, ``B·T·H·hd`` compute-dtype
+    elements, and the one-lane logsumexp, ``B·H·T`` float32), which are kept,
+    so the kernel runs once a step and not again in the backward. Off the
+    flash route nothing carries those names and only the input is kept."""
+    return jax.checkpoint(
+        blk, policy=jax.checkpoint_policies.save_only_these_names(
+            *FLASH_RESIDUALS))
 
 
 def _embed(c, params, tokens, positions=None):
@@ -847,7 +865,7 @@ class TransformerLM:
         def apply(i, bp, x):
             spec = c.layer_spec(i)
             blk = functools.partial(self._block, spec)
-            out = (jax.checkpoint(blk) if c.remat else blk)(bp, x, rngs[i])
+            out = (_remat(blk) if c.remat else blk)(bp, x, rngs[i])
             if spec.ffn != "experts":
                 return out
             if stats is not None:

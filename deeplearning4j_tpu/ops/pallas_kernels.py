@@ -37,6 +37,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 
 from deeplearning4j_tpu import obs
 from deeplearning4j_tpu.config import env_flag, env_str
@@ -732,12 +733,21 @@ def _flash_attention_3d(q, k, v, causal, block_q, block_k, window=None,
     return out
 
 
+# The two residuals of a flash call that only the forward kernel can produce,
+# as ``jax.ad_checkpoint.checkpoint_name`` names them: a ``jax.checkpoint``
+# whose policy saves these names keeps them and runs no forward kernel in its
+# backward (``models/transformer._remat``); under no policy a name is nothing.
+FLASH_RESIDUALS = ("flash_out", "flash_lse")
+
+
 def _flash_fwd(q, k, v, causal, block_q, block_k, window=None, kv_group=1):
     out, lse = _flash_forward(
         q, k, v,
         **_statics(q, causal, block_q, block_k, window, kv_group, "k"))
-    # keep one lane: the saved residual is [n, T], not 128x that
-    return out, (q, k, v, out, lse[..., 0])
+    # keep one lane: the saved residual is [n, T], not 128x that (and the
+    # slice is what is named: naming the kernel's output would keep the 128x)
+    out, lse = map(checkpoint_name, (out, lse[..., 0]), FLASH_RESIDUALS)
+    return out, (q, k, v, out, lse)
 
 
 def _flash_bwd(causal, block_q, block_k, window, kv_group, residuals, g):

@@ -236,37 +236,56 @@ def test_lm_step_names_reach_the_chips_program(v5e, monkeypatch):
 def test_mixed_lm_step_compiles_for_v5e(v5e, monkeypatch):
     """A step with a per-layer list (full and window layers with their own
     head counts, the gate, experts held 8 of 16, remat) compiles for the chip:
-    four flash kernels a layer (forward, forward again under remat, dQ,
-    dK/dV), the grouped products as the compiler's own kernels, and every
-    scope of the per-layer vocabulary in the program's names."""
+    three flash kernels a layer (forward, dQ, dK/dV: a rematerialised block
+    keeps the forward kernel's output and logsumexp, ``transformer._remat``,
+    and its backward does not run it again), the grouped products as the
+    compiler's own kernels, and every scope of the per-layer vocabulary in
+    the program's names. What the block keeps costs the named residuals'
+    bytes and no more: the lane-replicated logsumexp, 128 times the one that
+    is named, would not pass."""
     import re
 
     import chip_smoke
-    from deeplearning4j_tpu.models.transformer import TransformerLM
+    from deeplearning4j_tpu.models import transformer
     monkeypatch.setenv("DL4J_TPU_LM_ATTN", "pallas")
     sizes = dict(chip_smoke._MIXED_LM, seq=1024, d_model=128, d_ff=256,
                  vocab_size=512)
-    lm = TransformerLM(chip_smoke._mixed_config(sizes, 0))
+    rows = 2
+    lm = transformer.TransformerLM(chip_smoke._mixed_config(sizes, 0))
+    c = lm.conf
     params, opt = jax.eval_shape(lambda: (lm.init().params, lm.opt_state))
     lm.params = lm.opt_state = None
     sds = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e)
-    tokens = jax.ShapeDtypeStruct((2, sizes["seq"]), jnp.int32, sharding=v5e)
-    text = lm._build_step().lower(
-        jax.tree.map(sds, params), jax.tree.map(sds, opt),
-        jax.ShapeDtypeStruct((), jnp.int32, sharding=v5e),
-        jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=v5e),
-        tokens, tokens, None).compile().as_text()
+    tokens = jax.ShapeDtypeStruct((rows, sizes["seq"]), jnp.int32,
+                                  sharding=v5e)
+
+    def compiled():
+        return lm._build_step().lower(
+            jax.tree.map(sds, params), jax.tree.map(sds, opt),
+            jax.ShapeDtypeStruct((), jnp.int32, sharding=v5e),
+            jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=v5e),
+            tokens, tokens, None).compile()
+
+    step = compiled()
+    text = step.as_text()
     kernels = re.findall(r'custom_call_target="tpu_custom_call"[^\n]*'
                          r'op_name="([^"]*)"', text)
     flash = [n for n in kernels if n.endswith("pallas_call")]
-    assert len(flash) == 4 * lm.conf.n_layers
-    assert sum("block.attn_window" in n for n in flash) == 4 * 3
-    assert sum("block.attn_full" in n for n in flash) == 4 * 2
+    assert len(flash) == 3 * c.n_layers
+    assert sum("block.attn_window" in n for n in flash) == 3 * 3
+    assert sum("block.attn_full" in n for n in flash) == 3 * 2
     assert "ragged-dot" in text
     for scope in ("attn_gate", "router", "moe_dispatch", "experts",
                   "shared_expert"):
         assert re.search(rf'op_name="jit\(step\)/[^"]*block\.{scope}[)/]',
                          text), scope
+
+    # a layer's named residuals: the output in bfloat16, one float32 a row
+    heads = sum(c.layer_spec(i).n_heads for i in range(c.n_layers))
+    kept = rows * sizes["seq"] * heads * (c.hd * 2 + 4)
+    monkeypatch.setattr(transformer, "_remat", jax.checkpoint)
+    bare = compiled().memory_analysis().temp_size_in_bytes
+    assert step.memory_analysis().temp_size_in_bytes <= bare + kept
 
 
 def _smoke(*args, env=None):

@@ -526,6 +526,106 @@ class TestFlashWalk:
         obs.reset_metrics()
 
 
+class TestFlashRemat:
+    """A rematerialised block whose policy keeps ``FLASH_RESIDUALS``
+    (``transformer._remat``) runs the forward kernel once: its backward holds
+    dQ and dK/dV and no second forward, and computes the same bits."""
+
+    # (query heads, K/V heads, window): T 64 in blocks of 16, heads of 8
+    CASES = {"full": (2, 2, None), "window": (2, 2, 16), "gqa": (4, 2, None)}
+    T, HD, D, BLOCK, BLOCKS = 64, 8, 16, 16, 2
+
+    def _stack(self, case, wrap):
+        """(loss of a two-block stack, its weights and input); ``wrap`` is
+        what each block goes through."""
+        from deeplearning4j_tpu.ops.pallas_kernels import flash_attention
+        heads, kv_heads, window = self.CASES[case]
+        rng = np.random.RandomState(5)
+        cols = (heads + 2 * kv_heads) * self.HD
+        weights = [
+            {"qkv": jnp.asarray(rng.randn(self.D, cols) * 0.3, jnp.float32),
+             "proj": jnp.asarray(rng.randn(heads * self.HD, self.D) * 0.3,
+                                 jnp.float32)}
+            for _ in range(self.BLOCKS)]
+        x = jnp.asarray(rng.randn(1, self.T, self.D), jnp.float32)
+
+        def block(w, x):
+            q, k, v = (
+                a.reshape(1, self.T, -1, self.HD).transpose(0, 2, 1, 3)
+                for a in jnp.split(x @ w["qkv"], [heads * self.HD,
+                                                  (heads + kv_heads) * self.HD],
+                                   axis=-1))
+            o = flash_attention(q, k, v, causal=True, block_q=self.BLOCK,
+                                block_k=self.BLOCK, window=window)
+            return x + o.transpose(0, 2, 1, 3).reshape(x.shape[:2] + (-1,)) \
+                @ w["proj"]
+
+        def loss(weights, x):
+            for w in weights:
+                x = wrap(block)(w, x)
+            return jnp.square(x).sum()
+        return loss, weights, x
+
+    @pytest.mark.parametrize("case", sorted(CASES))
+    def test_backward_holds_no_second_forward(self, interpret_pallas, case):
+        import jax
+        from deeplearning4j_tpu.models.transformer import _remat
+
+        def calls(wrap):
+            loss, weights, x = self._stack(case, wrap)
+            jaxpr = jax.make_jaxpr(jax.grad(loss, (0, 1)))(weights, x).jaxpr
+            return sum(e.primitive.name == "pallas_call"
+                       for e in _eqns(jaxpr))
+        assert calls(_remat) == 3 * self.BLOCKS
+        assert calls(jax.checkpoint) == 4 * self.BLOCKS
+        assert calls(lambda blk: blk) == 3 * self.BLOCKS
+
+    @pytest.mark.parametrize("case", sorted(CASES))
+    def test_gradients_are_the_bare_checkpoints_bits(self, interpret_pallas,
+                                                     case):
+        import jax
+        from deeplearning4j_tpu.models.transformer import _remat
+
+        def grads(wrap):
+            loss, weights, x = self._stack(case, wrap)
+            return jax.tree.leaves(
+                jax.jit(jax.grad(loss, (0, 1)))(weights, x))
+        kept = grads(_remat)
+        assert all(np.abs(np.asarray(g)).max() > 0 for g in kept)
+        for other in (jax.checkpoint, lambda blk: blk):
+            for got, want in zip(kept, grads(other)):
+                np.testing.assert_array_equal(np.asarray(got),
+                                              np.asarray(want))
+
+    def test_a_name_under_no_policy_lowers_to_nothing(self, interpret_pallas,
+                                                      monkeypatch):
+        """Without a policy that saves them, ``FLASH_RESIDUALS`` change no
+        operation of a flash call's lowered forward and backward. (The
+        numbers MLIR's symbol table hangs on a private function's name are
+        left out: jax lowers each distinct equation once as a function named
+        after its primitive and inlines it, and a second ``name`` equation
+        moves that counter on by one.)"""
+        import re
+
+        import jax
+        from deeplearning4j_tpu.ops import pallas_kernels as pk
+        x = jnp.zeros((2, 64, 8), jnp.float32)
+
+        def lowered():
+            def loss(q, k, v):
+                return pk.flash_attention(q, k, v, causal=True, block_q=16,
+                                          block_k=16).sum()
+            text = jax.jit(jax.value_and_grad(loss, (0, 1, 2))).lower(
+                x, x, x).as_text()
+            return re.sub(r"(@\w+?)_\d+\b", r"\1", text)
+        named = lowered()
+        seen = []
+        monkeypatch.setattr(pk, "checkpoint_name",
+                            lambda a, name: seen.append(name) or a)
+        assert lowered() == named
+        assert tuple(seen) == pk.FLASH_RESIDUALS
+
+
 class TestSlidingWindow:
     """Causal sliding-window attention: the kernels mask entries more than
     window-1 positions in the past and skip fully out-of-window blocks."""
