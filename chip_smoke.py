@@ -19,7 +19,14 @@ on ONE TPU chip, in ONE process:
                   key/value heads, window 512, 8 of 16 experts held, remat)
                   at a small width: three steps, and the loss against dense
                   attention and a looped expert sum on the same weights
-  serve_lm        the same model behind ContinuousLM + ServingIngress, a few
+  train_looped_lm TransformerLM with a looped stack in Ouro's pattern (two
+                  layers run four times over the same weights, sandwich
+                  RMSNorms, 16 heads of 128, an exit gate and a loss over the
+                  four exits, remat) at a small width: three steps, one flash
+                  kernel of each kind an APPLICATION, the loss against dense
+                  attention without remat, and greedy ``generate`` through
+                  the 4 x 2 KV caches against ``output``'s last exit
+  serve_lm        the GPT-2-small model behind ContinuousLM + ServingIngress, a few
                   /v1/generate requests over HTTP admitted mid-decode
 
 Output: one JSON line per phase (checks, compile seconds and run seconds kept
@@ -50,7 +57,7 @@ import traceback
 import urllib.request
 
 PHASES = ("train_lenet", "train_resnet50", "train_lm", "train_mixed_lm",
-          "serve_lm")
+          "train_looped_lm", "serve_lm")
 
 # TransformerLM widths: GPT-2 small (Radford et al. 2019: 50257 BPE tokens,
 # 1024 positions, d_model 768, 12 heads, 12 layers, d_ff 3072).
@@ -68,6 +75,14 @@ _MIXED_LM = dict(vocab_size=4096, seq=2048, d_model=256, d_ff=512, head_dim=128,
 _TINY_MIXED_LM = dict(vocab_size=96, seq=32, d_model=64, d_ff=128, head_dim=16,
                       n_kv_heads=2, heads_full=4, heads_window=6, window=8,
                       block_size=8, n_experts=16, held=8, top_k=2, d_expert=32)
+
+# the looped model: Ouro's kernel shapes (16 ungrouped heads of 128, blocks of
+# 512, rows of 4 blocks) and its four runs, the widths around them small
+_LOOPED_LM = dict(vocab_size=4096, max_len=2048, d_model=256, n_heads=16,
+                  head_dim=128, n_layers=2, d_ff=512, block_size=512, loops=4)
+_TINY_LOOPED_LM = dict(vocab_size=96, max_len=32, d_model=64, n_heads=4,
+                       head_dim=16, n_layers=2, d_ff=128, block_size=8,
+                       loops=3)
 
 # relative bar for "the same numbers up to bf16": 8 mantissa bits leave
 # ~0.4% per rounding; the repo's cross-backend parity gate uses the same 2e-2
@@ -89,6 +104,7 @@ def _sizes(rehearse):
                         batch=4, batches_per_fit=4),
             lm=dict(conf=_TINY_LM, batch=2),
             mixed=dict(conf=_TINY_MIXED_LM, batch=2),
+            looped=dict(conf=_TINY_LOOPED_LM, batch=2),
             # (prompt length, n_new): the first streams and stays decoding
             # while the others are admitted
             serve=dict(conf=_TINY_LM,
@@ -100,6 +116,7 @@ def _sizes(rehearse):
                     batch=128, batches_per_fit=9),
         lm=dict(conf=_GPT2_SMALL, batch=8),
         mixed=dict(conf=_MIXED_LM, batch=2),
+        looped=dict(conf=_LOOPED_LM, batch=2),
         serve=dict(conf=_GPT2_SMALL,
                    requests=((5, 160), (37, 16), (130, 40), (300, 8))),
         dp=dict(conf=_GPT2_SMALL, batch=8))
@@ -366,6 +383,66 @@ def phase_train_mixed_lm(sz, seed, rehearse):
             "tokens_per_step": int(toks[:, 1:].size), "losses": losses,
             "loss_rel_err_vs_plain": rel, "loss_kernels": got,
             "loss_plain": want, "counters": counters, **facts}
+
+
+def phase_train_looped_lm(sz, seed, rehearse):
+    import dataclasses
+
+    import numpy as np
+    from deeplearning4j_tpu.models.transformer import (TransformerConfig,
+                                                       TransformerLM)
+
+    lm = TransformerLM(TransformerConfig(
+        pos_embed="rope", rope_base=1e6, rope_layout="half", norm="rmsnorm",
+        norm_eps=1e-6, bias=False, ffn="swiglu", tie_embeddings=False,
+        post_norm=True, exit_gate=True, exit_entropy=0.1, remat=True,
+        compute_dtype="bfloat16", seed=seed, **sz["conf"])).init()
+    c = lm.conf
+    rng = np.random.default_rng(seed)
+    toks = rng.integers(0, c.vocab_size, (sz["batch"], c.max_len + 1))
+    steps = 3
+    losses, run_s = _lm_steps(lm, toks, steps)
+    counters = lm.exit_counters()
+    tokens = steps * toks[:, 1:].size
+    checks = {"loss_falls": _falls(losses),
+              "tokens_counted": counters["exit.tokens"] == tokens,
+              # at the gate's initial balance the last exits hold an eighth
+              "every_exit_carries_loss":
+                  min(counters["exit.mass"]) > 0.02 * tokens,
+              "exits_sum_to_the_tokens":
+                  abs(sum(counters["exit.mass"]) - tokens) <= c.loops * steps}
+    facts = {}
+    if not rehearse:   # interpret mode lowers to plain HLO, not Mosaic
+        text = lm._step.lower(
+            lm.params, lm.opt_state, lm.iteration, lm._rng,
+            toks[:, :-1].astype(np.int32), toks[:, 1:].astype(np.int32),
+            None).as_text()
+        # one of each an application, remat or not
+        checks["flash_kernels_in_step"] = _flash_kernels_in_step(
+            text, c.applications, facts)
+    # the training loss against dense attention, nothing rematerialised
+    plain = TransformerLM(dataclasses.replace(c, block_size=None,
+                                              remat=False))
+    plain.params = lm.params
+    loss = lambda m: float(m._loss(m.params, toks[:, :-1], toks[:, 1:], None))
+    got, want = loss(lm), loss(plain)
+    rel = abs(got - want) / abs(want)
+    checks["loss_matches_plain"] = rel <= _BF16_REL
+    # all the runs a token through the loops x n_layers caches: greedy rows
+    # pick the last exit's argmax wherever it is clear of bfloat16's rounding
+    prompt, new = 8, 8
+    rows = lm.generate(toks[:, :prompt], new, temperature=0.0)
+    logits = lm.output(rows[:, :-1])[:, prompt - 1:]
+    best = np.sort(logits, axis=-1)
+    clear = best[..., -1] - best[..., -2] > _BF16_REL * np.abs(best[..., -1])
+    checks["generate_reads_the_last_exit"] = bool(
+        np.array_equal(rows[:, :prompt], toks[:, :prompt])
+        and np.all((rows[:, prompt:] == logits.argmax(-1)) | ~clear))
+    return {"checks": checks, "run_s": run_s, "steps": steps,
+            "tokens_per_step": int(toks[:, 1:].size), "losses": losses,
+            "loss_rel_err_vs_plain": rel, "applications": c.applications,
+            "greedy_tokens_clear_of_rounding": int(clear.sum()),
+            "counters": counters, **facts}
 
 
 def _post(port, body, first_chunk=None):
@@ -637,6 +714,7 @@ def main(argv=None):
                  "train_resnet50": (phase_train_resnet50, sz["resnet"]),
                  "train_lm": (phase_train_lm, sz["lm"]),
                  "train_mixed_lm": (phase_train_mixed_lm, sz["mixed"]),
+                 "train_looped_lm": (phase_train_looped_lm, sz["looped"]),
                  "serve_lm": (phase_serve_lm, sz["serve"])}
         plan = [(p, *table[p]) for p in PHASES if p in phases]
     ok = native

@@ -18,7 +18,11 @@ rather than as layer-zoo glue:
   logsumexp (``B·T·H·hd`` compute-dtype elements + ``B·H·T`` float32 a
   layer), so its backward recomputes everything but the forward kernel;
 - generation is a ``lax.scan`` over a preallocated KV cache — static
-  shapes, one compiled program for the whole sampling loop.
+  shapes, one compiled program for the whole sampling loop;
+- a looped model (``loops``: the whole stack run several times over the
+  same weights; ``post_norm``, ``exit_gate``) is the same walk
+  (``_stack_runs``) for training and serving, a KV cache entry a run and
+  layer, and a loss over every run's exit (``_exit_terms``).
 """
 
 from __future__ import annotations
@@ -157,7 +161,11 @@ SCOPES = ("embed", "block", "ln1", "qkv", "attn", "proj", "ln2", "mlp",
           # attention by the layer's kind in ``attn``'s place, the gate on
           # its output, and the expert layer's parts in ``mlp``'s place
           "attn_full", "attn_window", "attn_gate", "router", "moe_dispatch",
-          "experts", "shared_expert")
+          "experts", "shared_expert",
+          # a looped model (``TransformerConfig.loops``, ``post_norm``,
+          # ``exit_gate``): the norm on each sublayer's output, and an exit's
+          # gate, exit distribution, entropy and weighting of its loss
+          "attn_norm", "mlp_norm", "exit_gate")
 
 
 @dataclass(frozen=True)
@@ -263,6 +271,18 @@ class TransformerConfig:
     # per-layer list, one LayerSpec a layer: attention kind and window,
     # query heads, rope, FFN kind. None: every layer the model's settings.
     layers: Optional[Tuple[LayerSpec, ...]] = None
+    # ---- a looped model; the defaults are one run and one exit -----------
+    # how many times the whole stack of layers runs over the SAME weights;
+    # the final norm closes every run, and its output is both what that
+    # run's exit reads and what the next run starts from
+    loops: int = 1
+    post_norm: bool = False               # a norm on each sublayer's OUTPUT too
+    # a learned exit gate (d_model -> 1, with bias) after every run: the
+    # training loss is the expectation of the runs' losses under the gate's
+    # exit distribution less ``exit_entropy`` times its entropy; ``output``,
+    # ``eval_loss`` and the served programs read the last run's exit
+    exit_gate: bool = False
+    exit_entropy: float = 0.0
 
     def __post_init__(self):
         if self.head_dim is None and self.d_model % self.n_heads:
@@ -273,6 +293,8 @@ class TransformerConfig:
             raise ValueError(f"window must be >= 1, got {self.window}")
         if self.pos_embed not in ("learned", "rope"):
             raise ValueError(f"unknown pos_embed {self.pos_embed!r}")
+        if self.loops < 1:
+            raise ValueError(f"loops must be >= 1, got {self.loops}")
         if self.ema_decay is not None and not 0.0 < self.ema_decay < 1.0:
             raise ValueError(f"ema_decay must be in (0, 1), "
                              f"got {self.ema_decay}")
@@ -316,10 +338,17 @@ class TransformerConfig:
     def kv_group(self):
         return self.n_heads // self.kv_heads
 
+    @property
+    def applications(self):
+        """Block applications a forward pass makes, each with a KV cache
+        entry of its own where the model is served: ``loops * n_layers``."""
+        return self.loops * self.n_layers
+
     def layer_spec(self, i):
         """Layer ``i``'s ``LayerSpec`` with nothing left None but a full
-        layer's ``window``."""
-        spec = (self.layers[i] if self.layers is not None
+        layer's ``window``; ``i`` may be an application's index, run after
+        run (``i % n_layers`` is its layer)."""
+        spec = (self.layers[i % self.n_layers] if self.layers is not None
                 else LayerSpec(window=self.window))
         return replace(spec, n_heads=spec.n_heads or self.n_heads,
                        rope=spec.rope or Rope(base=self.rope_base))
@@ -328,12 +357,15 @@ class TransformerConfig:
         """The ``LayerSpec`` every layer shares, for ``what``: a trainer that
         builds ONE block program and runs it for every layer. It refuses a
         per-layer list or experts by name, never trains every layer as
-        layer 0."""
-        if self.layers is not None or self.experts is not None:
+        layer 0, and a looped model (`loops`, `post_norm`, `exit_gate`)
+        likewise, never as one run."""
+        looped = self.loops > 1 or self.post_norm or self.exit_gate
+        if self.layers is not None or self.experts is not None or looped:
             raise NotImplementedError(
                 f"{what} runs one block program for every layer and is not "
                 "implemented for a configuration with a per-layer list "
-                "(`layers`) or `experts`; train it through "
+                "(`layers`), `experts` or a looped stack (`loops`, "
+                "`post_norm`, `exit_gate`); train it through "
                 "TransformerLM.fit_batch")
         return self.layer_spec(0)
 
@@ -416,6 +448,8 @@ def _block_apply(c, bp, x, spec, drop=None, rng=None, attend=None, ffn=None,
     r1 = r2 = None
     if rng is not None:
         r1, r2 = jax.random.split(rng)
+    # a sublayer's output onto the residual stream, through its dropout
+    add = lambda x, y, r: x + (drop(y, r) if drop else y)
     # ONE scope element an op ("block.ln1", never "ln1" inside "block"): see
     # the note at SCOPES for what the flash kernels' instruction names need
     scope = jax.named_scope
@@ -452,12 +486,19 @@ def _block_apply(c, bp, x, spec, drop=None, rng=None, attend=None, ffn=None,
                  * gate[..., None].astype(o.dtype)).reshape(B, T, qd)
     with scope("block.proj"):
         a = _linear(c, bp, "proj", o)
-        x = x + (drop(a, r1) if drop else a)
+        if not c.post_norm:
+            x = add(x, a, r1)
+    if c.post_norm:   # sandwich: the sublayer's output is normed, then summed
+        with scope("block.attn_norm"):
+            x = add(x, _norm(c, bp, "attn_norm", a), r1)
     with scope("block.ln2"):
         hloc = _norm(c, bp, "ln2", x)
     if spec.ffn == "experts" and ffn is None:
         m, stats = expert_ffn(c.experts, bp, hloc)
-        return x + (drop(m, r2) if drop else m), stats
+        if c.post_norm:
+            with scope("block.mlp_norm"):
+                m = _norm(c, bp, "mlp_norm", m)
+        return add(x, m, r2), stats
     with scope("block.mlp"):
         if ffn is not None:
             m = ffn(bp, hloc)
@@ -466,7 +507,10 @@ def _block_apply(c, bp, x, spec, drop=None, rng=None, attend=None, ffn=None,
         else:
             m = jax.nn.gelu(hloc @ bp["fc"] + bp["fc_b"]) @ bp["out"] \
                 + bp["out_b"]
-        return x + (drop(m, r2) if drop else m)
+        if not c.post_norm:
+            return add(x, m, r2)
+    with scope("block.mlp_norm"):
+        return add(x, _norm(c, bp, "mlp_norm", m), r2)
 
 
 def _remat(blk):
@@ -507,28 +551,56 @@ def _cast_params(c, params):
         else a, params)
 
 
-def _head(c, params, x):
-    """Final norm, then the logits in f32: the embedding again where it is
-    tied, else the head."""
-    with jax.named_scope("final_ln"):
-        x = _norm(c, params, "lnf", x)
+def _head(c, params, h):
+    """An exit's logits in f32 from the final-normed state ``h``: the
+    embedding again where it is tied, else the head."""
     with jax.named_scope("logits_loss"):
         if not c.tie_embeddings:
-            return (x @ params["head"]).astype(jnp.float32)
-        return (x @ params["wte"].T).astype(jnp.float32)   # tied embeddings
+            return (h @ params["head"]).astype(jnp.float32)
+        return (h @ params["wte"].T).astype(jnp.float32)   # tied embeddings
+
+
+def _stack_runs(c, params, x, apply_block, after_run=None):
+    """THE stack walk, for the training step and the served programs alike:
+    the ``n_layers`` blocks ``c.loops`` times over the same (cast) ``params``,
+    ``apply_block(k, block_params, x)`` for application ``k = run * n_layers +
+    layer`` (the layer's index where the stack runs once), and after every
+    run the final norm, whose output is what that run's exit reads
+    (``after_run(run, params, h)``), what the next run starts from, and for
+    the last run what is returned.
+
+    The runs are unrolled, as the layers are. As a ``lax.scan`` over the runs
+    the program is one run's size and compiles in half the time, but the
+    training step of the 2.6 B looped cell then needs 5 GB more of
+    temporaries (13.0 against 7.9 GB at six layers, compiled for the v5e:
+    PERF.md section 6, PR 32), and the trace's readers count an instruction's
+    mean run once a step (``benchmark/scope_reduce.py``), so every scope
+    inside the loop would read at ``1 / loops`` of its time."""
+    L = c.n_layers
+    for run in range(c.loops):
+        for i in range(L):
+            x = apply_block(run * L + i, params[f"b{i}"], x)
+        with jax.named_scope("final_ln"):
+            x = _norm(c, params, "lnf", x)
+        if after_run is not None:
+            after_run(run, params, x)
+    return x
+
+
+def _forward_states(c, params, tokens, apply_block, after_run=None):
+    """Embed + compute_dtype cast + the stack walk (``_stack_runs``):
+    ``(the cast parameters, the last run's final-normed state)``."""
+    x = _embed(c, params, tokens)
+    params = _cast_params(c, params)
+    return params, _stack_runs(c, params, x, apply_block, after_run)
 
 
 def _forward_tokens(c, params, tokens, apply_block):
-    """THE canonical token forward: embed + compute_dtype cast + per-layer
-    ``apply_block(i, block_params, x)`` + final norm + logits in f32.
-    Shared by TransformerLM, the MoE family, and the EP trainer so the
-    cast/loop/head logic exists once; the served programs call the same
-    three pieces around their own layer loop."""
-    x = _embed(c, params, tokens)
-    params = _cast_params(c, params)
-    for i in range(c.n_layers):
-        x = apply_block(i, params[f"b{i}"], x)
-    return _head(c, params, x)
+    """THE canonical token forward: ``_forward_states`` + the last exit's
+    logits in f32. Shared by TransformerLM, the MoE family, and the EP
+    trainer so the cast/loop/head logic exists once; the served programs
+    call the same pieces around their KV caches."""
+    return _head(c, *_forward_states(c, params, tokens, apply_block))
 
 
 # ---- attention over a KV cache: what the served programs hand _block_apply --
@@ -628,6 +700,46 @@ def _adamw_apply(c, params, grads, opt, t, lr_t, mask=None):
     return new_p, {"m": new_m, "v": new_v}
 
 
+def _token_nll(c, logits, targets):
+    """Each token's next-token loss [B, T] from its f32 ``logits``, label
+    smoothing included."""
+    logp = jax.nn.log_softmax(logits, axis=-1)
+    nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
+    if c.label_smoothing > 0.0:
+        # smoothed CE: (1-a)*nll + a*mean over the vocabulary
+        a = c.label_smoothing
+        nll = (1.0 - a) * nll - a * logp.mean(-1)
+    return nll
+
+
+def _exit_terms(c, last, ep, h, targets, m, log_surv):
+    """One exit of a looped model, from its run's final-normed state ``h``
+    and the exit's leaves ``ep`` (the head or the tied embedding, the gate):
+    with ``lam = sigmoid(h . w + b)`` a token leaves here with probability
+    ``p = lam * surv``, ``surv`` the probability that it left at no earlier
+    exit (``log_surv`` [B, T], f32), and at the ``last`` exit with all that
+    is left, ``p = surv``. Returns this exit's summand of the loss,
+    ``sum_i m_i p_i (L_i + exit_entropy * log p_i)`` (the expected loss less
+    ``exit_entropy`` times the exit distribution's entropy, ``-sum p log p``,
+    exit by exit), the next exit's ``log_surv`` and the mass ``sum_i m_i
+    p_i``. The logits live inside: rematerialised (``_exits_loss``), one
+    exit's are alive at a time."""
+    logits = _head(c, ep, h)
+    with jax.named_scope("logits_loss"):
+        nll = _token_nll(c, logits, targets)
+        if c.z_loss > 0.0:
+            nll = nll + c.z_loss * jax.nn.logsumexp(logits, axis=-1) ** 2
+    with jax.named_scope("exit_gate"):
+        z = (h @ ep["exit_gate"] + ep["exit_gate_b"])[..., 0] \
+            .astype(jnp.float32)
+        log_p = log_surv if last else log_surv + jax.nn.log_sigmoid(z)
+        p = jnp.exp(log_p) * m
+        return ((p * (nll + c.exit_entropy * log_p)).sum(),
+                log_surv + jax.nn.log_sigmoid(-z), p.sum())
+
+
+_APPLICATIONS_DOC = ("Block applications a forward pass of the last traced "
+                     "TransformerLM makes: loops x n_layers")
 _COUNT_LOW = 1 << 30
 _MOE_DOCS = {
     "moe.local_rows": "Expert assignments that met an expert held here, "
@@ -636,6 +748,14 @@ _MOE_DOCS = {
                          "padding included, since init",
     "moe.rows_over_buffer": "Expert assignments left out because the static "
                             "row buffer was full, since init (0 when sound)",
+}
+
+
+_EXIT_DOCS = {
+    "exit.mass": "Tokens' summed probability of leaving at this exit of a "
+                 "looped model, since init (read by "
+                 "TransformerLM.exit_counters)",
+    "exit.tokens": "Tokens that counted in a looped model's loss, since init",
 }
 
 
@@ -768,6 +888,9 @@ class TransformerLM:
             p["wpe"] = normal(ks[1], (c.max_len, d))
         if not c.tie_embeddings:
             p["head"] = normal(ks[2], (d, c.vocab_size))
+        if c.exit_gate:
+            p["exit_gate"] = normal(ks[3], (d, 1))
+            p["exit_gate_b"] = jnp.zeros((1,))
         for i in range(c.n_layers):
             k = ks[4 + 8 * i:4 + 8 * (i + 1)]
             spec = c.layer_spec(i)
@@ -779,6 +902,9 @@ class TransformerLM:
             linear(bp, "qkv", k[0], (d, qd + 2 * c.kv_heads * hd))
             linear(bp, "proj", k[1], (qd, d), rs)
             norm(bp, "ln2")
+            if c.post_norm:
+                norm(bp, "attn_norm")
+                norm(bp, "mlp_norm")
             if c.attn_gate:
                 bp["attn_gate"] = normal(k[4], (d, spec.n_heads))
             if spec.ffn == "experts":
@@ -817,6 +943,10 @@ class TransformerLM:
         if c.has_experts:
             self.opt_state["moe"] = {k: jnp.zeros((2,), jnp.int32)
                                      for k in STATS}
+        if c.exit_gate:
+            self.opt_state["exit"] = {
+                "mass": jnp.zeros((c.loops, 2), jnp.int32),
+                "tokens": jnp.zeros((2,), jnp.int32)}
 
     def num_params(self):
         return sum(int(np.prod(a.shape))
@@ -841,6 +971,30 @@ class TransformerLM:
             obs.metrics.gauge(name, _MOE_DOCS[name]).set(n)
         return out
 
+    def exit_counters(self):
+        """The exits' counts since ``init``, summed over steps:
+        ``exit.mass`` (a list, one an exit: the tokens' summed probability
+        of leaving there, a step's sum rounded to whole tokens) and
+        ``exit.tokens`` (the tokens that counted in a loss), so that
+        ``mass[t] / tokens`` is the share of the loss exit ``t`` carried.
+        Carried on the device and fetched HERE as ``moe_counters`` does: one
+        sync, never inside a timed loop; the gauges ``exit.tokens`` and
+        ``exit.mass.<t>`` (t from 1) take what was read. ``{}`` for a model
+        without an exit gate."""
+        kept = (self.opt_state or {}).get("exit")
+        if kept is None:
+            return {}
+        host = jax.device_get(kept)
+        whole = lambda v: int(v[0]) * _COUNT_LOW + int(v[1])
+        out = {"exit.mass": [whole(v) for v in host["mass"]],
+               "exit.tokens": whole(host["tokens"])}
+        obs.metrics.gauge("exit.tokens", _EXIT_DOCS["exit.tokens"]).set(
+            out["exit.tokens"])
+        for t, n in enumerate(out["exit.mass"], 1):
+            obs.metrics.gauge(f"exit.mass.{t}",
+                              _EXIT_DOCS["exit.mass"]).set(n)
+        return out
+
     # ---- forward -------------------------------------------------------
     def _drop(self, x, rng):
         """Inverted dropout on a residual branch; identity when rng is None
@@ -855,36 +1009,52 @@ class TransformerLM:
         return _block_apply(self.conf, bp, x, spec, drop=self._drop, rng=rng,
                             plan=self._shard_plan)
 
-    def _logits(self, params, tokens, rng=None, stats=None):
-        """``stats``: a list that takes each expert layer's statistics (they
+    def _states(self, params, tokens, rng=None, stats=None, after_run=None):
+        """``_forward_states`` with this model's block: dropout where it
+        trains with one, each block rematerialised where ``remat`` is set.
+        ``stats``: a list that takes each expert layer's statistics (they
         leave a rematerialised block as outputs of it); None drops them."""
         c = self.conf
-        rngs = (jax.random.split(rng, c.n_layers)
-                if rng is not None and c.dropout > 0 else [None] * c.n_layers)
+        n = c.applications
+        rngs = (jax.random.split(rng, n)
+                if rng is not None and c.dropout > 0 else [None] * n)
+        obs.metrics.gauge("lm.block_applications", _APPLICATIONS_DOC).set(n)
 
-        def apply(i, bp, x):
-            spec = c.layer_spec(i)
+        def apply(k, bp, x):
+            spec = c.layer_spec(k)
             blk = functools.partial(self._block, spec)
-            out = (_remat(blk) if c.remat else blk)(bp, x, rngs[i])
+            out = (_remat(blk) if c.remat else blk)(bp, x, rngs[k])
             if spec.ffn != "experts":
                 return out
             if stats is not None:
                 stats.append(out[1])
             return out[0]
 
-        return _forward_tokens(c, params, tokens, apply)
+        return _forward_states(c, params, tokens, apply, after_run)
 
-    def _loss(self, params, tokens, targets, mask, rng=None, stats=None):
+    def _logits(self, params, tokens, rng=None, stats=None):
+        """The last exit's logits."""
+        return _head(self.conf, *self._states(params, tokens, rng, stats))
+
+    def _loss(self, params, tokens, targets, mask, rng=None, stats=None,
+              exits=None):
+        """The training loss: the last exit's (every model's only one), or
+        with an exit gate the loss over all the exits (``_exits_loss``:
+        ``exits``, a list, then takes each exit's mass)."""
+        if self.conf.exit_gate:
+            return self._exits_loss(params, tokens, targets, mask, rng, stats,
+                                    exits)
+        return self._last_exit_loss(params, tokens, targets, mask, rng, stats)
+
+    def _last_exit_loss(self, params, tokens, targets, mask, rng=None,
+                        stats=None):
+        """Mean next-token loss of the last exit's logits: the model as it is
+        served (``eval_loss``), and the training loss where there is no exit
+        gate."""
         c = self.conf
         logits = self._logits(params, tokens, rng, stats)
         with jax.named_scope("logits_loss"):
-            logp = jax.nn.log_softmax(logits, axis=-1)
-            nll = -jnp.take_along_axis(logp, targets[..., None],
-                                       axis=-1)[..., 0]
-            if c.label_smoothing > 0.0:
-                # smoothed CE: (1-a)*nll + a*mean over the vocabulary
-                a = c.label_smoothing
-                nll = (1.0 - a) * nll - a * logp.mean(-1)
+            nll = _token_nll(c, logits, targets)
             m = jnp.ones_like(nll) if mask is None else mask.astype(nll.dtype)
             denom = jnp.maximum(m.sum(), 1.0)
             loss = (nll * m).sum() / denom
@@ -893,6 +1063,34 @@ class TransformerLM:
                 z = jax.nn.logsumexp(logits, axis=-1)
                 loss = loss + c.z_loss * ((z ** 2) * m).sum() / denom
             return loss
+
+    def _exits_loss(self, params, tokens, targets, mask, rng, stats, exits):
+        """``_loss`` of a model with an exit gate: the expectation of the
+        exits' losses under the gate's exit distribution less
+        ``exit_entropy`` times its entropy, every run's exit through
+        ``_exit_terms``, rematerialised where the blocks are, so that the
+        backward holds one exit's vocabulary-wide logits at a time and the
+        forward none."""
+        c = self.conf
+        m = (jnp.ones(targets.shape, jnp.float32) if mask is None
+             else mask.astype(jnp.float32))
+        total, log_surv = 0.0, jnp.zeros(targets.shape, jnp.float32)
+        leaves = ("wte" if c.tie_embeddings else "head", "exit_gate",
+                  "exit_gate_b")
+
+        def after_run(run, cast, h):
+            nonlocal total, log_surv
+            terms = functools.partial(_exit_terms, c, run == c.loops - 1)
+            if c.remat:
+                terms = jax.checkpoint(terms)
+            term, log_surv, mass = terms({k: cast[k] for k in leaves}, h,
+                                         targets, m, log_surv)
+            total = total + term
+            if exits is not None:
+                exits.append(mass)
+
+        self._states(params, tokens, rng, stats, after_run)
+        return total / jnp.maximum(m.sum(), 1.0)
 
     # ---- training ------------------------------------------------------
     def _build_step(self):
@@ -907,12 +1105,15 @@ class TransformerLM:
             rng, sub = jax.random.split(rng)
             fwd_params = params if plan is None else plan.gather_params(params)
             sub = sub if c.dropout > 0 else None
-            if c.has_experts:
+            if c.has_experts or c.exit_gate:
                 def loss_and_stats(p):
-                    found = []
-                    loss = self._loss(p, tokens, targets, mask, sub, found)
-                    return loss, {k: sum(f[k] for f in found) for k in STATS}
-                (loss, stats), grads = jax.value_and_grad(
+                    found, exits = [], []
+                    loss = self._loss(p, tokens, targets, mask, sub, found,
+                                      exits)
+                    stats = {k: sum(f[k] for f in found) for k in STATS} \
+                        if c.has_experts else None
+                    return loss, (stats, exits)
+                (loss, (stats, exits)), grads = jax.value_and_grad(
                     loss_and_stats, has_aux=True)(fwd_params)
             else:
                 loss, grads = jax.value_and_grad(self._loss)(
@@ -939,6 +1140,14 @@ class TransformerLM:
             if c.has_experts:
                 new_opt["moe"] = {k: _count(opt["moe"][k], stats[k])
                                   for k in STATS}
+            if c.exit_gate:
+                # in whole tokens: an exit's summed probability, rounded
+                mass = jnp.round(jnp.stack(exits)).astype(jnp.int32)
+                counted = targets.size if mask is None else \
+                    jnp.sum(mask != 0).astype(jnp.int32)
+                new_opt["exit"] = {
+                    "mass": jax.vmap(_count)(opt["exit"]["mass"], mass),
+                    "tokens": _count(opt["exit"]["tokens"], counted)}
             if plan is not None:
                 # pin updated state to its at-rest placement: level <= 2
                 # all-gathers the sharded delta onto the replicated
@@ -1010,10 +1219,11 @@ class TransformerLM:
         return self
 
     def eval_loss(self, tokens):
-        """Mean next-token NLL on held-out tokens (no update)."""
+        """Mean next-token NLL on held-out tokens (no update); of the last
+        exit where the model has several."""
         tokens = jnp.asarray(tokens, jnp.int32)
-        return float(self._loss(self.params, tokens[:, :-1], tokens[:, 1:],
-                                None))
+        return float(self._last_exit_loss(self.params, tokens[:, :-1],
+                                          tokens[:, 1:], None))
 
     def perplexity(self, tokens):
         return float(np.exp(self.eval_loss(tokens)))
@@ -1174,9 +1384,9 @@ class TransformerLM:
         S = slots
         return {
             "k": [jnp.zeros((S, c.kv_heads, total, hd), cdt)
-                  for _ in range(c.n_layers)],
+                  for _ in range(c.applications)],
             "v": [jnp.zeros((S, c.kv_heads, total, hd), cdt)
-                  for _ in range(c.n_layers)],
+                  for _ in range(c.applications)],
             "pos": jnp.zeros((S,), jnp.int32),
             "last": jnp.zeros((S,), jnp.int32),
             "out": jnp.zeros((S, total), jnp.int32),
@@ -1377,8 +1587,8 @@ class TransformerLM:
         (``lax.cond``) and the provided K/V pages — a prefix-cache hit,
         computed by an earlier dispatch of this same program — are
         written instead. Either way the program returns the window's
-        pages ``[L, kv_heads, W, hd]`` so the scheduler can memoise
-        them."""
+        pages ``[applications, kv_heads, W, hd]`` (one entry a run and layer:
+        ``c.applications``) so the scheduler can memoise them."""
         c = self.conf
         c.served_without_experts("chunked prefill (ContinuousLM)")
         hd = c.hd
@@ -1401,11 +1611,12 @@ class TransformerLM:
             x = _embed(c, params, toks[None], pos_w)         # [1, W, d]
             params = _cast_params(c, params)
             new_k, new_v, pk, pv = [], [], [], []
-            for i in range(c.n_layers):
+
+            def apply(i, bp, x):
                 spec = c.layer_spec(i)
                 keep = _cache_keep(pos_w, total, spec.window)
 
-                def attend(q, k, v, i=i, keep=keep):
+                def attend(q, k, v):
                     # window K/V land in the cache row BEFORE attention, so
                     # within-window causality reads them back at cache dtype
                     # — exactly what the decode step's per-token writes see
@@ -1418,15 +1629,17 @@ class TransformerLM:
                     pv.append(v[0].astype(cdt))
                     return _attend_cache(q, kc[None], vc[None], keep)
 
-                x = _block_apply(c, params[f"b{i}"], x, spec, attend=attend,
-                                 positions=pos_w)
+                return _block_apply(c, bp, x, spec, attend=attend,
+                                    positions=pos_w)
+
+            _stack_runs(c, params, x, apply)
             return (tuple(new_k), tuple(new_v),
                     jnp.stack(pk), jnp.stack(pv))
 
         def prefill(params, state, slot, toks, start, nvalid, final,
                     inject, ik, iv):
             """toks: [W] i32 (padded past nvalid); ik/iv:
-            [L, kv_heads, W, hd] prefix-cache pages (zeros unless
+            [applications, kv_heads, W, hd] prefix-cache pages (zeros unless
             ``inject``). Returns (state, k_pages, v_pages)."""
             krows = [jax.lax.dynamic_slice(
                 b, (slot, 0, 0, 0), (1, c.kv_heads, total, hd))[0]
@@ -1480,7 +1693,8 @@ class TransformerLM:
                                  "ContinuousLM)")
 
         def token_step(params, tok, pos, kcs, vcs, write=None):
-            """tok: [B]; kcs/vcs: per layer [B, kv_heads, total, hd] caches
+            """tok: [B]; kcs/vcs: per run and layer (``c.applications``)
+            [B, kv_heads, total, hd] caches
             (the GQA cache is kv_group× smaller than MHA's) holding ROTATED
             keys; pos: scalar, or [B] i32 with ``vector_pos``; write: [B]
             bool active-row mask (vector_pos only)."""
@@ -1495,19 +1709,22 @@ class TransformerLM:
             x = _embed(c, params, tok[:, None], positions)   # [B, 1, d]
             params = _cast_params(c, params)
             new_k, new_v = [], []
-            for i in range(c.n_layers):
+
+            def apply(i, bp, x):
                 spec = c.layer_spec(i)
                 keep = _cache_keep(positions, total, spec.window)
 
-                def attend(q, k, v, i=i, keep=keep):
+                def attend(q, k, v):
                     kc, vc = put(kcs[i], k), put(vcs[i], v)
                     new_k.append(kc)
                     new_v.append(vc)
                     return _attend_cache(q, kc, vc, keep)
 
-                x = _block_apply(c, params[f"b{i}"], x, spec, attend=attend,
-                                 positions=positions)
-            return _head(c, params, x)[:, 0], new_k, new_v
+                return _block_apply(c, bp, x, spec, attend=attend,
+                                    positions=positions)
+
+            h = _stack_runs(c, params, x, apply)
+            return _head(c, params, h)[:, 0], new_k, new_v
 
         return token_step
 
@@ -1515,7 +1732,7 @@ class TransformerLM:
                         top_p=None, rep_penalty=None):
         c = self.conf
         hd = c.hd
-        L = c.n_layers
+        L = c.applications
         total = P + n_new
         token_step = self._make_token_step(total)
 
@@ -1608,7 +1825,7 @@ class TransformerLM:
     def _build_beam(self, B, P, n_new, W):
         c = self.conf
         hd = c.hd
-        L = c.n_layers
+        L = c.applications
         total = P + n_new
         token_step = self._make_token_step(total)
 

@@ -507,7 +507,7 @@ class ContinuousLM(ServingFrontEnd):
         c = self.lm.conf
         # host metadata reads only: sizes/dtypes, never values
         dsize = np.dtype(self.lm._cache_dtype()).itemsize
-        kv_slot = 2 * c.n_layers * c.kv_heads * c.max_len * c.hd * dsize
+        kv_slot = 2 * c.applications * c.kv_heads * c.max_len * c.hd * dsize
         params_b = sum(a.size * a.dtype.itemsize
                        for a in jax.tree.leaves(self.lm.params))
         budget = env_int("DL4J_TPU_MEM_BUDGET", minimum=1)
@@ -663,7 +663,7 @@ class ContinuousLM(ServingFrontEnd):
         if pages is None:
             import jax.numpy as jnp
             c = self.lm.conf
-            shape = (c.n_layers, c.kv_heads, W, c.hd)
+            shape = (c.applications, c.kv_heads, W, c.hd)
             z = jnp.zeros(shape, self.lm._cache_dtype())
             pages = self._zero_pages[W] = (z, z)
         return pages

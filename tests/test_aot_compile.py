@@ -288,6 +288,63 @@ def test_mixed_lm_step_compiles_for_v5e(v5e, monkeypatch):
     assert step.memory_analysis().temp_size_in_bytes <= bare + kept
 
 
+def test_looped_lm_step_compiles_for_v5e(v5e, monkeypatch):
+    """A step with a looped stack (two layers run four times over the same
+    weights, heads of 128, sandwich norms, an exit gate and the loss over four
+    exits, remat) compiles for the chip: three flash kernels an APPLICATION,
+    none twice (a rematerialised block keeps the forward kernel's output
+    through every run), the three scopes of the looped vocabulary in the
+    program's names, and the exits' vocabulary-wide logits not alive
+    together: doubling the runs adds four exits, and beyond what the added
+    block applications keep the temporaries grow by less than two exits'
+    float32 logits (read: 1.5), where four exits alive together
+    would take four, their log-softmax and gradients not counted."""
+    import re
+
+    import chip_smoke
+    from deeplearning4j_tpu.models import transformer
+    monkeypatch.setenv("DL4J_TPU_LM_ATTN", "pallas")
+    rows, vocab = 2, 8192
+    sizes = dict(chip_smoke._LOOPED_LM, max_len=1024, vocab_size=vocab)
+
+    def compiled(loops):
+        lm = transformer.TransformerLM(transformer.TransformerConfig(
+            pos_embed="rope", rope_layout="half", norm="rmsnorm", bias=False,
+            ffn="swiglu", tie_embeddings=False, post_norm=True,
+            exit_gate=True, exit_entropy=0.1, remat=True,
+            compute_dtype="bfloat16", **dict(sizes, loops=loops)))
+        params, opt = jax.eval_shape(lambda: (lm.init().params, lm.opt_state))
+        lm.params = lm.opt_state = None
+        sds = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e)
+        tokens = jax.ShapeDtypeStruct((rows, sizes["max_len"]), jnp.int32,
+                                      sharding=v5e)
+        return lm.conf, lm._build_step().lower(
+            jax.tree.map(sds, params), jax.tree.map(sds, opt),
+            jax.ShapeDtypeStruct((), jnp.int32, sharding=v5e),
+            jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=v5e),
+            tokens, tokens, None).compile()
+
+    c, step = compiled(4)
+    text = step.as_text()
+    kernels = re.findall(r'custom_call_target="tpu_custom_call"[^\n]*'
+                         r'op_name="([^"]*)"', text)
+    assert len(kernels) == 3 * c.applications == 24
+    assert all(n.endswith("block.attn/pallas_call")
+               or n.endswith("block.attn)/pallas_call") for n in kernels)
+    for scope in ("block.attn_norm", "block.mlp_norm", "exit_gate",
+                  "final_ln", "logits_loss"):
+        assert re.search(rf'op_name="jit\(step\)/[^"]*{scope}[)/]', text), \
+            scope
+    one_exit = rows * sizes["max_len"] * vocab * 4
+    # a rematerialised block keeps its input, the attention output and lse
+    kept_a_run = rows * sizes["max_len"] * c.n_layers * (
+        c.d_model * 2 + c.n_heads * (c.hd * 2 + 4))
+    _, twice = compiled(8)
+    grown = twice.memory_analysis().temp_size_in_bytes \
+        - step.memory_analysis().temp_size_in_bytes
+    assert grown < 4 * kept_a_run + 2 * one_exit
+
+
 def _smoke(*args, env=None):
     return subprocess.run([sys.executable, SMOKE, *args], cwd=REPO,
                           env=env, capture_output=True, text=True,
@@ -295,15 +352,16 @@ def _smoke(*args, env=None):
 
 
 def test_chip_smoke_rehearsal_passes_on_cpu():
-    """The three LM phases at tiny size on the CPU (the ResNet rehearsal is
+    """The four LM phases at tiny size on the CPU (the ResNet rehearsal is
     `make smoke-rehearse`): every check passes and the last line names the
     CPU — it can never be read as a chip result."""
-    r = _smoke("--rehearse", "--phases", "train_lm,train_mixed_lm,serve_lm")
+    r = _smoke("--rehearse", "--phases",
+               "train_lm,train_mixed_lm,train_looped_lm,serve_lm")
     assert r.returncode == 0, r.stdout[-3000:] + r.stderr[-3000:]
     lines = [json.loads(l) for l in r.stdout.splitlines()
              if l.startswith("{")]
     assert [l["phase"] for l in lines[:-1]] == [
-        "setup", "train_lm", "train_mixed_lm", "serve_lm"]
+        "setup", "train_lm", "train_mixed_lm", "train_looped_lm", "serve_lm"]
     assert all(l["ok"] for l in lines)
     assert lines[-1] == {"ok": True, "device": {
         "platform": "cpu", "kind": "cpu", "count": 1}}

@@ -525,6 +525,13 @@ SERVED = {
     "rope_half_partial": half_partial_rope,
     "attn_gate": lambda: tiny(attn_gate=True),
     "mixed_layers": lambda: mixed(rope_base=100.0),
+    # a looped stack: three runs over the same two layers, one KV cache entry
+    # a run and layer, the last exit read (tests/test_ouro.py holds it
+    # against the family's reference)
+    "looped": lambda: tiny(loops=3, post_norm=True, exit_gate=True,
+                           norm="rmsnorm", bias=False, ffn="swiglu",
+                           tie_embeddings=False, pos_embed="rope",
+                           rope_layout="half"),
 }
 PROMPT, NEW = 5, 7
 

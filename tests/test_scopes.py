@@ -71,23 +71,64 @@ def mixed_stacks():
         return name_stacks(lowered.as_text(debug_info=True))
 
 
+@pytest.fixture(scope="module")
+def looped_stacks():
+    """The name stacks of a tiny looped step: two layers run three times,
+    sandwich norms, the exit gate and the loss over the exits, under remat."""
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("DL4J_TPU_PALLAS_INTERPRET", "1")
+        mp.setenv("DL4J_TPU_LM_ATTN", "pallas")
+        lm = transformer.TransformerLM(transformer.TransformerConfig(
+            vocab_size=64, max_len=32, d_model=32, n_heads=2, n_layers=2,
+            d_ff=64, block_size=16, pos_embed="rope", norm="rmsnorm",
+            bias=False, ffn="swiglu", tie_embeddings=False, loops=3,
+            post_norm=True, exit_gate=True, exit_entropy=0.1, remat=True,
+            compute_dtype="bfloat16")).init()
+        tokens = jnp.zeros((2, 32), jnp.int32)
+        lowered = lm._build_step().lower(
+            lm.params, lm.opt_state, jnp.int32(0), jax.random.PRNGKey(0),
+            tokens, tokens, None)
+        return name_stacks(lowered.as_text(debug_info=True))
+
+
 # what a model with a per-layer list enters in ``attn``'s and ``mlp``'s place
 PER_LAYER_SCOPES = ("attn_full", "attn_window", "attn_gate", "router",
                     "moe_dispatch", "experts", "shared_expert")
+# what a looped model enters besides (PR 32)
+LOOPED_SCOPES = ("attn_norm", "mlp_norm", "exit_gate")
 
 
 def test_the_benchmark_reads_the_programs_vocabulary():
     """``scope_reduce.LM_SCOPES`` is the benchmark's accepted copy (its
     ``unscoped_share`` finds ``block`` in every layer's scope either way);
-    what PR 28 added comes after it."""
-    assert transformer.SCOPES == scope_reduce.LM_SCOPES + PER_LAYER_SCOPES
+    what PR 28 and PR 32 added comes after it."""
+    assert transformer.SCOPES == scope_reduce.LM_SCOPES + PER_LAYER_SCOPES \
+        + LOOPED_SCOPES
 
 
 @pytest.mark.parametrize("scope", transformer.SCOPES)
 def test_lm_step_enters_every_scope_of_the_vocabulary(lm_stacks, mixed_stacks,
-                                                      scope):
-    stacks = mixed_stacks if scope in PER_LAYER_SCOPES else lm_stacks
+                                                      looped_stacks, scope):
+    stacks = (mixed_stacks if scope in PER_LAYER_SCOPES else
+              looped_stacks if scope in LOOPED_SCOPES else lm_stacks)
     assert any(scope in scope_reduce.tokens(s) for s in stacks)
+
+
+def test_looped_scopes_are_one_element_each_forward_and_backward(
+        looped_stacks):
+    """The post-norms and the exit gate are entered beside ``block.proj`` and
+    ``logits_loss``, never inside them (ONE scope element an op), forward and
+    backward; every application's flash kernels stay under ``block.attn``."""
+    for scope in ("block.attn_norm", "block.mlp_norm", "exit_gate"):
+        mine = [s for s in looped_stacks
+                if scope.split(".")[-1] in scope_reduce.tokens(s)]
+        assert any("transpose" in scope_reduce.tokens(s) for s in mine), scope
+        assert any("transpose" not in scope_reduce.tokens(s) for s in mine)
+        others = set(transformer.SCOPES) - {"block", scope.split(".")[-1]}
+        for s in mine:
+            assert not others & scope_reduce.tokens(s), s
+    kernels = {s for s in looped_stacks if s.endswith("/pallas_call")}
+    assert kernels and all("attn" in scope_reduce.tokens(s) for s in kernels)
 
 
 def test_per_layer_scopes_name_the_kernels_of_their_layer_type(mixed_stacks):
