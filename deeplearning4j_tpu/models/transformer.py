@@ -517,9 +517,10 @@ def _remat(blk):
     """``blk`` rematerialised: its backward recomputes it from its input,
     except the two results only the flash forward kernel can produce
     (``FLASH_RESIDUALS``: the attention output, ``B·T·H·hd`` compute-dtype
-    elements, and the one-lane logsumexp, ``B·H·T`` float32), which are kept,
-    so the kernel runs once a step and not again in the backward. Off the
-    flash route nothing carries those names and only the input is kept."""
+    elements, and the logsumexp, ``B·H·T`` float32 as the kernel wrote it),
+    which are kept, so the kernel runs once a step and not again in the
+    backward. Off the flash route nothing carries those names and only the
+    input is kept."""
     return jax.checkpoint(
         blk, policy=jax.checkpoint_policies.save_only_these_names(
             *FLASH_RESIDUALS))

@@ -7,7 +7,9 @@ grid over (batch·heads, query blocks), K/V streamed block-by-block with the
 running-max/sum recurrence — no O(T²) score materialization in HBM) and the
 matching FlashAttention-2-style backward (a dQ kernel streaming K/V blocks
 and a dK/dV kernel streaming Q/dO blocks, both recomputing P from the
-forward's saved logsumexp — nothing O(T²) is ever stored). The grid of all
+forward's saved logsumexp — nothing O(T²) is ever stored; the logsumexp
+and the backward's delta cross the kernels' boundary one float32 a query
+row, ``_stat_rows``). The grid of all
 three is ``(rows of batch·heads, steps)``: the steps are the live (Q block,
 K block) pairs of a row and no others (``flash_walk``; a block pair the
 causal mask or the window leaves empty is no grid step), read from an int32
@@ -56,13 +58,20 @@ _STEPS_WALKED = obs.gauge(
 _STEPS_LIVE = obs.gauge(
     "flash.steps_live", "Of flash.steps_walked, the steps on a block pair "
     "with an in-mask entry")
+# What lse and delta take in HBM where they cross the boundary of those
+# kernels, from shapes: 4·n·T bytes a statistic a kernel (the forward writes
+# lse; dQ and dK/dV each read lse and delta). It was 512·n·T while the
+# forward and dQ kernels took them lane-replicated.
+_STAT_BYTES = obs.gauge(
+    "flash.stat_bytes", "Bytes of lse and delta crossing the boundary of "
+    "the flash kernels traced so far")
 
-# Per-query-row scalars (running max/sum, logsumexp, delta) cross the
-# kernel boundary lane-replicated as [..., rows, _LANES]: Mosaic wants the
-# last two dims of every block divisible by (8, 128) or equal to the
-# array's, which a [1, block_q] block of an [n, T] array is not.
+# Per-query-row scalars (running max/sum, logsumexp, delta) live INSIDE the
+# forward and dQ kernels as lane-replicated columns [rows, _LANES]: a tile
+# of scores is [q, k], and a column broadcasts over its keys for nothing.
+# They cross the kernels' boundary one float32 a row (``_stat_rows``); the
+# turn between the two is made in VMEM, once a Q block.
 _LANES = 128
-_SUBLANES = 8
 
 
 def _interpret_mode():
@@ -95,21 +104,22 @@ class FlashPlan(NamedTuple):
 # (16 MiB scoped by default on a v5e; a raised limit read slower there), the
 # tiles' temporaries left aside
 _VMEM_BUDGET = 8 * 2 ** 20
-# the rows of a step are unrolled: each is a copy of the step's code; and a
-# step's rows lie in one group of _SUBLANES rows of n (_stat_rows)
+# the rows of a step are unrolled: each is a copy of the step's code
 _MAX_HEADS = 8
 
 
 def _flash_vmem_bytes(heads, block_q, block_k, d, itemsize):
     """VMEM of the hungriest of the three kernels, the dQ one: every input
-    and output block twice (the pipeline double-buffers them), the
-    lane-replicated lse and delta blocks among them, and the float32
-    accumulator."""
+    and output block twice (the pipeline double-buffers them), lse and
+    delta among them at one float32 a row, and its float32 scratch once:
+    the accumulator and the two statistics turned into lane-replicated
+    columns (the forward holds m and l the same way and one block less)."""
     q_like = block_q * d * itemsize            # q, dO, dQ
     k_like = block_k * d * itemsize            # k, v
-    stats = block_q * _LANES * 4               # lse, delta
-    return heads * (2 * (3 * q_like + 2 * k_like + 2 * stats)
-                    + block_q * d * 4)
+    stat_rows = block_q * 4                    # lse, delta as they arrive
+    stat_cols = block_q * _LANES * 4           # and as the tiles take them
+    return heads * (2 * (3 * q_like + 2 * k_like + 2 * stat_rows)
+                    + 2 * stat_cols + block_q * d * 4)
 
 
 def _tile(block):
@@ -126,9 +136,7 @@ def flash_plan(n, t, d, itemsize, block_q, block_k, kv_group=1):
     float32 tile of scores is four register files that live in VMEM
     between every two vector passes, and a causal block on the diagonal
     leaves out the tiles above it; 128-tiles leave out more and read
-    slower (more, smaller products), and Mosaic refuses a 128-wide piece
-    of a row of the dK/dV kernel's statistics at a row it only knows when
-    it runs.
+    slower (more, smaller products).
 
     Heads: a grid step has a fixed cost, and the rows of a step fill each
     other's waits, so where a row of n is one or two blocks several rows
@@ -363,6 +371,52 @@ def _lanes(x, width):
     return x if reps == 1 else jnp.tile(x, (1, reps))
 
 
+def _stat_rows(n, t, heads, block_q):
+    """How a per-row statistic (lse, delta) crosses the boundary of all
+    three kernels: ``(shape, block, index map)`` of one float32 a query row,
+    ``[n, T / block_q, 1, block_q]`` in blocks of this step's ``heads`` rows
+    of n by one Q block (the map on ``(row, Q block, K block)``, as
+    ``_walk_call`` takes it). A block's last two dims equal the array's, so
+    Mosaic takes it at any block size, and a [1, block_q] row of it is
+    block_q floats in HBM and in VMEM: nothing is replicated, and the array
+    the forward kernel writes is the one the two backward kernels read."""
+    return ((n, t // block_q, 1, block_q), (heads, 1, 1, block_q),
+            lambda b, qi, kb: (b, qi, 0, 0))
+
+
+def _stat_row(ref, h, q0, tq):
+    """The [1, tq] piece from column ``q0`` of the step's head ``h`` in a
+    block of ``_stat_rows``."""
+    from jax.experimental import pallas as pl
+
+    return ref[h, 0, :, pl.ds(q0, tq)]
+
+
+def _as_row(col):
+    """A column of statistics, [rows, 1] or lane-replicated [rows, _LANES],
+    as the [1, rows] row the boundary holds (``_stat_rows``): the diagonal
+    of the column spread over the lanes, 128 rows at a time -- a select
+    and a sum over sublanes that adds zeros, so the values are the
+    column's own bits. (A transpose of the replicated column is the same
+    row and read slower in the forward: PERF.md, PR 33.)"""
+    rows = col.shape[0]
+    group = _LANES if rows % _LANES == 0 else rows
+    if col.shape[1] not in (1, group):     # replicated wider than a group
+        col = col[:, :1]
+    diagonal = (jax.lax.broadcasted_iota(jnp.int32, (group, group), 0)
+                == jax.lax.broadcasted_iota(jnp.int32, (group, group), 1))
+    pieces = [jnp.where(diagonal, col[r0:r0 + group], 0.0).sum(
+        axis=0, keepdims=True) for r0 in range(0, rows, group)]
+    return pieces[0] if len(pieces) == 1 else jnp.concatenate(pieces, axis=1)
+
+
+def _as_column(row):
+    """A [1, rows] row of statistics as the lane-replicated column
+    [rows, _LANES] that broadcasts over the keys of a [q, k] tile: the
+    row on every sublane, transposed."""
+    return jnp.broadcast_to(row, (_LANES, row.shape[1])).T
+
+
 _NT = (((1,), (1,)), ((), ()))     # a @ bᵀ
 _NN = (((1,), (0,)), ((), ()))     # a @ b
 
@@ -390,7 +444,9 @@ def _flash_kernel(table, q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch, plan,
     online-softmax update over all its live tiles. Scores, m, l and the
     accumulator are float32; P meets V in V's dtype.
 
-    m/l are stored lane-replicated as [heads, block_q, _LANES]."""
+    m and l are kept lane-replicated as [heads, block_q, _LANES]; the
+    logsumexp they end in leaves as one float32 a row (``_stat_rows``),
+    turned where the Q block is finalised."""
     from jax.experimental import pallas as pl
 
     qi, kb, first, last = _step(table, steps, single)
@@ -429,8 +485,7 @@ def _flash_kernel(table, q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch, plan,
             if single:
                 o_ref[h, rows, :] = (
                     pv / jnp.maximum(l_cur, 1e-30)).astype(o_ref.dtype)
-                lse_ref[h, rows, :] = jnp.broadcast_to(
-                    _lse(m_new, l_cur), (tq, _LANES))
+                lse_ref[h, 0, :, rows] = _as_row(_lse(m_new, l_cur))
             elif first:
                 m_scr[h, rows, :] = jnp.broadcast_to(m_new, (tq, _LANES))
                 l_scr[h, rows, :] = jnp.broadcast_to(l_cur, (tq, _LANES))
@@ -453,14 +508,14 @@ def _flash_kernel(table, q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch, plan,
                 l_fin = l_scr[h]                       # [block_q, 128]
                 o_ref[h] = (acc_scr[h] / jnp.maximum(l_fin[:, :1], 1e-30)
                             ).astype(o_ref.dtype)
-                lse_ref[h] = _lse(m_scr[h], l_fin)
+                lse_ref[h, 0] = _as_row(_lse(m_scr[h], l_fin))
 
 
 def _lse(m, l):
-    """The logsumexp residual for the backward's P recomputation, written
-    lane-replicated like m/l (see _LANES). A fully masked row (l == 0; only
-    padded rows can hit this) gets +LARGE so exp(s - lse) underflows to an
-    exact 0 instead of NaN."""
+    """The logsumexp residual for the backward's P recomputation, a column
+    like m and l. A fully masked row (l == 0; only padded rows can hit
+    this) gets +LARGE so exp(s - lse) underflows to an exact 0 instead of
+    NaN."""
     return jnp.where(l > 0.0, m + jnp.log(jnp.maximum(l, 1e-30)), -NEG_INF)
 
 
@@ -516,7 +571,8 @@ def _statics(q, causal, block_q, block_k, window, kv_group, inners):
     for each value of it. ``inners`` names the walks of the kernels this
     call site adds to the program ("k": forward or dQ, "q": dK/dV), for the
     gauges: they count here, once a call site, and not inside the shared
-    trace."""
+    trace. The forward ("k" alone) has one statistic at its boundary, lse;
+    each kernel of the backward ("kq") has two, lse and delta."""
     n, t, d = q.shape
     plan = flash_plan(n, t, d, q.dtype.itemsize, block_q, block_k, kv_group)
     for inner in inners:
@@ -524,6 +580,8 @@ def _statics(q, causal, block_q, block_k, window, kv_group, inners):
         for gauge, steps in ((_STEPS_WALKED, walk.steps),
                              (_STEPS_LIVE, walk.live)):
             gauge.set(gauge.value + n // plan.heads * steps)
+    statistics = 1 if inners == "k" else 2
+    _STAT_BYTES.set(_STAT_BYTES.value + len(inners) * statistics * 4 * n * t)
     return dict(causal=causal, block_q=block_q, block_k=block_k,
                 window=window, kv_group=kv_group, interpret=_interpret_mode(),
                 plan=plan)
@@ -557,6 +615,7 @@ def _flash_forward(q, k, v, *, causal, block_q, block_k, window, kv_group,
         n_qb=t // block_q, causal=causal, scale=1.0 / (d ** 0.5),
         window=window)
     kv_index = _kv_block(kv_group)
+    stat_shape, stat_block, stat_index = _stat_rows(n, t, hb, block_q)
     scratch = [] if walk.single else [
         pltpu.VMEM((hb, block_q, _LANES), jnp.float32),   # running max
         pltpu.VMEM((hb, block_q, _LANES), jnp.float32),   # running sum
@@ -565,12 +624,12 @@ def _flash_forward(q, k, v, *, causal, block_q, block_k, window, kv_group,
     return _walk_call(
         kernel, walk, n // hb,
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
-                   jax.ShapeDtypeStruct((n, t, _LANES), jnp.float32)],  # lse
+                   jax.ShapeDtypeStruct(stat_shape, jnp.float32)],   # lse
         in_specs=[((hb, block_q, d), _q_block),
                   ((hb, block_k, d), kv_index),
                   ((hb, block_k, d), kv_index)],
         out_specs=[((hb, block_q, d), _q_block),
-                   ((hb, block_q, _LANES), _q_block)],
+                   (stat_block, stat_index)],
         scratch_shapes=scratch,
         interpret=interpret,
     )(q, k, v)
@@ -583,21 +642,39 @@ def _flash_dq_kernel(table, q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
     of the walk) and accumulate dQ = Σ_kb dS @ K, with P recomputed from
     the saved logsumexp (FlashAttention-2 eq. 12-16), tile by tile. The
     softmax scale rides in q for the scores, as in the forward, and meets
-    dQ once, as it is written."""
+    dQ once, as it is written.
+
+    lse and delta arrive as rows, one float32 a query (``_stat_rows``), and
+    the [q, k] tiles want them as columns: the block's index does not
+    change along a Q block's steps, so its first step turns both
+    (``_as_column``) into VMEM scratch and the others read that; where
+    every Q block is one step (``single``) a strip turns its own piece."""
     from jax.experimental import pallas as pl
 
     qi, kb, first, last = _step(table, steps, single)
     tq, tk = plan.tile_q, plan.tile_k
     if not single:
-        dq_scr, = scratch
+        dq_scr, lse_scr, delta_scr = scratch
 
     def _compute(h, kind, first):
+        if first and not single:
+            # the statistics' block stays through the Q block's steps: the
+            # turn is made in its first and kept for the others
+            turned = [_as_column(ref[h, 0]) for ref in (lse_ref, delta_ref)]
+            lse_scr[h], delta_scr[h] = turned
         for q0, row in _strips(_tiles(kind, block_q, block_k, plan), 0):
             rows = pl.ds(q0, tq)
             q = q_ref[h, rows, :] * scale              # [tq, d]
             g = g_ref[h, rows, :]                      # [tq, d] dO
-            lse = _lanes(lse_ref[h, rows, :], tk)      # [tq, tk]
-            delta = _lanes(delta_ref[h, rows, :], tk)  # rowsum(dO*O)
+            if single:
+                lse, delta = (_as_column(_stat_row(ref, h, q0, tq))
+                              for ref in (lse_ref, delta_ref))
+            elif first:
+                lse, delta = (x[q0:q0 + tq] for x in turned)
+            else:
+                lse, delta = lse_scr[h, rows, :], delta_scr[h, rows, :]
+            lse = _lanes(lse, tk)                      # [tq, tk]
+            delta = _lanes(delta, tk)                  # rowsum(dO*O)
             dq = None
             for k0, masked in row:
                 k_t = k_ref[h, pl.ds(k0, tk), :]       # [tk, d]
@@ -629,52 +706,18 @@ def _flash_dq_kernel(table, q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
             dq_ref[...] = (dq_scr[...] * scale).astype(dq_ref.dtype)
 
 
-def _stat_rows(x, heads, block_q):
-    """A per-row statistic ``x`` [n, T] as the dK/dV kernel takes it, with
-    its block and the block's index map (on ``(row, Q block, K block)``, as
-    ``_walk_call`` takes them).
-
-    Where the block is lane-aligned, the [n, T] array itself in groups of
-    the 8 rows of n that share a tile of its layout, a block holding the
-    group of this step's ``heads`` (a power of two up to 8): nothing is
-    copied. Else (small blocks) one row a block, [n, T / block_q, 1,
-    block_q]: a block shape Mosaic takes at any size, and a relayout XLA
-    has to make."""
-    n, t = x.shape
-    if block_q % _LANES == 0 or block_q == t:
-        x = jnp.pad(x, ((0, -n % _SUBLANES), (0, 0)))
-        return (x.reshape(-1, _SUBLANES, t), (1, _SUBLANES, block_q),
-                lambda b, qi, kb: (b * heads // _SUBLANES, 0, qi))
-    return (x.reshape(n, t // block_q, 1, block_q), (heads, 1, 1, block_q),
-            lambda b, qi, kb: (b, qi, 0, 0))
-
-
-def _stat_row(ref, h, row0, q0, tq):
-    """The [1, tq] piece from column ``q0`` of the step's head ``h`` in a
-    block of ``_stat_rows``; the step's heads start at row ``row0`` of
-    their group of 8."""
-    from jax.experimental import pallas as pl
-
-    if len(ref.shape) == 4:
-        return ref[h, 0, :, pl.ds(q0, tq)]
-    return ref[0, pl.ds(row0 + h, 1), pl.ds(q0, tq)]
-
-
 def _flash_dkv_kernel(table, q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
                       dk_ref, dv_ref, *scratch, plan, block_q, block_k, n_qb,
                       steps, single, causal, scale, window=None):
     """dK/dV pass: for a fixed K/V block, stream its live Q/dO blocks (the
     steps of the walk); dV = Σ_qb Pᵀ dO, dK = Σ_qb dSᵀ Q. The tiles are
     computed transposed, keys along dim 0 (Sᵀ = K Qᵀ, dPᵀ = V dOᵀ), so that
-    both sums are plain products with nothing to turn; lse and delta come
-    as rows to broadcast over the keys (``_stat_rows``)."""
+    both sums are plain products with nothing to turn, and lse and delta
+    broadcast over the keys as the rows they arrive as (``_stat_rows``)."""
     from jax.experimental import pallas as pl
 
     qi, kb, first, last = _step(table, steps, single)
     tq, tk = plan.tile_q, plan.tile_k
-    # where this step's heads start in their group of 8 rows (_stat_rows)
-    row0 = (0 if plan.heads == _SUBLANES
-            else pl.program_id(0) * plan.heads % _SUBLANES)
     if not single:
         dk_scr, dv_scr = scratch
 
@@ -687,8 +730,8 @@ def _flash_dkv_kernel(table, q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
             for q0, masked in col:
                 q = q_ref[h, pl.ds(q0, tq), :] * scale           # [tq, d]
                 g = g_ref[h, pl.ds(q0, tq), :]
-                lse = _stat_row(lse_ref, h, row0, q0, tq)        # [1, tq]
-                delta = _stat_row(delta_ref, h, row0, q0, tq)
+                lse = _stat_row(lse_ref, h, q0, tq)    # [1, tq]
+                delta = _stat_row(delta_ref, h, q0, tq)
                 s = _dot(k_t, q, _NT)                  # [tk, tq]
                 if masked:
                     s = jnp.where(
@@ -744,9 +787,8 @@ def _flash_fwd(q, k, v, causal, block_q, block_k, window=None, kv_group=1):
     out, lse = _flash_forward(
         q, k, v,
         **_statics(q, causal, block_q, block_k, window, kv_group, "k"))
-    # keep one lane: the saved residual is [n, T], not 128x that (and the
-    # slice is what is named: naming the kernel's output would keep the 128x)
-    out, lse = map(checkpoint_name, (out, lse[..., 0]), FLASH_RESIDUALS)
+    # what the kernel wrote is what is named: one float32 a row
+    out, lse = map(checkpoint_name, (out, lse), FLASH_RESIDUALS)
     return out, (q, k, v, out, lse)
 
 
@@ -790,16 +832,13 @@ def _flash_backward(q, k, v, out, lse, g, *, causal, block_q, block_k, window,
     static = dict(plan=plan, block_q=block_q, block_k=block_k,
                   n_qb=t // block_q, causal=causal, scale=1.0 / (d ** 0.5),
                   window=window)
+    stat_shape, stat_block, stat_index = _stat_rows(n, t, hb, block_q)
     # delta_i = Σ_d dO ⊙ O — a cheap fused elementwise+reduce; XLA keeps it
-    # out of the kernels' VMEM budget
+    # out of the kernels' VMEM budget and writes it as lse came. The dK/dV
+    # kernel, whose tiles are [k, q], broadcasts the rows as they are; the
+    # dQ kernel turns them into columns in VMEM
     delta = (g.astype(jnp.float32) * out.astype(jnp.float32)).sum(-1)
-    # the dQ kernel broadcasts them over the keys of a [q, k] tile and takes
-    # them lane-replicated (see _LANES); the dK/dV kernel, whose tiles are
-    # [k, q], takes them as rows
-    lse_cols, delta_cols = (jnp.broadcast_to(x[..., None], (n, t, _LANES))
-                            for x in (lse, delta))
-    (lse_rows, row_block, row_index), (delta_rows, _, _) = (
-        _stat_rows(x, hb, block_q) for x in (lse, delta))
+    delta = delta.reshape(stat_shape)
     kv_index = _kv_block(kv_group)
 
     walk = flash_walk(causal, window, block_q, block_k, t, "k")
@@ -810,13 +849,15 @@ def _flash_backward(q, k, v, out, lse, g, *, causal, block_q, block_k, window,
                   ((hb, block_k, d), kv_index),
                   ((hb, block_k, d), kv_index),
                   ((hb, block_q, d), _q_block),
-                  ((hb, block_q, _LANES), _q_block),
-                  ((hb, block_q, _LANES), _q_block)],
+                  (stat_block, stat_index),
+                  (stat_block, stat_index)],
         out_specs=[((hb, block_q, d), _q_block)],
         scratch_shapes=[] if walk.single else [
-            pltpu.VMEM((hb, block_q, d), jnp.float32)],
+            pltpu.VMEM((hb, block_q, d), jnp.float32),        # dQ
+            pltpu.VMEM((hb, block_q, _LANES), jnp.float32),   # lse, turned
+            pltpu.VMEM((hb, block_q, _LANES), jnp.float32)],  # delta, turned
         interpret=interpret,
-    )(q, k, v, g, lse_cols, delta_cols)
+    )(q, k, v, g, lse, delta)
 
     # dK/dV walk K block by K block. With GQA the kernel accumulates PER
     # Q-HEAD (output shaped like q); the group-sum down to the kv heads
@@ -831,15 +872,15 @@ def _flash_backward(q, k, v, out, lse, g, *, causal, block_q, block_k, window,
                   ((hb, block_k, d), kv_index),
                   ((hb, block_k, d), kv_index),
                   ((hb, block_q, d), _q_block),
-                  (row_block, row_index),
-                  (row_block, row_index)],
+                  (stat_block, stat_index),
+                  (stat_block, stat_index)],
         out_specs=[((hb, block_k, d), _k_block),
                    ((hb, block_k, d), _k_block)],
         scratch_shapes=[] if walk.single else [
             pltpu.VMEM((hb, block_k, d), jnp.float32),
             pltpu.VMEM((hb, block_k, d), jnp.float32)],
         interpret=interpret,
-    )(q, k, v, g, lse_rows, delta_rows)
+    )(q, k, v, g, lse, delta)
     if kv_group > 1:
         dk = dk.astype(jnp.float32).reshape(
             n // kv_group, kv_group, t, d).sum(1).astype(k.dtype)
@@ -877,12 +918,20 @@ def flash_attention(q, k, v, *, causal=False, block_q=512, block_k=512,
     tiles, which blocks build a mask) follows from the shapes:
     ``flash_plan``. Products take their
     operands in the inputs' dtype (bfloat16 in, bfloat16 operands) and
-    accumulate in float32; the softmax statistics are float32. Measured on
+    accumulate in float32; the softmax statistics are float32. What is
+    kept for the backward besides q, k, v and the output is the logsumexp,
+    one float32 a query row as the forward kernel wrote it
+    (``[n, T / block_q, 1, block_q]``, ``FLASH_RESIDUALS``); it and the
+    backward's delta reach both backward kernels in that form
+    (``_stat_rows``): no lane-replicated ``[n, T, 128]`` copy of either
+    exists outside the kernels, at any block size. Measured on
     a v5e (PERF.md, PR 27; bfloat16, d 64, causal, forward + dQ + dK/dV
     device ms a call): [128, 1024, 64] at block 512 1.44 (2.38 before PR
     27), [512, 256, 64] at block 256 0.64 (1.56), [64, 1024, 64] at block
     128 3.59, five times block 512's time a row: small blocks pay per grid
-    step. No route through the lax.scan path has been timed on a chip.
+    step; with the statistics a value a row (PR 33) a call and the XLA
+    passes around it read 1.75 ms where 2.07 and 0.92 where 1.41 (host
+    clock). No route through the lax.scan path has been timed on a chip.
     """
     if window is not None:
         if not causal:

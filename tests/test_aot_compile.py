@@ -62,6 +62,29 @@ def _compile(fn, *shapes):
     return compiled.as_text().count("tpu_custom_call")
 
 
+def _assert_statistics_cross_compact(text, rows_of_n, t, block):
+    """In a compiled step: no operand or result of a flash custom call is a
+    float32 array of trailing dimension 128 and ``n·T·128`` elements, the
+    lane-replicated lse or delta (``rows_of_n``: every n = batch·heads among
+    the step's layers); what crosses is ``f32[n, T / block, 1, block]``, the
+    forward's result and two operands of each backward kernel."""
+    import math
+    import re
+    flash = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line
+             and "pallas_call" in line]
+    assert flash
+    compact = 0
+    for line in flash:
+        for dims in re.findall(r"f32\[([\d,]+)\]", line):
+            shape = tuple(int(x) for x in dims.split(","))
+            assert not (shape[-1] == 128 and math.prod(shape) in
+                        {n * t * 128 for n in rows_of_n}), line[:400]
+            compact += shape[1:] == (t // block, 1, block) \
+                and shape[0] in rows_of_n
+    assert compact == len(flash) * 5 // 3
+
+
 # (q shape, kv shape, block): the chip_smoke train_lm shape (GPT-2 small,
 # batch 8 x 1024, d64), one GQA 16/4 shape at head dim 128, T 2048, and the
 # two cells of the benchmark (GPT-2 medium: two blocks a row with the
@@ -76,6 +99,9 @@ FLASH_SHAPES = {
     # layers see one block (a fourth entry is the window)
     "laguna_full": ((2, 48, 8192, 128), (2, 8, 8192, 128), 512),
     "laguna_window": ((2, 64, 8192, 128), (2, 8, 8192, 128), 512, 512),
+    # the fourth cell (Ouro-2.6B, 2 x 4096 tokens): 16 ungrouped heads of
+    # 128, rows of 8 blocks
+    "ouro": ((2, 16, 4096, 128), (2, 16, 4096, 128), 512),
 }
 
 
@@ -221,6 +247,7 @@ def test_lm_step_names_reach_the_chips_program(v5e, monkeypatch):
     bwd = [k for k in kernels if flash_bwd_roofline.is_flash_bwd(k)]
     assert (len(kernels), len(fwd), len(bwd)) == (3 * layers, layers,
                                                   2 * layers)
+    _assert_statistics_cross_compact(text, {2 * 2}, 128, 128)
     stacks = {re.search(r'op_name="([^"]*)"', k).group(1) for k in kernels}
     assert stacks == {"jit(step)/jvp(block.attn)/pallas_call",
                       "jit(step)/transpose(jvp(block.attn))/pallas_call"}
@@ -280,12 +307,20 @@ def test_mixed_lm_step_compiles_for_v5e(v5e, monkeypatch):
         assert re.search(rf'op_name="jit\(step\)/[^"]*block\.{scope}[)/]',
                          text), scope
 
+    heads = [c.layer_spec(i).n_heads for i in range(c.n_layers)]
+    _assert_statistics_cross_compact(text, {rows * h for h in heads},
+                                     sizes["seq"], c.block_size)
+
     # a layer's named residuals: the output in bfloat16, one float32 a row
-    heads = sum(c.layer_spec(i).n_heads for i in range(c.n_layers))
-    kept = rows * sizes["seq"] * heads * (c.hd * 2 + 4)
+    kept = rows * sizes["seq"] * sum(heads) * (c.hd * 2 + 4)
     monkeypatch.setattr(transformer, "_remat", jax.checkpoint)
     bare = compiled().memory_analysis().temp_size_in_bytes
-    assert step.memory_analysis().temp_size_in_bytes <= bare + kept
+    temp = step.memory_analysis().temp_size_in_bytes
+    assert temp <= bare + kept
+    # and nothing holds a logsumexp 128 times: while the forward kernel
+    # wrote it lane-replicated this step compiled to 324.4 MB of temporaries
+    # (PR 32's tree; 238.4 now), a window layer's copy 67.1 MB of them
+    assert temp <= 324_435_456 - rows * sizes["seq"] * max(heads) * 128 * 4
 
 
 def test_looped_lm_step_compiles_for_v5e(v5e, monkeypatch):
@@ -329,6 +364,8 @@ def test_looped_lm_step_compiles_for_v5e(v5e, monkeypatch):
     kernels = re.findall(r'custom_call_target="tpu_custom_call"[^\n]*'
                          r'op_name="([^"]*)"', text)
     assert len(kernels) == 3 * c.applications == 24
+    _assert_statistics_cross_compact(text, {rows * c.n_heads},
+                                     sizes["max_len"], c.block_size)
     assert all(n.endswith("block.attn/pallas_call")
                or n.endswith("block.attn)/pallas_call") for n in kernels)
     for scope in ("block.attn_norm", "block.mlp_norm", "exit_gate",

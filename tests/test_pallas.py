@@ -283,42 +283,160 @@ class TestFlashGridStep:
 
     @pytest.mark.parametrize("case", sorted(CASES))
     def test_bf16_forward_and_grads_match_dense(self, interpret_pallas, case):
+        _assert_bf16_flash_matches_dense(self.CASES[case], self.BF16_TOL)
+
+
+def _assert_bf16_flash_matches_dense(case, tol):
+    """Forward, dQ, dK and dV of the kernels (interpreter mode) on bfloat16
+    inputs against float32 ``dense_attention`` on the same numbers, each
+    within ``tol`` of the reference's largest entry."""
+    import jax
+    from deeplearning4j_tpu.ops.pallas_kernels import flash_attention
+    c = dict(case)
+    q_shape = c.pop("q")
+    kv_shape = c.pop("kv", q_shape)
+    bq, bk = c.pop("bq"), c.pop("bk")
+    causal = c.pop("causal", True)
+    window = c.pop("window", None)
+    rng = np.random.RandomState(7)
+    q = jnp.asarray(rng.randn(*q_shape), jnp.bfloat16)
+    k = jnp.asarray(rng.randn(*kv_shape), jnp.bfloat16)
+    v = jnp.asarray(rng.randn(*kv_shape), jnp.bfloat16)
+    cot = jnp.asarray(rng.randn(*q_shape), jnp.float32)
+    group = q_shape[-3] // kv_shape[-3]
+
+    def flash(a, b, c):
+        return flash_attention(a, b, c, causal=causal, block_q=bq,
+                               block_k=bk, window=window)
+
+    def dense(a, b, c):
+        a, b, c = (x.astype(jnp.float32) for x in (a, b, c))
+        if group > 1:
+            b, c = (jnp.repeat(x, group, axis=-3) for x in (b, c))
+        return dense_attention(a, b, c, causal=causal, window=window)
+
+    def grads(fn):
+        return jax.grad(lambda a, b, c: (fn(a, b, c).astype(jnp.float32)
+                                         * cot).sum(), (0, 1, 2))(q, k, v)
+    got = (flash(q, k, v),) + grads(flash)
+    want = (dense(q, k, v),) + grads(dense)
+    for g, w, name in zip(got, want, ("out", "dq", "dk", "dv")):
+        assert g.dtype == jnp.bfloat16
+        w = np.asarray(w, np.float32)
+        np.testing.assert_allclose(
+            np.asarray(g, np.float32), w, rtol=0,
+            atol=tol * np.abs(w).max(), err_msg=name)
+
+
+class TestFlashBoundary:
+    """lse and delta cross the boundary of the three kernels one float32 a
+    query row (``_stat_rows``): the forward writes, and the two backward
+    kernels read, ``[n, T / block, 1, block]``; no lane-replicated
+    ``[n, T, 128]`` copy of either is written, sliced or broadcast around
+    them, in any of the shapes the callers send."""
+
+    # q [batch, heads, T, d] (d never 128, so that a trailing 128 is a
+    # replicated statistic and nothing else); n = batch x heads
+    CASES = {
+        "plain": dict(q=(1, 2, 512, 32), bq=128, bk=128),
+        "remat": dict(q=(1, 2, 512, 32), bq=128, bk=128, remat=True),
+        "window": dict(q=(1, 2, 512, 32), bq=128, bk=128, window=200),
+        "gqa": dict(q=(1, 4, 384, 32), kv=(1, 2, 384, 32), bq=128, bk=128),
+        "gqa_remat": dict(q=(1, 4, 384, 32), kv=(1, 2, 384, 32), bq=128,
+                          bk=128, remat=True),
+        "padded": dict(q=(1, 2, 450, 32), bq=128, bk=128),
+        "heads_share_a_step": dict(q=(1, 8, 256, 32), bq=256, bk=256),
+        "two_blocks_heads_share": dict(q=(1, 4, 512, 32), bq=256, bk=256),
+        "block_under_128": dict(q=(1, 3, 64, 8), bq=16, bk=16),
+        "block_under_128_remat": dict(q=(1, 3, 64, 8), bq=16, bk=16,
+                                      remat=True),
+    }
+
+    @staticmethod
+    def _sizes(case):
+        b, h, t, _ = case["q"]
+        return b * h, -(-t // case["bq"]) * case["bq"]   # n, padded T
+
+    def _jaxpr(self, case):
         import jax
+        from deeplearning4j_tpu.models.transformer import _remat
         from deeplearning4j_tpu.ops.pallas_kernels import flash_attention
-        c = dict(self.CASES[case])
-        q_shape = c.pop("q")
-        kv_shape = c.pop("kv", q_shape)
-        bq, bk = c.pop("bq"), c.pop("bk")
-        causal = c.pop("causal", True)
-        window = c.pop("window", None)
-        rng = np.random.RandomState(7)
-        q = jnp.asarray(rng.randn(*q_shape), jnp.bfloat16)
-        k = jnp.asarray(rng.randn(*kv_shape), jnp.bfloat16)
-        v = jnp.asarray(rng.randn(*kv_shape), jnp.bfloat16)
-        cot = jnp.asarray(rng.randn(*q_shape), jnp.float32)
-        group = q_shape[-3] // kv_shape[-3]
+        q = jax.ShapeDtypeStruct(case["q"], jnp.bfloat16)
+        kv = jax.ShapeDtypeStruct(case.get("kv", case["q"]), jnp.bfloat16)
 
-        def flash(a, b, c):
-            return flash_attention(a, b, c, causal=causal, block_q=bq,
-                                   block_k=bk, window=window)
+        def attend(a, b, c):
+            return flash_attention(a, b, c, causal=True, block_q=case["bq"],
+                                   block_k=case["bk"],
+                                   window=case.get("window"))
+        if case.get("remat"):
+            attend = _remat(attend)
 
-        def dense(a, b, c):
-            a, b, c = (x.astype(jnp.float32) for x in (a, b, c))
-            if group > 1:
-                b, c = (jnp.repeat(x, group, axis=-3) for x in (b, c))
-            return dense_attention(a, b, c, causal=causal, window=window)
+        def loss(a, b, c):
+            return attend(a, b, c).astype(jnp.float32).sum()
+        return jax.make_jaxpr(jax.grad(loss, (0, 1, 2)))(q, kv, kv).jaxpr
 
-        def grads(fn):
-            return jax.grad(lambda a, b, c: (fn(a, b, c).astype(jnp.float32)
-                                             * cot).sum(), (0, 1, 2))(q, k, v)
-        got = (flash(q, k, v),) + grads(flash)
-        want = (dense(q, k, v),) + grads(dense)
-        for g, w, name in zip(got, want, ("out", "dq", "dk", "dv")):
-            assert g.dtype == jnp.bfloat16
-            w = np.asarray(w, np.float32)
-            np.testing.assert_allclose(
-                np.asarray(g, np.float32), w, rtol=0,
-                atol=self.BF16_TOL * np.abs(w).max(), err_msg=name)
+    @pytest.mark.parametrize("case", sorted(CASES))
+    def test_no_replicated_statistic_around_the_kernels(self,
+                                                        interpret_pallas,
+                                                        case):
+        case = self.CASES[case]
+        n, t = self._sizes(case)
+        stat = (n, t // case["bq"], 1, case["bq"])
+        calls, named = [], []
+
+        def walk(jaxpr):
+            for eqn in jaxpr.eqns:
+                for var in list(eqn.invars) + list(eqn.outvars):
+                    aval = getattr(var, "aval", None)
+                    shape = getattr(aval, "shape", ())
+                    # a float32 [n, T, 128] inside or outside a kernel's
+                    # operands: the replicated lse or delta
+                    assert not (aval is not None and aval.dtype == jnp.float32
+                                and shape == (n, t, 128)), eqn
+                if eqn.primitive.name == "broadcast_in_dim":
+                    assert eqn.params["shape"][-1:] != (128,), eqn
+                if eqn.primitive.name == "name":
+                    named.append((eqn.params["name"], eqn.invars[0].aval))
+                if eqn.primitive.name == "pallas_call":
+                    calls.append(eqn)
+                    continue
+                for sub in _sub_jaxprs(eqn):
+                    walk(sub)
+        walk(self._jaxpr(case))
+        assert len(calls) == 3
+        # the forward's second result and the last two operands of each
+        # backward kernel: one float32 a row, the same array's shape
+        fwd = [c for c in calls if len(c.outvars) == 2
+               and c.outvars[1].aval.shape == stat]
+        assert len(fwd) == 1 and fwd[0].outvars[1].aval.dtype == jnp.float32
+        for call in calls:
+            if call is not fwd[0]:
+                assert [(v.aval.shape, v.aval.dtype)
+                        for v in call.invars[-2:]] == [(stat, jnp.float32)] * 2
+        lse = [aval for name, aval in named if name == "flash_lse"]
+        assert lse and all(a.size == n * t and a.dtype == jnp.float32
+                           for a in lse)
+
+    @pytest.mark.parametrize("case", sorted(
+        c for c in CASES if "remat" not in c))
+    def test_forward_and_grads_match_dense(self, interpret_pallas, case):
+        _assert_bf16_flash_matches_dense(self.CASES[case],
+                                         TestFlashGridStep.BF16_TOL)
+
+    @pytest.mark.parametrize("case", ["plain", "gqa", "padded",
+                                      "block_under_128"])
+    def test_gauge_counts_four_bytes_a_row_a_statistic_a_kernel(
+            self, interpret_pallas, case):
+        """``flash.stat_bytes``: the forward has lse at its boundary, dQ and
+        dK/dV lse and delta each: five statistics a layer, 4 n T bytes each
+        (512 n T while the forward and dQ took them lane-replicated)."""
+        from deeplearning4j_tpu import obs
+        case = self.CASES[case]
+        n, t = self._sizes(case)
+        obs.reset_metrics()
+        self._jaxpr(case)
+        assert obs.metrics.value("flash.stat_bytes") == 5 * 4 * n * t
+        obs.reset_metrics()
 
 
 class TestFlashPlan:
@@ -332,8 +450,18 @@ class TestFlashPlan:
         "gpt2m_t256": ((512, 256, 64, 2, 256, 256, 1), (8, 256, 256)),
         # chip_smoke's GPT-2 small: 96 rows
         "gpt2s_t1024": ((96, 1024, 64, 2, 512, 512, 1), (4, 256, 256)),
-        # float32 blocks are twice the bytes: half the heads
-        "float32": ((64, 1024, 64, 4, 512, 512, 1), (2, 256, 256)),
+        # float32 blocks are twice the bytes. With lse and delta one
+        # float32 a row at the dQ kernel's boundary (two double-buffered
+        # [block, 128] blocks a statistic before PR 33, a [block, 128]
+        # scratch a statistic now) a step holds 4 float32 heads at block
+        # 512 (2 before), 8 at block 256 (4), 2 at block 1024 (1), and 8
+        # bfloat16 heads at block 384 (4)
+        "float32": ((64, 1024, 64, 4, 512, 512, 1), (4, 256, 256)),
+        "float32_block_256": ((128, 512, 64, 4, 256, 256, 1), (8, 256, 256)),
+        "bfloat16_block_384": ((128, 768, 64, 2, 384, 384, 1),
+                               (8, 384, 384)),
+        "float32_block_1024": ((64, 1024, 64, 4, 1024, 1024, 1),
+                               (2, 256, 256)),
         "d128": ((128, 1024, 128, 2, 512, 512, 1), (2, 256, 256)),
         # grouped-query attention and long rows keep one row a step
         "gqa": ((32, 2048, 128, 2, 512, 512, 4), (1, 256, 256)),
