@@ -323,11 +323,11 @@ def _plain_mixed_loss(c, params, tokens):
     def looped(bp, h):
         flat = h.reshape(-1, h.shape[-1])
         w, chosen = expert_layer.route(ex, flat, bp["router"])
-        y = expert_layer.swiglu(flat, bp["sh_gate"], bp["sh_up"],
+        y = expert_layer.glu(flat, bp["sh_gate"], bp["sh_up"],
                                 bp["sh_down"])
         for e in range(count):
             w_e = jnp.where(chosen == first + e, w, 0.0).sum(-1)
-            y = y + w_e[:, None].astype(h.dtype) * expert_layer.swiglu(
+            y = y + w_e[:, None].astype(h.dtype) * expert_layer.glu(
                 flat, bp["W_gate"][e], bp["W_up"][e], bp["W_down"][e])
         return y.reshape(h.shape)
 

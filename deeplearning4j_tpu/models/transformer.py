@@ -39,8 +39,8 @@ import numpy as np
 from deeplearning4j_tpu import obs
 from deeplearning4j_tpu.config import env_int, env_str
 
-from deeplearning4j_tpu.models.expert_layer import (STATS, Experts,
-                                                    expert_ffn, swiglu)
+from deeplearning4j_tpu.models.expert_layer import (STATS, Experts, decide,
+                                                    expert_ffn, glu)
 from deeplearning4j_tpu.ops.pallas_kernels import (FLASH_RESIDUALS,
                                                    flash_attention,
                                                    pallas_supported)
@@ -216,7 +216,10 @@ class Rope:
 @dataclass(frozen=True)
 class LayerSpec:
     """What one layer of a per-layer list states (``TransformerConfig
-    .layers``); None takes the model's own setting."""
+    .layers``); None takes the model's own setting. A layer states "no
+    position at all" by a rope that rotates none of a head's dims,
+    ``rope=Rope(share=0.0)``: the block then builds no cos / sin and turns
+    nothing (``rope=None`` is the model's rope, never "none")."""
     window: Optional[int] = None      # None = full causal attention
     n_heads: Optional[int] = None     # query heads (the K/V heads are the model's)
     rope: Optional[Rope] = None
@@ -455,6 +458,12 @@ def _block_apply(c, bp, x, spec, drop=None, rng=None, attend=None, ffn=None,
     scope = jax.named_scope
     with scope("block.ln1"):
         hloc = _norm(c, bp, "ln1", x)
+    routing = None
+    if spec.ffn == "experts" and ffn is None \
+            and c.experts.router_input == "block":
+        # the router reads what attention reads: the decision is made here,
+        # crosses the attention sublayer and is applied after ``ln2``
+        routing = decide(c.experts, bp["router"], hloc.reshape(B * T, d))
     with scope("block.qkv"):
         qkv = _linear(c, bp, "qkv", hloc)
     with scope(spec.attn_scope if c.layers is not None else "block.attn"):
@@ -463,7 +472,7 @@ def _block_apply(c, bp, x, spec, drop=None, rng=None, attend=None, ffn=None,
         split = lambda a, n: a.reshape(B, T, n, hd).transpose(0, 2, 1, 3)
         q = split(q, H)
         k, v = split(k, c.kv_heads), split(v, c.kv_heads)
-        if c.pos_embed == "rope":
+        if c.pos_embed == "rope" and spec.rope.rotated(hd):
             pos = jnp.arange(T) if positions is None else positions
             cos, sin = _rope_cos_sin(spec.rope, hd, pos)
             if cos.ndim == 3:      # [B, T, rot/2]: the same for every head
@@ -494,7 +503,7 @@ def _block_apply(c, bp, x, spec, drop=None, rng=None, attend=None, ffn=None,
     with scope("block.ln2"):
         hloc = _norm(c, bp, "ln2", x)
     if spec.ffn == "experts" and ffn is None:
-        m, stats = expert_ffn(c.experts, bp, hloc)
+        m, stats = expert_ffn(c.experts, bp, hloc, routing)
         if c.post_norm:
             with scope("block.mlp_norm"):
                 m = _norm(c, bp, "mlp_norm", m)
@@ -503,7 +512,7 @@ def _block_apply(c, bp, x, spec, drop=None, rng=None, attend=None, ffn=None,
         if ffn is not None:
             m = ffn(bp, hloc)
         elif c.ffn == "swiglu":
-            m = swiglu(hloc, bp["fc_gate"], bp["fc"], bp["out"])
+            m = glu(hloc, bp["fc_gate"], bp["fc"], bp["out"])
         else:
             m = jax.nn.gelu(hloc @ bp["fc"] + bp["fc_b"]) @ bp["out"] \
                 + bp["out_b"]
@@ -749,6 +758,10 @@ _MOE_DOCS = {
                          "padding included, since init",
     "moe.rows_over_buffer": "Expert assignments left out because the static "
                             "row buffer was full, since init (0 when sound)",
+    "moe.peak_group_rows": "Assignments of the fullest held expert of a layer "
+                           "and step, summed over layers and steps since init",
+    "moe.even_group_rows": "moe.local_rows over the held experts' count: what "
+                           "moe.peak_group_rows reads at an even load",
 }
 
 
@@ -957,17 +970,24 @@ class TransformerLM:
         """The expert layers' counts since ``init``, summed over layers and
         steps: ``moe.local_rows`` (assignments that met a held expert),
         ``moe.rows_computed`` (rows the grouped products ran over, padding
-        and all) and ``moe.rows_over_buffer`` (assignments left out because
-        the row buffer was full: 0 in a sound run). They are carried on the
-        device beside the optimizer's state and fetched HERE, one sync, so
-        never call it inside a timed loop; the ``obs.metrics`` gauges of the
-        same names take what was read. ``{}`` for a model without experts."""
+        and all), ``moe.rows_over_buffer`` (assignments left out because
+        the row buffer was full: 0 in a sound run) and ``moe.peak_group_rows``
+        (a layer's and step's fullest held expert's assignments). They are
+        carried on the device beside the optimizer's state and fetched HERE,
+        one sync, so never call it inside a timed loop; the ``obs.metrics``
+        gauges of the same names take what was read. Beside them ``moe.even_group_rows``,
+        ``moe.local_rows`` over the held experts' count (linear in a counter,
+        so a caller's difference of two reads is right for it too): what
+        ``moe.peak_group_rows`` reads when the held experts are evenly
+        loaded. ``{}`` for a model without experts."""
         moe = (self.opt_state or {}).get("moe")
         if moe is None:
             return {}
         host = jax.device_get(moe)
         out = {f"moe.{k}": int(v[0]) * _COUNT_LOW + int(v[1])
                for k, v in host.items()}
+        out["moe.even_group_rows"] = out["moe.local_rows"] \
+            / self.conf.experts.held_range[1]
         for name, n in out.items():
             obs.metrics.gauge(name, _MOE_DOCS[name]).set(n)
         return out

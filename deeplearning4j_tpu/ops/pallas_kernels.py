@@ -58,6 +58,16 @@ _STEPS_WALKED = obs.gauge(
 _STEPS_LIVE = obs.gauge(
     "flash.steps_live", "Of flash.steps_walked, the steps on a block pair "
     "with an in-mask entry")
+# Of the live steps of the calls WITH A WINDOW traced so far (rows of n times
+# the walk's steps, as above), how many take the ``edge`` branch, every tile
+# masked by position, and not the unmasked ``full`` one (``_branches``): all
+# of them where the window is one block, 56 of 252 a row at a window of eight.
+_WINDOW_LIVE = obs.gauge(
+    "flash.window_steps_live", "Live grid steps of the flash kernels with a "
+    "window traced so far")
+_WINDOW_EDGE = obs.gauge(
+    "flash.window_steps_edge", "Of flash.window_steps_live, the steps that "
+    "mask every tile by position (the edge branch)")
 # What lse and delta take in HBM where they cross the boundary of those
 # kernels, from shapes: 4·n·T bytes a statistic a kernel (the forward writes
 # lse; dQ and dK/dV each read lse and delta). It was 512·n·T while the
@@ -176,6 +186,15 @@ def _block_live(qi, kb, block_q, block_k, window):
     return live
 
 
+def _block_full(qi, kb, block_q, block_k, window):
+    """Whether every pair of a live (q-block, k-block) pair is in the mask:
+    the block lies under the diagonal and, with a window, inside it."""
+    full = (kb + 1) * block_k - 1 <= qi * block_q
+    if window is not None:
+        full &= (qi + 1) * block_q - 1 - kb * block_k < window
+    return full
+
+
 class FlashWalk(NamedTuple):
     """The grid steps of one row of n (batch·heads) in one of the flash
     kernels: step ``s`` works on Q block ``q[s]`` and K block ``k[s]``;
@@ -275,9 +294,7 @@ def _branches(qi, kb, first, *, causal, block_q, block_k, window, n_qb,
         kinds = ([("diag", None)] if n_qb == 1 else
                  [("diag", kb == qi), ("full", kb < qi)])
     else:
-        full = (kb + 1) * block_k - 1 <= qi * block_q
-        if window is not None:
-            full &= (qi + 1) * block_q - 1 - kb * block_k < window
+        full = _block_full(qi, kb, block_q, block_k, window)
         kinds = [("full", full), ("edge", jnp.logical_not(full))]
     firsts = ([(True, None)] if first is None else
               [(True, first), (False, jnp.logical_not(first))])
@@ -577,8 +594,12 @@ def _statics(q, causal, block_q, block_k, window, kv_group, inners):
     plan = flash_plan(n, t, d, q.dtype.itemsize, block_q, block_k, kv_group)
     for inner in inners:
         walk = flash_walk(causal, window, block_q, block_k, t, inner)
-        for gauge, steps in ((_STEPS_WALKED, walk.steps),
-                             (_STEPS_LIVE, walk.live)):
+        counts = [(_STEPS_WALKED, walk.steps), (_STEPS_LIVE, walk.live)]
+        if causal and window is not None:
+            edge = np.count_nonzero(~_block_full(walk.q, walk.k, block_q,
+                                                 block_k, window))
+            counts += [(_WINDOW_LIVE, walk.live), (_WINDOW_EDGE, edge)]
+        for gauge, steps in counts:
             gauge.set(gauge.value + n // plan.heads * steps)
     statistics = 1 if inners == "k" else 2
     _STAT_BYTES.set(_STAT_BYTES.value + len(inners) * statistics * 4 * n * t)

@@ -102,6 +102,13 @@ FLASH_SHAPES = {
     # the fourth cell (Ouro-2.6B, 2 x 4096 tokens): 16 ungrouped heads of
     # 128, rows of 8 blocks
     "ouro": ((2, 16, 4096, 128), (2, 16, 4096, 128), 512),
+    # the fifth cell (SmallThinker-21B-A3B, 1 x 16384 tokens): groups of 7
+    # query heads a key/value head, rows of 32 blocks; a window of 4096 is
+    # 9 live blocks a row, 7 of them unmasked: the first walk in which the
+    # `full` and the `edge` branch of a windowed kernel both run
+    "smallthinker_full": ((1, 28, 16384, 128), (1, 4, 16384, 128), 512),
+    "smallthinker_window": ((1, 28, 16384, 128), (1, 4, 16384, 128), 512,
+                            4096),
 }
 
 

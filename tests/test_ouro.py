@@ -469,7 +469,12 @@ def test_save_and_load_round_trip_the_gate_and_the_counters(seeded, tmp_path):
 # dropout, and the Laguna cell's rehearsal twin. A PR that means to change
 # one of these steps reads the new text and replaces its hash: PR 33 did for
 # the two on the flash route (lse and delta one float32 a row at the
-# kernels' boundary); the two off it stand as PR 31 left them.
+# kernels' boundary); the two off it stand as PR 31 left them. PR 34 renewed
+# Laguna's once, for the expert layers' fourth counter (a diff and a max over
+# a layer's group sizes, one more int32 pair beside the optimizer's state:
+# CHANGES.md shows the lowered text's diff), and added SmallThinker's twin:
+# the early router, the ReLU gate, the softmax scoring and a layer without
+# position are all choices at trace time, and what they lower to is held too.
 LOWERED_BEFORE = {
     "gpt2": "f19a640cb05302ba6b1a96247726095553a0daa69a62f3ba0adb18d9229d8743",
     "gpt2_flash_bf16":
@@ -477,7 +482,9 @@ LOWERED_BEFORE = {
     "gpt2_remat_rope":
         "8e7c5ef5fab6e2288fcbbc65e11159aa928b9b4301d789f30c51f345a9493539",
     "laguna_tiny":
-        "1d89987782cd645741b50f7620829c7d20594868454c5c29269cc73783242385",
+        "71a47289edd879ac6ef222efc5338b8d4ed4d96e2d803b2e48a44a10ef963855",
+    "smallthinker_tiny":
+        "074338f798d6bd9a1e00b0feff69a30999cc167970dd97a566786ea71fa4c785",
 }
 
 
@@ -492,12 +499,15 @@ def test_the_defaults_leave_the_gpt2_and_laguna_steps_as_they_were(
     monkeypatch.setenv("DL4J_TPU_LM_ATTN", "pallas")
     gpt2 = dict(vocab_size=512, max_len=64, d_model=64, n_heads=4, n_layers=2,
                 d_ff=256)
-    if name == "laguna_tiny":
-        from benchmark.drivers import laguna_train
+    if name in ("laguna_tiny", "smallthinker_tiny"):
+        from benchmark.drivers import laguna_train, smallthinker_train
+        driver, twin, seq = {
+            "laguna_tiny": (laguna_train, "laguna-tiny.json", 32),
+            "smallthinker_tiny": (smallthinker_train,
+                                  "smallthinker-tiny.json", 64)}[name]
         with open(os.path.join(ROOT, "benchmark", "rehearsal", "configs",
-                               "laguna-tiny.json")) as f:
-            conf, rows, seq = laguna_train.program_config(json.load(f), 32,
-                                                          7), 2, 32
+                               twin)) as f:
+            conf, rows = driver.program_config(json.load(f), seq, 7), 2
     else:
         conf, rows, seq = TransformerConfig(**gpt2, **{
             "gpt2": {},
