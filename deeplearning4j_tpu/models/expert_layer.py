@@ -29,11 +29,17 @@ reads its input calls ``decide`` ahead of attention and hands the
 How: the ``tokens x top_k`` assignments are sorted by the expert they meet
 (those that meet no held expert last), the first ``rows`` of them are
 gathered into a static buffer, and the three products of the gated experts
-run as grouped matrix products over the held experts
-(``jax.lax.ragged_dot``: on a TPU the compiler's grouped-matmul kernel,
-forward and backward), each row weighted and added back to its token. The
-rows of the buffer that no assignment fills are rows of zeros in the last
-expert's group.
+run as grouped matrix products over the held experts, each row weighted and
+added back to its token. The group sizes sum to the rows that hold an
+assignment, not to the buffer: **the rows of the buffer that no assignment
+fills lie in no group**, and the products (forward, input gradient, weight
+gradient) visit only the row tiles that a group has a row in
+(``ops/pallas_kernels.grouped_matmul``, a Pallas product whose grid is as
+long as the groups' tiles; off the TPU, without the interpreter flag,
+``jax.lax.ragged_dot`` over the same group sizes). What a product leaves in
+the rows outside every group is undefined, so they are masked where they are
+used: ``apply`` selects by ``valid`` on the way in and on the way out, which
+also keeps a cotangent there from any token and any weight.
 
 **No token is dropped silently.** The buffer holds ``row_buffer`` times the
 balanced load ``tokens * top_k * count / n_experts`` (never more than
@@ -52,6 +58,11 @@ from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+
+from deeplearning4j_tpu.ops.pallas_kernels import (group_tiles,
+                                                   grouped_matmul,
+                                                   grouped_row_tile,
+                                                   pallas_supported)
 
 __all__ = ["Experts", "Routing", "decide", "apply", "expert_ffn", "glu",
            "STATS"]
@@ -117,8 +128,11 @@ class Routing(NamedTuple):
     weights: jax.Array       # [N, top_k] float32
     order: jax.Array         # [rows] int32 into the flattened [N * top_k]
     valid: jax.Array         # [rows] bool: the row holds an assignment
-    group_sizes: jax.Array   # [count] int32, summing to rows
+    group_sizes: jax.Array   # [count] int32, summing to the valid rows
     stats: dict              # STATS, int32 scalars
+    # the walk of the Pallas grouped products over the buffer's row tiles
+    # (``pallas_kernels.group_tiles``); None where ``ragged_dot`` runs them
+    tiles: Optional[jax.Array] = None
 
 
 def glu(h, gate, up, down, act=jax.nn.silu):
@@ -146,8 +160,10 @@ def dispatch(ex, chosen, tokens):
     """Which assignment fills which row of the buffer. ``chosen``: [N, top_k]
     expert ids. Returns ``(assignment [rows] int32 into the flattened
     [N * top_k], valid [rows] bool, group_sizes [count] int32 that sum to
-    ``rows``, stats)``; rows are grouped by held expert, in the experts'
-    order, the rows that hold no assignment last."""
+    the valid rows, stats, tiles)``; rows are grouped by held expert, in the
+    experts' order, the rows that hold no assignment last and in no group.
+    ``tiles`` is the walk of the Pallas products over the row tiles that hold
+    a group's rows, where they run (``Routing.tiles``)."""
     first, count = ex.held_range
     rows = ex.rows(tokens)
     local = chosen.reshape(-1) - first
@@ -156,19 +172,31 @@ def dispatch(ex, chosen, tokens):
     # assignments that meet held expert e or one before it, for each e
     upto = (key[None, :] <= jnp.arange(count)[:, None]).sum(1, dtype=jnp.int32)
     ends = jnp.minimum(upto, rows)
-    # The rows past the last assignment join the last held expert's group (as
-    # rows of zeros, see apply): every row of the buffer then lies in a
-    # group. What a grouped product leaves in rows outside every group is
-    # undefined on the TPU (zeros on the CPU), and the backward pass would
-    # scatter it into real tokens' gradients.
-    group_sizes = jnp.diff(ends, prepend=0).at[-1].add(rows - ends[-1])
+    # The rows past the last assignment lie in no group: the products do not
+    # visit their tiles. What a grouped product leaves in rows outside every
+    # group is undefined on the TPU (zeros on the CPU): apply masks them by
+    # ``valid`` where it uses them, forward and backward.
+    group_sizes = jnp.diff(ends, prepend=0)
     local_rows = upto[-1]
+    tiles, visited = None, ends[-1]
+    if pallas_supported():
+        row_tile = _row_tile(ex, tokens)
+        tiles = group_tiles(group_sizes, rows, row_tile)
+        visited = tiles[-1] * row_tile
     stats = {"local_rows": local_rows,
-             "rows_computed": jnp.int32(rows),
+             # the rows the products visit: their row tiles, partial ones
+             # whole; the groups' sum where ragged_dot runs them
+             "rows_computed": visited,
              "rows_over_buffer": jnp.maximum(local_rows - rows, 0),
              # the fullest held expert's assignments, buffer or no buffer
              "peak_group_rows": jnp.diff(upto, prepend=0).max()}
-    return order, jnp.arange(rows) < ends[-1], group_sizes, stats
+    return order, jnp.arange(rows) < ends[-1], group_sizes, stats, tiles
+
+
+def _row_tile(ex, tokens):
+    """The grouped products' row tile, from a held expert's rows at an even
+    load."""
+    return grouped_row_tile(tokens * ex.top_k // ex.n_experts)
 
 
 def decide(ex, router, r):
@@ -186,21 +214,32 @@ def apply(ex, ep, flat, routing):
     ``W_down`` [count, d_expert, d] of the held experts; ``sh_gate``,
     ``sh_up``, ``sh_down`` where there is a shared expert. Returns [N, d]."""
     scope = jax.named_scope
-    w, order, valid, group_sizes, _ = routing
+    w, order, valid, group_sizes, _, tiles = routing
     act = _GATES[ex.gate]
     with scope("block.moe_dispatch"):
         token = order // ex.top_k
-        # a row that holds no assignment is a row of zeros with weight 0:
-        # 0 through an expert is 0, forward and backward
+        # a row that holds no assignment lies in no group: the select keeps
+        # what the input gradient leaves there (undefined) from any token
         rows = jnp.where(valid[:, None], flat[token], 0)
         w_rows = jnp.where(valid, w.reshape(-1)[order], 0.0)
     with scope("block.experts"):
-        grouped = lambda a, b: jax.lax.ragged_dot(a, b, group_sizes)
+        if tiles is None:
+            grouped = lambda a, b: jax.lax.ragged_dot(a, b, group_sizes)
+        else:
+            row_tile = _row_tile(ex, flat.shape[0])
+            grouped = lambda a, b: grouped_matmul(a, b, tiles, row_tile)
         out = grouped(act(grouped(rows, ep["W_gate"]))
                       * grouped(rows, ep["W_up"]), ep["W_down"])
     with scope("block.moe_dispatch"):
+        # ... and what the products leave there (undefined) from any token's
+        # sum: selected after the weighting, a weight of 0 would not do
+        # (0 * NaN is NaN). Backward the select zeroes the row's cotangent,
+        # and what the row's weight then collects (0 * NaN) is dropped by
+        # the select on w_rows above.
+        weighted = jnp.where(valid[:, None],
+                             out.astype(jnp.float32) * w_rows[:, None], 0.0)
         y = jnp.zeros(flat.shape, jnp.float32).at[token].add(
-            out.astype(jnp.float32) * w_rows[:, None]).astype(flat.dtype)
+            weighted).astype(flat.dtype)
     if ex.d_shared:
         with scope("block.shared_expert"):
             y = y + glu(flat, ep["sh_gate"], ep["sh_up"], ep["sh_down"], act)

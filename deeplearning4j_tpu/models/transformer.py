@@ -754,8 +754,8 @@ _COUNT_LOW = 1 << 30
 _MOE_DOCS = {
     "moe.local_rows": "Expert assignments that met an expert held here, "
                       "since init (read by TransformerLM.moe_counters)",
-    "moe.rows_computed": "Rows the grouped expert products ran over, "
-                         "padding included, since init",
+    "moe.rows_computed": "Rows the grouped expert products visited (their "
+                         "row tiles, a partial one whole), since init",
     "moe.rows_over_buffer": "Expert assignments left out because the static "
                             "row buffer was full, since init (0 when sound)",
     "moe.peak_group_rows": "Assignments of the fullest held expert of a layer "
@@ -969,8 +969,9 @@ class TransformerLM:
     def moe_counters(self):
         """The expert layers' counts since ``init``, summed over layers and
         steps: ``moe.local_rows`` (assignments that met a held expert),
-        ``moe.rows_computed`` (rows the grouped products ran over, padding
-        and all), ``moe.rows_over_buffer`` (assignments left out because
+        ``moe.rows_computed`` (rows the grouped products visited: their row
+        tiles, a partial one whole, the buffer's empty tail not at all),
+        ``moe.rows_over_buffer`` (assignments left out because
         the row buffer was full: 0 in a sound run) and ``moe.peak_group_rows``
         (a layer's and step's fullest held expert's assignments). They are
         carried on the device beside the optimizer's state and fetched HERE,

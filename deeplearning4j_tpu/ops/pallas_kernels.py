@@ -1006,6 +1006,359 @@ def flash_attention(q, k, v, *, causal=False, block_q=512, block_k=512,
 
 
 # ---------------------------------------------------------------------------
+# grouped matrix products over the row tiles that hold a group's rows
+# (``models/expert_layer.apply``): rows sorted by group, group e's rows
+# against ``w[e]``. The grid's row axis is a walk like the flash kernels':
+# one step a (row tile, group) pair that share a row, read from an int32
+# table by scalar prefetch, and as long as the pairs there are, a value
+# computed from the group sizes (the scheme of
+# ``jax.experimental.pallas.ops.tpu.megablox``). A row tile that lies in no
+# group is never fetched and never written: what the forward and the input
+# gradient leave there is undefined, and the weight gradient does not read
+# it.
+# ---------------------------------------------------------------------------
+
+_ROW_TILE = obs.gauge(
+    "moe.row_tile", "Rows of a tile of the Pallas grouped expert products "
+    "traced last (not set where ragged_dot runs them, off the TPU)")
+
+# A product's blocks (double-buffered), accumulator and float32 result may
+# take this much of a core's VMEM: under the 16 MiB scoped by default on a
+# v5e, with room for what Mosaic adds (tests/test_aot_compile.py compiles the
+# cells' widths for the described chip).
+_GROUPED_VMEM = 12 * 2 ** 20
+_MAX_ROW_TILE = 256
+
+
+def grouped_row_tile(even_group):
+    """The row tile of the grouped products for groups of ``even_group``
+    rows at an even load: the largest power of two that leaves an even group
+    at least two tiles (a group meets a partial tile at each end, and visits
+    it whole), from 8 to 256. At 128 rows a tile of a [2560, 768] weight is
+    128 FLOP a byte of weight fetched, under the v5e's 240; at 256 the MXU
+    bounds it, and on a v5e 512 read no faster on groups of 1536 rows and
+    slower on groups of 512 (PERF.md, PR 35)."""
+    tile = 8
+    while tile * 2 <= min(even_group // 2, _MAX_ROW_TILE):
+        tile *= 2
+    return tile
+
+
+def group_tiles(group_sizes, rows, row_tile):
+    """The walk of the grouped products over ``rows`` rows in tiles of
+    ``row_tile``: an int32 table ``[offsets (groups + 1) | group (V) | tile
+    (V) | steps (1)]`` with ``V = ceil(rows / row_tile) + groups - 1`` the
+    most steps there can be. Step ``s < steps`` works on the rows of
+    ``group[s]`` that lie in row tile ``tile[s]``; a group of no rows takes
+    one step (of no rows: its weight gradient is written, as zeros), any
+    other one a tile it has a row in, in order, so a tile that two groups
+    share is visited by consecutive steps. ``group_sizes`` may sum to fewer
+    than ``rows``: the tiles past the last group are no step."""
+    groups = group_sizes.shape[0]
+    group_sizes = group_sizes.astype(jnp.int32)
+    most = _most_steps(rows, row_tile, groups)
+    ends = jnp.cumsum(group_sizes)
+    starts = ends - group_sizes
+    first = starts // row_tile
+    steps_of = jnp.where(group_sizes > 0,
+                         (ends - 1) // row_tile - first + 1, 1)
+    upto = jnp.cumsum(steps_of)
+    group = jnp.repeat(jnp.arange(groups, dtype=jnp.int32), steps_of,
+                       total_repeat_length=most)
+    tile = first[group] + jnp.arange(most, dtype=jnp.int32) \
+        - (upto - steps_of)[group]
+    tile = jnp.clip(tile, 0, -(-rows // row_tile) - 1)
+    _ROW_TILE.set(row_tile)
+    return jnp.concatenate([jnp.zeros(1, jnp.int32), ends, group, tile,
+                            upto[-1:]]).astype(jnp.int32)
+
+
+def _most_steps(rows, row_tile, groups):
+    return -(-rows // row_tile) + groups - 1
+
+
+def _step_group(table, groups, s):
+    """The group of step ``s`` of the walk ``table`` (``group_tiles``)."""
+    return table[groups + 1 + s]
+
+
+def _step_tile(table, groups, most, s):
+    return table[groups + 1 + most + s]
+
+
+class _GroupedStep(NamedTuple):
+    """What a grid step of a grouped product reads from the table."""
+    group: jax.Array
+    lo: jax.Array        # the group's first row, and one past its last
+    hi: jax.Array
+    row0: jax.Array      # the tile's first row
+    inside: jax.Array    # the whole tile lies in the group: nothing to mask
+
+
+def _grouped_step(table, s, groups, most, row_tile):
+    g = _step_group(table, groups, s)
+    lo, hi = table[g], table[g + 1]
+    row0 = _step_tile(table, groups, most, s) * row_tile
+    return _GroupedStep(g, lo, hi, row0,
+                        (lo <= row0) & (row0 + row_tile <= hi))
+
+
+def _rows_in_group(step, shape):
+    row = step.row0 + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    return (row >= step.lo) & (row < step.hi)
+
+
+def _gmm_kernel(table, a_ref, w_ref, o_ref, *scratch, groups, most, row_tile,
+                k_steps, dims):
+    """``o[tile rows of the group] = a[those rows] @ w[group]`` (``dims``
+    _NN) or ``@ w[group]ᵀ`` (_NT), accumulated in float32 over the
+    ``k_steps`` blocks of the contraction and rounded once. Rows of the tile
+    outside the group keep what the tile held: the step before wrote them if
+    they are another group's, and nothing did if they are no group's."""
+    from jax.experimental import pallas as pl
+
+    step = _grouped_step(table, pl.program_id(1), groups, most, row_tile)
+    ki = pl.program_id(2)
+
+    def finish(acc):
+        @pl.when(step.inside)
+        def _whole():
+            o_ref[...] = acc.astype(o_ref.dtype)
+
+        @pl.when(jnp.logical_not(step.inside))
+        def _part():
+            o_ref[...] = jnp.where(_rows_in_group(step, acc.shape),
+                                   acc.astype(o_ref.dtype), o_ref[...])
+
+    @pl.when(step.hi > step.lo)
+    def _compute():
+        part = _dot(a_ref[...], w_ref[...], dims)
+        if k_steps == 1:
+            finish(part)
+            return
+        acc_ref, = scratch
+
+        @pl.when(ki == 0)
+        def _first():
+            acc_ref[...] = part
+
+        @pl.when(ki > 0)
+        def _later():
+            acc_ref[...] += part
+
+        @pl.when(ki == k_steps - 1)
+        def _last():
+            finish(acc_ref[...])
+
+
+_TN = (((0,), (0,)), ((), ()))     # aᵀ @ b
+
+
+def _tgmm_kernel(table, a_ref, g_ref, o_ref, acc_ref, *, groups, most,
+                 row_tile):
+    """``o[group] = Σ over the group's row tiles of a[rows]ᵀ @ g[rows]``: the
+    steps of a group are consecutive, the first writes the float32
+    accumulator, the last rounds it into the output block. Rows of a tile
+    outside the group are zeroed in BOTH operands: the other group's are
+    finite, what lies past the last group need not be."""
+    from jax.experimental import pallas as pl
+
+    s = pl.program_id(2)
+    step = _grouped_step(table, s, groups, most, row_tile)
+    first = jnp.logical_or(
+        s == 0,
+        _step_group(table, groups, jnp.maximum(s - 1, 0)) != step.group)
+    last = jnp.logical_or(
+        s == pl.num_programs(2) - 1,
+        _step_group(table, groups, jnp.minimum(s + 1, most - 1))
+        != step.group)
+
+    def add(part):
+        @pl.when(first)
+        def _first():
+            acc_ref[...] = part
+
+        @pl.when(jnp.logical_not(first))
+        def _later():
+            acc_ref[...] += part
+
+    @pl.when(step.inside)
+    def _whole():
+        add(_dot(a_ref[...], g_ref[...], _TN))
+
+    @pl.when(jnp.logical_not(step.inside))
+    def _part():
+        a, g = a_ref[...], g_ref[...]
+        a = jnp.where(_rows_in_group(step, a.shape), a, jnp.zeros_like(a))
+        g = jnp.where(_rows_in_group(step, g.shape), g, jnp.zeros_like(g))
+        add(_dot(a, g, _TN))
+
+    @pl.when(last)
+    def _store():
+        o_ref[...] = acc_ref[...].astype(o_ref.dtype)
+
+
+def _blocks(width):
+    """The blocks a ``width``-wide axis may be cut into, widest first: the
+    whole axis, then the multiples of 128 lanes that divide it."""
+    return [width] + [b for b in range(width - width % 128, 0, -128)
+                      if b < width and width % b == 0]
+
+
+def grouped_plan(row_tile, k, n, itemsize):
+    """``(contraction block, width block)`` of a forward or input-gradient
+    product ``[row_tile, k] x [k, n]`` and ``(k block, n block)`` of the
+    weight gradient's ``[k, n]`` output, from the widths: the widest that
+    fit ``_GROUPED_VMEM``. The product keeps the whole contraction in one
+    block where it can and cuts the width first: a group's weight block is
+    then fetched once a group and not once a row tile, and nothing is
+    accumulated across steps. The weight gradient cuts its longer axis."""
+    def product_bytes(bk, bn):
+        blocks = 2 * itemsize * (row_tile * bk + bk * bn + row_tile * bn)
+        return blocks + 4 * row_tile * bn * (1 if bk == k else 2)
+
+    def gradient_bytes(bk, bn):
+        return (4 + 2 * itemsize) * bk * bn \
+            + 2 * itemsize * row_tile * (bk + bn)
+
+    product = next(((bk, bn) for bk in _blocks(k) for bn in _blocks(n)
+                    if product_bytes(bk, bn) <= _GROUPED_VMEM), None)
+    gradient = max(((bk, bn) for bk in _blocks(k) for bn in _blocks(n)
+                    if gradient_bytes(bk, bn) <= _GROUPED_VMEM),
+                   key=lambda b: (b[0] * b[1], min(b)), default=None)
+    if product is None or gradient is None:
+        raise ValueError(
+            f"no blocks of a [{row_tile}, {k}] x [{k}, {n}] grouped product "
+            f"fit {_GROUPED_VMEM} bytes of VMEM")
+    return product, gradient
+
+
+@functools.partial(
+    jax.jit, inline=True,
+    static_argnames=("row_tile", "transposed", "blocks", "interpret"))
+def _gmm(a, w, table, *, row_tile, transposed, blocks, interpret):
+    """``a`` [m, k] against ``w`` [groups, k, n] (``transposed``: [groups, n,
+    k]) to [m, n], over the walk ``table``."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    m, k = a.shape
+    groups = w.shape[0]
+    n = w.shape[1] if transposed else w.shape[2]
+    bk, bn = blocks
+    most = _most_steps(m, row_tile, groups)
+    k_steps = k // bk
+
+    def tile_of(s, table):
+        return _step_tile(table, groups, most, s)
+
+    if transposed:
+        w_spec = pl.BlockSpec(
+            (None, bn, bk),
+            lambda j, s, ki, t: (_step_group(t, groups, s), j, ki))
+    else:
+        w_spec = pl.BlockSpec(
+            (None, bk, bn),
+            lambda j, s, ki, t: (_step_group(t, groups, s), ki, j))
+    kernel = functools.partial(
+        _gmm_kernel, groups=groups, most=most, row_tile=row_tile,
+        k_steps=k_steps, dims=_NT if transposed else _NN)
+    return pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct((m, n), a.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(n // bn, table[-1], k_steps),
+            in_specs=[
+                pl.BlockSpec((row_tile, bk),
+                             lambda j, s, ki, t: (tile_of(s, t), ki)),
+                w_spec],
+            out_specs=pl.BlockSpec((row_tile, bn),
+                                   lambda j, s, ki, t: (tile_of(s, t), j)),
+            scratch_shapes=[] if k_steps == 1 else [
+                pltpu.VMEM((row_tile, bn), jnp.float32)]),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
+        interpret=interpret)(table, a, w)
+
+
+@functools.partial(
+    jax.jit, inline=True,
+    static_argnames=("row_tile", "groups", "blocks", "interpret"))
+def _tgmm(a, g, table, *, row_tile, groups, blocks, interpret):
+    """``a`` [m, k] and ``g`` [m, n] to [groups, k, n]: each group's
+    ``a[rows]ᵀ @ g[rows]``, over the walk ``table``."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    m, k = a.shape
+    n = g.shape[1]
+    bk, bn = blocks
+    most = _most_steps(m, row_tile, groups)
+
+    def tile_of(s, table):
+        return _step_tile(table, groups, most, s)
+
+    kernel = functools.partial(_tgmm_kernel, groups=groups, most=most,
+                               row_tile=row_tile)
+    return pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct((groups, k, n), a.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(k // bk, n // bn, table[-1]),
+            in_specs=[
+                pl.BlockSpec((row_tile, bk),
+                             lambda i, j, s, t: (tile_of(s, t), i)),
+                pl.BlockSpec((row_tile, bn),
+                             lambda i, j, s, t: (tile_of(s, t), j))],
+            out_specs=pl.BlockSpec(
+                (None, bk, bn),
+                lambda i, j, s, t: (_step_group(t, groups, s), i, j)),
+            scratch_shapes=[pltpu.VMEM((bk, bn), jnp.float32)]),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret)(table, a, g)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def grouped_matmul(a, w, table, row_tile):
+    """Rows ``a`` [m, k], sorted by group, against their group's ``w[e]``
+    [groups, k, n], to [m, n]: ``jax.lax.ragged_dot`` over the walk
+    ``table = group_tiles(group_sizes, m, row_tile)``, which visits only the
+    row tiles that hold a group's rows. Forward, input gradient (the same
+    product against ``w[e]ᵀ``) and weight gradient (grouped along the
+    contracted rows) take their operands as they come and accumulate in
+    float32. **Rows outside every group are undefined in the result and in
+    the input gradient** (never written: mask them where they are used), and
+    are not read by the weight gradient, NaN or not."""
+    _, k, n = w.shape
+    blocks, _ = grouped_plan(row_tile, k, n, a.dtype.itemsize)
+    return _gmm(a, w, table, row_tile=row_tile, transposed=False,
+                blocks=blocks, interpret=_interpret_mode())
+
+
+def _grouped_fwd(a, w, table, row_tile):
+    return grouped_matmul(a, w, table, row_tile), (a, w, table)
+
+
+def _grouped_bwd(row_tile, residuals, g):
+    a, w, table = residuals
+    groups, k, n = w.shape
+    itemsize, interpret = a.dtype.itemsize, _interpret_mode()
+    da = _gmm(g, w, table, row_tile=row_tile, transposed=True,
+              blocks=grouped_plan(row_tile, n, k, itemsize)[0],
+              interpret=interpret)
+    dw = _tgmm(a, g, table, row_tile=row_tile, groups=groups,
+               blocks=grouped_plan(row_tile, k, n, itemsize)[1],
+               interpret=interpret)
+    return da, dw.astype(w.dtype), None
+
+
+grouped_matmul.defvjp(_grouped_fwd, _grouped_bwd)
+
+
+# ---------------------------------------------------------------------------
 # fused LSTM cell ("Optimizing Performance of Recurrent Neural Networks on
 # GPUs", arxiv 1604.01946; the cuDNN RNN fusion strategy, arxiv 1410.0759):
 # one kernel per time step fusing the recurrent matmul epilogue

@@ -72,7 +72,7 @@ def _assert_statistics_cross_compact(text, rows_of_n, t, block):
     import re
     flash = [line for line in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line
-             and "pallas_call" in line]
+             and "pallas_call" in line and "block.experts" not in line]
     assert flash
     compact = 0
     for line in flash:
@@ -133,6 +133,38 @@ def test_flash_kernels_compile_for_v5e(v5e, shape, wrt):
                    "dq": (jax.grad(loss, argnums=0), 2),
                    "dkv": (jax.grad(loss, argnums=(1, 2)), 2)}[wrt]
     assert _compile(fn, q, kv, kv) == kernels
+
+
+# the grouped products of the two expert cells: (rows of the buffer, k, n,
+# held experts, a held expert's rows at an even load)
+GROUPED_SHAPES = {
+    "smallthinker_gate_up": (98304, 2560, 768, 16, 1536),
+    "smallthinker_down": (98304, 768, 2560, 16, 1536),
+    "laguna_gate_up": (32768, 2048, 512, 32, 512),
+    "laguna_down": (32768, 512, 2048, 32, 512),
+    # the rehearsal twins' widths: narrower than a lane tile, a row tile of 8
+    "twin": (64, 64, 32, 8, 16),
+}
+
+
+@pytest.mark.parametrize("shape", sorted(GROUPED_SHAPES))
+def test_grouped_products_compile_for_v5e(v5e, shape):
+    """Forward, input gradient and weight gradient of the expert layer's
+    grouped product at the blocks its plan gives: three Mosaic kernels, each
+    inside the VMEM the compiler scopes by default."""
+    rows, k, n, groups, even = GROUPED_SHAPES[shape]
+    tile = pk.grouped_row_tile(even)
+    sds = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=v5e)
+
+    def all_three(a, w, sizes, g):
+        table = pk.group_tiles(sizes, rows, tile)
+        out, vjp = jax.vjp(
+            lambda a, w: pk.grouped_matmul(a, w, table, tile), a, w)
+        return out, vjp(g)
+
+    assert _compile(all_three, sds((rows, k)), sds((groups, k, n)),
+                    sds((groups,), jnp.int32), sds((rows, n))) == 3
 
 
 def test_flash_walk_at_its_bound_compiles_for_v5e(v5e):
@@ -272,16 +304,20 @@ def test_mixed_lm_step_compiles_for_v5e(v5e, monkeypatch):
     head counts, the gate, experts held 8 of 16, remat) compiles for the chip:
     three flash kernels a layer (forward, dQ, dK/dV: a rematerialised block
     keeps the forward kernel's output and logsumexp, ``transformer._remat``,
-    and its backward does not run it again), the grouped products as the
-    compiler's own kernels, and every scope of the per-layer vocabulary in
-    the program's names. What the block keeps costs the named residuals'
+    and its backward does not run it again), the grouped products as Pallas
+    calls whose names hold ``block.experts`` (``moe_experts_roofline`` finds
+    them by it) and none of the compiler's own, and every scope of the
+    per-layer vocabulary in the program's names. The expert layer asks the
+    backend which product to take and sees the CPU here: the test answers
+    for the chip. What the block keeps costs the named residuals'
     bytes and no more: the lane-replicated logsumexp, 128 times the one that
     is named, would not pass."""
     import re
 
     import chip_smoke
-    from deeplearning4j_tpu.models import transformer
+    from deeplearning4j_tpu.models import expert_layer, transformer
     monkeypatch.setenv("DL4J_TPU_LM_ATTN", "pallas")
+    monkeypatch.setattr(expert_layer, "pallas_supported", lambda: True)
     sizes = dict(chip_smoke._MIXED_LM, seq=1024, d_model=128, d_ff=256,
                  vocab_size=512)
     rows = 2
@@ -304,11 +340,17 @@ def test_mixed_lm_step_compiles_for_v5e(v5e, monkeypatch):
     text = step.as_text()
     kernels = re.findall(r'custom_call_target="tpu_custom_call"[^\n]*'
                          r'op_name="([^"]*)"', text)
-    flash = [n for n in kernels if n.endswith("pallas_call")]
+    flash = [n for n in kernels if n.endswith("pallas_call")
+             and "block.attn" in n]
     assert len(flash) == 3 * c.n_layers
     assert sum("block.attn_window" in n for n in flash) == 3 * 3
     assert sum("block.attn_full" in n for n in flash) == 3 * 2
-    assert "ragged-dot" in text
+    # a sparse layer's three products: forward, forward again under remat,
+    # input gradient, weight gradient
+    grouped = [n for n in kernels if n.endswith("pallas_call")
+               and "block.experts" in n]
+    assert len(grouped) == 4 * 3 * 4 == len(kernels) - len(flash)
+    assert "ragged-dot" not in text
     for scope in ("attn_gate", "router", "moe_dispatch", "experts",
                   "shared_expert"):
         assert re.search(rf'op_name="jit\(step\)/[^"]*block\.{scope}[)/]',

@@ -263,7 +263,9 @@ def test_one_expert_taking_most_rows_is_still_exact_with_room_for_all():
 
 def test_rows_past_the_buffer_are_counted_never_silent():
     """The same routing into a buffer of the balanced load: what does not
-    fit is left out and ``rows_over_buffer`` says how many."""
+    fit is left out and ``rows_over_buffer`` says how many. The groups then
+    fill the buffer, and ``rows_computed``, the rows the products visit, is
+    all of it."""
     config = tiny_config()
     h, lp = layer_inputs(config)
     h = jnp.abs(h)
@@ -275,6 +277,74 @@ def test_rows_past_the_buffer_are_counted_never_silent():
     assert int(stats["rows_computed"]) == rows
     assert int(stats["local_rows"]) > rows
     assert int(stats["rows_over_buffer"]) == int(stats["local_rows"]) - rows
+
+
+@pytest.mark.parametrize("products", ["pallas", "ragged_dot"])
+def test_rows_computed_is_the_rows_the_products_visit(products, monkeypatch):
+    """With room for every assignment the buffer's tail lies in no group:
+    ``rows_computed`` is the groups' sum where ``ragged_dot`` runs the
+    products, and the walk's steps times the row tile where the Pallas
+    product does: at most a tile a held expert over the assignments."""
+    from deeplearning4j_tpu import obs
+    from deeplearning4j_tpu.ops import pallas_kernels as pk
+    if products == "pallas":
+        monkeypatch.setenv("DL4J_TPU_PALLAS_INTERPRET", "1")
+    config = tiny_config()
+    h, lp = layer_inputs(config)
+    ex, ep = program_experts(config, lp)
+    tokens, count = ROWS * SEQ, ex.held_range[1]
+    rows = ex.rows(tokens)
+    assert rows == tokens * ex.top_k
+    obs.metrics.gauge("moe.row_tile").set(-1)
+    w, chosen = expert_layer.route(ex, h.reshape(tokens, -1), ep["router"])
+    routing = expert_layer.Routing(w, *expert_layer.dispatch(ex, chosen,
+                                                             tokens))
+    stats = {k: int(v) for k, v in routing.stats.items()}
+    local = stats["local_rows"]
+    assert 0 < local < rows and int(routing.group_sizes.sum()) == local
+    assert int(routing.valid.sum()) == local
+    if products == "ragged_dot":
+        assert routing.tiles is None
+        assert stats["rows_computed"] == local
+        assert obs.metrics.value("moe.row_tile") == -1
+    else:
+        tile = pk.grouped_row_tile(tokens * ex.top_k // ex.n_experts)
+        assert obs.metrics.value("moe.row_tile") == tile == 8
+        sizes = np.asarray(routing.group_sizes)
+        bounds = np.concatenate([[0], np.cumsum(sizes)])
+        visited = sum(1 if hi == lo else (hi - 1) // tile - lo // tile + 1
+                      for lo, hi in zip(bounds[:-1], bounds[1:]))
+        assert stats["rows_computed"] == int(routing.tiles[-1]) * tile \
+            == visited * tile
+        assert 1.0 <= stats["rows_computed"] / local \
+            <= 1 + count * tile / local
+        assert stats["rows_computed"] < rows
+    got, _ = expert_ffn(ex, ep, h, routing)
+    np.testing.assert_allclose(got, reference_layer(config, h, lp),
+                               rtol=1e-5, atol=1e-5)
+
+
+def test_the_pallas_products_leave_no_trace_of_the_rows_in_no_group(
+        monkeypatch):
+    """The layer and every gradient of it (tokens, router, the three expert
+    weights) under the Pallas product, whose rows outside every group are
+    never written (NaN under the interpreter), against ``ragged_dot`` over
+    the same groups, whose rows there are zeros on the CPU."""
+    config = tiny_config()
+    h, lp = layer_inputs(config)
+    ex, ep = program_experts(config, lp)
+
+    def loss(ep, h):
+        y, _ = expert_ffn(ex, ep, h)
+        return jnp.square(y).sum()
+
+    want = jax.value_and_grad(loss, (0, 1))(ep, h)
+    monkeypatch.setenv("DL4J_TPU_PALLAS_INTERPRET", "1")
+    got = jax.value_and_grad(loss, (0, 1))(ep, h)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert bool(jnp.isfinite(g).all())
+        np.testing.assert_allclose(g, w, rtol=1e-4,
+                                   atol=1e-5 * float(jnp.abs(w).max()))
 
 
 def test_the_drivers_window_fails_when_a_row_was_left_out():

@@ -754,6 +754,118 @@ class TestFlashRemat:
         assert tuple(seen) == pk.FLASH_RESIDUALS
 
 
+# (rows of the buffer, k, n, group sizes, row tile, dtype): the grouped
+# products of the expert layer, ``pallas_kernels.grouped_matmul``
+GROUPED_CASES = {
+    # every row in a group, every group of whole tiles
+    "groups_fill_the_buffer": (64, 32, 16, [8] * 8, 8, "float32"),
+    # three quarters of the buffer in no group
+    "tail_of_three_quarters_in_no_group":
+        (128, 32, 48, [5, 9, 3, 15], 8, "float32"),
+    # one expert takes most rows, several take none
+    "one_full_group_and_empty_ones":
+        (96, 32, 16, [70, 0, 0, 1, 0, 0, 2, 0], 16, "float32"),
+    # boundaries inside a tile, three groups in one tile, rows that are no
+    # multiple of the tile
+    "group_boundaries_inside_a_tile": (72, 32, 16, [10, 7, 2, 3, 30], 16,
+                                       "float32"),
+    # Laguna's twin: d_expert narrower than the row tile, bfloat16
+    "laguna_twin_bf16": (128, 64, 32, [0, 40, 11, 0, 9, 30, 2, 1], 64,
+                         "bfloat16"),
+    # blocks of the contraction and of the output's width: the accumulator
+    # across k steps, the weight gradient's output in six blocks
+    "contraction_and_width_in_blocks": (64, 256, 384, [13, 0, 20, 7], 16,
+                                        "bfloat16"),
+}
+
+
+class TestGroupedMatmul:
+    """The expert layer's grouped product over the row tiles that hold a
+    group's rows, against a float32 loop over the groups."""
+
+    @staticmethod
+    def _loop(a, w, g, sizes):
+        a, w, g = (np.asarray(x, np.float32) for x in (a, w, g))
+        out = np.zeros((a.shape[0], w.shape[2]), np.float32)
+        da, dw = np.zeros(a.shape, np.float32), np.zeros(w.shape, np.float32)
+        lo = 0
+        for e, size in enumerate(sizes):
+            hi = lo + size
+            out[lo:hi] = a[lo:hi] @ w[e]
+            da[lo:hi] = g[lo:hi] @ w[e].T
+            dw[e] = a[lo:hi].T @ g[lo:hi]
+            lo = hi
+        return out, da, dw
+
+    @pytest.mark.parametrize("case", sorted(GROUPED_CASES))
+    def test_forward_and_both_gradients_with_a_poisoned_tail(
+            self, case, interpret_pallas, monkeypatch):
+        """The operands' rows outside every group are NaN: result and input
+        gradient are finite and right on the rows inside the groups, the
+        weight gradient is finite and right, and the walk visits no tile
+        without a group's row."""
+        import jax
+        from deeplearning4j_tpu.ops import pallas_kernels as pk
+        rows, k, n, sizes, tile, dtype = GROUPED_CASES[case]
+        live, groups = sum(sizes), len(sizes)
+        keys = jax.random.split(jax.random.PRNGKey(3), 3)
+        a = jax.random.normal(keys[0], (rows, k), dtype)
+        w = jax.random.normal(keys[1], (groups, k, n), dtype)
+        g = jax.random.normal(keys[2], (rows, n), dtype)
+        inside = (jnp.arange(rows) < live)[:, None]
+        want = self._loop(jnp.where(inside, a, 0), w, jnp.where(inside, g, 0),
+                          sizes)
+        a, g = jnp.where(inside, a, jnp.nan), jnp.where(inside, g, jnp.nan)
+        if case == "contraction_and_width_in_blocks":
+            monkeypatch.setattr(pk, "_GROUPED_VMEM", 150_000)
+            assert pk.grouped_plan(tile, k, n, 2) == ((128, 128), (128, 128))
+        table = pk.group_tiles(jnp.asarray(sizes, jnp.int32), rows, tile)
+        out, vjp = jax.vjp(
+            lambda a, w: pk.grouped_matmul(a, w, table, tile), a, w)
+        da, dw = vjp(g)
+        tol = 1e-5 if dtype == "float32" else 2e-2
+        for name, got, ref in (("out", out[:live], want[0][:live]),
+                               ("da", da[:live], want[1][:live]),
+                               ("dw", dw, want[2])):
+            got = np.asarray(got, np.float32)
+            assert np.isfinite(got).all(), name
+            np.testing.assert_allclose(got, ref, rtol=tol,
+                                       atol=tol * np.abs(ref).max(),
+                                       err_msg=name)
+        # a step a (tile, group) pair with a row in common, one a group
+        # of no rows
+        bounds = np.concatenate([[0], np.cumsum(sizes)])
+        steps = sum(1 if hi == lo else (hi - 1) // tile - lo // tile + 1
+                    for lo, hi in zip(bounds[:-1], bounds[1:]))
+        assert int(table[-1]) == steps <= -(-live // tile) + groups
+        walked = np.asarray(table[groups + 1:-1]).reshape(2, -1)[:, :steps]
+        assert (np.diff(walked[0]) >= 0).all() and \
+            (np.diff(walked[1]) >= 0).all()
+        assert walked[1].max() <= max(live - 1, 0) // tile
+
+    def test_row_tile_follows_the_even_group(self):
+        from deeplearning4j_tpu.ops import pallas_kernels as pk
+        # the fifth cell, the third, chip_smoke's mixed model, the twins
+        assert [pk.grouped_row_tile(x) for x in (1536, 512, 256, 16, 3)] \
+            == [256, 256, 128, 8, 8]
+
+    def test_the_plan_fits_the_budget_at_the_cells_widths(self):
+        from deeplearning4j_tpu.ops import pallas_kernels as pk
+        # the fifth cell's gate / up product keeps a whole [2560, 768] weight
+        # in a block; its down product and its weight gradients are halved
+        assert pk.grouped_plan(256, 2560, 768, 2) == ((2560, 768), (1280, 768))
+        assert pk.grouped_plan(256, 768, 2560, 2) == ((768, 1280), (768, 1280))
+        for tile, k, n in ((256, 2560, 768), (256, 768, 2560),
+                           (256, 2048, 512), (256, 512, 2048), (8, 64, 32)):
+            (bk, bn), (ok, on) = pk.grouped_plan(tile, k, n, 2)
+            assert k % bk == 0 and n % bn == 0 and k % ok == 0 and n % on == 0
+            blocks = 4 * (tile * bk + bk * bn + tile * bn)
+            assert blocks + 4 * tile * bn * (1 + (bk < k)) <= pk._GROUPED_VMEM
+            assert 8 * ok * on + 4 * tile * (ok + on) <= pk._GROUPED_VMEM
+        with pytest.raises(ValueError, match="fit"):
+            pk.grouped_plan(256, 100_000, 100_000, 2)
+
+
 class TestSlidingWindow:
     """Causal sliding-window attention: the kernels mask entries more than
     window-1 positions in the past and skip fully out-of-window blocks."""

@@ -252,8 +252,58 @@ def test_the_kernel_route_trains_the_same_model(seeded, monkeypatch):
     monkeypatch.setenv("DL4J_TPU_PALLAS_INTERPRET", "1")
     monkeypatch.setenv("DL4J_TPU_LM_ATTN", "pallas")
     lm = program(tiny_config(compute_dtype="bfloat16", block_size=16), weights)
+    obs.metrics.gauge("moe.row_tile").set(-1)
     got = float(lm.fit_batch(tokens))
     assert abs(got - want) / want < 2e-3   # bfloat16: 8 mantissa bits
+    # the grouped products are Pallas calls on this route: the gauge holds
+    # their row tile since the step was traced, and the counters the rows
+    # their walk visited, at most a tile a held expert and layer over the
+    # assignments
+    ex = lm.conf.experts
+    tile = pk.grouped_row_tile(ROWS * SEQ * ex.top_k // ex.n_experts)
+    assert obs.metrics.value("moe.row_tile") == tile
+    counts = lm.moe_counters()
+    assert counts["moe.rows_over_buffer"] == 0
+    assert counts["moe.local_rows"] < counts["moe.rows_computed"] \
+        <= counts["moe.local_rows"] + 4 * ex.held_range[1] * tile
+    assert counts["moe.rows_computed"] < 4 * ex.rows(ROWS * SEQ)
+
+
+def _primitives(eqn):
+    """The primitives of an equation and of every jaxpr under it."""
+    yield eqn.primitive.name
+    for value in eqn.params.values():
+        for item in (value if isinstance(value, (list, tuple)) else [value]):
+            item = getattr(item, "jaxpr", item)
+            for sub in getattr(item, "eqns", ()):
+                yield from _primitives(sub)
+
+
+def test_the_grouped_products_are_pallas_calls_under_the_experts_scope(
+        seeded, monkeypatch):
+    """``moe_experts_roofline`` finds the products by the scope in their name
+    stack: a block's three products are Pallas calls, each under
+    ``block.experts``, and no ``ragged_dot`` is left beside them; without
+    the interpreter flag (off the TPU) it is the other way round."""
+    def products(conf):
+        lm = TransformerLM(conf).init()
+        jaxpr = jax.make_jaxpr(lambda bp, x: _block_apply(
+            conf, bp, x, conf.layer_spec(1))[0])(
+                lm.params["b1"], jnp.zeros((ROWS, SEQ, conf.d_model)))
+        found = {"pallas_call": [], "ragged_dot_general": []}
+        for eqn in jaxpr.eqns:
+            for prim in _primitives(eqn):
+                if prim in found:
+                    found[prim].append(str(eqn.source_info.name_stack))
+        return found
+
+    conf = driver.program_config(seeded[0], SEQ, SEED)
+    plain = products(conf)
+    assert not plain["pallas_call"] and len(plain["ragged_dot_general"]) == 3
+    monkeypatch.setenv("DL4J_TPU_PALLAS_INTERPRET", "1")
+    own = products(conf)
+    assert not own["ragged_dot_general"] and len(own["pallas_call"]) == 3
+    assert all("block.experts" in stack for stack in own["pallas_call"])
 
 
 def test_no_dense_ffn_leaf_and_the_served_path_refuses_by_name(seeded):
