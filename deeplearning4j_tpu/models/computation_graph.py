@@ -59,6 +59,7 @@ from deeplearning4j_tpu.testing import faults
 
 class ComputationGraph(DeviceStateMixin):
     def __init__(self, conf: ComputationGraphConfiguration):
+        obs.compilation.install()
         self.conf = conf
         self.topological_order = conf.topological_order
         self.layer_names = conf.layer_names()

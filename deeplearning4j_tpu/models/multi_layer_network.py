@@ -46,6 +46,7 @@ from deeplearning4j_tpu.testing import faults
 
 class MultiLayerNetwork(DeviceStateMixin):
     def __init__(self, conf: MultiLayerConfiguration):
+        obs.compilation.install()
         self.conf = conf
         self.layers = conf.layers
         self.params_list = None

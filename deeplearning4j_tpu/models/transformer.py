@@ -788,6 +788,7 @@ class TransformerLM:
     block and one step, branched at trace time."""
 
     def __init__(self, config: TransformerConfig):
+        obs.compilation.install()
         self.conf = config
         self.params = None
         self.opt_state = None
@@ -1197,14 +1198,19 @@ class TransformerLM:
                 if mask is not None:
                     mask = jax.device_put(jnp.asarray(mask),
                                           self._data_sharding)
-        if self._step is None:
-            self._step = self._build_step()
         if getattr(self, "_rng", None) is None:
             self._rng = jax.random.PRNGKey(self.conf.seed + 1)
         if getattr(self, "_it_host", None) is None:
             # host-side mirror of the (device-carried) step counter so the
             # per-step listener callback never forces a device->host fetch
             self._it_host = int(self.iteration)  # graftlint: disable=G001 -- one-time adoption sync, not per-step
+        if self._step is None:
+            # the first call of a new step traces, lowers and compiles it:
+            # bracketed whole, so that the compile log and a trace lay that
+            # time to ``lm.step``; the call itself is the path below
+            with obs.building("lm.step"):
+                self._step = self._build_step()
+                return self.fit_batch(tokens, targets, mask)
         with obs.span("lm.step_call"):
             (self.params, self.opt_state, self.iteration, self._rng,
              loss) = self._step(self.params, self.opt_state, self.iteration,

@@ -4,14 +4,12 @@ depths, collective round latencies, and checkpoint commit times live.
 The reference ships a full stats pipeline (StatsListener → storage →
 training UI, SURVEY §5.1); this module is its process-wide aggregation
 core for the TPU-first repro. Every subsystem records into named metrics
-here and three export surfaces read them back out:
+here and two export surfaces read them back out:
 
-- :func:`metrics_snapshot` — the full registry as a JSON-able dict
+- :func:`metrics_snapshot` — the full registry as a JSON-able dict, with
+  the log of compiled programs (``obs/compilation.py``) under ``compiles``
   (served at ``/train/metrics/data`` by ``ui/server.py``);
-- :func:`prometheus_text` — Prometheus text exposition (``/metrics``);
-- :func:`metrics_summary` — the compact per-histogram summary
-  (count/mean/p50/p99/max) that ``bench.py`` embeds in BENCH output so a
-  perf regression carries its own diagnosis.
+- :func:`prometheus_text` — Prometheus text exposition (``/metrics``).
 
 Metric kinds: :class:`Counter` (monotonic), :class:`Gauge` (last value),
 :class:`Histogram` (fixed bucket bounds, cumulative at export, with a
@@ -40,7 +38,7 @@ import threading
 import time
 
 __all__ = ["Counter", "Gauge", "Histogram", "counter", "gauge", "histogram",
-           "timer", "enabled", "value", "metrics_snapshot", "metrics_summary",
+           "timer", "enabled", "value", "metrics_snapshot",
            "prometheus_text", "reset_metrics", "TIME_BUCKETS"]
 
 # default bucket bounds (seconds) for duration histograms: half-millisecond
@@ -220,18 +218,6 @@ class Histogram(_Metric):
                     "buckets": [[b, c] for b, c in
                                 zip(self.buckets + ("+Inf",), self._counts)]}
 
-    def summary(self):
-        """Compact digest for bench output: count/mean/p50/p99/max."""
-        with self._lock:
-            count, total, mx = self._count, self._sum, self._max
-        if not count:
-            return {"count": 0}
-        return {"count": count,
-                "mean": total / count,
-                "p50": self.quantile(0.5),
-                "p99": self.quantile(0.99),
-                "max": mx}
-
 
 class _Timer:
     __slots__ = ("_hist", "_t0")
@@ -300,30 +286,17 @@ def reset_metrics():
 
 
 def metrics_snapshot():
-    """The whole registry as one JSON-able dict, grouped by kind."""
+    """The whole registry as one JSON-able dict, grouped by kind, and under
+    ``compiles`` the log of compiled programs (which, when, how long, whether
+    the persistent cache held it: ``obs/compilation.py``)."""
+    from deeplearning4j_tpu.obs import compilation
     with _REGISTRY_LOCK:
         metrics = list(_REGISTRY.values())
     out = {"enabled": enabled(),
            "counters": {}, "gauges": {}, "histograms": {}}
     for m in metrics:
         out[m.kind + "s"][m.name] = m.snapshot()
-    return out
-
-
-def metrics_summary():
-    """Compact form for BENCH lines: counter/gauge values plus per-
-    histogram digests (count/mean/p50/p99/max), empties omitted."""
-    with _REGISTRY_LOCK:
-        metrics = list(_REGISTRY.values())
-    out = {}
-    for m in metrics:
-        if isinstance(m, Histogram):
-            s = m.summary()
-            if s["count"]:
-                out[m.name] = {k: (round(v, 6) if isinstance(v, float) else v)
-                               for k, v in s.items()}
-        elif m.value:
-            out[m.name] = m.value
+    out["compiles"] = compilation.compiles()
     return out
 
 

@@ -97,6 +97,7 @@ class ServingFrontEnd:
     _thread_name = "dl4j-serve"
 
     def __init__(self, queue_cap=None):
+        obs.compilation.install()
         self._lock = threading.Lock()
         self._more = threading.Condition(self._lock)
         self._pending = deque()

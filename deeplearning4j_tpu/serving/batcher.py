@@ -119,7 +119,8 @@ class InferenceServer(ServingFrontEnd):
         for shape in row_shapes:
             for b in self._buckets:
                 x = np.zeros((b,) + tuple(shape), dtype)
-                self.model.output(x)
+                with obs.building(f"serve.warm.out.b{b}"):
+                    self.model.output(x)
                 sig = _infer_signature(self.model, x)
                 with self._lock:
                     self._sigs.add(sig)

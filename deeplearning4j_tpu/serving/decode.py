@@ -408,21 +408,26 @@ class ContinuousLM(ServingFrontEnd):
         # the admit writer too — same no-op shape _release dispatches
         # (slot 0 rewritten inactive), so the first real admission pays
         # no compile either
-        self._state = self._admit_fn(
-            self._state, np.int32(0), np.zeros(c.max_len, np.int32),
-            np.int32(1), np.int32(0), np.float32(0.0),
-            np.int32(c.vocab_size), np.float32(1.0), np.bool_(False),
-            np.int32(0))
+        # one bracket a program: the compile log (obs.compiles()) then names
+        # the boot's seconds by program family and rung
+        with obs.building("serve.warm.admit"):
+            self._state = self._admit_fn(
+                self._state, np.int32(0), np.zeros(c.max_len, np.int32),
+                np.int32(1), np.int32(0), np.float32(0.0),
+                np.int32(c.vocab_size), np.float32(1.0), np.bool_(False),
+                np.int32(0))
         for w in self._kv_ladder:
-            _, step = lm._decode_fns(s, self._chunk, w)
-            self._state = step(lm.params, self._state)
+            with obs.building(f"serve.warm.decode.w{w}"):
+                _, step = lm._decode_fns(s, self._chunk, w)
+                self._state = step(lm.params, self._state)
         for w in self._prefill_ladder:
-            pf = lm._prefill_fn(s, w)
-            ik, iv = self._inject_zeros(w)
-            self._state, _, _ = pf(
-                lm.params, self._state, np.int32(0),
-                np.zeros(w, np.int32), np.int32(0), np.int32(0),
-                np.bool_(False), np.bool_(False), ik, iv)
+            with obs.building(f"serve.warm.prefill.w{w}"):
+                pf = lm._prefill_fn(s, w)
+                ik, iv = self._inject_zeros(w)
+                self._state, _, _ = pf(
+                    lm.params, self._state, np.int32(0),
+                    np.zeros(w, np.int32), np.int32(0), np.int32(0),
+                    np.bool_(False), np.bool_(False), ik, iv)
         # the warm dispatches scribbled positions/outputs into the pool
         # (sampling keys are counter-derived, so the rng needs no reset);
         # rebuild it so the first real request starts from a blank slate

@@ -1,12 +1,13 @@
 """Runtime compile watcher (the dynamic twin of graftlint G025-G027,
 mirroring leakwatch's relationship to G022-G024).
 
-``install()`` registers one ``jax.monitoring`` listener for the
-``/jax/core/compile/backend_compile_duration`` event — the same signal
-``tools/compile_counter.py`` counts, generalized from "how many" to
-"WHERE FROM": every backend compile records the in-repo fragment of the
-triggering call stack. Each event is then *attributed* to the static
-dispatch inventory siglint derives
+``install()`` subscribes to the process's one ``jax.monitoring``
+registration (``obs/compilation.py``, whose callback runs synchronously on
+the compiling thread as ``/jax/core/compile/backend_compile_duration``
+ends) — the same signal ``tools/compile_counter.py`` counts, generalized
+from "how many" to "WHERE FROM": every backend compile records the in-repo
+fragment of the triggering call stack. Each event is then *attributed* to
+the static dispatch inventory siglint derives
 (``tools.graftlint.signatures.signature_inventory_for_paths``): the
 innermost recorded frame that falls inside an inventoried dispatch
 site's ``(path, lineno..end_lineno)`` range names the (model class,
@@ -40,9 +41,9 @@ steady loop are exactly as much of a regression as jit ones).
 Enablement is the registered ``DL4J_TPU_COMPILEWATCH`` knob (default
 OFF — the listener itself is a cheap counter bump, but the stack walk
 per compile and the inventory build are test-lane costs; ``bench.py``
-opts in explicitly for its steady re-verification). Old JAX exposes no
-listener unregister, so like compile_counter the registration is a
-process singleton and ``uninstall()`` just deactivates recording.
+opts in explicitly for its steady re-verification). The subscription is
+for the life of the process, like the registration under it, and
+``uninstall()`` just deactivates recording.
 
 Scope limits (the static side covers what this side cannot):
 
@@ -66,6 +67,8 @@ import threading
 import time
 from contextlib import contextmanager
 
+from deeplearning4j_tpu.obs import compilation
+
 __all__ = ["enabled", "install", "uninstall", "installed", "watch",
            "extend_watch_paths", "inventory", "outlaws", "snapshot",
            "events", "attributed", "counts_by_family", "counts_by_site",
@@ -82,7 +85,6 @@ _installed = False
 _active = False
 _steady_depth = [0]
 
-_EVENT = "/jax/core/compile/backend_compile_duration"
 _MAX_FRAMES = 25
 
 # repo root: the parent of the deeplearning4j_tpu package — only frames
@@ -124,7 +126,8 @@ def _repo_frames():
     f = sys._getframe(2)
     while f is not None and len(out) < _MAX_FRAMES:
         name = f.f_code.co_filename
-        if name != __file__ and not name.startswith("<"):
+        if name not in (__file__, compilation.__file__) \
+                and not name.startswith("<"):
             ap = os.path.abspath(name)
             if ap.startswith(_REPO_ROOT + os.sep) and \
                     "site-packages" not in ap:
@@ -133,9 +136,7 @@ def _repo_frames():
     return out
 
 
-def _listener(event, duration, **kwargs):  # noqa: ARG001 — monitoring API
-    if event != _EVENT:
-        return
+def _on_program(entry):  # noqa: ARG001 — the stack is what is read
     with _state:
         if not _active:
             return
@@ -149,22 +150,18 @@ def installed():
 
 
 def install():
-    """Register the (process-singleton) monitoring listener and start
+    """Subscribe (once a process) to the compile listener and start
     recording. Idempotent."""
     global _installed, _active
     with _state:
-        if _installed:
-            _active = True
-            return
-        import jax.monitoring
-        jax.monitoring.register_event_duration_secs_listener(_listener)
+        compilation.subscribe(_on_program)
         _installed = True
         _active = True
 
 
 def uninstall():
-    """Stop recording. The listener stays registered (one per process)
-    but drops every event while inactive."""
+    """Stop recording. The subscription stays (one per process) but drops
+    every event while inactive."""
     global _active
     with _state:
         _active = False

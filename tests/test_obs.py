@@ -13,7 +13,10 @@ import contextlib
 import glob
 import json
 import os
+import subprocess
+import sys
 import threading
+import time
 import urllib.request
 
 import numpy as np
@@ -24,6 +27,9 @@ from deeplearning4j_tpu.datasets.dataset import ArrayDataSetIterator, DataSet
 from deeplearning4j_tpu.models.multi_layer_network import MultiLayerNetwork
 from deeplearning4j_tpu.nn.layers import DenseLayer, OutputLayer
 from deeplearning4j_tpu.obs import metrics as obs_metrics
+
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def make_data(n=120, d=4, c=3, seed=0):
@@ -154,15 +160,13 @@ class TestRegistry:
         obs.counter("t.obs.c2").inc(2)
         h = obs.histogram("t.obs.h2_seconds")
         h.record(0.01)
-        json.dumps(obs.metrics_snapshot())   # must not raise
-        summary = obs.metrics_summary()
-        assert summary["t.obs.c2"] == 2
-        assert summary["t.obs.h2_seconds"]["count"] == 1
-        assert set(summary["t.obs.h2_seconds"]) == {
-            "count", "mean", "p50", "p99", "max"}
-        # empty metrics are omitted from the compact form
-        obs.histogram("t.obs.h3_seconds")
-        assert "t.obs.h3_seconds" not in obs.metrics_summary()
+        snap = obs.metrics_snapshot()
+        json.dumps(snap)   # must not raise
+        assert snap["counters"]["t.obs.c2"] == 2
+        assert snap["histograms"]["t.obs.h2_seconds"]["count"] == 1
+        # the log of compiled programs rides under a key of its own
+        assert snap["compiles"] == obs.compiles()
+        assert not hasattr(obs, "metrics_summary")
 
     def test_prometheus_exposition_format(self):
         obs.counter("t.obs.prom", "events seen").inc(3)
@@ -236,6 +240,286 @@ class TestTracing:
                   if plane.name == "/host:CPU"
                   for line in plane.lines for e in line.events}
         assert events["dl4j:t.group"]["steps"] == 4
+
+
+# ---------------------------------------------------------------------------
+# the compile log: one jax.monitoring registration, owners, the cache (ISSUE 36)
+# ---------------------------------------------------------------------------
+def tiny_lm(seed=0, **changes):
+    from deeplearning4j_tpu.models.transformer import (TransformerConfig,
+                                                       TransformerLM)
+    conf = dict(vocab_size=40, max_len=32, d_model=16, n_heads=2, n_layers=1,
+                d_ff=32, seed=seed)
+    conf.update(changes)
+    return TransformerLM(TransformerConfig(**conf)).init()
+
+
+TOKENS = np.random.default_rng(0).integers(0, 40, (2, 17)).astype(np.int32)
+
+_BOOT = """
+import json, os
+os.environ["JAX_PLATFORMS"] = "cpu"
+import numpy as np
+from deeplearning4j_tpu import obs
+from deeplearning4j_tpu.models.transformer import (TransformerConfig,
+                                                   TransformerLM)
+lm = TransformerLM(TransformerConfig(
+    vocab_size=40, max_len=32, d_model=16, n_heads=2, n_layers=1,
+    d_ff=32, seed=0)).init()
+lm.fit_batch(np.arange(34, dtype=np.int32).reshape(2, 17))
+print("BOOT", json.dumps({
+    "step": [e for e in obs.compiles() if e["owner"] == "lm.step"],
+    "hits": obs.metrics.value("compile.cache_hits_total"),
+    "writes": obs.metrics.value("compile.cache_writes_total")}))
+"""
+
+
+def since(t0):
+    return [e for e in obs.compiles() if e["end"] >= t0]
+
+
+class TestCompileLog:
+    @pytest.mark.parametrize("kernels", ["dense", "pallas_interpreter"])
+    def test_first_fit_batch_leaves_one_entry_owned_by_lm_step(
+            self, kernels, monkeypatch):
+        """Under the Pallas interpreter the kernels' lowering rules trace
+        jitted functions of their own: those seconds lie inside the lowering
+        event's and are counted once."""
+        changes = {}
+        if kernels == "pallas_interpreter":
+            monkeypatch.setenv("DL4J_TPU_PALLAS_INTERPRET", "1")
+            monkeypatch.setenv("DL4J_TPU_LM_ATTN", "pallas")
+            changes = {"block_size": 8}
+        lm = tiny_lm(**changes)
+        t0 = time.perf_counter()
+        lm.fit_batch(TOKENS)
+        owned = [e for e in since(t0) if e["owner"] == "lm.step"]
+        assert len(owned) == 1
+        e = owned[0]
+        assert e["fun_name"] == "jit(step)"
+        parts = (e["trace_seconds"], e["lower_seconds"],
+                 e["backend_seconds"])
+        assert min(parts) > 0
+        assert obs.metrics.value("lm.step.build_seconds") >= sum(parts)
+        assert e["end"] <= time.perf_counter()
+        assert obs.metrics.value("compile.programs_total") >= 1
+        assert obs.metrics.value("compile.trace_seconds_total") \
+            >= e["trace_seconds"]
+        json.dumps(obs.compiles())
+
+    def test_second_fit_batch_adds_no_entry_and_stays_out_of_the_bracket(
+            self, monkeypatch):
+        lm = tiny_lm()
+        lm.fit_batch(TOKENS)
+        built = obs.metrics.value("lm.step.build_seconds")
+        entered = []
+        real = obs.building
+        monkeypatch.setattr(obs, "building",
+                            lambda owner: entered.append(owner) or real(owner))
+        t0 = time.perf_counter()
+        lm.fit_batch(TOKENS)
+        assert since(t0) == [] and entered == []
+        assert obs.metrics.value("lm.step.build_seconds") == built
+        # a new step program (here: the old one dropped) is a new build
+        lm._step = None
+        lm.fit_batch(TOKENS)
+        assert entered == ["lm.step"]
+        assert [e["owner"] for e in since(t0)] == ["lm.step"]
+
+    def test_second_boot_is_served_by_the_persistent_cache(self, tmp_path):
+        env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=str(tmp_path))
+        env.pop("DL4J_TPU_FAULT_SPEC", None)
+        env.pop("DL4J_TPU_METRICS", None)
+
+        def boot():
+            r = subprocess.run([sys.executable, "-c", _BOOT], env=env,
+                               capture_output=True, text=True, timeout=300,
+                               cwd=REPO)
+            assert r.returncode == 0, r.stderr[-2000:]
+            line = [l for l in r.stdout.splitlines()
+                    if l.startswith("BOOT ")][-1]
+            return json.loads(line[5:])
+
+        cold, warm = boot(), boot()
+        (c,), (w,) = cold["step"], warm["step"]
+        assert c["cache_asked"] and not c["cache_served"] \
+            and c["cache_written"]
+        assert cold["hits"] == 0 and cold["writes"] > 0
+        assert w["cache_asked"] and w["cache_served"] \
+            and not w["cache_written"]
+        assert w["retrieval_seconds"] > 0
+        assert warm["hits"] >= cold["writes"] and warm["writes"] == 0
+        # trace and lowering are paid on the warm boot too
+        assert w["trace_seconds"] > 0 and w["lower_seconds"] > 0
+
+    def test_install_twice_registers_once(self):
+        from jax._src import monitoring
+
+        from deeplearning4j_tpu.obs import compilation
+        compilation.install()
+        compilation.install()
+        tiny_lm()           # the constructors install too
+        assert monitoring.get_event_duration_listeners().count(
+            compilation._on_duration) == 1
+        assert monitoring.get_event_listeners().count(
+            compilation._on_event) == 1
+
+    def test_metrics_off_keeps_the_registry_silent_and_the_counters_counting(
+            self, monkeypatch):
+        import jax
+
+        from tools.compile_counter import CompileCacheCounter, CompileCounter
+        monkeypatch.setenv("DL4J_TPU_METRICS", "0")
+        t0 = time.perf_counter()
+        with CompileCounter() as cc, CompileCacheCounter() as cache:
+            with obs.building("t.obs.off"):
+                jax.jit(lambda x: x * 2 + 1)(np.ones(3, np.float32))
+        assert cc.count == 1 and cc.seconds > 0
+        assert cache.hits + cache.misses == 1
+        snap = obs.metrics_snapshot()
+        assert all(v == 0 for k, v in snap["counters"].items()
+                   if k.startswith("compile."))
+        assert snap["gauges"]["t.obs.off.build_seconds"] == 0
+        # the log is kept whatever the knob says
+        assert [e["owner"] for e in since(t0)] == ["t.obs.off"]
+
+    def test_building_nests_and_is_per_thread(self):
+        import jax
+
+        def program(k):
+            return jax.jit(lambda x: x + k)(np.ones(2, np.float32))
+
+        other = {}
+
+        def elsewhere():
+            t0 = time.perf_counter()
+            program(12.5)
+            other["entries"] = since(t0)
+
+        t0 = time.perf_counter()
+        with obs.building("t.obs.outer"):
+            program(1.5)
+            with obs.building("t.obs.inner"):
+                program(2.5)
+                t = threading.Thread(target=elsewhere)
+                t.start()
+                t.join(timeout=60)
+                assert not t.is_alive()
+            program(3.5)
+        program(4.5)
+        mine = [e["owner"] for e in since(t0)
+                if e not in other["entries"]]
+        assert mine == ["t.obs.outer", "t.obs.inner", "t.obs.outer", None]
+        # the other thread compiled inside no bracket of its own
+        assert [e["owner"] for e in other["entries"]] == [None]
+        assert obs.metrics.value("t.obs.outer.build_seconds") \
+            >= obs.metrics.value("t.obs.inner.build_seconds") > 0
+
+    def test_nested_jits_trace_time_is_counted_once(self):
+        import jax
+        import jax.numpy as jnp
+
+        @jax.jit
+        def inner(x):
+            return jnp.sin(x) * 2
+
+        @jax.jit
+        def outer(x):
+            y = x
+            for _ in range(20):
+                y = inner(y + 1)
+            return jax.checkpoint(lambda z: inner(z).sum())(y)
+
+        t0 = time.perf_counter()
+        outer(np.ones((4, 4), np.float32))
+        wall = time.perf_counter() - t0
+        (e,) = [e for e in since(t0) if e["fun_name"] == "jit(outer)"]
+        assert 0 < e["trace_seconds"] + e["lower_seconds"] \
+            + e["backend_seconds"] <= wall
+
+    def test_lowered_and_never_compiled_is_no_program(self):
+        import jax
+
+        def only_lowered(x):
+            return x * 3
+
+        t0 = time.perf_counter()
+        jax.jit(only_lowered).lower(np.ones(3, np.float32))
+        assert all("only_lowered" not in e["fun_name"] for e in since(t0))
+        jax.jit(lambda x: x - 7)(np.ones(3, np.float32))
+        (e,) = [e for e in since(t0) if e["fun_name"] == "jit(<lambda>)"]
+        assert e["lower_seconds"] > 0
+
+    def test_a_subscriber_runs_on_the_compiling_thread_with_its_stack(self):
+        import jax
+
+        from deeplearning4j_tpu.obs import compilation
+        seen = []
+
+        def callback(entry):
+            seen.append((entry["fun_name"], threading.get_ident(),
+                         sys._getframe(1).f_code.co_filename))
+
+        compilation.subscribe(callback)
+        compilation.subscribe(callback)      # once a callback
+        try:
+            jax.jit(lambda x: x * 5 - 2)(np.ones(3, np.float32))
+        finally:
+            compilation._subscribers.remove(callback)
+        assert seen == [("jit(<lambda>)", threading.get_ident(),
+                         compilation.__file__)]
+
+    def test_warm_start_logs_one_owned_row_a_program_of_the_ladder(self):
+        from deeplearning4j_tpu.serving import ContinuousLM
+        lm = tiny_lm()
+        t0 = time.perf_counter()
+        srv = ContinuousLM(lm, slots=2, chunk=2)
+        srv.warm_start()
+        # the eager helpers a rung compiles beside its program (an iota, a
+        # broadcast) are its rows too; the ladder's own are these three
+        owned = {e["owner"]: e["fun_name"] for e in since(t0)
+                 if e["fun_name"] in ("jit(admit)", "jit(chunk_run)",
+                                      "jit(prefill)")}
+        want = {"serve.warm.admit": "jit(admit)"}
+        want.update({f"serve.warm.decode.w{w}": "jit(chunk_run)"
+                     for w in srv._kv_ladder})
+        want.update({f"serve.warm.prefill.w{w}": "jit(prefill)"
+                     for w in srv._prefill_ladder})
+        assert owned == want
+        for owner in want:
+            assert obs.metrics.value(owner + ".build_seconds") > 0
+
+    def test_prometheus_text_holds_the_compile_counters(self):
+        import jax
+        jax.jit(lambda x: x / 3)(np.ones(3, np.float32))
+        text = obs.prometheus_text()
+        assert "# TYPE dl4j_tpu_compile_programs_total counter" in text
+        programs = [l for l in text.splitlines()
+                    if l.startswith("dl4j_tpu_compile_programs_total ")]
+        assert programs and int(programs[0].split()[1]) >= 1
+        for name in ("trace_seconds", "lower_seconds", "backend_seconds",
+                     "cache_requests", "cache_hits", "cache_writes",
+                     "cache_retrieval_seconds", "cache_saved_seconds"):
+            assert f"dl4j_tpu_compile_{name}_total " in text
+
+    def test_compile_counters_are_differences_of_the_one_listeners_tallies(
+            self):
+        import jax
+
+        from tools.compile_counter import CompileCacheCounter, CompileCounter
+        f = jax.jit(lambda x: x * 11)
+        with CompileCounter() as outer, CompileCacheCounter() as cache:
+            f(np.ones(3, np.float32))
+            assert outer.count == 1          # live inside the body
+            with CompileCounter() as nested:
+                f(np.ones(3, np.float32))    # jit cache hit: no event
+                f(np.ones(5, np.float32))    # a new shape: one compile
+            assert nested.count == 1 and outer.count == 2
+        f(np.ones(7, np.float32))
+        assert outer.count == 2 and nested.count == 1   # frozen on leaving
+        assert outer.seconds > nested.seconds > 0
+        assert cache.hits + cache.misses == 2
+        assert CompileCounter().count == 0
 
 
 # ---------------------------------------------------------------------------
@@ -536,3 +820,21 @@ class TestUIExport:
         assert snap["counters"]["train.steps_total"] == 12
         assert snap["histograms"]["train.dispatch_group_seconds"][
             "count"] == 1
+
+    def test_compiles_reach_both_endpoints_after_one_fit_batch(self, server):
+        t0 = time.perf_counter()
+        tiny_lm(seed=3).fit_batch(TOKENS)
+        _, _, body = self._get(server, "/metrics")
+        values = {l.split()[0]: float(l.split()[1])
+                  for l in body.decode().splitlines()
+                  if l.startswith("dl4j_tpu_compile_")}
+        assert values["dl4j_tpu_compile_programs_total"] >= 1
+        assert values["dl4j_tpu_compile_trace_seconds_total"] > 0
+        assert values["dl4j_tpu_compile_lower_seconds_total"] > 0
+        assert values["dl4j_tpu_compile_backend_seconds_total"] > 0
+        assert values["dl4j_tpu_compile_cache_requests_total"] >= 1
+        assert "dl4j_tpu_lm_step_build_seconds" in body.decode()
+        _, _, body = self._get(server, "/train/metrics/data")
+        rows = [e for e in json.loads(body)["compiles"]
+                if e["owner"] == "lm.step" and e["end"] >= t0]
+        assert [e["fun_name"] for e in rows] == ["jit(step)"]

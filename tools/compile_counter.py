@@ -1,14 +1,16 @@
-"""XLA compilation counter: how many backend compiles a code region triggered.
+"""XLA compilation counters: how many backend compiles, and how many
+persistent-cache hits and writes, a code region triggered.
 
 The recompile regressions this repo fights (one fresh ``_jit_train`` entry per
 trailing-batch shape — the exact overhead the fused loop's shape bucketing
-removes) are invisible in wall-time assertions on fast hosts. This counter
-makes them a hard number tests and ``bench.py`` can gate on.
+removes) are invisible in wall-time assertions on fast hosts. These counters
+make them a hard number tests and ``chip_smoke.py`` can gate on.
 
-Counts ``/jax/core/compile/backend_compile_duration`` events from
-``jax.monitoring`` — one per actual XLA ``backend_compile`` (jit cache hits
-emit nothing). The listener is registered once per process and toggled by the
-context manager, so nested counters each see every event.
+Both are before / after differences of the tallies that the process's one
+``jax.monitoring`` registration keeps (``deeplearning4j_tpu/obs/compilation.py``:
+one per ``/jax/core/compile/backend_compile_duration`` event — jit cache hits
+emit nothing — and the cache's events), so nested counters, and counters on
+any thread, each see every event, whatever ``DL4J_TPU_METRICS`` says.
 
 Usage::
 
@@ -21,106 +23,58 @@ Usage::
 
 from __future__ import annotations
 
-import threading
-
-_lock = threading.Lock()
-_active = []   # stack of running counters; listener is a process singleton
-_registered = False
-
-_EVENT = "/jax/core/compile/backend_compile_duration"
+from deeplearning4j_tpu.obs import compilation
 
 
-def _listener(event, duration, **kwargs):  # noqa: ARG001 — monitoring API
-    if event == _EVENT:
-        with _lock:
-            for c in _active:
-                c.count += 1
-                c.seconds += duration
-
-
-def _ensure_registered():
-    global _registered
-    if _registered:
-        return
-    import jax.monitoring
-    jax.monitoring.register_event_duration_secs_listener(_listener)
-    _registered = True
-
-
-class CompileCounter:
-    """Context manager counting XLA backend compilations in its body
-    (``count``) and the wall seconds they took (``seconds``)."""
+class _Difference:
+    """Context manager over the tallies' growth since entry: live inside the
+    body, frozen on leaving."""
 
     def __init__(self):
-        self.count = 0
-        self.seconds = 0.0
+        self._start = self._end = compilation.tallies()
 
     def __enter__(self):
-        _ensure_registered()
-        with _lock:
-            self.count = 0
-            self.seconds = 0.0
-            _active.append(self)
+        compilation.install()
+        self._start, self._end = compilation.tallies(), None
         return self
 
     def __exit__(self, *exc):
-        with _lock:
-            _active.remove(self)
+        self._end = compilation.tallies()
         return False
 
-
-# ---------------------------------------------------------------------------
-# persistent-compile-cache hit/miss counter (the warm-restart assertion)
-# ---------------------------------------------------------------------------
-
-_HIT_EVENT = "/jax/compilation_cache/cache_hits"
-_MISS_EVENT = "/jax/compilation_cache/cache_misses"
-
-_cache_active = []       # stack of running CompileCacheCounters
-_cache_registered = False
+    def _grown(self, tally):
+        end = self._end or compilation.tallies()
+        return end[tally] - self._start[tally]
 
 
-def _cache_listener(event, **kwargs):  # noqa: ARG001 — monitoring API
-    if event in (_HIT_EVENT, _MISS_EVENT):
-        with _lock:
-            for c in _cache_active:
-                if event == _HIT_EVENT:
-                    c.hits += 1
-                else:
-                    c.misses += 1
+class CompileCounter(_Difference):
+    """Context manager counting XLA backend compilations in its body
+    (``count``) and the wall seconds they took (``seconds``)."""
+
+    @property
+    def count(self):
+        return self._grown("programs")
+
+    @property
+    def seconds(self):
+        return self._grown("backend_seconds")
 
 
-def _ensure_cache_registered():
-    global _cache_registered
-    if _cache_registered:
-        return
-    import jax.monitoring
-    jax.monitoring.register_event_listener(_cache_listener)
-    _cache_registered = True
-
-
-class CompileCacheCounter:
+class CompileCacheCounter(_Difference):
     """Counts persistent-XLA-cache (``JAX_COMPILATION_CACHE_DIR``, else
     ``<repo>/.jax_cache``) hits and misses in its body. ``misses == 0 and hits > 0`` is THE
     "warm restart compiles nothing" assertion for server warm-start:
     current jax versions emit ``backend_compile_duration`` even when the
     executable is served from the persistent cache (the event times the
     compile-OR-retrieve path), so :class:`CompileCounter` alone cannot
-    distinguish a cache-served boot from a cold one."""
+    distinguish a cache-served boot from a cold one. ``misses`` is jax's
+    ``cache_misses`` event, which fires where an entry is WRITTEN
+    (``compile.cache_writes_total``)."""
 
-    def __init__(self):
-        self.hits = 0
-        self.misses = 0
+    @property
+    def hits(self):
+        return self._grown("cache_hits")
 
-    def __enter__(self):
-        _ensure_cache_registered()
-        with _lock:
-            self.hits = 0
-            self.misses = 0
-            _cache_active.append(self)
-        return self
-
-    def __exit__(self, *exc):
-        with _lock:
-            _cache_active.remove(self)
-        return False
+    @property
+    def misses(self):
+        return self._grown("cache_writes")
