@@ -378,13 +378,23 @@ def phase_train_mixed_lm(sz, seed, rehearse):
             n: text.count(f'kernel_name = "{n}"')
             for n in ("_gmm_kernel", "_tgmm_kernel")}
         facts["ragged_dots"] = text.count("ragged_dot")
+        # ... and the rows around them move in Pallas calls too: the gather
+        # (forward, again in the rematerialised block, and as the combine's
+        # backward), the combine (forward, and as the gather's backward) and
+        # the pass that lays the source of each out as words
+        facts["row_movement"] = {
+            n: text.count(f'kernel_name = "{n}"')
+            for n in ("_gather_kernel", "_combine_kernel", "_words_kernel")}
         # remat or not, one of each a layer: a rematerialised block keeps the
         # forward kernel's output and logsumexp and does not run it again
         checks["flash_kernels_in_step"] = _flash_kernels_in_step(
-            text, c.n_layers, facts, others=12 * sparse)
+            text, c.n_layers, facts, others=(12 + 10) * sparse)
         checks["grouped_products_in_step"] = facts["ragged_dots"] == 0 \
             and facts["grouped_products"] == {"_gmm_kernel": 9 * sparse,
                                               "_tgmm_kernel": 3 * sparse}
+        checks["rows_moved_in_step"] = facts["row_movement"] == {
+            "_gather_kernel": 3 * sparse, "_combine_kernel": 2 * sparse,
+            "_words_kernel": 5 * sparse}
     got = lm.eval_loss(toks)
     want = _plain_mixed_loss(c, lm.params, toks)
     rel = abs(got - want) / abs(want)

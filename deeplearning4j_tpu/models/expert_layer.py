@@ -19,27 +19,35 @@ chips or for their exchange. With ``held = (0, n_experts)`` it is the whole
 layer.
 
 The layer is two halves with a seam between them. ``decide`` is the index
-half: scores, top k, the sort by held expert, the group sizes and the
-statistics (a ``Routing``). ``apply`` gathers the rows, runs the three
-grouped products and adds each weighted row back to its token.
-``expert_ffn`` is one after the other on one tensor; a block whose router
-reads its input calls ``decide`` ahead of attention and hands the
+half: scores, top k, the sort by held expert, the group sizes, each
+assignment's row and the statistics (a ``Routing``). ``apply`` gathers the
+rows, runs the three grouped products and adds each weighted row back to its
+token. ``expert_ffn`` is one after the other on one tensor; a block whose
+router reads its input calls ``decide`` ahead of attention and hands the
 ``Routing`` to ``expert_ffn`` after it.
 
 How: the ``tokens x top_k`` assignments are sorted by the expert they meet
-(those that meet no held expert last), the first ``rows`` of them are
-gathered into a static buffer, and the three products of the gated experts
-run as grouped matrix products over the held experts, each row weighted and
-added back to its token. The group sizes sum to the rows that hold an
-assignment, not to the buffer: **the rows of the buffer that no assignment
-fills lie in no group**, and the products (forward, input gradient, weight
-gradient) visit only the row tiles that a group has a row in
-(``ops/pallas_kernels.grouped_matmul``, a Pallas product whose grid is as
-long as the groups' tiles; off the TPU, without the interpreter flag,
-``jax.lax.ragged_dot`` over the same group sizes). What a product leaves in
-the rows outside every group is undefined, so they are masked where they are
-used: ``apply`` selects by ``valid`` on the way in and on the way out, which
-also keeps a cotangent there from any token and any weight.
+(those that meet no held expert last), the first ``rows`` of them fill a
+static buffer, and the three products of the gated experts run as grouped
+matrix products over the held experts, each row weighted and added back to
+its token. The group sizes sum to the rows that hold an assignment, not to
+the buffer: **the rows of the buffer that no assignment fills lie in no
+group**, and on the TPU (and under the interpreter flag) everything follows
+one walk over the row tiles that a group has a row in
+(``ops/pallas_kernels.group_tiles``): the products, forward, input gradient
+and weight gradient (``grouped_matmul``), and the rows around them.
+``gather_rows`` fills those tiles, one DMA a row that holds an assignment and
+zeros for the rest of a visited tile; ``combine_rows`` adds a token's weighted
+rows in float32, fetching only the rows its assignments fill (``Routing.pos``;
+an assignment without a row is skipped, not weighted by zero: ``0 * NaN``);
+each is the other's transpose, so the backward moves no other row either.
+**A row tile past the last group is never written, by the gather or by a
+product, and never read**: what it holds is undefined, forward and backward
+(``moe.rows_computed`` counts the rows of the walk). Off the TPU, without the
+interpreter flag, the products are ``jax.lax.ragged_dot`` over the same group
+sizes and the rows move by XLA's gather and float32 scatter-add over the
+whole buffer, selected by ``valid`` on the way in and, after the weighting,
+on the way out: the tests' oracle.
 
 **No token is dropped silently.** The buffer holds ``row_buffer`` times the
 balanced load ``tokens * top_k * count / n_experts`` (never more than
@@ -59,7 +67,8 @@ from typing import NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from deeplearning4j_tpu.ops.pallas_kernels import (group_tiles,
+from deeplearning4j_tpu.ops.pallas_kernels import (combine_rows, gather_rows,
+                                                   group_tiles,
                                                    grouped_matmul,
                                                    grouped_row_tile,
                                                    pallas_supported)
@@ -127,6 +136,9 @@ class Routing(NamedTuple):
     which assignment fills which row of the buffer (``dispatch``)."""
     weights: jax.Array       # [N, top_k] float32
     order: jax.Array         # [rows] int32 into the flattened [N * top_k]
+    # [N, top_k] int32: the row an assignment fills, the inverse of ``order``
+    # on the valid rows; -1 where it met no held expert or fell past the buffer
+    pos: jax.Array
     valid: jax.Array         # [rows] bool: the row holds an assignment
     group_sizes: jax.Array   # [count] int32, summing to the valid rows
     stats: dict              # STATS, int32 scalars
@@ -159,16 +171,18 @@ def route(ex, h, router):
 def dispatch(ex, chosen, tokens):
     """Which assignment fills which row of the buffer. ``chosen``: [N, top_k]
     expert ids. Returns ``(assignment [rows] int32 into the flattened
-    [N * top_k], valid [rows] bool, group_sizes [count] int32 that sum to
-    the valid rows, stats, tiles)``; rows are grouped by held expert, in the
+    [N * top_k], its inverse [N, top_k] int32 on the valid rows and -1
+    elsewhere, valid [rows] bool, group_sizes [count] int32 that sum to the
+    valid rows, stats, tiles)``; rows are grouped by held expert, in the
     experts' order, the rows that hold no assignment last and in no group.
-    ``tiles`` is the walk of the Pallas products over the row tiles that hold
+    ``tiles`` is the walk of the Pallas kernels over the row tiles that hold
     a group's rows, where they run (``Routing.tiles``)."""
     first, count = ex.held_range
     rows = ex.rows(tokens)
     local = chosen.reshape(-1) - first
     key = jnp.where((local >= 0) & (local < count), local, count)
-    order = jnp.argsort(key, stable=True)[:rows].astype(jnp.int32)
+    by_expert = jnp.argsort(key, stable=True).astype(jnp.int32)
+    order = by_expert[:rows]
     # assignments that meet held expert e or one before it, for each e
     upto = (key[None, :] <= jnp.arange(count)[:, None]).sum(1, dtype=jnp.int32)
     ends = jnp.minimum(upto, rows)
@@ -190,7 +204,12 @@ def dispatch(ex, chosen, tokens):
              "rows_over_buffer": jnp.maximum(local_rows - rows, 0),
              # the fullest held expert's assignments, buffer or no buffer
              "peak_group_rows": jnp.diff(upto, prepend=0).max()}
-    return order, jnp.arange(rows) < ends[-1], group_sizes, stats, tiles
+    valid = jnp.arange(rows) < ends[-1]
+    # the inverse of the sort is a second sort (XLA's scatter of as many row
+    # numbers takes five times as long on the chip)
+    pos = jnp.argsort(by_expert).astype(jnp.int32)
+    pos = jnp.where(pos < ends[-1], pos, -1).reshape(chosen.shape)
+    return order, pos, valid, group_sizes, stats, tiles
 
 
 def _row_tile(ex, tokens):
@@ -214,36 +233,60 @@ def apply(ex, ep, flat, routing):
     ``W_down`` [count, d_expert, d] of the held experts; ``sh_gate``,
     ``sh_up``, ``sh_down`` where there is a shared expert. Returns [N, d]."""
     scope = jax.named_scope
-    w, order, valid, group_sizes, _, tiles = routing
+    gather, grouped, combine = _along_the_walk(ex, flat, routing) \
+        if routing.tiles is not None else _over_the_buffer(ex, flat, routing)
     act = _GATES[ex.gate]
     with scope("block.moe_dispatch"):
-        token = order // ex.top_k
-        # a row that holds no assignment lies in no group: the select keeps
-        # what the input gradient leaves there (undefined) from any token
-        rows = jnp.where(valid[:, None], flat[token], 0)
-        w_rows = jnp.where(valid, w.reshape(-1)[order], 0.0)
+        # the one array, a reference a product: backward their cotangents
+        # are summed where the rows are moved, not over the whole buffer
+        for_gate, for_up = gather()
     with scope("block.experts"):
-        if tiles is None:
-            grouped = lambda a, b: jax.lax.ragged_dot(a, b, group_sizes)
-        else:
-            row_tile = _row_tile(ex, flat.shape[0])
-            grouped = lambda a, b: grouped_matmul(a, b, tiles, row_tile)
-        out = grouped(act(grouped(rows, ep["W_gate"]))
-                      * grouped(rows, ep["W_up"]), ep["W_down"])
+        out = grouped(act(grouped(for_gate, ep["W_gate"]))
+                      * grouped(for_up, ep["W_up"]), ep["W_down"])
     with scope("block.moe_dispatch"):
-        # ... and what the products leave there (undefined) from any token's
-        # sum: selected after the weighting, a weight of 0 would not do
-        # (0 * NaN is NaN). Backward the select zeroes the row's cotangent,
-        # and what the row's weight then collects (0 * NaN) is dropped by
-        # the select on w_rows above.
-        weighted = jnp.where(valid[:, None],
-                             out.astype(jnp.float32) * w_rows[:, None], 0.0)
-        y = jnp.zeros(flat.shape, jnp.float32).at[token].add(
-            weighted).astype(flat.dtype)
+        y = combine(out)
     if ex.d_shared:
         with scope("block.shared_expert"):
             y = y + glu(flat, ep["sh_gate"], ep["sh_up"], ep["sh_down"], act)
     return y
+
+
+def _along_the_walk(ex, flat, routing):
+    """``(gather, grouped product, combine)`` on the TPU: all three follow
+    the walk ``routing.tiles`` over the row tiles that hold an assignment."""
+    w, order, pos, _, _, _, tiles = routing
+    row_tile = _row_tile(ex, flat.shape[0])
+    # one DMA a row that holds an assignment; a token's sum over the rows
+    # its assignments fill, in float32: an assignment without a row is
+    # skipped, not weighted by zero
+    return (lambda: gather_rows(flat, order, pos, tiles, row_tile, 2),
+            lambda a, b: grouped_matmul(a, b, tiles, row_tile),
+            lambda out: combine_rows(out, w, order, pos, tiles, row_tile))
+
+
+def _over_the_buffer(ex, flat, routing):
+    """The same three off the TPU, the tests' oracle: ``ragged_dot`` over the
+    group sizes between XLA's gather and float32 scatter-add over the whole
+    buffer. A row that holds no assignment lies in no group: what a product
+    leaves there is undefined, so it is selected away by ``valid`` on the way
+    in (which keeps the input gradient there from any token) and on the way
+    out."""
+    w, order, _, valid, group_sizes, _, _ = routing
+    token = order // ex.top_k
+
+    def combine(out):
+        # selected after the weighting, a weight of 0 would not do (0 * NaN
+        # is NaN). Backward the select zeroes the row's cotangent, and what
+        # the row's weight then collects (0 * NaN) is dropped by the select
+        # on the rows' weights.
+        w_rows = jnp.where(valid, w.reshape(-1)[order], 0.0)
+        weighted = jnp.where(
+            valid[:, None], out.astype(jnp.float32) * w_rows[:, None], 0.0)
+        return jnp.zeros(flat.shape, jnp.float32).at[token].add(
+            weighted).astype(flat.dtype)
+
+    return (lambda: (jnp.where(valid[:, None], flat[token], 0),) * 2,
+            lambda a, b: jax.lax.ragged_dot(a, b, group_sizes), combine)
 
 
 def expert_ffn(ex, ep, h, routing=None):

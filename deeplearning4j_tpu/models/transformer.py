@@ -755,7 +755,8 @@ _MOE_DOCS = {
     "moe.local_rows": "Expert assignments that met an expert held here, "
                       "since init (read by TransformerLM.moe_counters)",
     "moe.rows_computed": "Rows the grouped expert products visited (their "
-                         "row tiles, a partial one whole), since init",
+                         "row tiles, a partial one whole), since init; the "
+                         "rows the layer moves follow the same walk",
     "moe.rows_over_buffer": "Expert assignments left out because the static "
                             "row buffer was full, since init (0 when sound)",
     "moe.peak_group_rows": "Assignments of the fullest held expert of a layer "
@@ -971,7 +972,10 @@ class TransformerLM:
         """The expert layers' counts since ``init``, summed over layers and
         steps: ``moe.local_rows`` (assignments that met a held expert),
         ``moe.rows_computed`` (rows the grouped products visited: their row
-        tiles, a partial one whole, the buffer's empty tail not at all),
+        tiles, a partial one whole, the buffer's empty tail not at all; the
+        layer MOVES rows along the same walk, ``gather_rows`` into those
+        tiles and ``combine_rows`` out of them, a tile two experts share
+        once, so this bounds the rows moved as well as multiplied),
         ``moe.rows_over_buffer`` (assignments left out because
         the row buffer was full: 0 in a sound run) and ``moe.peak_group_rows``
         (a layer's and step's fullest held expert's assignments). They are

@@ -1359,6 +1359,455 @@ grouped_matmul.defvjp(_grouped_fwd, _grouped_bwd)
 
 
 # ---------------------------------------------------------------------------
+# the rows around those products, along the same walk
+# (``models/expert_layer.apply``): ``gather_rows`` fills the row tiles the
+# walk visits with their tokens' rows, ``combine_rows`` adds each token's
+# weighted rows back, and each is the other's transpose. One row is one DMA:
+# XLA's gather and scatter-add have static shapes and pay for every row of
+# the buffer, these pay for the rows that hold an assignment. Mosaic slices
+# a tiled array by whole tiles only, and half a 32-bit sublane (one bfloat16
+# row) not at all, so a source crosses the boundary as whole words with the
+# row as a leading dimension (``_row_words``: ``[n, 1, words]`` uint32, a row
+# contiguous in HBM) and is turned back in VMEM.
+# ---------------------------------------------------------------------------
+
+# what the combine's fetched rows (one buffer, not double-buffered) may take
+# of a core's VMEM; its output blocks and float32 sums take about as much
+_COMBINE_VMEM = 4 * 2 ** 20
+_MAX_TOKEN_TILE = 256
+# rows of a tile turned from fetched words into lanes at a time: what the
+# kernels hold in flight is a chunk's, not a tile's
+_ROW_CHUNK = 32
+
+
+def _packs(dtype, d):
+    """Two bfloat16 columns ride in one 32-bit word where each half of the
+    row is whole lane tiles (both cells' widths are)."""
+    return dtype == jnp.bfloat16 and d % (2 * _LANES) == 0
+
+
+def _words_a_row(dtype, d):
+    return d // 2 if _packs(jnp.dtype(dtype), d) else d
+
+
+def _words_kernel(*refs, d, sources):
+    """A tile of rows ``[tile, d]``, the sum of the ``sources`` (rounded to
+    their dtype, as XLA's own sum of them would be), as ``[tile, 1, words]``
+    uint32: bfloat16 columns ``j`` and ``j + d / 2`` in the low and the high
+    half of word ``j``, any other row as its float32 bits."""
+    *x_refs, o_ref = refs[-sources - 1:]
+    words = o_ref.shape[-1]
+
+    def bits(rows, lanes):
+        x = x_refs[0][rows, lanes].astype(jnp.float32)
+        for other in x_refs[1:]:
+            x = (x + other[rows, lanes].astype(jnp.float32)).astype(
+                other.dtype).astype(jnp.float32)
+        return jax.lax.bitcast_convert_type(x, jnp.uint32)
+
+    def pack(rows, _, count):
+        if words == d:
+            word = bits(rows, slice(None))
+        else:
+            word = (bits(rows, slice(0, words)) >> 16) \
+                | (bits(rows, slice(words, d)) & jnp.uint32(0xFFFF0000))
+        o_ref[rows] = word.reshape(count, 1, words)
+
+    _for_chunks(x_refs[0].shape[0], pack)
+
+
+@functools.partial(jax.jit, inline=True,
+                   static_argnames=("row_tile", "interpret"))
+def _row_words(xs, table=None, *, row_tile, interpret):
+    """The sum of the arrays ``xs`` (a tuple, each [n, d]) as ``[n, 1,
+    words]`` uint32, a row contiguous and one DMA: two bfloat16 columns a
+    word (``_packs``; the halves unpack into lanes ``[:d / 2]`` and ``[d /
+    2:]``), or the row's float32 bits. With the walk ``table``, over the row
+    tiles it visits alone; the rows of the others are never read or written,
+    and ``combine_rows`` fetches none of them."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    n, d = xs[0].shape
+    words = _words_a_row(xs[0].dtype, d)
+    if table is None:
+        row_tile = min(row_tile, n)
+        prefetch, tiles = (), -(-n // row_tile)
+    else:
+        groups = _table_groups(table, n, row_tile)
+        prefetch = (table,)
+        tiles = _live_tiles(table, groups, _most_steps(n, row_tile, groups))
+    return pl.pallas_call(
+        functools.partial(_words_kernel, d=d, sources=len(xs)),
+        out_shape=jax.ShapeDtypeStruct((n, 1, words), jnp.uint32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(prefetch), grid=(tiles,),
+            in_specs=[pl.BlockSpec((row_tile, d), lambda i, *_: (i, 0))]
+            * len(xs),
+            out_specs=pl.BlockSpec((row_tile, 1, words),
+                                   lambda i, *_: (i, 0, 0))),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret)(*prefetch, *xs)
+
+
+def _unpacked(words, d):
+    """The float32 pieces, left to right, of fetched rows ``words`` [r,
+    words] uint32 of a ``d``-wide source (``_row_words``)."""
+    as_f32 = lambda bits: jax.lax.bitcast_convert_type(bits, jnp.float32)
+    if words.shape[1] == d:
+        return [as_f32(words)]
+    return [as_f32(words << 16), as_f32(words & jnp.uint32(0xFFFF0000))]
+
+
+def _for_chunks(rows, body):
+    """``body(chunk, first row, rows in it)`` over a tile of ``rows`` rows,
+    ``_ROW_CHUNK`` at a time, ``chunk`` the index that slices them out of a
+    ref: one traced body in a loop where the tile is whole chunks (a
+    step's set-up is traced and lowered, not only run), static slices
+    otherwise."""
+    from jax.experimental import pallas as pl
+
+    if rows % _ROW_CHUNK or rows == _ROW_CHUNK:
+        for r0 in range(0, rows, _ROW_CHUNK):
+            count = min(_ROW_CHUNK, rows - r0)
+            body(slice(r0, r0 + count), r0, count)
+        return
+
+    def chunk(c, carry):
+        r0 = pl.multiple_of(c * _ROW_CHUNK, _ROW_CHUNK)
+        body(pl.ds(r0, _ROW_CHUNK), r0, _ROW_CHUNK)
+        return carry
+
+    jax.lax.fori_loop(0, rows // _ROW_CHUNK, chunk, 0)
+
+
+def _lane_sums(x):
+    """A row's sum over ``x``'s columns, as far as lane tiles add to one
+    another: [rows, _LANES] partial sums whose own sum is the row's (the
+    caller's, outside the kernel: a column one lane wide crosses a kernel's
+    boundary an element a DMA), or [rows, 1] where the width is no lane
+    tiles."""
+    width = x.shape[1]
+    if width % _LANES:
+        return x.sum(axis=1, keepdims=True)
+    return sum(x[:, c:c + _LANES] for c in range(0, width, _LANES))
+
+
+def _lane_slices(pieces):
+    width = pieces[0].shape[1]
+    return [slice(i * width, (i + 1) * width) for i in range(len(pieces))]
+
+
+# one-row copies started, and waited for in one wait, a step of the fetch
+# loops: an index's load and the two addresses are a chain of scalar work a
+# copy, and several chains share bundles
+_FETCH_UNROLL = 8
+
+
+def _fetch_rows(src_hbm, buf, sem, count, src_row):
+    """``count`` one-row copies ``src_hbm[src_row(i)] -> buf[i]``, all in
+    flight before the first is waited for."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    def start(i):
+        # the row clamped here, and the slot a loop's counter: every copy is
+        # inside both arrays, which is what lets the callers compile without
+        # Mosaic's own check of each, two traps a copy and most of its cost
+        row = jnp.clip(src_row(i), 0, src_hbm.shape[0] - 1)
+        pltpu.make_async_copy(src_hbm.at[row], buf.at[i], sem).start()
+
+    def wait(rows):
+        # a wait is for bytes: any copy of as many rows stands for them
+        pltpu.make_async_copy(src_hbm.at[pl.ds(0, rows)],
+                              buf.at[pl.ds(0, rows)], sem).wait()
+
+    def start_many(j, carry):
+        for u in range(_FETCH_UNROLL):
+            start(j * _FETCH_UNROLL + u)
+        return carry
+
+    def start_one(i, carry):
+        start(i)
+        return carry
+
+    def wait_many(j, carry):
+        wait(_FETCH_UNROLL)
+        return carry
+
+    def wait_one(i, carry):
+        wait(1)
+        return carry
+
+    many = count // _FETCH_UNROLL
+    rest = many * _FETCH_UNROLL, count
+    jax.lax.fori_loop(0, many, start_many, 0)
+    jax.lax.fori_loop(*rest, start_one, 0)
+    jax.lax.fori_loop(0, many, wait_many, 0)
+    jax.lax.fori_loop(*rest, wait_one, 0)
+
+
+def _live_tiles(table, groups, most):
+    """The row tiles the walk ``table`` visits are ``0 .. its last step's``:
+    the groups abut from row 0, so a tile two groups share counts once."""
+    return _step_tile(table, groups, most, table[-1] - 1) + 1
+
+
+def _gather_kernel(table, token, src_hbm, *refs, groups, row_tile, d, scaled,
+                   dotted):
+    """Row tile ``i`` of the walk: ``o[r] = src[token[r]]`` (times
+    ``scale[r]``) for its rows under ``ends[-1]``, zeros for the rest of the
+    tile; ``dotted``: also ``dot[r] = <other[r], src[token[r]]>`` in
+    float32, ``other`` read only where the row holds an assignment."""
+    from jax.experimental import pallas as pl
+
+    refs = list(refs)
+    scale_ref = refs.pop(0) if scaled else None
+    other_ref = refs.pop(0) if dotted else None
+    o_ref = refs.pop(0)
+    dot_ref = refs.pop(0) if dotted else None
+    buf, sem = refs
+    row0 = pl.program_id(0) * row_tile
+    held = jnp.clip(table[groups] - row0, 0, row_tile)
+    _fetch_rows(src_hbm, buf, sem, held, lambda r: token[row0 + r])
+
+    def turn(rows, first, count):
+        pieces = _unpacked(buf[rows].reshape(count, -1), d)
+        lanes = _lane_slices(pieces)
+        inside = first + jax.lax.broadcasted_iota(
+            jnp.int32, (count, 1), 0) < held
+        if dotted:
+            dot_ref[rows] = _lane_sums(sum(
+                jnp.where(inside, other_ref[rows, at].astype(jnp.float32)
+                          * piece, 0.0) for at, piece in zip(lanes, pieces)))
+        for at, piece in zip(lanes, pieces):
+            if scaled:
+                piece = piece * _lanes(scale_ref[rows], piece.shape[1])
+            o_ref[rows, at] = jnp.where(inside, piece, 0.0).astype(
+                o_ref.dtype)
+
+    _for_chunks(row_tile, turn)
+
+
+@functools.partial(jax.jit, inline=True,
+                   static_argnames=("row_tile", "dtype", "interpret"))
+def _gather(src, token, table, scale=None, other=None, *, row_tile, dtype,
+            interpret):
+    """``src`` [n, d] by ``token`` [rows] over the walk ``table`` to [rows, d]
+    ``dtype`` (and, with ``other`` [rows, d], the rows' dots with it, [rows]
+    float32); ``scale`` [rows] float32."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    rows, d = token.shape[0], src.shape[1]
+    words = _row_words((src,), row_tile=row_tile, interpret=interpret)
+    groups = _table_groups(table, rows, row_tile)
+    most = _most_steps(rows, row_tile, groups)
+    tile = lambda width: pl.BlockSpec((row_tile, width),
+                                      lambda i, *_: (i, 0))
+    # a scalar a row crosses the boundary a lane tile wide (the scale
+    # replicated, the dots as the partial sums of ``_lane_sums``)
+    lanes = (_LANES, 1)[words.shape[-1] % _LANES != 0]
+    operands, in_specs = [words], [pl.BlockSpec(memory_space=pl.ANY)]
+    out_shape, out_specs = [jax.ShapeDtypeStruct((rows, d), dtype)], [tile(d)]
+    if scale is not None:
+        operands.append(jnp.broadcast_to(scale[:, None], (rows, lanes)))
+        in_specs.append(tile(lanes))
+    if other is not None:
+        operands.append(other)
+        in_specs.append(tile(d))
+        out_shape.append(jax.ShapeDtypeStruct((rows, lanes), jnp.float32))
+        out_specs.append(tile(lanes))
+    kernel = functools.partial(
+        _gather_kernel, groups=groups, row_tile=row_tile, d=d,
+        scaled=scale is not None, dotted=other is not None)
+    out = pl.pallas_call(
+        kernel, out_shape=out_shape,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(_live_tiles(table, groups, most),),
+            in_specs=in_specs, out_specs=out_specs,
+            scratch_shapes=[pltpu.VMEM((row_tile,) + words.shape[1:],
+                                       jnp.uint32),
+                            pltpu.SemaphoreType.DMA(())]),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",), disable_bounds_checks=True),
+        interpret=interpret)(table, token, *operands)
+    return out[0] if other is None else (out[0], out[1].sum(axis=1))
+
+
+def _table_groups(table, rows, row_tile):
+    """How many groups the walk ``table`` of ``group_tiles`` has, from its
+    length."""
+    return (table.shape[0] - 2 * -(-rows // row_tile)) // 3
+
+
+def combine_plan(top_k, d, dtype):
+    """The token tile of ``combine_rows``: the largest power of two, from 8
+    to 256, whose ``top_k`` fetched rows a token fit ``_COMBINE_VMEM`` as
+    words (128 at top 6 of 2560 and at top 8 of 2048 bfloat16 columns)."""
+    words = _words_a_row(dtype, d)
+    tile = 8
+    while tile < _MAX_TOKEN_TILE \
+            and 2 * tile * top_k * words * 4 <= _COMBINE_VMEM:
+        tile *= 2
+    return tile
+
+
+def _combine_kernel(counts, row_ref, token_ref, *refs, token_tile, d,
+                    weighted):
+    """Token tile ``j``: ``o[t] = Σ w · src[row]`` over the tile's
+    ``counts[j]`` assignments that have a row, listed ahead of the others
+    (``row``, ``token`` within the tile, ``w``): each row one DMA, added to
+    its token's float32 sum as it came (whole words a row), the sums turned
+    into lanes and rounded once a tile. An assignment with no row is not in
+    the list: not fetched, not read, not multiplied."""
+    from jax.experimental import pallas as pl
+
+    refs = list(refs)
+    w_ref = refs.pop(0) if weighted else None
+    src_hbm, o_ref, buf, sums, sem = refs
+    count = counts[pl.program_id(0)]
+    _fetch_rows(src_hbm, buf, sem, count, lambda i: row_ref[0, i])
+    sums[...] = jnp.zeros(sums.shape, jnp.float32)
+
+    def add(i, carry):
+        for at, piece in enumerate(_unpacked(buf[i], d)):
+            if weighted:
+                piece = piece * w_ref[0, i]
+            sums[at, token_ref[0, i]] += piece
+        return carry
+
+    jax.lax.fori_loop(0, count, add, 0)
+    width = sums.shape[-1]
+
+    def turn(rows, _, count):
+        for at in range(sums.shape[0]):
+            o_ref[rows, at * width:(at + 1) * width] = sums[at, rows].reshape(
+                count, width).astype(o_ref.dtype)
+
+    _for_chunks(token_tile, turn)
+
+
+@functools.partial(jax.jit, inline=True,
+                   static_argnames=("row_tile", "dtype", "interpret"))
+def _combine(srcs, pos, table, w=None, *, row_tile, dtype, interpret):
+    """The sum of ``srcs`` (a tuple, each [rows, d]), laid out as words over
+    the walk ``table``'s row tiles, by ``pos`` [n, top_k] (-1: no row) and
+    ``w`` [n, top_k] float32 (None: ones) to [n, d] ``dtype``."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    (n, top_k), d = pos.shape, srcs[0].shape[1]
+    words = _row_words(srcs, table, row_tile=row_tile, interpret=interpret)
+    token_tile = combine_plan(top_k, d, srcs[0].dtype)
+    tiles, slots = -(-n // token_tile), token_tile * top_k
+    # a tile's assignments that have a row, ahead of those that have none:
+    # the kernel's scalar loops are as long as the rows it fetches
+    a_tile = lambda x, fill: jnp.pad(
+        x.reshape(-1), (0, tiles * slots - n * top_k),
+        constant_values=fill).reshape(tiles, slots)
+    row = a_tile(pos, -1)
+    token = jnp.broadcast_to(jnp.arange(slots, dtype=jnp.int32) // top_k,
+                             row.shape)
+    listed = jax.lax.sort(
+        ((row < 0).astype(jnp.int32), row, token)
+        + (() if w is None else (a_tile(w.astype(jnp.float32), 0.0),)),
+        dimension=1, num_keys=1, is_stable=True)[1:]
+    counts = (row >= 0).sum(1, dtype=jnp.int32)
+    scalars = pl.BlockSpec((None, 1, slots), lambda j, *_: (j, 0, 0),
+                           memory_space=pltpu.SMEM)
+    kernel = functools.partial(_combine_kernel, token_tile=token_tile, d=d,
+                               weighted=w is not None)
+    return pl.pallas_call(
+        kernel, out_shape=jax.ShapeDtypeStruct((n, d), dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(tiles,),
+            in_specs=[scalars] * len(listed)
+            + [pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((token_tile, d), lambda j, *_: (j, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((slots,) + words.shape[1:], jnp.uint32),
+                pltpu.VMEM((d // words.shape[-1], token_tile)
+                           + words.shape[1:], jnp.float32),
+                pltpu.SemaphoreType.DMA(())]),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",), disable_bounds_checks=True),
+        interpret=interpret)(counts, *(x[:, None] for x in listed), words)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def gather_rows(src, order, pos, table, row_tile, copies=1):
+    """``rows[r] = src[order[r] // top_k]`` [rows, d] in ``src``'s dtype for
+    the row tiles the walk ``table = group_tiles(group_sizes, rows,
+    row_tile)`` visits: ``order`` [rows] int32 says which of the ``n x
+    top_k`` assignments fills row ``r``, ``pos`` [n, top_k] int32 is its
+    inverse on the rows under the groups' sum (-1: the assignment has no
+    row). Rows of a visited tile past the groups' sum are zeros; **a row
+    tile past the last group is never written**, as ``grouped_matmul``
+    leaves it and never reads it. Returns a tuple of ``copies`` references
+    to the one array, a reader each: their cotangents come back apart and
+    are added a row tile at a time, where XLA's own sum would pass over the
+    whole buffer. The cotangent for ``src`` is ``combine_rows`` of that sum
+    with unit weights: a token's sum over its rows, in float32, rounded
+    once; no cotangent is read from a row outside every group."""
+    return (_gather(src, order // pos.shape[1], table, row_tile=row_tile,
+                    dtype=src.dtype, interpret=_interpret_mode()),) * copies
+
+
+def _gather_rows_fwd(src, order, pos, table, row_tile, copies):
+    return gather_rows(src, order, pos, table, row_tile, copies), (pos, table)
+
+
+def _gather_rows_bwd(row_tile, copies, residuals, gs):
+    pos, table = residuals
+    return _combine(gs, pos, table, row_tile=row_tile, dtype=gs[0].dtype,
+                    interpret=_interpret_mode()), None, None, None
+
+
+gather_rows.defvjp(_gather_rows_fwd, _gather_rows_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def combine_rows(out, w, order, pos, table, row_tile):
+    """``y[n] = Σ_k w[n, k] · out[pos[n, k]]`` [n, d] in ``out``'s dtype over
+    the assignments with ``pos[n, k] >= 0``: the weighted rows of the buffer
+    added back to their tokens, a token tile at a time, in float32 and
+    rounded once. An assignment without a row is skipped, not fetched and
+    multiplied by zero (``0 * NaN``), and no row outside every group is
+    read. The cotangents: for ``out``, ``gather_rows`` of ``dy`` times the
+    row's weight; for ``w``, the dot of ``out[r]`` and ``dy``'s row, taken in
+    the same visit of the row tile (``order``, ``table``, ``row_tile`` as
+    ``gather_rows`` takes them: the backward follows the walk)."""
+    return _combine((out,), pos, table, w, row_tile=row_tile,
+                    dtype=out.dtype, interpret=_interpret_mode())
+
+
+def _combine_rows_fwd(out, w, order, pos, table, row_tile):
+    return combine_rows(out, w, order, pos, table, row_tile), \
+        (out, w, order, pos, table)
+
+
+def _combine_rows_bwd(row_tile, residuals, dy):
+    out, w, order, pos, table = residuals
+    rows = order.shape[0]
+    # the rows' weights in the rows' order, ``w.reshape(-1)[order]`` on the
+    # rows that hold an assignment: sorted by row, which on the chip is an
+    # eighth of XLA's gather of as many scalars
+    w_rows = jax.lax.sort((jnp.where(pos < 0, rows, pos).reshape(-1),
+                           w.reshape(-1)), num_keys=1)[1][:rows]
+    d_out, dots = _gather(
+        dy, order // pos.shape[1], table, w_rows, out,
+        row_tile=row_tile, dtype=out.dtype, interpret=_interpret_mode())
+    d_w = jnp.where(pos >= 0, dots[jnp.maximum(pos, 0)], 0.0)
+    return d_out, d_w.astype(w.dtype), None, None, None
+
+
+combine_rows.defvjp(_combine_rows_fwd, _combine_rows_bwd)
+
+
+# ---------------------------------------------------------------------------
 # fused LSTM cell ("Optimizing Performance of Recurrent Neural Networks on
 # GPUs", arxiv 1604.01946; the cuDNN RNN fusion strategy, arxiv 1410.0759):
 # one kernel per time step fusing the recurrent matmul epilogue

@@ -347,6 +347,64 @@ def test_the_pallas_products_leave_no_trace_of_the_rows_in_no_group(
                                    atol=1e-5 * float(jnp.abs(w).max()))
 
 
+def _equations(eqn):
+    """An equation and those of every jaxpr under it."""
+    yield eqn
+    for value in eqn.params.values():
+        for item in (value if isinstance(value, (list, tuple)) else [value]):
+            item = getattr(item, "jaxpr", item)
+            for sub in getattr(item, "eqns", ()):
+                yield from _equations(sub)
+
+
+@pytest.mark.parametrize("dtype,row_buffer", [
+    ("float32", 8.0), ("bfloat16", 8.0), ("float32", 2.0), ("float32", 0.5)])
+def test_the_rows_move_the_same_along_the_walk_and_over_the_buffer(
+        dtype, row_buffer, monkeypatch):
+    """The layer at the rehearsal configuration (top 2 of 16, 2 held, a
+    shared expert, weights scaled by 2.5), loss and every gradient: the
+    Pallas path, whose rows move one DMA each over the row tiles the walk
+    visits (``gather_rows``, ``combine_rows``: no gather, no scatter-add and
+    no select over the buffer is left in its jaxpr), against the
+    ``ragged_dot`` path's XLA gather, float32 select and scatter-add; with a
+    buffer of every assignment, of twice the balanced load, and of half of
+    it (assignments left out, on both paths the same ones)."""
+    config = tiny_config()
+    h, lp = layer_inputs(config)
+    ex, ep = program_experts(config, lp, row_buffer=row_buffer)
+    ep, h = jax.tree.map(lambda a: a.astype(dtype), (ep, h))
+
+    def loss(ep, h):
+        y, stats = expert_ffn(ex, ep, h)
+        return jnp.square(y.astype(jnp.float32)).sum(), stats
+
+    (want, stats), want_grads = jax.value_and_grad(loss, (0, 1), True)(ep, h)
+    assert (int(stats["rows_over_buffer"]) > 0) == (row_buffer < 1)
+    monkeypatch.setenv("DL4J_TPU_PALLAS_INTERPRET", "1")
+    (got, _), got_grads = jax.value_and_grad(loss, (0, 1), True)(ep, h)
+    tol = 1e-4 if dtype == "float32" else 3e-2
+    np.testing.assert_allclose(got, want, rtol=tol)
+    for g, w in zip(jax.tree.leaves(got_grads), jax.tree.leaves(want_grads)):
+        g, w = np.asarray(g, np.float32), np.asarray(w, np.float32)
+        assert np.isfinite(g).all()
+        np.testing.assert_allclose(g, w, rtol=tol,
+                                   atol=tol * np.abs(w).max())
+    jaxpr = jax.make_jaxpr(jax.grad(lambda ep, h: loss(ep, h)[0], (0, 1)))(
+        ep, h)
+    found = [sub for eqn in jaxpr.eqns for sub in _equations(eqn)]
+    # the gather and the combine, each behind the pass that lays its source
+    # out as words, around the three products; backward two, six, two (the
+    # combine's is the gather with the rows' weights and dots, the gather's
+    # the combine with unit weights)
+    assert sum(e.primitive.name == "pallas_call" for e in found) == 7 + 10
+    # and XLA moves no row: what it still gathers and scatters (the chosen
+    # logits, the rows' weights and numbers) is narrower than one
+    d = h.shape[-1]
+    assert not [e for e in found
+                if e.primitive.name in ("gather", "scatter", "scatter-add")
+                and e.outvars[0].aval.shape[-1:] == (d,)]
+
+
 def test_the_drivers_window_fails_when_a_row_was_left_out():
     """``moe.rows_over_buffer`` > 0 fails every step of the window."""
     from benchmark import run

@@ -481,6 +481,12 @@ def test_save_and_load_round_trip_the_gate_and_the_counters(seeded, tmp_path):
 # the interpreter flag the three grouped products of a sparse layer are
 # interpreted Pallas calls (``pallas_kernels.grouped_matmul``) where
 # ``ragged_dot``'s masked batched products were; the three GPT-2 steps stand.
+# PR 37 renewed both twins once more: ``dispatch`` sorts a second time for
+# each assignment's row (``Routing.pos``), and under the interpreter flag a
+# sparse layer's rows move in interpreted Pallas calls
+# (``pallas_kernels.gather_rows`` / ``combine_rows`` and the pass that lays
+# their sources out as words) where XLA's gather, the two selects by
+# ``valid`` and the float32 scatter-add were; the three GPT-2 steps stand.
 LOWERED_BEFORE = {
     "gpt2": "f19a640cb05302ba6b1a96247726095553a0daa69a62f3ba0adb18d9229d8743",
     "gpt2_flash_bf16":
@@ -488,9 +494,9 @@ LOWERED_BEFORE = {
     "gpt2_remat_rope":
         "8e7c5ef5fab6e2288fcbbc65e11159aa928b9b4301d789f30c51f345a9493539",
     "laguna_tiny":
-        "31c39a2ef5faf78716e8180917c86bc0e748e973f9030272a4dc45e9025de98b",
+        "b2f11698d2e441e16d08c1e1af0bd65424e3d78f59f17a1566e94c20bbd1926f",
     "smallthinker_tiny":
-        "63257facb442894373b82d84f9be12c09093286bfabbedbe9af8a21136e77973",
+        "48483549c7c0ec6618763e954a916134d00e71191a33cfec8a5f4f842356c362",
 }
 
 
@@ -501,7 +507,8 @@ def test_the_defaults_leave_the_gpt2_and_laguna_steps_as_they_were(
     text it lowered to before the fields were there. Renewed since, each
     once and on purpose (the comment above ``LOWERED_BEFORE``): the two flash
     steps by PR 33, ``laguna_tiny`` by PR 34 and PR 35, ``smallthinker_tiny``
-    by PR 35 (the expert layer's groups, masks and product)."""
+    by PR 35 (the expert layer's groups, masks and product), both by PR 37
+    (the expert layer's rows move in Pallas calls)."""
     import hashlib
     import re
     monkeypatch.setenv("DL4J_TPU_PALLAS_INTERPRET", "1")

@@ -866,6 +866,142 @@ class TestGroupedMatmul:
             pk.grouped_plan(256, 100_000, 100_000, 2)
 
 
+# (tokens, experts, top_k, held, row_buffer, d, dtype, the expert no token
+# chooses): the rows around the grouped products, ``gather_rows`` and
+# ``combine_rows``, on the indices ``expert_layer.dispatch`` makes
+MOVE_CASES = {
+    # the fifth cell's twin: top 6, a buffer of every assignment of which a
+    # quarter is live, bfloat16 columns packed two a word
+    "top6_quarter_of_the_buffer_live": (64, 16, 6, 4, 4.0, 256, "bfloat16",
+                                        None),
+    # the third cell's twin: top 8, half the buffer live
+    "top8_half_of_the_buffer_live": (64, 32, 8, 4, 2.0, 256, "bfloat16",
+                                     None),
+    # a held expert with no assignment: a group of no rows in the walk
+    "an_empty_group": (48, 8, 2, 4, 2.0, 128, "float32", 2),
+    # more assignments than rows: the last ones have no row (pos -1)
+    "rows_over_buffer": (64, 8, 3, 4, 0.5, 128, "float32", None),
+    # float32 rows of a width that is no lane tile, one word a column
+    "float32_narrow_rows": (40, 8, 3, 2, 4.0, 48, "float32", None),
+    # bfloat16 rows too narrow to pack go as float32 words
+    "bfloat16_narrow_rows": (40, 8, 3, 2, 4.0, 64, "bfloat16", None),
+    # a row tile of two chunks of 32: the kernels' chunk loop is a loop
+    "row_tiles_of_two_chunks": (256, 8, 4, 4, 2.0, 256, "bfloat16", None),
+}
+
+
+class TestRowMovement:
+    """``gather_rows`` and ``combine_rows`` against ``src[token]`` and
+    ``.at[token].add`` in float32 on the rows inside the groups, forward and
+    all three gradients, the buffer's tail and the dead tiles poisoned."""
+
+    @staticmethod
+    def _case(case):
+        from deeplearning4j_tpu.models import expert_layer
+        tokens, experts, top_k, held, buffer, d, dtype, unused = \
+            MOVE_CASES[case]
+        rng = np.random.RandomState(len(case))
+        scores = rng.rand(tokens, experts)
+        if unused is not None:
+            scores[:, unused] = -1.0
+        chosen = jnp.asarray(np.argsort(-scores, -1)[:, :top_k], jnp.int32)
+        ex = expert_layer.Experts(experts, top_k, 8, held=(0, held),
+                                  row_buffer=buffer)
+        routing = expert_layer.Routing(
+            jnp.asarray(rng.rand(tokens, top_k) + 0.1, jnp.float32),
+            *expert_layer.dispatch(ex, chosen, tokens))
+        tile = expert_layer._row_tile(ex, tokens)
+        rows = routing.order.shape[0]
+        src = jnp.asarray(rng.randn(tokens, d), dtype)
+        out = jnp.asarray(rng.randn(rows, d), dtype)
+        return routing, tile, src, out, rng
+
+    @pytest.mark.parametrize("case", sorted(MOVE_CASES))
+    def test_forward_and_three_gradients_with_poisoned_dead_rows(
+            self, case, interpret_pallas):
+        import jax
+        from deeplearning4j_tpu.ops import pallas_kernels as pk
+        routing, tile, src, out, rng = self._case(case)
+        w, order, pos, valid, sizes, stats, table = routing
+        tokens, top_k = w.shape
+        rows, d = out.shape
+        live = int(valid.sum())
+        assert table is not None and 0 < live <= rows
+        token = np.asarray(order) // top_k
+        sizes = np.asarray(sizes)
+        walked = np.asarray(table[len(sizes) + 1:-1]).reshape(2, -1)[
+            1, :int(table[-1])]
+        # what the cases are for
+        if case.startswith("top"):
+            share = {"top6_quarter_of_the_buffer_live": 4,
+                     "top8_half_of_the_buffer_live": 2}[case]
+            assert 0.75 * rows < share * live < 1.25 * rows
+            assert (np.bincount(walked) > 1).any()  # a tile two groups share
+            assert (np.asarray(pos) < 0).all(axis=1).any()   # a token left out
+        if case == "an_empty_group":
+            assert sizes[2] == 0 and sizes.sum() == live
+        if case == "rows_over_buffer":
+            assert int(stats["rows_over_buffer"]) > 0 and live == rows
+        if case == "row_tiles_of_two_chunks":
+            assert tile == 2 * pk._ROW_CHUNK
+        # pos is the inverse of order on the valid rows, -1 elsewhere
+        flat_pos = np.asarray(pos).reshape(-1)
+        np.testing.assert_array_equal(flat_pos[np.asarray(order)[:live]],
+                                      np.arange(live))
+        assert (flat_pos >= 0).sum() == live
+        visited = (int(walked.max()) + 1) * tile
+        f32 = lambda x: np.asarray(x, np.float32)
+        tol = 1e-5 if out.dtype == jnp.float32 else 1e-2
+        poison = lambda x: jnp.where(valid[:, None], x, jnp.nan)
+
+        # the gather: a row's own bits inside the groups, zeros in the rest
+        # of a visited tile, nothing written past the walk; two references
+        # to the one array, for two readers
+        (got, again), vjp = jax.vjp(
+            lambda s: pk.gather_rows(s, order, pos, table, tile, 2), src)
+        assert again is got
+        np.testing.assert_array_equal(f32(got)[:live], f32(src)[token[:live]])
+        assert (f32(got)[live:visited] == 0).all()
+        assert np.isnan(f32(got)[visited:]).all()
+        # its transpose: the readers' cotangents summed as their dtype sums
+        # them, then a token's sum over its rows, in float32
+        g = [jnp.asarray(rng.randn(rows, d), out.dtype) for _ in range(2)]
+        d_src, = vjp(tuple(poison(x) for x in g))
+        want = np.zeros((tokens, d), np.float32)
+        np.add.at(want, token[:live], f32(g[0] + g[1])[:live])
+        np.testing.assert_allclose(f32(d_src), want, rtol=tol, atol=tol)
+
+        # the combine, the rows outside the groups NaN
+        w_rows = f32(w).reshape(-1)[np.asarray(order)]
+        y, vjp = jax.vjp(
+            lambda o, w: pk.combine_rows(o, w, order, pos, table, tile),
+            poison(out), w)
+        want = np.zeros((tokens, d), np.float32)
+        np.add.at(want, token[:live], w_rows[:live, None] * f32(out)[:live])
+        np.testing.assert_allclose(f32(y), want, rtol=tol, atol=tol)
+        dy = jnp.asarray(rng.randn(tokens, d), out.dtype)
+        d_out, d_w = vjp(dy)
+        np.testing.assert_allclose(
+            f32(d_out)[:live], w_rows[:live, None] * f32(dy)[token[:live]],
+            rtol=tol, atol=tol)
+        assert (f32(d_out)[live:visited] == 0).all()
+        want = np.zeros(tokens * top_k, np.float32)
+        want[np.asarray(order)[:live]] = (
+            f32(out)[:live] * f32(dy)[token[:live]]).sum(1)
+        np.testing.assert_allclose(f32(d_w).reshape(-1), want, rtol=tol,
+                                   atol=tol * np.abs(want).max())
+
+    def test_the_token_tile_follows_top_k_and_the_width(self):
+        from deeplearning4j_tpu.ops import pallas_kernels as pk
+        # the fifth cell, the third, float32 rows, the twins
+        assert [pk.combine_plan(*x) for x in (
+            (6, 2560, "bfloat16"), (8, 2048, "bfloat16"),
+            (6, 2560, "float32"), (2, 64, "bfloat16"))] == [128, 128, 64, 256]
+        for top_k, d, dtype in ((6, 2560, "bfloat16"), (8, 2048, "bfloat16")):
+            tile = pk.combine_plan(top_k, d, dtype)
+            assert tile * top_k * (d // 2) * 4 <= pk._COMBINE_VMEM
+
+
 class TestSlidingWindow:
     """Causal sliding-window attention: the kernels mask entries more than
     window-1 positions in the past and skip fully out-of-window blocks."""

@@ -135,10 +135,12 @@ def test_per_layer_scopes_name_the_kernels_of_their_layer_type(mixed_stacks):
     """The flash kernels of a window layer sit under ``block.attn_window``,
     forward and (rematerialised) backward, those of a full layer under
     ``block.attn_full``; no layer with a per-layer list enters ``attn``; the
-    expert layer's grouped products sit under ``block.experts``."""
+    expert layer's grouped products sit under ``block.experts`` and the
+    kernels that move its rows under ``block.moe_dispatch``."""
     calls = {s for s in mixed_stacks if s.endswith("/pallas_call")}
     grouped = {s for s in calls if "experts" in scope_reduce.tokens(s)}
-    kernels = calls - grouped
+    moved = {s for s in calls if "moe_dispatch" in scope_reduce.tokens(s)}
+    kernels = calls - grouped - moved
     assert kernels
     for s in kernels:
         assert len({"attn_full", "attn_window"} & scope_reduce.tokens(s)) == 1
@@ -153,6 +155,11 @@ def test_per_layer_scopes_name_the_kernels_of_their_layer_type(mixed_stacks):
     assert any("transpose" in scope_reduce.tokens(s) for s in grouped)
     assert any("transpose" not in scope_reduce.tokens(s) for s in grouped)
     assert not any(s.endswith("ragged_dot_general") for s in mixed_stacks)
+    # ... and since PR 37 the gather and the combine around them, forward and
+    # backward, which ``moe_dispatch_ms`` times and the roofline does not
+    assert any("transpose" in scope_reduce.tokens(s) for s in moved)
+    assert any("transpose" not in scope_reduce.tokens(s) for s in moved)
+    assert not grouped & moved
 
 
 def test_lm_scopes_carry_no_layer_index(lm_stacks):

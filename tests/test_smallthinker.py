@@ -302,8 +302,15 @@ def test_the_grouped_products_are_pallas_calls_under_the_experts_scope(
     assert not plain["pallas_call"] and len(plain["ragged_dot_general"]) == 3
     monkeypatch.setenv("DL4J_TPU_PALLAS_INTERPRET", "1")
     own = products(conf)
-    assert not own["ragged_dot_general"] and len(own["pallas_call"]) == 3
-    assert all("block.experts" in stack for stack in own["pallas_call"])
+    # ... and the rows move in four more (the gather and the combine, each
+    # behind the pass that lays its source out as words), under the
+    # dispatch's scope: moe_dispatch_ms times them and the roofline's reader
+    # does not
+    stacks = own["pallas_call"]
+    assert not own["ragged_dot_general"] and len(stacks) == 7
+    assert ["block.experts" in stack for stack in stacks] \
+        == [False, False, True, True, True, False, False]
+    assert sum("block.moe_dispatch" in stack for stack in stacks) == 4
 
 
 def test_no_dense_ffn_leaf_and_the_served_path_refuses_by_name(seeded):
@@ -380,6 +387,38 @@ def program_layer(config, u, r, lp):
 def reference_layer(config, u, r, lp):
     return jnp.stack([ref.experts(config, "float32", ur, ref.routing(
         config, "float32", rr, lp["router"]), lp) for ur, rr in zip(u, r)])
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_the_rows_move_the_same_along_the_walk_and_over_the_buffer(
+        dtype, monkeypatch):
+    """The layer at the rehearsal configuration (top 3 of 8 by softmax, 2
+    held ReGLU experts, the router reading another tensor than the experts,
+    a buffer of every assignment), loss and every gradient (the experts'
+    input, the router's input, the router and the three expert weights): the
+    Pallas path, whose rows move one DMA each over the row tiles the walk
+    visits, against the ``ragged_dot`` path's XLA gather, float32 select and
+    scatter-add over the whole buffer."""
+    config = tiny_config()
+    u, r, lp = jax.tree.map(lambda a: a.astype(dtype), layer_inputs(config))
+
+    def loss(u, r, lp):
+        y, stats = program_layer(config, u, r, lp)
+        return jnp.square(y.astype(jnp.float32)).sum(), stats
+
+    grad = jax.value_and_grad(loss, (0, 1, 2), True)
+    (want, stats), want_grads = grad(u, r, lp)
+    assert int(stats["rows_over_buffer"]) == 0
+    assert 0 < int(stats["local_rows"]) < ROWS * SEQ * 3
+    monkeypatch.setenv("DL4J_TPU_PALLAS_INTERPRET", "1")
+    (got, _), got_grads = grad(u, r, lp)
+    tol = 1e-4 if dtype == "float32" else 3e-2
+    np.testing.assert_allclose(got, want, rtol=tol)
+    for g, w in zip(jax.tree.leaves(got_grads), jax.tree.leaves(want_grads)):
+        g, w = np.asarray(g, np.float32), np.asarray(w, np.float32)
+        assert np.isfinite(g).all()
+        np.testing.assert_allclose(g, w, rtol=tol,
+                                   atol=tol * np.abs(w).max())
 
 
 def share_of(config, lp, first, count):
@@ -472,8 +511,9 @@ def test_peak_group_rows_against_a_hand_count(seeded):
     # a buffer of half the balanced load does not hide the peak
     small = Experts(n_experts=8, top_k=3, d_expert=32, held=(0, 2),
                     row_buffer=0.5, scoring="softmax")
-    assert int(expert_layer.dispatch(small, jnp.asarray(chosen), len(chosen))
-               [3]["peak_group_rows"]) == max(by_hand)
+    assert int(expert_layer.Routing(None, *expert_layer.dispatch(
+        small, jnp.asarray(chosen), len(chosen))).stats["peak_group_rows"]) \
+        == max(by_hand)
 
     config, weights, (tokens, *_) = seeded
     lm = program(config, weights)

@@ -17,10 +17,21 @@ call of the forward, of the input gradient and of the weight gradient:
   the input gradient, (k, n block) of the weight gradient's output)``, one
   list a product (``gate_up``, ``down``).
 
+``--move`` times the rows' movement around the products instead, at the same
+shapes and at a routing drawn evenly over all experts (a quarter and a half
+of the buffer live): ``pallas_kernels.gather_rows`` and ``combine_rows`` and
+the backward of each against XLA's ``take`` and float32 ``.at[].add`` over
+the whole buffer on the same indices, one JSON line each with the host-clock
+ms of a call and the ns a live row; the ``words`` lines time the pass that
+lays a source out as whole words a row (over the tokens for the gather, over
+the row tiles the walk visits for the combine), which each kernel call
+includes, and ``dispatch`` the index half that both forms share.
+
 ``--check`` poisons the operands' rows past the groups with NaN and compares
 result and gradients on the rows inside the groups with a float32 loop over
-the experts. Without a TPU it exits non-zero: a CPU timing is no device
-number (PERF.md)."""
+the experts (``--move``: with ``src[token]`` and ``.at[token].add`` in
+float32). Without a TPU it exits non-zero: a CPU timing is no device number
+(PERF.md)."""
 
 from __future__ import annotations
 
@@ -39,6 +50,11 @@ import numpy as np
 CELLS = {
     "smallthinker": (98304, 24000, 2560, 768, 16),
     "laguna": (32768, 16400, 2048, 512, 32),
+}
+# the layer of each cell, for --move: (tokens, experts, top_k, row_buffer)
+LAYERS = {
+    "smallthinker": (16384, 64, 6, 4.0),
+    "laguna": (16384, 256, 8, 2.0),
 }
 
 
@@ -115,9 +131,119 @@ def gap(x, ref, rows=None):
             "rel": float(np.linalg.norm(x - ref) / max(np.linalg.norm(ref), 1e-30))}
 
 
+def move(args, platform):
+    """The ``--move`` lines: the two kernels and their backward against
+    XLA's whole-buffer forms, on the indices ``expert_layer.dispatch``
+    makes."""
+    from deeplearning4j_tpu.models import expert_layer
+    expert_layer.pallas_supported = lambda: True     # the walk, on any backend
+    for cell in args.cells.split(","):
+        move_cell(args, cell, platform)
+    return 0
+
+
+def four(how, gather, combine, flat, out, w, dy):
+    """A form's gather and combine and the backward of each, jitted, with
+    their operands."""
+    return [
+        (f"{how}.gather", jax.jit(gather), (flat,)),
+        (f"{how}.gather.bwd",
+         jax.jit(lambda flat, g: jax.vjp(gather, flat)[1](g)), (flat, out)),
+        (f"{how}.combine", jax.jit(combine), (out, w)),
+        (f"{how}.combine.bwd",
+         jax.jit(lambda out, w, g: jax.vjp(combine, out, w)[1](g)),
+         (out, w, dy))]
+
+
+def move_cell(args, cell, platform):
+    from deeplearning4j_tpu.models import expert_layer
+    from deeplearning4j_tpu.ops import pallas_kernels as pk
+    rows, _, d, de, held = CELLS[cell]
+    tokens, n_experts, top_k, buffer = LAYERS[cell]
+    tokens, d = tokens // args.shrink, d // args.shrink
+    ex = expert_layer.Experts(n_experts, top_k, de, held=(0, held),
+                              row_buffer=buffer)
+    keys = jax.random.split(jax.random.PRNGKey(37), 5)
+    chosen = jax.lax.top_k(
+        jax.random.normal(keys[0], (tokens, n_experts)), top_k)[1]
+    dispatch = jax.jit(lambda c: expert_layer.dispatch(ex, c, tokens))
+    order, pos, valid, _, stats, table = dispatch(chosen)
+    rows, live = order.shape[0], int(stats["local_rows"])
+    tile = expert_layer._row_tile(ex, tokens)
+    token = order // top_k
+    flat = jax.random.normal(keys[1], (tokens, d), jnp.bfloat16)
+    out = jax.random.normal(keys[2], (rows, d), jnp.bfloat16)
+    w = jax.random.uniform(keys[3], (tokens, top_k), jnp.float32)
+    dy = jax.random.normal(keys[4], (tokens, d), jnp.bfloat16)
+    nan = jnp.asarray(jnp.nan, jnp.bfloat16)
+    out_nan = jnp.where(valid[:, None], out, nan)
+
+    def xla_gather(flat):
+        return jnp.where(valid[:, None], flat[token], 0)
+
+    def xla_combine(out, w):
+        w_rows = jnp.where(valid, w.reshape(-1)[order], 0.0)
+        weighted = jnp.where(valid[:, None], out.astype(jnp.float32)
+                             * w_rows[:, None], 0.0)
+        return jnp.zeros(flat.shape, jnp.float32).at[token].add(
+            weighted).astype(flat.dtype)
+
+    def own_gather(flat):
+        return pk.gather_rows(flat, order, pos, table, tile)[0]
+
+    def own_combine(out, w):
+        return pk.combine_rows(out, w, order, pos, table, tile)
+
+    words = jax.jit(lambda x, *walk: pk._row_words(
+        (x,), *walk, row_tile=tile, interpret=pk._interpret_mode()))
+    calls = [("words.tokens", words, (flat,)),
+             ("words.live_tiles", words, (out, table)),
+             ("dispatch", dispatch, (chosen,))] \
+        + four("xla", xla_gather, xla_combine, flat, out, w, dy) \
+        + four("pallas", own_gather, own_combine, flat, out, w, dy)
+    results = {}
+    for name, fn, xs in calls:
+        line = {"cell": cell, "how": name, "rows": rows, "live_rows": live,
+                "tokens": tokens, "top_k": top_k, "d": d, "row_tile": tile,
+                "platform": platform}
+        try:
+            ms, _ = timed(fn, *xs)
+            if platform == "tpu":
+                line["ms"] = ms
+                line["ns_a_live_row"] = 1e6 * ms / live
+            if args.check and name.split(".")[0] in ("xla", "pallas"):
+                poisoned = tuple(out_nan if x is out else x for x in xs) \
+                    if name.startswith("pallas") else xs
+                results[name] = fn(*poisoned)
+        except Exception as e:  # noqa: BLE001
+            line["error"] = repr(e)[-600:]
+        print(json.dumps(line), flush=True)
+    if not args.check:
+        return
+    f32 = lambda x: np.asarray(x, np.float32)
+    inside = slice(0, live)
+    pairs = {
+        "gather": (f32(results["pallas.gather"])[inside],
+                   f32(results["xla.gather"])[inside]),
+        "gather.bwd": (f32(results["pallas.gather.bwd"][0]),
+                       f32(results["xla.gather.bwd"][0])),
+        "combine": (f32(results["pallas.combine"]),
+                    f32(results["xla.combine"])),
+        "combine.bwd.out": (f32(results["pallas.combine.bwd"][0])[inside],
+                            f32(results["xla.combine.bwd"][0])[inside]),
+        "combine.bwd.w": (f32(results["pallas.combine.bwd"][1]),
+                          f32(results["xla.combine.bwd"][1]))}
+    print(json.dumps({"cell": cell, "how": "poisoned_tail",
+                      "platform": platform,
+                      **{k: gap(a, b) for k, (a, b) in pairs.items()}}),
+          flush=True)
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--cells", default="smallthinker,laguna")
+    ap.add_argument("--move", action="store_true",
+                    help="time the rows' movement, not the products")
     ap.add_argument("--plans", default="{}",
                     help="{'<cell>.<product>': [(tile, fwd, da, dw)]}")
     ap.add_argument("--check", action="store_true")
@@ -129,6 +255,8 @@ def main():
     if platform != "tpu" and not args.allow_cpu:
         print("no TPU: a CPU timing is no device number", file=sys.stderr)
         return 1
+    if args.move:
+        return move(args, platform)
     from deeplearning4j_tpu.ops import pallas_kernels as pk
     own = getattr(pk, "grouped_matmul", None)
     plans = ast.literal_eval(args.plans)
