@@ -15,10 +15,11 @@ K block) pairs of a row and no others (``flash_walk``; a block pair the
 causal mask or the window leaves empty is no grid step), read from an int32
 table that reaches the kernels and their index maps by scalar prefetch.
 Inside a block the three share one step: ``flash_plan`` derives heads a
-grid step and compute tiles from the shapes, ``_branches`` says which blocks
-need a mask, ``_tiles`` which tiles of a block hold anything to compute; the
-walk says which step of a row writes the accumulators and which one
-finalises.
+grid step (or, with grouped-query attention, the K/V group a step holds,
+its query heads folded into the rows of each tile) and compute tiles from
+the shapes, ``_branches`` says which blocks need a mask, ``_tiles`` which
+tiles of a block hold anything to compute; the walk says which step of a
+row writes the accumulators and which one finalises.
 
 ``DL4J_TPU_FLASH_BWD=scan`` falls the backward to the mathematically
 identical lax.scan implementation
@@ -48,7 +49,8 @@ NEG_INF = -1e30
 
 # Grid steps of the flash kernels in the programs traced so far, summed over
 # the call sites (a layer's forward is one, its backward two: dQ and dK/dV),
-# each call's rows of n times the steps of its walk; and how many of them
+# each call's rows of the grid (n over the heads or the K/V group a step
+# holds) times the steps of its walk; and how many of them
 # hold an in-mask entry. They move where a program is traced, never in a
 # step. live / walked is 1 since the grid is the walk; under the rectangle
 # grid it was 136/256 on a causal row of 16 blocks, 31/256 under a window
@@ -58,6 +60,12 @@ _STEPS_WALKED = obs.gauge(
 _STEPS_LIVE = obs.gauge(
     "flash.steps_live", "Of flash.steps_walked, the steps on a block pair "
     "with an in-mask entry")
+# Of flash.steps_walked, the steps of the calls whose grid step holds a whole
+# K/V group (``FlashPlan.group``): all of them in a model whose attention is
+# grouped, none where it is not.
+_GROUPED_STEPS = obs.gauge(
+    "flash.grouped_steps", "Of flash.steps_walked, the steps that hold a "
+    "whole K/V group of query heads")
 # Of the live steps of the calls WITH A WINDOW traced so far (rows of n times
 # the walk's steps, as above), how many take the ``edge`` branch, every tile
 # masked by position, and not the unmasked ``full`` one (``_branches``): all
@@ -103,19 +111,32 @@ def pallas_supported():
 class FlashPlan(NamedTuple):
     """What one grid step of the flash kernels works on, beyond the
     ``(block_q, block_k)`` block the DMA moves: ``heads`` rows of n
-    (batch·heads) a step, and the ``(tile_q, tile_k)`` tile of scores the
-    products and the softmax are computed on inside the block."""
+    (batch·heads) a step, the ``(tile_q, tile_k)`` tile of scores the
+    products and the softmax are computed on inside the block, and
+    ``group``, the query heads of one K/V head a step holds, folded into the
+    rows of every tile (``heads`` is then 1)."""
     heads: int
     tile_q: int
     tile_k: int
+    group: int = 1
 
 
 # what the hungriest kernel's blocks and scratch may take of a core's VMEM
 # (16 MiB scoped by default on a v5e; a raised limit read slower there), the
 # tiles' temporaries left aside
 _VMEM_BUDGET = 8 * 2 ** 20
-# the rows of a step are unrolled: each is a copy of the step's code
+# the heads of an ungrouped step are unrolled: each is a copy of the step's
+# code (a K/V group's heads are not: they are folded into a tile's rows)
 _MAX_HEADS = 8
+# A step that holds a K/V group takes the group's rows of a Q block at once:
+# its blocks, scratch and tiles may take this much of a core's VMEM, and the
+# compiler is told so (``vmem_limit_bytes``; a v5e core has 128 MiB; a group
+# of 8 at block 512, d 128 takes 17.9 MiB in the forward, past the 16 scoped
+# by default)
+_GROUP_VMEM = 48 * 2 ** 20
+# the strips of tile_q rows a grouped step's block is cut into: each is a
+# copy of the step's code
+_MAX_STRIPS = 8
 
 
 def _flash_vmem_bytes(heads, block_q, block_k, d, itemsize):
@@ -132,8 +153,43 @@ def _flash_vmem_bytes(heads, block_q, block_k, d, itemsize):
                     + 2 * stat_cols + block_q * d * 4)
 
 
+def _group_vmem_bytes(group, block_q, block_k, tile_q, tile_k, d, itemsize):
+    """VMEM of the dQ kernel where a step holds a K/V group: the group's
+    Q-like blocks, one K/V block, lse and delta as one [1, group·block_q]
+    row each (eight sublanes of VMEM), all twice, the group's scratch once,
+    and four float32 tiles of [group·tile_q, tile_k] (scores, P, dP, dS)."""
+    rows = group * block_q
+    blocks = (3 * rows * d * itemsize + 2 * block_k * d * itemsize
+              + 2 * 8 * rows * 4)
+    return (2 * blocks + rows * (d + 2 * _LANES) * 4
+            + 4 * group * tile_q * tile_k * 4)
+
+
 def _tile(block):
     return 256 if block % 256 == 0 else block
+
+
+def _group_plan(kv_group, block_q, block_k, d, itemsize):
+    """The ``FlashPlan`` of a step that holds a K/V group, or None where
+    none fits. ``tile_q`` is the largest power of two that divides the
+    block, at most 256, that keeps the step within ``_GROUP_VMEM`` (on a
+    v5e a group of 6-8 read the same at 128-row tiles and 0.0-0.6 %
+    slower: PERF.md). It stays a whole number of sublane tiles, so that
+    the group's [group, tile_q, d] rows merge into one operand, and a
+    block is at most ``_MAX_STRIPS`` strips of it, each a copy of the
+    step's code. A block of 300 rows (tiles of 4) or a group of 32 at
+    block 512, d 128 (past the budget at any tile) is no such step."""
+    sublanes = 32 // itemsize
+    tile_k = _tile(block_k)
+
+    def fits(tile_q):
+        return (tile_q % sublanes == 0 and block_q // tile_q <= _MAX_STRIPS
+                and _group_vmem_bytes(kv_group, block_q, block_k, tile_q,
+                                      tile_k, d, itemsize) <= _GROUP_VMEM)
+    tile_q = min(block_q & -block_q, 256)
+    while tile_q > sublanes and not fits(tile_q):
+        tile_q //= 2
+    return FlashPlan(1, tile_q, tile_k, kv_group) if fits(tile_q) else None
 
 
 def flash_plan(n, t, d, itemsize, block_q, block_k, kv_group=1):
@@ -152,8 +208,18 @@ def flash_plan(n, t, d, itemsize, block_q, block_k, kv_group=1):
     other's waits, so where a row of n is one or two blocks several rows
     share a step: the largest power of two that divides n, up to
     ``_MAX_HEADS``, whose blocks stay inside ``_VMEM_BUDGET``.
-    Grouped-query attention keeps one row a step: its K/V index map
-    serves one K/V head a step."""
+
+    Grouped-query attention (``kv_group`` > 1): a step holds the whole
+    group, whose query heads share the step's K/V block, fetched once, and
+    are folded into the rows of each tile: one [group·tile_q, d] x
+    [d, tile_k] product a tile for all of them, so the kernels' code does
+    not grow with the group (``_group_plan``). Where the fold does not fit
+    a step, the call keeps one query head a step, one tile a block side as
+    above."""
+    if kv_group > 1:
+        plan = _group_plan(kv_group, block_q, block_k, d, itemsize)
+        if plan is not None:
+            return plan
     heads = 1
     if kv_group == 1 and t // block_q <= 2 and t // block_k <= 2:
         while (n % (2 * heads) == 0 and 2 * heads <= _MAX_HEADS
@@ -163,13 +229,19 @@ def flash_plan(n, t, d, itemsize, block_q, block_k, kv_group=1):
     return FlashPlan(heads, _tile(block_q), _tile(block_k))
 
 
-def _keep(offset, shape, window, transposed=False):
+def _keep(offset, shape, window, transposed=False, tile_q=None):
     """The in-mask entries of a tile of scores: q_pos >= k_pos, and with
     ``window`` q_pos - k_pos < window. ``offset`` is q_pos - k_pos of the
     tile's first entry (an int, or a traced scalar); queries run along dim
-    0, along dim 1 where ``transposed``."""
+    0, along dim 1 where ``transposed``. ``tile_q``: the query rows are a
+    group's heads folded, ``tile_q`` rows each (a power of two,
+    ``flash_plan``), and a row's place in its head is its index modulo
+    ``tile_q``."""
     q_dim, k_dim = (1, 0) if transposed else (0, 1)
-    dist = (offset + jax.lax.broadcasted_iota(jnp.int32, shape, q_dim)
+    q_pos = jax.lax.broadcasted_iota(jnp.int32, shape, q_dim)
+    if tile_q is not None:
+        q_pos = q_pos & (tile_q - 1)
+    dist = (offset + q_pos
             - jax.lax.broadcasted_iota(jnp.int32, shape, k_dim))
     keep = dist >= 0
     if window is not None:
@@ -388,7 +460,7 @@ def _lanes(x, width):
     return x if reps == 1 else jnp.tile(x, (1, reps))
 
 
-def _stat_rows(n, t, heads, block_q):
+def _stat_rows(n, t, heads, block_q, group=1):
     """How a per-row statistic (lse, delta) crosses the boundary of all
     three kernels: ``(shape, block, index map)`` of one float32 a query row,
     ``[n, T / block_q, 1, block_q]`` in blocks of this step's ``heads`` rows
@@ -396,9 +468,15 @@ def _stat_rows(n, t, heads, block_q):
     ``_walk_call`` takes it). A block's last two dims equal the array's, so
     Mosaic takes it at any block size, and a [1, block_q] row of it is
     block_q floats in HBM and in VMEM: nothing is replicated, and the array
-    the forward kernel writes is the one the two backward kernels read."""
-    return ((n, t // block_q, 1, block_q), (heads, 1, 1, block_q),
-            lambda b, qi, kb: (b, qi, 0, 0))
+    the forward kernel writes is the one the two backward kernels read.
+
+    Where a step holds a K/V group (``group`` > 1) a block is the group's
+    rows of a Q block as one row, ``[n / group, T / block_q, 1,
+    group·block_q]``, in the order the kernels fold them in
+    (``_folded_rows``): strip by strip of ``tile_q`` query rows, the
+    group's heads one after another in each."""
+    return ((n // group, t // block_q, 1, group * block_q),
+            (heads, 1, 1, group * block_q), lambda b, qi, kb: (b, qi, 0, 0))
 
 
 def _stat_row(ref, h, q0, tq):
@@ -409,7 +487,41 @@ def _stat_row(ref, h, q0, tq):
     return ref[h, 0, :, pl.ds(q0, tq)]
 
 
-def _as_row(col):
+def _folded_rows(ref, h, q0, tq, group):
+    """Query rows ``q0`` to ``q0 + tq`` of a Q-like block ([rows of n, block,
+    d]): of the step's head ``h``, or where the step holds a K/V group,
+    of all of its heads folded into one [group·tq, d] operand, head after
+    head (a merge of leading dims: ``tq`` is a whole number of sublane
+    tiles)."""
+    from jax.experimental import pallas as pl
+
+    if group == 1:
+        return ref[h, pl.ds(q0, tq), :]
+    x = ref[:, pl.ds(q0, tq), :]
+    return x.reshape(group * tq, x.shape[-1])
+
+
+def _put_rows(ref, h, q0, tq, group, x):
+    """``x`` ([group·tq, d], float32) written to the rows ``_folded_rows``
+    reads, in the ref's dtype."""
+    from jax.experimental import pallas as pl
+
+    if group == 1:
+        ref[h, pl.ds(q0, tq), :] = x.astype(ref.dtype)
+    else:
+        ref[:, pl.ds(q0, tq), :] = x.reshape(
+            group, tq, x.shape[-1]).astype(ref.dtype)
+
+
+def _put_block(ref, x, group, tq):
+    """A group's whole Q-like block from ``x`` [group·block, d], rows in the
+    folded order of the scratch (strip by strip of ``tq`` rows, the group's
+    heads one after another in each): strip by strip."""
+    for q0 in range(0, ref.shape[1], tq):
+        _put_rows(ref, 0, q0, tq, group, x[q0 * group:(q0 + tq) * group])
+
+
+def _as_row(col, folded=False):
     """A column of statistics, [rows, 1] or lane-replicated [rows, _LANES],
     as the [1, rows] row the boundary holds (``_stat_rows``): the diagonal
     of the column spread over the lanes, 128 rows at a time -- a select
@@ -417,6 +529,10 @@ def _as_row(col):
     column's own bits. (A transpose of the replicated column is the same
     row and read slower in the forward: PERF.md, PR 33.)"""
     rows = col.shape[0]
+    if folded:
+        # a column of a K/V group's rows is turned by that transpose, once:
+        # 128 rows at a time would be code that grows with the group
+        return jnp.broadcast_to(col[:, :1], (rows, _LANES)).T[:1]
     group = _LANES if rows % _LANES == 0 else rows
     if col.shape[1] not in (1, group):     # replicated wider than a group
         col = col[:, :1]
@@ -463,33 +579,40 @@ def _flash_kernel(table, q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch, plan,
 
     m and l are kept lane-replicated as [heads, block_q, _LANES]; the
     logsumexp they end in leaves as one float32 a row (``_stat_rows``),
-    turned where the Q block is finalised."""
+    turned where the Q block is finalised.
+
+    Where the step holds a K/V group (``plan.group`` > 1) its heads'
+    strips are folded into one: [group·tile_q, d] query rows against the
+    shared K/V tile, and the scratch holds the group's rows strip by strip
+    (``_stat_rows``)."""
     from jax.experimental import pallas as pl
 
     qi, kb, first, last = _step(table, steps, single)
-    tq, tk = plan.tile_q, plan.tile_k
+    tq, tk, group = plan.tile_q, plan.tile_k, plan.group
+    fold = None if group == 1 else tq
     if not single:
         m_scr, l_scr, acc_scr = scratch
 
     def _compute(h, kind, first):
         for q0, row in _strips(_tiles(kind, block_q, block_k, plan), 0):
-            rows = pl.ds(q0, tq)
-            q = q_ref[h, rows, :] * scale              # [tq, d]
+            rows = pl.ds(q0 * group, group * tq)       # of scratch and lse
+            q = _folded_rows(q_ref, h, q0, tq, group) * scale  # [g·tq, d]
             s, v = [], []
             for k0, masked in row:
-                s_t = _dot(q, k_ref[h, pl.ds(k0, tk), :], _NT)   # [tq, tk]
+                s_t = _dot(q, k_ref[h, pl.ds(k0, tk), :], _NT)   # [g·tq, tk]
                 if masked:
                     s_t = jnp.where(
                         _keep(_offset(kind, qi, kb, block_q, block_k, q0,
-                                      k0), s_t.shape, window), s_t, NEG_INF)
+                                      k0), s_t.shape, window, tile_q=fold),
+                        s_t, NEG_INF)
                 s.append(s_t)
                 v.append(v_ref[h, pl.ds(k0, tk), :])
             m_cur = functools.reduce(jnp.maximum, s).max(
-                axis=-1, keepdims=True)                # [tq, 1]
+                axis=-1, keepdims=True)                # [g·tq, 1]
             if first:
                 m_new = m_tile = m_cur
             else:
-                m_prev = m_scr[h, rows, :]             # [tq, 128], lanes equal
+                m_prev = m_scr[h, rows, :]     # [g·tq, 128], lanes equal
                 m_new = jnp.maximum(m_prev, m_cur)     # broadcast over lanes
                 m_tile = _lanes(m_new, tk)
                 correction = jnp.exp(jnp.where(m_prev == NEG_INF, NEG_INF,
@@ -498,14 +621,17 @@ def _flash_kernel(table, q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch, plan,
             l_cur = functools.reduce(jnp.add, p).sum(axis=-1, keepdims=True)
             pv = functools.reduce(jnp.add, [
                 _dot(p_t.astype(v_t.dtype), v_t, _NN)
-                for p_t, v_t in zip(p, v)])            # [tq, d]
+                for p_t, v_t in zip(p, v)])            # [g·tq, d]
             if single:
-                o_ref[h, rows, :] = (
-                    pv / jnp.maximum(l_cur, 1e-30)).astype(o_ref.dtype)
-                lse_ref[h, 0, :, rows] = _as_row(_lse(m_new, l_cur))
+                _put_rows(o_ref, h, q0, tq, group,
+                          pv / jnp.maximum(l_cur, 1e-30))
+                lse_ref[h, 0, :, rows] = _as_row(_lse(m_new, l_cur),
+                                                 folded=group > 1)
             elif first:
-                m_scr[h, rows, :] = jnp.broadcast_to(m_new, (tq, _LANES))
-                l_scr[h, rows, :] = jnp.broadcast_to(l_cur, (tq, _LANES))
+                m_scr[h, rows, :] = jnp.broadcast_to(m_new,
+                                                     (group * tq, _LANES))
+                l_scr[h, rows, :] = jnp.broadcast_to(l_cur,
+                                                     (group * tq, _LANES))
                 acc_scr[h, rows, :] = pv
             else:
                 m_scr[h, rows, :] = m_new
@@ -523,9 +649,13 @@ def _flash_kernel(table, q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch, plan,
         def _finalize():
             for h in range(plan.heads):
                 l_fin = l_scr[h]                       # [block_q, 128]
-                o_ref[h] = (acc_scr[h] / jnp.maximum(l_fin[:, :1], 1e-30)
-                            ).astype(o_ref.dtype)
-                lse_ref[h, 0] = _as_row(_lse(m_scr[h], l_fin))
+                o = acc_scr[h] / jnp.maximum(l_fin[:, :1], 1e-30)
+                if group == 1:
+                    o_ref[h] = o.astype(o_ref.dtype)
+                else:
+                    _put_block(o_ref, o, group, tq)
+                lse_ref[h, 0] = _as_row(_lse(m_scr[h], l_fin),
+                                        folded=group > 1)
 
 
 def _lse(m, l):
@@ -537,14 +667,15 @@ def _lse(m, l):
 
 
 def _walk_call(kernel, walk, rows, *, in_specs, out_specs, out_shape,
-               scratch_shapes, interpret):
+               scratch_shapes, interpret, vmem_limit=None):
     """The ``pallas_call`` of ``kernel`` over the grid ``(rows, steps of
     the walk)``. The walk's table is prefetched into SMEM ahead of the
     arrays (one operand: each costs a call a copy of its own): the kernel
     takes it as its first ref with ``steps`` and ``single`` as statics
     (``_step``). A spec is ``(block shape, index map)``, the map written on
     ``(row, Q block, K block)`` and handed the step's two here; every block
-    lives in VMEM."""
+    lives in VMEM, of which the compiler scopes ``vmem_limit`` bytes where
+    it is given (its default where not)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -563,7 +694,10 @@ def _walk_call(kernel, walk, rows, *, in_specs, out_specs, out_shape,
             in_specs=[spec(*x) for x in in_specs],
             out_specs=[spec(*x) for x in out_specs],
             scratch_shapes=scratch_shapes),
-        interpret=interpret)
+        interpret=interpret,
+        **({} if vmem_limit is None else dict(
+            compiler_params=pltpu.CompilerParams(
+                vmem_limit_bytes=vmem_limit))))
     return functools.partial(call, walk.table)
 
 
@@ -577,9 +711,23 @@ def _k_block(b, qi, kb):
 
 
 def _kv_block(kv_group):
-    """Index map of a block of K or V: the step's K block of the K/V head
-    that the row's group of ``kv_group`` query heads shares."""
+    """Index map of a block of K or V where a step holds one query head:
+    the step's K block of the K/V head that the row's group of
+    ``kv_group`` query heads shares. (At a ``kv_group`` of 1 it is
+    ``_k_block`` with a division by 1, kept so that the lowered text of an
+    ungrouped program stays what it was.)"""
     return lambda b, qi, kb: (b // kv_group, kb, 0)
+
+
+def _qkv_specs(plan, kv_group, block_q, block_k, d):
+    """``(spec of a Q-like block, spec of a K or V block)``: a step's
+    ``heads`` rows of n and the K/V block of each; where the step holds a
+    K/V group, the group's query heads (consecutive rows of n) and the one
+    K/V head they share, whose index is the step's row."""
+    q_like = ((plan.heads * plan.group, block_q, d), _q_block)
+    if plan.group > 1:
+        return q_like, ((1, block_k, d), _k_block)
+    return q_like, ((plan.heads, block_k, d), _kv_block(kv_group))
 
 
 def _statics(q, causal, block_q, block_k, window, kv_group, inners):
@@ -599,8 +747,10 @@ def _statics(q, causal, block_q, block_k, window, kv_group, inners):
             edge = np.count_nonzero(~_block_full(walk.q, walk.k, block_q,
                                                  block_k, window))
             counts += [(_WINDOW_LIVE, walk.live), (_WINDOW_EDGE, edge)]
+        if plan.group > 1:
+            counts.append((_GROUPED_STEPS, walk.steps))
         for gauge, steps in counts:
-            gauge.set(gauge.value + n // plan.heads * steps)
+            gauge.set(gauge.value + n // (plan.heads * plan.group) * steps)
     statistics = 1 if inners == "k" else 2
     _STAT_BYTES.set(_STAT_BYTES.value + len(inners) * statistics * 4 * n * t)
     return dict(causal=causal, block_q=block_q, block_k=block_k,
@@ -624,35 +774,34 @@ def _flash_forward(q, k, v, *, causal, block_q, block_k, window, kv_group,
                    plan, interpret):
     """q: [n, T, d]; k/v: [n // kv_group, T, d] (n = batch·q-heads).
     ``kv_group`` > 1 is grouped-query attention: consecutive runs of
-    kv_group query heads share one K/V head, mapped by the BlockSpec
-    index (no materialized repeat). T must divide by the blocks."""
+    kv_group query heads share one K/V head, and a grid step holds such a
+    run and its K/V head (``FlashPlan.group``; no materialized repeat).
+    T must divide by the blocks."""
     from jax.experimental.pallas import tpu as pltpu
 
     n, t, d = q.shape
-    hb = plan.heads
+    hb, group = plan.heads, plan.group
     walk = flash_walk(causal, window, block_q, block_k, t, "k")
     kernel = functools.partial(
         _flash_kernel, plan=plan, block_q=block_q, block_k=block_k,
         n_qb=t // block_q, causal=causal, scale=1.0 / (d ** 0.5),
         window=window)
-    kv_index = _kv_block(kv_group)
-    stat_shape, stat_block, stat_index = _stat_rows(n, t, hb, block_q)
+    q_spec, kv_spec = _qkv_specs(plan, kv_group, block_q, block_k, d)
+    stat_shape, stat_block, stat_index = _stat_rows(n, t, hb, block_q, group)
     scratch = [] if walk.single else [
-        pltpu.VMEM((hb, block_q, _LANES), jnp.float32),   # running max
-        pltpu.VMEM((hb, block_q, _LANES), jnp.float32),   # running sum
-        pltpu.VMEM((hb, block_q, d), jnp.float32),        # unnormalized out
+        pltpu.VMEM((hb, group * block_q, _LANES), jnp.float32),  # running max
+        pltpu.VMEM((hb, group * block_q, _LANES), jnp.float32),  # running sum
+        pltpu.VMEM((hb, group * block_q, d), jnp.float32),   # unnormalized out
     ]
     return _walk_call(
-        kernel, walk, n // hb,
+        kernel, walk, n // (hb * group),
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
                    jax.ShapeDtypeStruct(stat_shape, jnp.float32)],   # lse
-        in_specs=[((hb, block_q, d), _q_block),
-                  ((hb, block_k, d), kv_index),
-                  ((hb, block_k, d), kv_index)],
-        out_specs=[((hb, block_q, d), _q_block),
-                   (stat_block, stat_index)],
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=[q_spec, (stat_block, stat_index)],
         scratch_shapes=scratch,
         interpret=interpret,
+        vmem_limit=_GROUP_VMEM if group > 1 else None,
     )(q, k, v)
 
 
@@ -669,11 +818,14 @@ def _flash_dq_kernel(table, q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
     the [q, k] tiles want them as columns: the block's index does not
     change along a Q block's steps, so its first step turns both
     (``_as_column``) into VMEM scratch and the others read that; where
-    every Q block is one step (``single``) a strip turns its own piece."""
+    every Q block is one step (``single``) a strip turns its own piece.
+    A step that holds a K/V group folds its heads' strips into one, as the
+    forward does."""
     from jax.experimental import pallas as pl
 
     qi, kb, first, last = _step(table, steps, single)
-    tq, tk = plan.tile_q, plan.tile_k
+    tq, tk, group = plan.tile_q, plan.tile_k, plan.group
+    fold = None if group == 1 else tq
     if not single:
         dq_scr, lse_scr, delta_scr = scratch
 
@@ -684,33 +836,36 @@ def _flash_dq_kernel(table, q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
             turned = [_as_column(ref[h, 0]) for ref in (lse_ref, delta_ref)]
             lse_scr[h], delta_scr[h] = turned
         for q0, row in _strips(_tiles(kind, block_q, block_k, plan), 0):
-            rows = pl.ds(q0, tq)
-            q = q_ref[h, rows, :] * scale              # [tq, d]
-            g = g_ref[h, rows, :]                      # [tq, d] dO
+            rows = pl.ds(q0 * group, group * tq)       # of scratch and stats
+            q = _folded_rows(q_ref, h, q0, tq, group) * scale  # [g·tq, d]
+            g = _folded_rows(g_ref, h, q0, tq, group)          # dO
             if single:
-                lse, delta = (_as_column(_stat_row(ref, h, q0, tq))
+                lse, delta = (_as_column(_stat_row(ref, h, q0 * group,
+                                                   group * tq))
                               for ref in (lse_ref, delta_ref))
             elif first:
-                lse, delta = (x[q0:q0 + tq] for x in turned)
+                lse, delta = (x[q0 * group:(q0 + tq) * group]
+                              for x in turned)
             else:
                 lse, delta = lse_scr[h, rows, :], delta_scr[h, rows, :]
-            lse = _lanes(lse, tk)                      # [tq, tk]
+            lse = _lanes(lse, tk)                      # [g·tq, tk]
             delta = _lanes(delta, tk)                  # rowsum(dO*O)
             dq = None
             for k0, masked in row:
                 k_t = k_ref[h, pl.ds(k0, tk), :]       # [tk, d]
-                s = _dot(q, k_t, _NT)                  # [tq, tk]
+                s = _dot(q, k_t, _NT)                  # [g·tq, tk]
                 if masked:
                     s = jnp.where(
                         _keep(_offset(kind, qi, kb, block_q, block_k, q0,
-                                      k0), s.shape, window), s, NEG_INF)
+                                      k0), s.shape, window, tile_q=fold),
+                        s, NEG_INF)
                 p = jnp.exp(s - lse)
                 dp = _dot(g, v_ref[h, pl.ds(k0, tk), :], _NT)
                 ds = p * (dp - delta)
-                part = _dot(ds.astype(k_t.dtype), k_t, _NN)      # [tq, d]
+                part = _dot(ds.astype(k_t.dtype), k_t, _NN)      # [g·tq, d]
                 dq = part if dq is None else dq + part
             if single:
-                dq_ref[h, rows, :] = (dq * scale).astype(dq_ref.dtype)
+                _put_rows(dq_ref, h, q0, tq, group, dq * scale)
             elif first:
                 dq_scr[h, rows, :] = dq
             else:
@@ -724,7 +879,10 @@ def _flash_dq_kernel(table, q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
     if not single:
         @pl.when(last)
         def _finalize():
-            dq_ref[...] = (dq_scr[...] * scale).astype(dq_ref.dtype)
+            if group == 1:
+                dq_ref[...] = (dq_scr[...] * scale).astype(dq_ref.dtype)
+            else:
+                _put_block(dq_ref, dq_scr[0] * scale, group, tq)
 
 
 def _flash_dkv_kernel(table, q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
@@ -734,11 +892,17 @@ def _flash_dkv_kernel(table, q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
     steps of the walk); dV = Σ_qb Pᵀ dO, dK = Σ_qb dSᵀ Q. The tiles are
     computed transposed, keys along dim 0 (Sᵀ = K Qᵀ, dPᵀ = V dOᵀ), so that
     both sums are plain products with nothing to turn, and lse and delta
-    broadcast over the keys as the rows they arrive as (``_stat_rows``)."""
+    broadcast over the keys as the rows they arrive as (``_stat_rows``).
+
+    Where the step holds a K/V group its heads' query rows are folded into
+    the columns of each tile, Sᵀ [tile_k, group·tile_q]: both products that
+    make dK and dV contract over the whole group's rows, so the group is
+    summed inside them, and dK, dV leave the kernel a K/V head a row."""
     from jax.experimental import pallas as pl
 
     qi, kb, first, last = _step(table, steps, single)
-    tq, tk = plan.tile_q, plan.tile_k
+    tq, tk, group = plan.tile_q, plan.tile_k, plan.group
+    fold = None if group == 1 else tq
     if not single:
         dk_scr, dv_scr = scratch
 
@@ -749,18 +913,19 @@ def _flash_dkv_kernel(table, q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
             v_t = v_ref[h, rows, :]
             dk = dv = None
             for q0, masked in col:
-                q = q_ref[h, pl.ds(q0, tq), :] * scale           # [tq, d]
-                g = g_ref[h, pl.ds(q0, tq), :]
-                lse = _stat_row(lse_ref, h, q0, tq)    # [1, tq]
-                delta = _stat_row(delta_ref, h, q0, tq)
-                s = _dot(k_t, q, _NT)                  # [tk, tq]
+                q = _folded_rows(q_ref, h, q0, tq, group) * scale  # [g·tq, d]
+                g = _folded_rows(g_ref, h, q0, tq, group)
+                lse = _stat_row(lse_ref, h, q0 * group, group * tq)
+                delta = _stat_row(delta_ref, h, q0 * group, group * tq)
+                s = _dot(k_t, q, _NT)                  # [tk, g·tq]
                 if masked:
                     s = jnp.where(
                         _keep(_offset(kind, qi, kb, block_q, block_k, q0,
-                                      k0), s.shape, window, transposed=True),
+                                      k0), s.shape, window, transposed=True,
+                              tile_q=fold),
                         s, NEG_INF)
                 p = jnp.exp(s - lse)
-                dp = _dot(v_t, g, _NT)                 # [tk, tq]
+                dp = _dot(v_t, g, _NT)                 # [tk, g·tq]
                 ds = p * (dp - delta)
                 dv_part = _dot(p.astype(g.dtype), g, _NN)        # [tk, d]
                 dk_part = _dot(ds.astype(q.dtype), q, _NN)       # q carries scale
@@ -845,54 +1010,64 @@ def _flash_bwd(causal, block_q, block_k, window, kv_group, residuals, g):
 @_trace_once
 def _flash_backward(q, k, v, out, lse, g, *, causal, block_q, block_k, window,
                     kv_group, plan, interpret):
-    """dQ, dK, dV of ``_flash_forward`` from its residuals and dO."""
+    """dQ, dK, dV of ``_flash_forward`` from its residuals and dO. Where a
+    step holds a K/V group (``plan.group``) the dK/dV kernel sums the
+    group inside its products and writes dK and dV a K/V head a row; a
+    grouped call that takes one query head a step writes them a query head
+    a row, summed down to the K/V heads in float32 after the kernel."""
     from jax.experimental.pallas import tpu as pltpu
 
     n, t, d = q.shape
-    hb = plan.heads
+    hb, group = plan.heads, plan.group
     static = dict(plan=plan, block_q=block_q, block_k=block_k,
                   n_qb=t // block_q, causal=causal, scale=1.0 / (d ** 0.5),
                   window=window)
-    stat_shape, stat_block, stat_index = _stat_rows(n, t, hb, block_q)
+    stat_shape, stat_block, stat_index = _stat_rows(n, t, hb, block_q, group)
     # delta_i = Σ_d dO ⊙ O — a cheap fused elementwise+reduce; XLA keeps it
     # out of the kernels' VMEM budget and writes it as lse came. The dK/dV
     # kernel, whose tiles are [k, q], broadcasts the rows as they are; the
     # dQ kernel turns them into columns in VMEM
     delta = (g.astype(jnp.float32) * out.astype(jnp.float32)).sum(-1)
-    delta = delta.reshape(stat_shape)
-    kv_index = _kv_block(kv_group)
+    if group == 1:
+        delta = delta.reshape(stat_shape)
+    else:       # the group's rows strip by strip, as the kernels fold them
+        tq = plan.tile_q
+        delta = delta.reshape(n // group, group, t // block_q,
+                              block_q // tq, tq).transpose(
+            0, 2, 3, 1, 4).reshape(stat_shape)
+    q_spec, kv_spec = _qkv_specs(plan, kv_group, block_q, block_k, d)
+    rows = n // (hb * group)
+    vmem_limit = _GROUP_VMEM if group > 1 else None
 
     walk = flash_walk(causal, window, block_q, block_k, t, "k")
     dq, = _walk_call(
-        functools.partial(_flash_dq_kernel, **static), walk, n // hb,
+        functools.partial(_flash_dq_kernel, **static), walk, rows,
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype)],
-        in_specs=[((hb, block_q, d), _q_block),
-                  ((hb, block_k, d), kv_index),
-                  ((hb, block_k, d), kv_index),
-                  ((hb, block_q, d), _q_block),
+        in_specs=[q_spec, kv_spec, kv_spec, q_spec,
                   (stat_block, stat_index),
                   (stat_block, stat_index)],
-        out_specs=[((hb, block_q, d), _q_block)],
+        out_specs=[q_spec],
         scratch_shapes=[] if walk.single else [
-            pltpu.VMEM((hb, block_q, d), jnp.float32),        # dQ
-            pltpu.VMEM((hb, block_q, _LANES), jnp.float32),   # lse, turned
-            pltpu.VMEM((hb, block_q, _LANES), jnp.float32)],  # delta, turned
+            pltpu.VMEM((hb, group * block_q, d), jnp.float32),        # dQ
+            pltpu.VMEM((hb, group * block_q, _LANES), jnp.float32),   # lse
+            pltpu.VMEM((hb, group * block_q, _LANES), jnp.float32)],  # delta
         interpret=interpret,
+        vmem_limit=vmem_limit,
     )(q, k, v, g, lse, delta)
 
-    # dK/dV walk K block by K block. With GQA the kernel accumulates PER
-    # Q-HEAD (output shaped like q); the group-sum down to the kv heads
-    # happens outside — revisiting one output block from different
-    # outer-grid steps would race.
+    # dK/dV walk K block by K block, a K/V head's block a step: with one
+    # head a step, or with the group that shares it, so no two steps of
+    # the grid write the same output block from different rows. A group
+    # that holds one query head a step (``flash_plan``: too large to fold)
+    # is written a query head a row and summed to its K/V head outside
+    per_head = kv_group > 1 and group == 1
+    dkv_shape = (n, t, d) if per_head else k.shape
     walk = flash_walk(causal, window, block_q, block_k, t, "q")
     dk, dv = _walk_call(
-        functools.partial(_flash_dkv_kernel, **static), walk, n // hb,
-        out_shape=[jax.ShapeDtypeStruct((n, t, d), k.dtype),
-                   jax.ShapeDtypeStruct((n, t, d), v.dtype)],
-        in_specs=[((hb, block_q, d), _q_block),
-                  ((hb, block_k, d), kv_index),
-                  ((hb, block_k, d), kv_index),
-                  ((hb, block_q, d), _q_block),
+        functools.partial(_flash_dkv_kernel, **static), walk, rows,
+        out_shape=[jax.ShapeDtypeStruct(dkv_shape, k.dtype),
+                   jax.ShapeDtypeStruct(dkv_shape, v.dtype)],
+        in_specs=[q_spec, kv_spec, kv_spec, q_spec,
                   (stat_block, stat_index),
                   (stat_block, stat_index)],
         out_specs=[((hb, block_k, d), _k_block),
@@ -901,8 +1076,9 @@ def _flash_backward(q, k, v, out, lse, g, *, causal, block_q, block_k, window,
             pltpu.VMEM((hb, block_k, d), jnp.float32),
             pltpu.VMEM((hb, block_k, d), jnp.float32)],
         interpret=interpret,
+        vmem_limit=vmem_limit,
     )(q, k, v, g, lse, delta)
-    if kv_group > 1:
+    if per_head:
         dk = dk.astype(jnp.float32).reshape(
             n // kv_group, kv_group, t, d).sum(1).astype(k.dtype)
         dv = dv.astype(jnp.float32).reshape(
@@ -920,8 +1096,13 @@ def flash_attention(q, k, v, *, causal=False, block_q=512, block_k=512,
     k/v may carry FEWER heads than q (grouped-query attention): with head
     axis -3, q [..., Hq, T, d] against k/v [..., Hkv, T, d] where
     Hq % Hkv == 0 — consecutive runs of Hq/Hkv query heads share a K/V
-    head via the kernel's BlockSpec index map (no materialized repeat,
-    and dK/dV group-sum on the backward).
+    head. A grid step of each kernel holds one such group and its K/V
+    head, fetched once, the group's query heads folded into the rows of
+    each tile's products (no materialized repeat); the dK/dV kernel's
+    products sum the group, and dK, dV leave it a K/V head a row. A group
+    too large for the VMEM of a step, or a block that does not cut into
+    whole strips of sublane tiles, keeps one query head a step
+    (``flash_plan``), and its dK, dV are summed after the kernel.
 
     Pads T to the block size; leading dims are collapsed into the grid.
     Differentiable (pallas FlashAttention-2 backward; DL4J_TPU_FLASH_BWD=scan
@@ -935,9 +1116,9 @@ def flash_attention(q, k, v, *, causal=False, block_q=512, block_k=512,
     instead of O(T²/2), and a causal row takes n(n+1)/2 steps of its n²
     pairs. A row may walk up to ``MAX_WALK_STEPS`` pairs (a causal T of
     184,832 at block 512); past it the call raises and asks for larger
-    blocks. What a grid step does inside a block (heads a step, compute
-    tiles, which blocks build a mask) follows from the shapes:
-    ``flash_plan``. Products take their
+    blocks. What a grid step does inside a block (heads or the K/V group a
+    step, compute tiles, which blocks build a mask) follows from the
+    shapes: ``flash_plan``. Products take their
     operands in the inputs' dtype (bfloat16 in, bfloat16 operands) and
     accumulate in float32; the softmax statistics are float32. What is
     kept for the backward besides q, k, v and the output is the logsumexp,

@@ -66,8 +66,10 @@ def _assert_statistics_cross_compact(text, rows_of_n, t, block):
     """In a compiled step: no operand or result of a flash custom call is a
     float32 array of trailing dimension 128 and ``n·T·128`` elements, the
     lane-replicated lse or delta (``rows_of_n``: every n = batch·heads among
-    the step's layers); what crosses is ``f32[n, T / block, 1, block]``, the
-    forward's result and two operands of each backward kernel."""
+    the step's layers); what crosses is ``f32[n, T / block, 1, block]``
+    (``f32[n / G, T / block, 1, G·block]`` where a step holds a K/V group of
+    G query heads), the forward's result and two operands of each backward
+    kernel."""
     import math
     import re
     flash = [line for line in text.splitlines()
@@ -80,8 +82,9 @@ def _assert_statistics_cross_compact(text, rows_of_n, t, block):
             shape = tuple(int(x) for x in dims.split(","))
             assert not (shape[-1] == 128 and math.prod(shape) in
                         {n * t * 128 for n in rows_of_n}), line[:400]
-            compact += shape[1:] == (t // block, 1, block) \
-                and shape[0] in rows_of_n
+            compact += shape[1:3] == (t // block, 1) \
+                and shape[3] % block == 0 \
+                and shape[0] * (shape[3] // block) in rows_of_n
     assert compact == len(flash) * 5 // 3
 
 
@@ -113,6 +116,11 @@ FLASH_SHAPES = {
     # q, k and v as 20 ungrouped heads of 256 (192 + 64 rope; v 256), rows
     # of 16 blocks: the kernels' first width past 128
     "glm_latent": ((1, 20, 8192, 256), (1, 20, 8192, 256), 512),
+    # grouped calls that no step can hold whole keep one query head a step:
+    # 32 query heads a key/value head at d 128 pass the VMEM of a step; a
+    # row of 300 is one block of 300 rows, whose strips would be 4 rows
+    "gqa_32_d128": ((1, 32, 2048, 128), (1, 1, 2048, 128), 512),
+    "gqa_t300": ((2, 8, 300, 128), (2, 2, 300, 128), 512),
 }
 
 

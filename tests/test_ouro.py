@@ -490,6 +490,13 @@ def test_save_and_load_round_trip_the_gate_and_the_counters(seeded, tmp_path):
 # PR 39 (latent attention, the selection bias, the multi-token-prediction
 # module) changed none of them and added the Ouro cell's twin, read on PR
 # 37's tree: the four configurations of the benchmark's cells, each held.
+# Then the two grouped twins were renewed once more, when a grid step of the
+# flash kernels came to hold a whole K/V group, its query heads folded into the
+# rows of each tile, and the dK/dV kernel to write a K/V head a row (the
+# float32 sum of the group after it is gone); the GLM cell's twin was added,
+# read on the tree before that change: its latent attention is ungrouped, and
+# it stands with the other steps whose flash calls are ungrouped
+# (``gpt2_remat_rope`` takes no flash route).
 LOWERED_BEFORE = {
     "gpt2": "f19a640cb05302ba6b1a96247726095553a0daa69a62f3ba0adb18d9229d8743",
     "gpt2_flash_bf16":
@@ -497,11 +504,13 @@ LOWERED_BEFORE = {
     "gpt2_remat_rope":
         "8e7c5ef5fab6e2288fcbbc65e11159aa928b9b4301d789f30c51f345a9493539",
     "laguna_tiny":
-        "b2f11698d2e441e16d08c1e1af0bd65424e3d78f59f17a1566e94c20bbd1926f",
+        "4195244a8fefcdc30d59f9ab5d689dd18f6ac18b5c3c5936f70e937ae9b716d8",
     "smallthinker_tiny":
-        "48483549c7c0ec6618763e954a916134d00e71191a33cfec8a5f4f842356c362",
+        "8020963b18c47c5964c13d27fc3adac5df68f3d32fed4b107ace44adb91be7b8",
     "ouro_tiny":
         "0c35df2a221db3d80d2a57ba5f85206a8f318de4595372618704e2fc58257e03",
+    "glm_tiny":
+        "edd858b649e1fdaa19ba62a6b2a6a77e25b97b21c5f44d505a6ae9b8a03fd4d0",
 }
 
 
@@ -521,14 +530,15 @@ def test_the_defaults_leave_the_gpt2_and_laguna_steps_as_they_were(
     monkeypatch.setenv("DL4J_TPU_LM_ATTN", "pallas")
     gpt2 = dict(vocab_size=512, max_len=64, d_model=64, n_heads=4, n_layers=2,
                 d_ff=256)
-    if name in ("laguna_tiny", "smallthinker_tiny", "ouro_tiny"):
-        from benchmark.drivers import (laguna_train, ouro_train,
+    if name in ("laguna_tiny", "smallthinker_tiny", "ouro_tiny", "glm_tiny"):
+        from benchmark.drivers import (glm_train, laguna_train, ouro_train,
                                        smallthinker_train)
         driver, twin, seq = {
             "laguna_tiny": (laguna_train, "laguna-tiny.json", 32),
             "smallthinker_tiny": (smallthinker_train,
                                   "smallthinker-tiny.json", 64),
-            "ouro_tiny": (ouro_train, "ouro-tiny.json", 32)}[name]
+            "ouro_tiny": (ouro_train, "ouro-tiny.json", 32),
+            "glm_tiny": (glm_train, "glm-tiny.json", 32)}[name]
         with open(os.path.join(ROOT, "benchmark", "rehearsal", "configs",
                                twin)) as f:
             conf, rows = driver.program_config(json.load(f), seq, 7), 2

@@ -331,9 +331,10 @@ def _assert_bf16_flash_matches_dense(case, tol):
 class TestFlashBoundary:
     """lse and delta cross the boundary of the three kernels one float32 a
     query row (``_stat_rows``): the forward writes, and the two backward
-    kernels read, ``[n, T / block, 1, block]``; no lane-replicated
-    ``[n, T, 128]`` copy of either is written, sliced or broadcast around
-    them, in any of the shapes the callers send."""
+    kernels read, ``[n, T / block, 1, block]`` (``[n / G, T / block, 1,
+    G·block]`` where a step holds a K/V group of G query heads); no
+    lane-replicated ``[n, T, 128]`` copy of either is written, sliced or
+    broadcast around them, in any of the shapes the callers send."""
 
     # q [batch, heads, T, d] (d never 128, so that a trailing 128 is a
     # replicated statistic and nothing else); n = batch x heads
@@ -381,7 +382,8 @@ class TestFlashBoundary:
                                                         case):
         case = self.CASES[case]
         n, t = self._sizes(case)
-        stat = (n, t // case["bq"], 1, case["bq"])
+        group = case["q"][1] // case.get("kv", case["q"])[1]
+        stat = (n // group, t // case["bq"], 1, group * case["bq"])
         calls, named = [], []
 
         def walk(jaxpr):
@@ -440,10 +442,12 @@ class TestFlashBoundary:
 
 
 class TestFlashPlan:
-    """``flash_plan``: heads a grid step and the compute tile, from shapes
-    alone (no knob); and the tiles a block's kind leaves to compute."""
+    """``flash_plan``: heads a grid step, the compute tile and the K/V group
+    a step holds, from shapes alone (no knob); and the tiles a block's kind
+    leaves to compute."""
 
-    # (n, t, d, itemsize, block_q, block_k, kv_group) -> (heads, tile_q, tile_k)
+    # (n, t, d, itemsize, block_q, block_k, kv_group) -> (heads, tile_q,
+    # tile_k[, group]); a group of 1 where none is given
     CASES = {
         # the benchmark's cells: 8 x 16 heads at T 1024, 32 x 16 at T 256
         "gpt2m_t1024": ((128, 1024, 64, 2, 512, 512, 1), (4, 256, 256)),
@@ -463,9 +467,33 @@ class TestFlashPlan:
         "float32_block_1024": ((64, 1024, 64, 4, 1024, 1024, 1),
                                (2, 256, 256)),
         "d128": ((128, 1024, 128, 2, 512, 512, 1), (2, 256, 256)),
-        # grouped-query attention and long rows keep one row a step
-        "gqa": ((32, 2048, 128, 2, 512, 512, 4), (1, 256, 256)),
+        # long rows keep one row a step; grouped-query attention takes the
+        # whole group a step, its heads folded into the rows of 256-row
+        # strips of tiles: the cells' groups of 6 and 8 (Laguna's full and
+        # window layers, 2 x 48 and 2 x 64 query heads on 2 x 8 K/V heads)
+        # and of 7 (SmallThinker's, 28 on 4)
+        "gqa": ((32, 2048, 128, 2, 512, 512, 4), (1, 256, 256, 4)),
         "long_row": ((32, 4096, 64, 2, 512, 512, 1), (1, 256, 256)),
+        "laguna_full": ((96, 8192, 128, 2, 512, 512, 6), (1, 256, 256, 6)),
+        "laguna_window": ((128, 8192, 128, 2, 512, 512, 8),
+                          (1, 256, 256, 8)),
+        "smallthinker": ((28, 16384, 128, 2, 512, 512, 7), (1, 256, 256, 7)),
+        # a group the VMEM of a step holds only at narrower strips
+        "gqa_16_d256": ((32, 4096, 256, 2, 512, 512, 16), (1, 64, 256, 16)),
+        # a short grouped row is folded as well; tiles stay powers of two
+        # that divide the block
+        "gqa_short_row": ((8, 256, 64, 2, 256, 256, 2), (1, 256, 256, 2)),
+        "gqa_block_384": ((16, 768, 64, 2, 384, 384, 4), (1, 128, 384, 4)),
+        # strips stay whole sublane tiles: 16 bfloat16 rows, 8 float32
+        "gqa_block_48": ((8, 48, 64, 2, 48, 48, 4), (1, 16, 48, 4)),
+        "gqa_block_24_float32": ((8, 24, 64, 4, 24, 24, 4), (1, 8, 24, 4)),
+        # a group that folds into no step keeps one query head a step, one
+        # tile a block side: 32 query heads a K/V head at d 128 pass the
+        # VMEM of a step at any tile, a block of 300 rows cuts into tiles of
+        # 4, a bfloat16 block of 24 into tiles of 8
+        "gqa_32_d128": ((64, 4096, 128, 2, 512, 512, 32), (1, 256, 256)),
+        "gqa_block_300": ((8, 300, 64, 2, 300, 300, 4), (1, 300, 300)),
+        "gqa_block_24": ((8, 24, 64, 2, 24, 24, 4), (1, 24, 24)),
         # heads divide n
         "odd_n": ((3, 256, 64, 2, 256, 256, 1), (1, 256, 256)),
         "n_6": ((6, 32, 8, 4, 16, 16, 1), (2, 16, 16)),
@@ -481,12 +509,30 @@ class TestFlashPlan:
         from deeplearning4j_tpu.ops import pallas_kernels as pk
         args, want = self.CASES[case]
         plan = pk.flash_plan(*args)
-        assert tuple(plan) == want
-        n, t, d, itemsize, bq, bk, _ = args
-        assert n % plan.heads == 0
+        assert plan == pk.FlashPlan(*want)
+        n, t, d, itemsize, bq, bk, kv_group = args
+        assert n % (plan.heads * plan.group) == 0
         assert bq % plan.tile_q == 0 and bk % plan.tile_k == 0
         assert pk._flash_vmem_bytes(plan.heads, bq, bk, d,
                                     itemsize) <= pk._VMEM_BUDGET
+        assert plan.group in (1, kv_group)
+        if kv_group > 1:
+            assert plan.heads == 1
+        if plan.group > 1:
+            assert pk._group_vmem_bytes(kv_group, bq, bk, plan.tile_q,
+                                        plan.tile_k, d, itemsize) \
+                <= pk._GROUP_VMEM
+            assert plan.tile_q % (32 // itemsize) == 0
+            assert bq // plan.tile_q <= pk._MAX_STRIPS
+
+    def test_a_group_past_the_vmem_of_a_step_keeps_a_head_a_step(self):
+        """From shapes alone: a group whose blocks alone pass the VMEM a
+        grouped step may take runs one query head a step, as an ungrouped
+        long row does; smaller blocks fold it again."""
+        from deeplearning4j_tpu.ops import pallas_kernels as pk
+        assert pk.flash_plan(64, 4096, 256, 2, 1024, 1024, 64) \
+            == pk.FlashPlan(1, 256, 256)
+        assert pk.flash_plan(16, 4096, 256, 2, 256, 256, 16).group == 16
 
     @pytest.mark.parametrize("kind,tile,want", [
         # a 512-block in 256-tiles: the diagonal block leaves out the tile
@@ -652,6 +698,114 @@ class TestFlashWalk:
         assert (obs.metrics.value("flash.steps_walked"),
                 obs.metrics.value("flash.steps_live")) == (42, 42)
         obs.reset_metrics()
+
+
+class TestFlashGroupFold:
+    """A grid step that holds a K/V group folds the group's query heads into
+    the rows of each tile's products: the kernels' code, and so what a
+    program spends tracing and lowering them, does not grow with the group
+    (each head a copy of the step's code, as the rows of an ungrouped step
+    are, traced eight copies of the body at a group of 8)."""
+
+    @staticmethod
+    def _kernel_eqns(q_shape, kv_shape, block, window):
+        """Equations of each flash kernel's jaxpr in a traced forward +
+        backward, through its branches (``pl.when``) and loops."""
+        import jax
+        from deeplearning4j_tpu.ops import pallas_kernels as pk
+        q = jax.ShapeDtypeStruct(q_shape, jnp.bfloat16)
+        kv = jax.ShapeDtypeStruct(kv_shape, jnp.bfloat16)
+
+        def loss(a, b, c):
+            return pk.flash_attention(
+                a, b, c, causal=True, block_q=block, block_k=block,
+                window=window).astype(jnp.float32).sum()
+        calls = _pallas_calls(
+            jax.make_jaxpr(jax.grad(loss, (0, 1, 2)))(q, kv, kv).jaxpr)
+        assert sorted(calls) == sorted(TestFlashGridStep.PRODUCTS)
+        return {name: sum(1 for _ in _eqns(jaxpr))
+                for name, jaxpr in calls.items()}
+
+    @pytest.mark.parametrize("window", [None, 16, 40])
+    def test_a_group_of_8_is_the_code_of_one_head(self, interpret_pallas,
+                                                  window):
+        """One head and a group of 8 on the same walk (rows of 4 blocks of
+        16, one 16-row tile a block either way): at most a quarter more
+        equations in each kernel, the reshapes that fold and unfold."""
+        one = self._kernel_eqns((1, 1, 64, 16), (1, 1, 64, 16), 16, window)
+        eight = self._kernel_eqns((1, 8, 64, 16), (1, 1, 64, 16), 16,
+                                  window)
+        for name, count in one.items():
+            assert eight[name] <= 1.25 * count, (name, count, eight[name])
+
+    @pytest.mark.parametrize("window", [None, 512])
+    def test_the_cells_groups_trace_the_same_kernels(self, interpret_pallas,
+                                                     window):
+        """Laguna's groups of 6 and 8 and SmallThinker's of 7, at the cells'
+        blocks (512, d 128, 256-row tiles folded): the same number of
+        equations, kernel by kernel, and at most a quarter more than one
+        ungrouped head's."""
+        counts = [self._kernel_eqns((1, 8 * g, 2048, 128), (1, 8, 2048, 128),
+                                    512, window) for g in (6, 7, 8)]
+        one = self._kernel_eqns((1, 1, 2048, 128), (1, 1, 2048, 128), 512,
+                                window)
+        assert counts[0] == counts[1] == counts[2]
+        for name, count in one.items():
+            assert counts[0][name] <= 1.25 * count, (name, count)
+
+    @staticmethod
+    def _twin_gauges(name):
+        """``flash.steps_walked`` and ``flash.grouped_steps`` once the
+        training step of a rehearsal twin is traced."""
+        import json
+        import os
+
+        import jax
+        from deeplearning4j_tpu import obs
+        from deeplearning4j_tpu.models.transformer import (TransformerConfig,
+                                                           TransformerLM)
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        with open(os.path.join(root, "benchmark", "rehearsal", "configs",
+                               f"{name}-tiny.json")) as f:
+            config = json.load(f)
+        seq = {"laguna": 32, "smallthinker": 64, "gpt2": 64}[name]
+        if name == "gpt2":
+            conf = TransformerConfig(
+                vocab_size=config["vocab_size"],
+                max_len=config["n_positions"], d_model=config["n_embd"],
+                n_heads=config["n_head"], n_layers=config["n_layer"],
+                d_ff=4 * config["n_embd"],
+                block_size=config["assumed"]["block_size"],
+                compute_dtype=config["assumed"]["compute_dtype"])
+        else:
+            from benchmark.drivers import laguna_train, smallthinker_train
+            program = {"laguna": laguna_train,
+                       "smallthinker": smallthinker_train}[name]
+            conf = program.program_config(config, seq, 7)
+        lm = TransformerLM(conf)
+        params, opt = jax.eval_shape(lambda: (lm.init().params,
+                                              lm.opt_state))
+        lm.params = lm.opt_state = None
+        tokens = jax.ShapeDtypeStruct((2, seq), jnp.int32)
+        obs.reset_metrics()
+        lm._build_step().trace(
+            params, opt, jax.ShapeDtypeStruct((), jnp.int32),
+            jax.ShapeDtypeStruct((2,), jnp.uint32), tokens, tokens, None)
+        read = (obs.metrics.value("flash.steps_walked"),
+                obs.metrics.value("flash.grouped_steps"))
+        obs.reset_metrics()
+        return read
+
+    @pytest.mark.parametrize("name", ["laguna", "smallthinker", "gpt2"])
+    def test_gauge_counts_the_steps_that_hold_a_group(self, interpret_pallas,
+                                                      monkeypatch, name):
+        """``flash.grouped_steps``: every walked step of the grouped twins
+        (Laguna's 4 query heads on 2 K/V heads, SmallThinker's 14 on 2),
+        none of GPT-2's."""
+        monkeypatch.setenv("DL4J_TPU_LM_ATTN", "pallas")
+        walked, grouped = self._twin_gauges(name)
+        assert walked > 0
+        assert grouped == (0 if name == "gpt2" else walked)
 
 
 class TestFlashRemat:
@@ -1088,9 +1242,10 @@ class TestSlidingWindow:
 
 
 class TestGroupedQueryAttention:
-    """GQA: k/v carry fewer heads than q; the kernels map a run of
-    kv_group query heads onto one K/V head via the BlockSpec index (no
-    materialized repeat), with a group-sum for dK/dV."""
+    """GQA: k/v carry fewer heads than q; a grid step holds a run of
+    kv_group query heads and the one K/V head they share (no materialized
+    repeat), folded into the rows of each tile, and dK/dV are summed over
+    the group inside the kernel's products."""
 
     def _ref(self, q, k, v, g, **kw):
         return dense_attention(q, jnp.repeat(k, g, axis=-3),
@@ -1139,14 +1294,67 @@ class TestGroupedQueryAttention:
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=1e-5)
 
-    # the third cell's groups, 6 and 8 query heads a K/V head, on rows of
-    # 8 and 16 blocks, with and without a window of one block
+    # the cells' groups, 6 and 8 query heads a K/V head (Laguna), 7
+    # (SmallThinker), and 2, on rows of one block, 8 and 16 blocks, with a
+    # window of one block, of two and a half, and none
     @pytest.mark.parametrize("kv_group,blocks,window", [
-        (6, 16, 8), (8, 16, None), (8, 8, 8), (6, 8, None), (8, 8, 12)])
+        (6, 16, 8), (8, 16, None), (8, 8, 8), (6, 8, None), (8, 8, 12)] + [
+        (g, b, w) for g in (2, 6, 7, 8) for b in (1, 8) for w in (8, 20, None)
+        if (g, b, w) not in {(8, 8, 8), (6, 8, None)}])
     def test_long_rows_in_groups_match_dense(self, interpret_pallas,
                                              kv_group, blocks, window):
         _assert_flash_matches_dense((1, kv_group, 8 * blocks, 8),
                                     (1, 1, 8 * blocks, 8), 8, 8, window)
+
+    @pytest.mark.parametrize("window", [None, 16])
+    def test_dkv_leaves_the_kernel_a_kv_head_a_row(self, interpret_pallas,
+                                                   window):
+        """Two batch rows of 2 K/V heads, groups of 4: the dK/dV kernel
+        writes [n / G, T, d], and what it writes is the gradient: nothing
+        sums the group after it."""
+        import jax
+        q = jax.ShapeDtypeStruct((2, 8, 64, 8), jnp.float32)
+        kv = jax.ShapeDtypeStruct((2, 2, 64, 8), jnp.float32)
+
+        def loss(a, b, c):
+            from deeplearning4j_tpu.ops.pallas_kernels import flash_attention
+            return flash_attention(a, b, c, causal=True, block_q=16,
+                                   block_k=16, window=window).sum()
+        jaxpr = jax.make_jaxpr(jax.grad(loss, (1, 2)))(q, kv, kv)
+        dkv = [eqn for eqn in _eqns(jaxpr.jaxpr)
+               if eqn.primitive.name == "pallas_call"
+               and eqn.params["jaxpr"].debug_info.func_name
+               == "_flash_dkv_kernel"]
+        assert len(dkv) == 1
+        assert [v.aval.shape for v in dkv[0].outvars] == [(4, 64, 8)] * 2
+        # the kernel's results are the gradients, reshaped and nothing more
+        outs = {id(v) for v in dkv[0].outvars}
+        assert not [eqn for eqn in _eqns(jaxpr.jaxpr)
+                    if eqn.primitive.name in ("reduce_sum", "add", "add_any")
+                    and any(id(v) in outs for v in eqn.invars)]
+        _assert_flash_matches_dense((2, 8, 64, 8), (2, 2, 64, 8), 16, 16,
+                                    window)
+
+    # 32 query heads a K/V head at d 128 and block 512, a group that no
+    # step holds, on a row of one block and of two; and a row of 300 (one
+    # block of 300 rows at the default block 512, strips of 4), with and
+    # without a window
+    @pytest.mark.parametrize("q_shape,kv_shape,block,window", [
+        ((1, 32, 512, 128), (1, 1, 512, 128), 512, None),
+        ((1, 32, 1024, 128), (1, 1, 1024, 128), 512, None),
+        ((1, 8, 300, 16), (1, 2, 300, 16), 512, None),
+        ((1, 8, 300, 16), (1, 2, 300, 16), 512, 100)])
+    def test_groups_a_step_does_not_hold_match_dense(
+            self, interpret_pallas, q_shape, kv_shape, block, window):
+        """Where the group does not fold (``flash_plan``), a step holds one
+        query head, and dK/dV are summed to the K/V heads after the
+        kernel."""
+        from deeplearning4j_tpu.ops import pallas_kernels as pk
+        n, t, d = q_shape[1], q_shape[2], q_shape[3]
+        bq = min(block, t)
+        assert pk.flash_plan(n, t, d, 4, bq, bq,
+                             q_shape[1] // kv_shape[1]).group == 1
+        _assert_flash_matches_dense(q_shape, kv_shape, block, block, window)
 
     def test_scan_escape_hatch_gqa(self, rng, interpret_pallas, monkeypatch):
         import jax
