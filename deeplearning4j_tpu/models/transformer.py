@@ -27,7 +27,11 @@ rather than as layer-zoo glue:
   two normed low-rank latents, one rotated key shared by every head) builds
   q, k and v of one width and enters the same attention route; a
   multi-token-prediction module (``mtp``) adds a second head's loss on the
-  token after next (``_mtp_loss``).
+  token after next (``_mtp_loss``);
+- manifold-constrained hyper-connections (``hyper``) widen the residual
+  stream to n streams, mixed around each sublayer by maps computed from the
+  streams themselves, the stream-to-stream one projected onto the doubly
+  stochastic matrices by Sinkhorn-Knopp (``_hc_pre`` / ``_hc_post``).
 """
 
 from __future__ import annotations
@@ -104,7 +108,7 @@ def _full_heads(kv_group, k, v):
     return k, v
 
 
-def _blockwise_route(c, q, k, v, plan=None, window=None):
+def _blockwise_route(c, q, k, v, plan=None, window=None, scale=None):
     """Route the block_size attention: the pallas flash kernel (fused fwd
     + FlashAttention-2 bwd, ops/pallas_kernels.py) when the platform
     supports it, else the mathematically identical lax.scan recurrence.
@@ -112,7 +116,9 @@ def _blockwise_route(c, q, k, v, plan=None, window=None):
     jits once), so set it before the first fit_batch. A sliding window
     (``window``, the layer's) rides the pallas route — the scan has no
     window support, so that combination falls back to masked dense
-    attention. Head count, group and head size are the arrays' own.
+    attention. A softmax ``scale`` of the layer's own (None: ``1 /
+    sqrt(d)``) rides every route. Head count, group and widths (v's may be
+    its own) are the arrays' own.
 
     ``plan`` is the model's ``ShardingCore`` when it was ``shard()``-ed:
     GSPMD refuses to partition a Mosaic kernel ("wrap the call in a
@@ -124,7 +130,7 @@ def _blockwise_route(c, q, k, v, plan=None, window=None):
             # GQA rides the kernel's index map — no repeat materialized
             attend = functools.partial(
                 flash_attention, causal=True, block_q=c.block_size,
-                block_k=c.block_size, window=window)
+                block_k=c.block_size, window=window, scale=scale)
             if plan is not None and plan.batch_axis:
                 spec = plan.batch_spec()
                 attend = jax.shard_map(
@@ -134,12 +140,13 @@ def _blockwise_route(c, q, k, v, plan=None, window=None):
     # the JAX fallbacks want full heads
     k, v = _full_heads(q.shape[1] // k.shape[1], k, v)
     if window is not None:
-        return dense_attention(q, k, v, causal=True, window=window)
+        return dense_attention(q, k, v, causal=True, window=window,
+                               scale=scale)
     return blockwise_attention(q, k, v, causal=True,
-                               block_size=c.block_size)
+                               block_size=c.block_size, scale=scale)
 
 __all__ = ["TransformerConfig", "TransformerLM", "LayerSpec", "Rope",
-           "Experts", "Latent", "SCOPES"]
+           "Experts", "Latent", "HyperConnections", "SCOPES"]
 
 # The fixed vocabulary of ``jax.named_scope``s the LM step enters: what the
 # profiler's trace names a device op by (the stat ``tf_op`` of its event,
@@ -177,7 +184,14 @@ SCOPES = ("embed", "block", "ln1", "qkv", "attn", "proj", "ln2", "mlp",
           # (``block.q_latent``, ``block.kv_latent``) and the kernels
           # (``block.attn_latent``); the multi-token-prediction module's join
           # (``mtp.join``) and its head and loss (``mtp.logits_loss``)
-          "q_latent", "kv_latent", "attn_latent", "mtp", "join")
+          "q_latent", "kv_latent", "attn_latent", "mtp", "join",
+          # hyper-connections (``TransformerConfig.hyper``) around each
+          # sublayer: the norm over the n streams, the three maps, Sinkhorn
+          # and the mix into the sublayer's input (``block.hc_pre``); the
+          # streams' mix and the sublayer's output added back
+          # (``block.hc_post``). The sublayers' own norms stay under ``ln1``
+          # and ``ln2``
+          "hc_pre", "hc_post")
 
 
 @dataclass(frozen=True)
@@ -248,13 +262,43 @@ class Latent:
     normed ``q_rank`` latent, keys and values from a normed ``kv_rank`` latent,
     each head's query and key ``d_nope`` dims without position joined by
     ``d_rope`` rotated dims, the key's ONE rotated part shared by every head.
-    Values are ``d_v`` wide; the flash route takes q, k and v of one width, so
-    ``d_nope + d_rope == d_v == head_dim``."""
+    Values are ``d_v`` wide, at most the query's width, and ``head_dim`` is
+    ``d_v`` (the output's width a head); the flash route takes v at its own
+    width. ``scale`` multiplies the softmax's ``1 / sqrt(d_nope + d_rope)``:
+    DeepSeek's YaRN (``mscale_all_dim``) scales every logit by mscale²."""
     q_rank: int
     kv_rank: int
     d_nope: int
     d_rope: int
     d_v: int
+    scale: float = 1.0
+
+
+@dataclass(frozen=True)
+class HyperConnections:
+    """Manifold-constrained hyper-connections (mHC, arXiv:2512.24880): the
+    residual stream is ``streams`` streams of d_model, x_l in R^{n x C}, and
+    each sublayer F (attention, FFN) is wrapped as
+
+        x~ = RMSNorm(vec(x_l)) over n·C (no gain: it would scale phi's rows)
+        H_pre  = sigmoid(a_pre · x~ phi_pre + b_pre)              1 x n
+        H_post = 2 sigmoid(a_post · x~ phi_post + b_post)         1 x n
+        H_res  = Sinkhorn(exp(clamp(a_res · mat(x~ phi_res) + b_res)))   n x n
+        x_{l+1} = H_res x_l + H_post^T F(H_pre x_l)
+
+    Sinkhorn-Knopp alternates ``sinkhorn_iters`` row and column
+    normalisations, each sum plus ``eps``; the clamp is +-``clamp``. The
+    stream starts as n copies of the embedding and ends as their sum before
+    the final norm."""
+    streams: int = 4
+    sinkhorn_iters: int = 20
+    eps: float = 1e-6
+    clamp: float = 30.0
+
+    @property
+    def maps(self):
+        """Columns of phi and entries of b a sublayer: n + n + n²."""
+        return self.streams * (self.streams + 2)
 
 
 @dataclass
@@ -321,6 +365,8 @@ class TransformerConfig:
     # the token after next with ``mtp_weight`` times its loss. None: no module
     mtp: Optional[LayerSpec] = None
     mtp_weight: float = 0.3
+    # ---- hyper-connections; the default: one residual stream, x + F(x) ----
+    hyper: Optional[HyperConnections] = None
 
     def __post_init__(self):
         if self.head_dim is None and self.d_model % self.n_heads:
@@ -364,12 +410,12 @@ class TransformerConfig:
                 raise ValueError("rope needs an even rotated head dim")
         lat = self.latent
         if lat is not None and (self.pos_embed != "rope" or self.kv_group != 1
-                                or lat.d_nope + lat.d_rope != lat.d_v
+                                or lat.d_nope + lat.d_rope < lat.d_v
                                 or lat.d_v != self.hd or lat.d_rope % 2):
             raise ValueError(
                 "latent attention takes rope, one key/value head a query "
-                "head, and q, k, v of one width: d_nope + d_rope == d_v == "
-                f"head_dim, d_rope even; got {lat}, head_dim {self.hd}")
+                "head, and v at most as wide as q and k: d_v == head_dim <= "
+                f"d_nope + d_rope, d_rope even; got {lat}, head_dim {self.hd}")
         if self.mtp is not None and self.mtp.ffn == "experts" \
                 and self.experts is None:
             raise ValueError("the mtp layer has experts and the "
@@ -381,6 +427,11 @@ class TransformerConfig:
                 "a multi-token-prediction module (`mtp`) or a selection bias "
                 "(`Experts.bias_rate`) is not implemented for a looped stack "
                 "(`loops`, `exit_gate`)")
+        if self.hyper is not None and (self.loops > 1 or self.post_norm
+                                       or self.exit_gate):
+            raise NotImplementedError(
+                "hyper-connections (`hyper`) are not implemented for a looped "
+                "stack (`loops`, `post_norm`, `exit_gate`)")
 
     @property
     def hd(self):
@@ -423,17 +474,19 @@ class TransformerConfig:
         per-layer list or experts by name, never trains every layer as
         layer 0, a looped model (`loops`, `post_norm`, `exit_gate`)
         likewise, never as one run, and latent attention (`latent`) or a
-        multi-token-prediction module (`mtp`), never as the plain block."""
+        multi-token-prediction module (`mtp`), never as the plain block, and
+        hyper-connections (`hyper`), never on one stream."""
         looped = self.loops > 1 or self.post_norm or self.exit_gate
         if self.layers is not None or self.experts is not None or looped \
-                or self.latent is not None or self.mtp is not None:
+                or self.latent is not None or self.mtp is not None \
+                or self.hyper is not None:
             raise NotImplementedError(
                 f"{what} runs one block program for every layer and is not "
                 "implemented for a configuration with a per-layer list "
                 "(`layers`), `experts`, a looped stack (`loops`, "
-                "`post_norm`, `exit_gate`), latent attention (`latent`) or "
-                "a multi-token-prediction module (`mtp`); train it through "
-                "TransformerLM.fit_batch")
+                "`post_norm`, `exit_gate`), latent attention (`latent`), "
+                "a multi-token-prediction module (`mtp`) or hyper-connections "
+                "(`hyper`); train it through TransformerLM.fit_batch")
         return self.layer_spec(0)
 
     @property
@@ -463,7 +516,8 @@ class TransformerConfig:
         for one decoded token. Latent attention (`latent`) and the
         multi-token-prediction module (`mtp`) are refused by name too: the
         served caches hold per-head keys and values, not the latent, and
-        nothing drafts with the module."""
+        nothing drafts with the module; so are hyper-connections (`hyper`):
+        the served programs carry one residual stream a token."""
         if self.has_experts:
             raise NotImplementedError(
                 f"{what} is not implemented for a configuration with "
@@ -474,6 +528,10 @@ class TransformerConfig:
                 f"{what} is not implemented for latent attention (`latent`) "
                 "or a multi-token-prediction module (`mtp`); train and score "
                 "it through fit_batch / eval_loss / output")
+        if self.hyper is not None:
+            raise NotImplementedError(
+                f"{what} is not implemented for hyper-connections (`hyper`); "
+                "train and score it through fit_batch / eval_loss / output")
 
 
 def _decay_mask(params):
@@ -550,6 +608,76 @@ def _latent_qkv(c, bp, h, spec, positions):
         return q, k, kv[..., lat.d_nope:]
 
 
+def _latent_scale(c):
+    """The softmax scale of latent attention where ``Latent.scale`` moves it
+    off ``1 / sqrt(d_nope + d_rope)``, else None (the kernels' own)."""
+    lat = c.latent
+    if lat.scale == 1.0:
+        return None
+    return lat.scale / math.sqrt(lat.d_nope + lat.d_rope)
+
+
+def _sinkhorn(m, iters, eps):
+    """Sinkhorn-Knopp on the positive ``m`` [..., n, n]: ``iters`` rounds of
+    a row normalisation then a column one, each sum plus ``eps``."""
+    for _ in range(iters):
+        m = m / (m.sum(-1, keepdims=True) + eps)
+        m = m / (m.sum(-2, keepdims=True) + eps)
+    return m
+
+
+def _hc_pre(c, bp, x, name):
+    """mHC's maps of one sublayer (``HyperConnections``) from the streams
+    ``x`` [B, T, n, C], and the sublayer's input ``H_pre x`` [B, T, C]:
+    ``(input, H_post [B, T, n], H_res [B, T, n, n], the worst |row or column
+    sum - 1| of H_res)``. The norm over n·C is a scalar a token, so it is
+    applied to the product ``vec(x) phi`` (n(n + 2) columns) and not to x;
+    the maps, Sinkhorn and the mixes are float32. Leaves of ``bp``: ``name``
+    (phi: [n·C, n | n | n²]), ``name_alpha`` [3], ``name_b`` [n(n + 2)]."""
+    hc = c.hyper
+    B, T, n, C = x.shape
+    flat = x.reshape(B, T, n * C)
+    ms = jnp.mean(jnp.square(flat.astype(jnp.float32)), -1, keepdims=True)
+    z = jnp.matmul(flat, bp[name], preferred_element_type=jnp.float32) \
+        * jax.lax.rsqrt(ms + c.norm_eps)
+    alpha = bp[name + "_alpha"].astype(jnp.float32)
+    b = bp[name + "_b"].astype(jnp.float32)
+    pre = jax.nn.sigmoid(alpha[0] * z[..., :n] + b[:n])
+    post = 2.0 * jax.nn.sigmoid(alpha[1] * z[..., n:2 * n] + b[n:2 * n])
+    res = (alpha[2] * z[..., 2 * n:] + b[2 * n:]).reshape(B, T, n, n)
+    res = _sinkhorn(jnp.exp(jnp.clip(res, -hc.clamp, hc.clamp)),
+                    hc.sinkhorn_iters, hc.eps)
+    err = jnp.maximum(jnp.abs(res.sum(-1) - 1.0).max(),
+                      jnp.abs(res.sum(-2) - 1.0).max())
+    inp = (pre[..., None] * x.astype(jnp.float32)).sum(-2)
+    return inp.astype(x.dtype), post, res, err
+
+
+def _hc_post(x, y, post, res):
+    """``H_res x + H_post^T y``: the streams ``x`` [B, T, n, C] mixed, the
+    sublayer's output ``y`` [B, T, C] added to each by its weight."""
+    mixed = (res[..., None] * x[:, :, None].astype(jnp.float32)).sum(-2)
+    return (mixed + post[..., None] * y[:, :, None].astype(jnp.float32)) \
+        .astype(x.dtype)
+
+
+def _streams(c, x):
+    """The residual stream of a model with hyper-connections: n copies of
+    ``x`` [B, T, C], [B, T, n, C]; ``x`` itself where there are none."""
+    if c.hyper is None:
+        return x
+    B, T, C = x.shape
+    return jnp.broadcast_to(x[:, :, None], (B, T, c.hyper.streams, C))
+
+
+def _collapse(c, x):
+    """The streams summed back to one before a final norm (Zhu et al. 2024,
+    arXiv:2409.19606); ``x`` itself where there are none."""
+    if c.hyper is None:
+        return x
+    return x.astype(jnp.float32).sum(-2).astype(x.dtype)
+
+
 def _block_apply(c, bp, x, spec, drop=None, rng=None, attend=None, ffn=None,
                  plan=None, positions=None):
     """One pre-LN block from its param dict — THE canonical block math,
@@ -570,9 +698,15 @@ def _block_apply(c, bp, x, spec, drop=None, rng=None, attend=None, ffn=None,
     ([T], or [B, T] where the rows differ; None: ``arange(T)``) are what
     rope turns by. ``attend(q, k, v)`` takes ``q`` [B, H, T, hd] and the
     GROUPED ``k``, ``v`` [B, kv_heads, T, hd], rotated, and returns
-    [B, H, T, hd]. A layer with experts returns ``(x, statistics)``."""
-    B, T, d = x.shape
+    [B, H, T, hd]. A layer with experts returns ``(x, statistics)``.
+
+    With hyper-connections (``c.hyper``) ``x`` is the n streams [B, T, n,
+    C], each sublayer reads ``H_pre x`` and writes back through ``_hc_post``,
+    and every layer returns ``(x, statistics)`` with ``hc_err`` among them:
+    the worst row or column sum error of its two H_res after Sinkhorn."""
+    B, T, d = x.shape[0], x.shape[1], x.shape[-1]
     hd, H = c.hd, spec.n_heads
+    hc = c.hyper
     r1 = r2 = None
     if rng is not None:
         r1, r2 = jax.random.split(rng)
@@ -581,8 +715,12 @@ def _block_apply(c, bp, x, spec, drop=None, rng=None, attend=None, ffn=None,
     # ONE scope element an op ("block.ln1", never "ln1" inside "block"): see
     # the note at SCOPES for what the flash kernels' instruction names need
     scope = jax.named_scope
+    inp = x
+    if hc is not None:
+        with scope("block.hc_pre"):
+            inp, post1, res1, err1 = _hc_pre(c, bp, x, "hc1")
     with scope("block.ln1"):
-        hloc = _norm(c, bp, "ln1", x)
+        hloc = _norm(c, bp, "ln1", inp)
     routing = None
     if spec.ffn == "experts" and ffn is None \
             and c.experts.router_input == "block":
@@ -610,13 +748,15 @@ def _block_apply(c, bp, x, spec, drop=None, rng=None, attend=None, ffn=None,
                 rot = spec.rope.rotated(hd) if spec.rope.share < 1.0 else None
                 q = _apply_rope(q, cos, sin, c.rope_layout, rot)
                 k = _apply_rope(k, cos, sin, c.rope_layout, rot)
+        scale = None if c.latent is None else _latent_scale(c)
         if attend is not None:
             o = attend(q, k, v)
         elif c.block_size:
-            o = _blockwise_route(c, q, k, v, plan, spec.window)
+            o = _blockwise_route(c, q, k, v, plan, spec.window, scale)
         else:
             k, v = _full_heads(H // c.kv_heads, k, v)
-            o = dense_attention(q, k, v, causal=True, window=spec.window)
+            o = dense_attention(q, k, v, causal=True, window=spec.window,
+                                scale=scale)
         o = o.transpose(0, 2, 1, 3).reshape(B, T, qd)
     if c.attn_gate:
         with scope("block.attn_gate"):
@@ -625,18 +765,28 @@ def _block_apply(c, bp, x, spec, drop=None, rng=None, attend=None, ffn=None,
                  * gate[..., None].astype(o.dtype)).reshape(B, T, qd)
     with scope("block.proj"):
         a = _linear(c, bp, "proj", o)
-        if not c.post_norm:
+        if not c.post_norm and hc is None:
             x = add(x, a, r1)
+    if hc is not None:
+        with scope("block.hc_post"):
+            x = _hc_post(x, drop(a, r1) if drop else a, post1, res1)
+        with scope("block.hc_pre"):
+            inp, post2, res2, err2 = _hc_pre(c, bp, x, "hc2")
+            hc_stats = {"hc_err": jnp.maximum(err1, err2)}
     if c.post_norm:   # sandwich: the sublayer's output is normed, then summed
         with scope("block.attn_norm"):
             x = add(x, _norm(c, bp, "attn_norm", a), r1)
     with scope("block.ln2"):
-        hloc = _norm(c, bp, "ln2", x)
+        hloc = _norm(c, bp, "ln2", inp if hc is not None else x)
     if spec.ffn == "experts" and ffn is None:
         m, stats = expert_ffn(c.experts, bp, hloc, routing)
         if c.post_norm:
             with scope("block.mlp_norm"):
                 m = _norm(c, bp, "mlp_norm", m)
+        if hc is not None:
+            with scope("block.hc_post"):
+                return _hc_post(x, drop(m, r2) if drop else m, post2,
+                                res2), dict(stats, **hc_stats)
         return add(x, m, r2), stats
     with scope("block.mlp"):
         if ffn is not None:
@@ -646,20 +796,25 @@ def _block_apply(c, bp, x, spec, drop=None, rng=None, attend=None, ffn=None,
         else:
             m = jax.nn.gelu(hloc @ bp["fc"] + bp["fc_b"]) @ bp["out"] \
                 + bp["out_b"]
-        if not c.post_norm:
+        if not c.post_norm and hc is None:
             return add(x, m, r2)
+    if hc is not None:
+        with scope("block.hc_post"):
+            return _hc_post(x, drop(m, r2) if drop else m, post2,
+                            res2), hc_stats
     with scope("block.mlp_norm"):
         return add(x, _norm(c, bp, "mlp_norm", m), r2)
 
 
 def _remat(blk):
-    """``blk`` rematerialised: its backward recomputes it from its input,
-    except the two results only the flash forward kernel can produce
-    (``FLASH_RESIDUALS``: the attention output, ``B·T·H·hd`` compute-dtype
-    elements, and the logsumexp, ``B·H·T`` float32 as the kernel wrote it),
-    which are kept, so the kernel runs once a step and not again in the
-    backward. Off the flash route nothing carries those names and only the
-    input is kept."""
+    """``blk`` rematerialised: its backward recomputes it from its input
+    (``B·T·d`` compute-dtype elements, ``B·T·n·d`` with n hyper-connection
+    streams), except the two results only the flash forward kernel can
+    produce (``FLASH_RESIDUALS``: the attention output, ``B·T·H·hd``
+    compute-dtype elements, and the logsumexp, ``B·H·T`` float32 as the
+    kernel wrote it), which are kept, so the kernel runs once a step and not
+    again in the backward. Off the flash route nothing carries those names
+    and only the input is kept."""
     return jax.checkpoint(
         blk, policy=jax.checkpoint_policies.save_only_these_names(
             *FLASH_RESIDUALS))
@@ -715,13 +870,19 @@ def _stack_runs(c, params, x, apply_block, after_run=None):
     temporaries (13.0 against 7.9 GB at six layers, compiled for the v5e:
     PERF.md section 6, PR 32), and the trace's readers count an instruction's
     mean run once a step (``benchmark/scope_reduce.py``), so every scope
-    inside the loop would read at ``1 / loops`` of its time."""
+    inside the loop would read at ``1 / loops`` of its time.
+
+    With hyper-connections the walk starts from n copies of ``x`` and the
+    streams are summed before the final norm (``_streams``, ``_collapse``)."""
     L = c.n_layers
+    if c.hyper is not None:     # one run: ``hyper`` refuses a loop
+        with jax.named_scope("embed"):
+            x = _streams(c, x)
     for run in range(c.loops):
         for i in range(L):
             x = apply_block(run * L + i, params[f"b{i}"], x)
         with jax.named_scope("final_ln"):
-            x = _norm(c, params, "lnf", x)
+            x = _norm(c, params, "lnf", _collapse(c, x))
         if after_run is not None:
             after_run(run, params, x)
     return x
@@ -892,6 +1053,11 @@ def _exit_terms(c, last, ep, h, targets, m, log_surv):
                 log_surv + jax.nn.log_sigmoid(-z), p.sum())
 
 
+# what ``init`` starts mHC's three gating factors at: 1, so that the maps'
+# input-dependent part moves them at the seed (Zhu et al.'s 0.01 leaves the
+# streams copies of each other: benchmark/configs/xing4.0-29b-a4b.json)
+HC_ALPHA = 1.0
+
 _APPLICATIONS_DOC = ("Block applications a forward pass of the last traced "
                      "TransformerLM makes: loops x n_layers")
 _COUNT_LOW = 1 << 30
@@ -914,6 +1080,15 @@ _MOE_DOCS = {
                             "step, summed over layers and steps since init",
     "moe.router_even_rows": "moe.router_rows over the router's width: what "
                             "moe.router_peak_rows reads at an even load",
+}
+
+
+_HC_DOCS = {
+    "hc.sinkhorn_err": "Each step's worst row or column sum error of a "
+                       "hyper-connection's H_res after Sinkhorn, in "
+                       "millionths, summed over the steps since init (read "
+                       "by TransformerLM.hc_counters)",
+    "hc.steps": "Steps counted in hc.sinkhorn_err, since init",
 }
 
 
@@ -1082,6 +1257,13 @@ class TransformerLM:
                                              * (lat.d_nope + lat.d_v)))
             linear(bp, "proj", k[1], (qd, d), rs)
             norm(bp, "ln2")
+            if c.hyper is not None:
+                n, maps = c.hyper.streams, c.hyper.maps
+                kh = jax.random.split(jax.random.fold_in(k[4], 1), 2)
+                for name, key in zip(("hc1", "hc2"), kh):
+                    bp[name] = normal(key, (n * d, maps))
+                    bp[name + "_alpha"] = jnp.full((3,), HC_ALPHA)
+                    bp[name + "_b"] = jnp.zeros((maps,))
             if c.post_norm:
                 norm(bp, "attn_norm")
                 norm(bp, "mlp_norm")
@@ -1120,9 +1302,10 @@ class TransformerLM:
 
     def _init_opt_state(self):
         """AdamW's moments at zero beside ``self.params`` (and the Polyak
-        shadow, the expert layers' counters and the routers' selection
-        biases with the load that last moved each, where the configuration
-        has them: the biases start at 0)."""
+        shadow, the expert layers' counters, the routers' selection
+        biases with the load that last moved each and the hyper-connections'
+        counters, where the configuration has them: the biases start at
+        0)."""
         c = self.conf
         self.opt_state = {
             "m": jax.tree.map(jnp.zeros_like, self.params),
@@ -1145,6 +1328,9 @@ class TransformerLM:
             self.opt_state["exit"] = {
                 "mass": jnp.zeros((c.loops, 2), jnp.int32),
                 "tokens": jnp.zeros((2,), jnp.int32)}
+        if c.hyper is not None:
+            self.opt_state["hc"] = {k: jnp.zeros((2,), jnp.int32)
+                                    for k in ("sinkhorn_err", "steps")}
 
     def num_params(self):
         return sum(int(np.prod(a.shape))
@@ -1184,6 +1370,26 @@ class TransformerLM:
                 / self.conf.experts.n_experts
         for name, n in out.items():
             obs.metrics.gauge(name, _MOE_DOCS[name]).set(n)
+        return out
+
+    def hc_counters(self):
+        """The hyper-connections' counts since ``init``:
+        ``hc.sinkhorn_err``, each step's worst |row or column sum - 1| of any
+        token's H_res after Sinkhorn, over every layer and sublayer, in
+        whole millionths rounded up, summed over the steps, and
+        ``hc.steps``, the steps counted, so that a window's difference of
+        the two over the difference of the second is its mean worst error.
+        Carried on the device and fetched HERE as ``moe_counters`` does: one
+        sync, never inside a timed loop; the gauges of the same names take
+        what was read. ``{}`` for a model without hyper-connections."""
+        kept = (self.opt_state or {}).get("hc")
+        if kept is None:
+            return {}
+        host = jax.device_get(kept)
+        out = {f"hc.{k}": int(v[0]) * _COUNT_LOW + int(v[1])
+               for k, v in host.items()}
+        for name, n in out.items():
+            obs.metrics.gauge(name, _HC_DOCS[name]).set(n)
         return out
 
     def exit_counters(self):
@@ -1226,14 +1432,15 @@ class TransformerLM:
 
     def _apply_block(self, spec, bp, x, rng, stats, bias=None):
         """One block application as the step makes it: rematerialised where
-        ``remat`` is set; an expert layer's statistics appended to ``stats``
+        ``remat`` is set; an expert layer's statistics, and every layer's
+        where the model has hyper-connections, appended to ``stats``
         (they leave a rematerialised block as outputs of it; None drops
         them), its router's selection ``bias`` handed in beside its leaves."""
         blk = functools.partial(self._block, spec)
         if bias is not None:
             bp = dict(bp, router_bias=bias)
         out = (_remat(blk) if self.conf.remat else blk)(bp, x, rng)
-        if spec.ffn != "experts":
+        if spec.ffn != "experts" and self.conf.hyper is None:
             return out
         if stats is not None:
             stats.append(out[1])
@@ -1340,8 +1547,10 @@ class TransformerLM:
 
             h'_i = [RMSNorm_e(Emb(t_{i+1})) ; RMSNorm_h(H_i)] W_eh
 
-        runs its own decoder layer, its own final norm and the shared head, and
-        predicts t_{i+2}: the main target of position i + 1. The last position
+        runs its own decoder layer (with hyper-connections: on n copies of
+        h', with its own maps, summed before its final norm), its own final
+        norm and the shared head, and predicts t_{i+2}: the main target of
+        position i + 1. The last position
         has no such target and is masked; ``L_mtp`` is the mean over the
         positions that have one. Each head and its loss is rematerialised
         where the blocks are, so that one head's vocabulary-wide logits live
@@ -1361,12 +1570,13 @@ class TransformerLM:
         mp = cast["mtp"]
         emb = _embed(c, params, targets)          # Emb(t_{i+1})
         with jax.named_scope("mtp.join"):
-            x = jnp.concatenate([_norm(c, mp, "enorm", emb),
-                                 _norm(c, mp, "hnorm", h)], axis=-1) @ mp["eh"]
+            x = _streams(c, jnp.concatenate([_norm(c, mp, "enorm", emb),
+                                             _norm(c, mp, "hnorm", h)],
+                                            axis=-1) @ mp["eh"])
         x = self._apply_block(c.mtp_spec, mp["block"], x, None, stats,
                               bias.get("mtp"))
         with jax.named_scope("final_ln"):
-            x = _norm(c, mp, "lnf", x)
+            x = _norm(c, mp, "lnf", _collapse(c, x))
         extra = terms("mtp.logits_loss")(heads, x, later, m_later)
         return main / jnp.maximum(m.sum(), 1.0) \
             + c.mtp_weight * extra / jnp.maximum(m_later.sum(), 1.0)
@@ -1384,16 +1594,22 @@ class TransformerLM:
             rng, sub = jax.random.split(rng)
             fwd_params = params if plan is None else plan.gather_params(params)
             sub = sub if c.dropout > 0 else None
-            if c.has_experts or c.exit_gate:
+            if c.has_experts or c.exit_gate or c.hyper is not None:
                 def loss_and_stats(p):
                     found, exits = [], []
                     loss = self._loss(p, tokens, targets, mask, sub, found,
                                       exits, opt.get("expert_bias"))
-                    stats = {k: sum(f[k] for f in found)
+                    # an expert layer's statistics (a layer with
+                    # hyper-connections and no experts has hc_err alone)
+                    moe = [f for f in found if "local_rows" in f]
+                    stats = {k: sum(f[k] for f in moe)
                              for k in stat_names(c.experts)} \
                         if c.has_experts else None
                     if c.biased_layers:   # in the order the step applies them
-                        stats["load"] = [f["load"] for f in found]
+                        stats["load"] = [f["load"] for f in moe]
+                    if c.hyper is not None:
+                        stats = dict(stats or {}, hc_err=functools.reduce(
+                            jnp.maximum, [f["hc_err"] for f in found]))
                     return loss, (stats, exits)
                 (loss, (stats, exits)), grads = jax.value_and_grad(
                     loss_and_stats, has_aux=True)(fwd_params)
@@ -1429,6 +1645,13 @@ class TransformerLM:
             if c.has_experts:
                 new_opt["moe"] = {k: _count(opt["moe"][k], stats[k])
                                   for k in stat_names(c.experts)}
+            if c.hyper is not None:
+                # the step's worst H_res sum error in whole millionths,
+                # rounded up, and the step itself
+                worst = jnp.ceil(stats["hc_err"] * 1e6).astype(jnp.int32)
+                new_opt["hc"] = {
+                    "sinkhorn_err": _count(opt["hc"]["sinkhorn_err"], worst),
+                    "steps": _count(opt["hc"]["steps"], 1)}
             if c.exit_gate:
                 # in whole tokens: an exit's summed probability, rounded
                 mass = jnp.round(jnp.stack(exits)).astype(jnp.int32)

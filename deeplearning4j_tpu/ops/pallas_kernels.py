@@ -730,14 +730,16 @@ def _qkv_specs(plan, kv_group, block_q, block_k, d):
     return q_like, ((plan.heads, block_k, d), _kv_block(kv_group))
 
 
-def _statics(q, causal, block_q, block_k, window, kv_group, inners):
+def _statics(q, causal, block_q, block_k, window, kv_group, inners,
+             scale=None):
     """What the kernels are built from besides the arrays, read where the
     call is made: ``_flash_forward`` and ``_flash_backward`` are traced once
     for each value of it. ``inners`` names the walks of the kernels this
     call site adds to the program ("k": forward or dQ, "q": dK/dV), for the
     gauges: they count here, once a call site, and not inside the shared
     trace. The forward ("k" alone) has one statistic at its boundary, lse;
-    each kernel of the backward ("kq") has two, lse and delta."""
+    each kernel of the backward ("kq") has two, lse and delta. ``scale``:
+    the softmax's, None for ``1 / sqrt(d)`` of q's width."""
     n, t, d = q.shape
     plan = flash_plan(n, t, d, q.dtype.itemsize, block_q, block_k, kv_group)
     for inner in inners:
@@ -754,8 +756,8 @@ def _statics(q, causal, block_q, block_k, window, kv_group, inners):
     statistics = 1 if inners == "k" else 2
     _STAT_BYTES.set(_STAT_BYTES.value + len(inners) * statistics * 4 * n * t)
     return dict(causal=causal, block_q=block_q, block_k=block_k,
-                window=window, kv_group=kv_group, interpret=_interpret_mode(),
-                plan=plan)
+                window=window, kv_group=kv_group, scale=scale,
+                interpret=_interpret_mode(), plan=plan)
 
 
 # Traced once for each shape and inlined where it is called (``inline``: no
@@ -766,39 +768,42 @@ def _statics(q, causal, block_q, block_k, window, kv_group, inners):
 _trace_once = functools.partial(
     jax.jit, inline=True,
     static_argnames=("causal", "block_q", "block_k", "window", "kv_group",
-                     "plan", "interpret"))
+                     "scale", "plan", "interpret"))
 
 
 @_trace_once
 def _flash_forward(q, k, v, *, causal, block_q, block_k, window, kv_group,
-                   plan, interpret):
-    """q: [n, T, d]; k/v: [n // kv_group, T, d] (n = batch·q-heads).
+                   scale, plan, interpret):
+    """q, k: [n, T, d], [n // kv_group, T, d] (n = batch·q-heads); v:
+    [n // kv_group, T, dv], its own width, which the output takes.
     ``kv_group`` > 1 is grouped-query attention: consecutive runs of
     kv_group query heads share one K/V head, and a grid step holds such a
     run and its K/V head (``FlashPlan.group``; no materialized repeat).
-    T must divide by the blocks."""
+    T must divide by the blocks. ``scale`` None is ``1 / sqrt(d)``."""
     from jax.experimental.pallas import tpu as pltpu
 
     n, t, d = q.shape
+    dv = v.shape[-1]
     hb, group = plan.heads, plan.group
     walk = flash_walk(causal, window, block_q, block_k, t, "k")
     kernel = functools.partial(
         _flash_kernel, plan=plan, block_q=block_q, block_k=block_k,
-        n_qb=t // block_q, causal=causal, scale=1.0 / (d ** 0.5),
-        window=window)
-    q_spec, kv_spec = _qkv_specs(plan, kv_group, block_q, block_k, d)
+        n_qb=t // block_q, causal=causal,
+        scale=1.0 / (d ** 0.5) if scale is None else scale, window=window)
+    q_spec, k_spec = _qkv_specs(plan, kv_group, block_q, block_k, d)
+    o_spec, v_spec = _qkv_specs(plan, kv_group, block_q, block_k, dv)
     stat_shape, stat_block, stat_index = _stat_rows(n, t, hb, block_q, group)
     scratch = [] if walk.single else [
         pltpu.VMEM((hb, group * block_q, _LANES), jnp.float32),  # running max
         pltpu.VMEM((hb, group * block_q, _LANES), jnp.float32),  # running sum
-        pltpu.VMEM((hb, group * block_q, d), jnp.float32),   # unnormalized out
+        pltpu.VMEM((hb, group * block_q, dv), jnp.float32),  # unnormalized out
     ]
     return _walk_call(
         kernel, walk, n // (hb * group),
-        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_shape=[jax.ShapeDtypeStruct((n, t, dv), q.dtype),
                    jax.ShapeDtypeStruct(stat_shape, jnp.float32)],   # lse
-        in_specs=[q_spec, kv_spec, kv_spec],
-        out_specs=[q_spec, (stat_block, stat_index)],
+        in_specs=[q_spec, k_spec, v_spec],
+        out_specs=[o_spec, (stat_block, stat_index)],
         scratch_shapes=scratch,
         interpret=interpret,
         vmem_limit=_GROUP_VMEM if group > 1 else None,
@@ -953,12 +958,12 @@ def _flash_dkv_kernel(table, q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
             dv_ref[...] = dv_scr[...].astype(dv_ref.dtype)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
 def _flash_attention_3d(q, k, v, causal, block_q, block_k, window=None,
-                        kv_group=1):
+                        kv_group=1, scale=None):
     out, _lse = _flash_forward(
         q, k, v,
-        **_statics(q, causal, block_q, block_k, window, kv_group, "k"))
+        **_statics(q, causal, block_q, block_k, window, kv_group, "k", scale))
     return out
 
 
@@ -969,16 +974,18 @@ def _flash_attention_3d(q, k, v, causal, block_q, block_k, window=None,
 FLASH_RESIDUALS = ("flash_out", "flash_lse")
 
 
-def _flash_fwd(q, k, v, causal, block_q, block_k, window=None, kv_group=1):
+def _flash_fwd(q, k, v, causal, block_q, block_k, window=None, kv_group=1,
+               scale=None):
     out, lse = _flash_forward(
         q, k, v,
-        **_statics(q, causal, block_q, block_k, window, kv_group, "k"))
+        **_statics(q, causal, block_q, block_k, window, kv_group, "k", scale))
     # what the kernel wrote is what is named: one float32 a row
     out, lse = map(checkpoint_name, (out, lse), FLASH_RESIDUALS)
     return out, (q, k, v, out, lse)
 
 
-def _flash_bwd(causal, block_q, block_k, window, kv_group, residuals, g):
+def _flash_bwd(causal, block_q, block_k, window, kv_group, scale, residuals,
+               g):
     if env_str("DL4J_TPU_FLASH_BWD") == "scan":
         # escape hatch: the rematerializing lax.scan backward (dense
         # oracle when a window is set — the scan has no window support).
@@ -992,25 +999,27 @@ def _flash_bwd(causal, block_q, block_k, window, kv_group, residuals, g):
         if window is not None:
             _, vjp = jax.vjp(
                 lambda a, b, c: dense_attention(a, rep(b), rep(c),
-                                                causal=causal,
-                                                window=window), q, k, v)
+                                                causal=causal, window=window,
+                                                scale=scale), q, k, v)
         else:
             _, vjp = jax.vjp(
                 lambda a, b, c: blockwise_attention(a, rep(b), rep(c),
                                                     causal=causal,
-                                                    block_size=block_k),
+                                                    block_size=block_k,
+                                                    scale=scale),
                 q, k, v)
         return vjp(g)
     return _flash_backward(
         *residuals, g,
         **_statics(residuals[0], causal, block_q, block_k, window, kv_group,
-                   "kq"))
+                   "kq", scale))
 
 
 @_trace_once
 def _flash_backward(q, k, v, out, lse, g, *, causal, block_q, block_k, window,
-                    kv_group, plan, interpret):
-    """dQ, dK, dV of ``_flash_forward`` from its residuals and dO. Where a
+                    kv_group, scale, plan, interpret):
+    """dQ, dK, dV of ``_flash_forward`` from its residuals and dO (v, the
+    output, dO and dV at v's width). Where a
     step holds a K/V group (``plan.group``) the dK/dV kernel sums the
     group inside its products and writes dK and dV a K/V head a row; a
     grouped call that takes one query head a step writes them a query head
@@ -1018,9 +1027,11 @@ def _flash_backward(q, k, v, out, lse, g, *, causal, block_q, block_k, window,
     from jax.experimental.pallas import tpu as pltpu
 
     n, t, d = q.shape
+    dv = v.shape[-1]
     hb, group = plan.heads, plan.group
     static = dict(plan=plan, block_q=block_q, block_k=block_k,
-                  n_qb=t // block_q, causal=causal, scale=1.0 / (d ** 0.5),
+                  n_qb=t // block_q, causal=causal,
+                  scale=1.0 / (d ** 0.5) if scale is None else scale,
                   window=window)
     stat_shape, stat_block, stat_index = _stat_rows(n, t, hb, block_q, group)
     # delta_i = Σ_d dO ⊙ O — a cheap fused elementwise+reduce; XLA keeps it
@@ -1035,7 +1046,8 @@ def _flash_backward(q, k, v, out, lse, g, *, causal, block_q, block_k, window,
         delta = delta.reshape(n // group, group, t // block_q,
                               block_q // tq, tq).transpose(
             0, 2, 3, 1, 4).reshape(stat_shape)
-    q_spec, kv_spec = _qkv_specs(plan, kv_group, block_q, block_k, d)
+    q_spec, k_spec = _qkv_specs(plan, kv_group, block_q, block_k, d)
+    o_spec, v_spec = _qkv_specs(plan, kv_group, block_q, block_k, dv)
     rows = n // (hb * group)
     vmem_limit = _GROUP_VMEM if group > 1 else None
 
@@ -1043,7 +1055,7 @@ def _flash_backward(q, k, v, out, lse, g, *, causal, block_q, block_k, window,
     dq, = _walk_call(
         functools.partial(_flash_dq_kernel, **static), walk, rows,
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype)],
-        in_specs=[q_spec, kv_spec, kv_spec, q_spec,
+        in_specs=[q_spec, k_spec, v_spec, o_spec,
                   (stat_block, stat_index),
                   (stat_block, stat_index)],
         out_specs=[q_spec],
@@ -1061,37 +1073,41 @@ def _flash_backward(q, k, v, out, lse, g, *, causal, block_q, block_k, window,
     # that holds one query head a step (``flash_plan``: too large to fold)
     # is written a query head a row and summed to its K/V head outside
     per_head = kv_group > 1 and group == 1
-    dkv_shape = (n, t, d) if per_head else k.shape
+    dk_shape = (n, t, d) if per_head else k.shape
+    dv_shape = (n, t, dv) if per_head else v.shape
     walk = flash_walk(causal, window, block_q, block_k, t, "q")
-    dk, dv = _walk_call(
+    dk, dval = _walk_call(
         functools.partial(_flash_dkv_kernel, **static), walk, rows,
-        out_shape=[jax.ShapeDtypeStruct(dkv_shape, k.dtype),
-                   jax.ShapeDtypeStruct(dkv_shape, v.dtype)],
-        in_specs=[q_spec, kv_spec, kv_spec, q_spec,
+        out_shape=[jax.ShapeDtypeStruct(dk_shape, k.dtype),
+                   jax.ShapeDtypeStruct(dv_shape, v.dtype)],
+        in_specs=[q_spec, k_spec, v_spec, o_spec,
                   (stat_block, stat_index),
                   (stat_block, stat_index)],
         out_specs=[((hb, block_k, d), _k_block),
-                   ((hb, block_k, d), _k_block)],
+                   ((hb, block_k, dv), _k_block)],
         scratch_shapes=[] if walk.single else [
             pltpu.VMEM((hb, block_k, d), jnp.float32),
-            pltpu.VMEM((hb, block_k, d), jnp.float32)],
+            pltpu.VMEM((hb, block_k, dv), jnp.float32)],
         interpret=interpret,
         vmem_limit=vmem_limit,
     )(q, k, v, g, lse, delta)
     if per_head:
         dk = dk.astype(jnp.float32).reshape(
             n // kv_group, kv_group, t, d).sum(1).astype(k.dtype)
-        dv = dv.astype(jnp.float32).reshape(
-            n // kv_group, kv_group, t, d).sum(1).astype(v.dtype)
-    return dq, dk, dv
+        dval = dval.astype(jnp.float32).reshape(
+            n // kv_group, kv_group, t, dv).sum(1).astype(v.dtype)
+    return dq, dk, dval
 
 
 _flash_attention_3d.defvjp(_flash_fwd, _flash_bwd)
 
 
 def flash_attention(q, k, v, *, causal=False, block_q=512, block_k=512,
-                    window=None):
-    """Pallas flash attention. q: [..., T, d]; exact softmax attention.
+                    window=None, scale=None):
+    """Pallas flash attention. q: [..., T, d]; exact softmax attention,
+    ``softmax(q kᵀ · scale) v``, ``scale`` None for ``1 / sqrt(d)``. v may
+    be narrower or wider than q and k, ``[..., T, dv]``: the output, dO and
+    dV take its width, and nothing is padded to q's.
 
     k/v may carry FEWER heads than q (grouped-query attention): with head
     axis -3, q [..., Hq, T, d] against k/v [..., Hkv, T, d] where
@@ -1141,9 +1157,8 @@ def flash_attention(q, k, v, *, causal=False, block_q=512, block_k=512,
         window = int(window)
         if window < 1:
             raise ValueError(f"window must be >= 1, got {window}")
-    orig_shape = q.shape
+    out_shape = q.shape[:-1] + v.shape[-1:]
     t = q.shape[-2]
-    d = q.shape[-1]
     n_q = int(np.prod(q.shape[:-2], dtype=np.int64)) if q.shape[:-2] else 1
     n_kv = int(np.prod(k.shape[:-2], dtype=np.int64)) if k.shape[:-2] else 1
     if n_q % n_kv:
@@ -1162,7 +1177,7 @@ def flash_attention(q, k, v, *, causal=False, block_q=512, block_k=512,
     pad = max(pad_q, pad_k)
 
     def prep(x):
-        x = x.reshape((-1, t, d))
+        x = x.reshape((-1, t, x.shape[-1]))
         if pad:
             x = jnp.pad(x, [(0, 0), (0, pad), (0, 0)])
         return x
@@ -1177,13 +1192,14 @@ def flash_attention(q, k, v, *, causal=False, block_q=512, block_k=512,
         if kv_group > 1:
             k = jnp.repeat(k, kv_group, axis=-3)
             v = jnp.repeat(v, kv_group, axis=-3)
-        out = blockwise_attention(q, k, v, causal=False, block_size=block_k)
+        out = blockwise_attention(q, k, v, causal=False, block_size=block_k,
+                                  scale=scale)
         return out
     out = _flash_attention_3d(q3, k3, v3, causal, block_q, block_k, window,
-                              kv_group)
+                              kv_group, scale)
     if pad:
         out = out[:, :t]
-    return out.reshape(orig_shape)
+    return out.reshape(out_shape)
 
 
 # ---------------------------------------------------------------------------

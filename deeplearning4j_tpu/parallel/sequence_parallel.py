@@ -32,13 +32,17 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 NEG_INF = -1e30
 
 
-def _attend_block(q, k, v, bias, m_prev, l_prev, o_prev):
+def _attend_block(q, k, v, bias, m_prev, l_prev, o_prev, scale=None):
     """One flash-attention accumulation step.
 
-    q: [..., Tq, d]; k/v: [..., Tk, d]; bias: broadcastable to [..., Tq, Tk]
-    carries: m (running max, [..., Tq]), l (running sum), o (unnormalized out).
+    q: [..., Tq, d]; k/v: [..., Tk, d] (v may be [..., Tk, dv]); bias:
+    broadcastable to [..., Tq, Tk]; ``scale`` multiplies the scores (None:
+    ``1 / sqrt(d)``). carries: m (running max, [..., Tq]), l (running sum),
+    o (unnormalized out).
     """
-    s = jnp.einsum("...qd,...kd->...qk", q, k) / jnp.sqrt(q.shape[-1]).astype(q.dtype)
+    s = jnp.einsum("...qd,...kd->...qk", q, k)
+    s = s / jnp.sqrt(q.shape[-1]).astype(q.dtype) if scale is None \
+        else s * jnp.asarray(scale, q.dtype)
     if bias is not None:
         s = s + bias
     m_new = jnp.maximum(m_prev, s.max(axis=-1))
@@ -55,11 +59,13 @@ def _finalize(m, l, o):
     return o / jnp.maximum(l, 1e-30)[..., None]
 
 
-def blockwise_attention(q, k, v, *, causal=False, block_size=128, mask=None):
+def blockwise_attention(q, k, v, *, causal=False, block_size=128, mask=None,
+                        scale=None):
     """Memory-efficient exact attention (flash recurrence via lax.scan).
 
-    q/k/v: [batch, T, d] (or [batch, heads, T, d]). ``mask``: [batch, Tk]
-    key-validity mask. Returns softmax(QKᵀ/√d)V with O(T·block) memory.
+    q/k/v: [batch, T, d] (or [batch, heads, T, d]; v's last dim may be its
+    own). ``mask``: [batch, Tk] key-validity mask. Returns
+    softmax(QKᵀ·scale)V with O(T·block) memory, ``scale`` None for 1/√d.
     """
     tq = q.shape[-2]
     tk = k.shape[-2]
@@ -86,7 +92,7 @@ def blockwise_attention(q, k, v, *, causal=False, block_size=128, mask=None):
     batch_shape = q.shape[:-2]
     m0 = jnp.full(batch_shape + (tq,), NEG_INF, q.dtype)
     l0 = jnp.zeros(batch_shape + (tq,), q.dtype)
-    o0 = jnp.zeros(q.shape, q.dtype)
+    o0 = jnp.zeros(q.shape[:-1] + v.shape[-1:], q.dtype)
 
     def step(carry, inp):
         m, l, o = carry
@@ -106,7 +112,7 @@ def blockwise_attention(q, k, v, *, causal=False, block_size=128, mask=None):
             extra = jnp.where(mblk > 0, 0.0, NEG_INF).astype(q.dtype)
             extra = extra[:, None, :] if q.ndim == 3 else extra[:, None, None, :]
             bias = bias + extra
-        m, l, o = _attend_block(q, kblk, vblk, bias, m, l, o)
+        m, l, o = _attend_block(q, kblk, vblk, bias, m, l, o, scale)
         return (m, l, o), None
 
     (m, l, o), _ = jax.lax.scan(
@@ -259,11 +265,15 @@ def ulysses_attention(q, k, v, mesh: Mesh, *, axis="seq", causal=False):
     return fn(q, k, v)
 
 
-def dense_attention(q, k, v, *, causal=False, mask=None, window=None):
+def dense_attention(q, k, v, *, causal=False, mask=None, window=None,
+                    scale=None):
     """Reference O(T²) attention (test oracle). ``window`` (requires
     causal): each query sees only the last ``window`` positions —
-    sliding-window attention."""
-    s = jnp.einsum("...qd,...kd->...qk", q, k) / jnp.sqrt(q.shape[-1]).astype(q.dtype)
+    sliding-window attention. ``scale`` multiplies the scores (None:
+    ``1 / sqrt(d)``); v may have a width of its own."""
+    s = jnp.einsum("...qd,...kd->...qk", q, k)
+    s = s / jnp.sqrt(q.shape[-1]).astype(q.dtype) if scale is None \
+        else s * jnp.asarray(scale, q.dtype)
     tq, tk = s.shape[-2], s.shape[-1]
     if causal:
         cm = jnp.tril(jnp.ones((tq, tk), bool))

@@ -147,6 +147,29 @@ def test_flash_kernels_compile_for_v5e(v5e, shape, wrt):
     assert _compile(fn, q, kv, kv) == kernels
 
 
+@pytest.mark.parametrize("wrt", ["fwd", "dq", "dkv"])
+def test_flash_kernels_at_a_v_of_its_own_width_compile_for_v5e(v5e, wrt):
+    """The seventh cell's latent attention (Xing4.0, 1 x 4096 tokens): 4
+    ungrouped heads of q / k 192 (128 + 64 rope) against v 128 under YaRN's
+    softmax scale, rows of 8 blocks: v, the output, dO and dV cross the
+    kernels' boundary at 128, q, k, dQ and dK at 192."""
+    q = jax.ShapeDtypeStruct((1, 4, 4096, 192), jnp.bfloat16, sharding=v5e)
+    v = jax.ShapeDtypeStruct((1, 4, 4096, 128), jnp.bfloat16, sharding=v5e)
+
+    def attend(q, k, v):
+        return pk.flash_attention(q, k, v, causal=True, block_q=512,
+                                  block_k=512, scale=2.00474 / 192 ** 0.5)
+
+    def loss(q, k, v):
+        return attend(q, k, v).astype(jnp.float32).sum()
+
+    fn, kernels = {"fwd": (attend, 1),
+                   "dq": (jax.grad(loss, argnums=0), 2),
+                   "dkv": (jax.grad(loss, argnums=(1, 2)), 2)}[wrt]
+    assert _compile(fn, q, q, v) == kernels
+    assert jax.eval_shape(attend, q, q, v).shape == (1, 4, 4096, 128)
+
+
 # the grouped products of the two expert cells: (rows of the buffer, k, n,
 # held experts, a held expert's rows at an even load)
 GROUPED_SHAPES = {

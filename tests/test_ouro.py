@@ -496,7 +496,9 @@ def test_save_and_load_round_trip_the_gate_and_the_counters(seeded, tmp_path):
 # float32 sum of the group after it is gone); the GLM cell's twin was added,
 # read on the tree before that change: its latent attention is ungrouped, and
 # it stands with the other steps whose flash calls are ungrouped
-# (``gpt2_remat_rope`` takes no flash route).
+# (``gpt2_remat_rope`` takes no flash route). Hyper-connections, a v of its
+# own width and a softmax scale at the flash kernels changed none of the seven
+# and added the Xing cell's twin, read on its own tree.
 LOWERED_BEFORE = {
     "gpt2": "f19a640cb05302ba6b1a96247726095553a0daa69a62f3ba0adb18d9229d8743",
     "gpt2_flash_bf16":
@@ -511,6 +513,8 @@ LOWERED_BEFORE = {
         "0c35df2a221db3d80d2a57ba5f85206a8f318de4595372618704e2fc58257e03",
     "glm_tiny":
         "edd858b649e1fdaa19ba62a6b2a6a77e25b97b21c5f44d505a6ae9b8a03fd4d0",
+    "xing_tiny":
+        "ded9b37a4d14990802ab9d810a6acddb11f08cb99b718fe956c2bcb01d1e610d",
 }
 
 
@@ -523,22 +527,25 @@ def test_the_defaults_leave_the_gpt2_and_laguna_steps_as_they_were(
     steps by PR 33, ``laguna_tiny`` by PR 34 and PR 35, ``smallthinker_tiny``
     by PR 35 (the expert layer's groups, masks and product), both by PR 37
     (the expert layer's rows move in Pallas calls); ``ouro_tiny`` is held
-    since PR 39."""
+    since PR 39, ``glm_tiny`` since PR 41's parent, ``xing_tiny`` since
+    hyper-connections came."""
     import hashlib
     import re
     monkeypatch.setenv("DL4J_TPU_PALLAS_INTERPRET", "1")
     monkeypatch.setenv("DL4J_TPU_LM_ATTN", "pallas")
     gpt2 = dict(vocab_size=512, max_len=64, d_model=64, n_heads=4, n_layers=2,
                 d_ff=256)
-    if name in ("laguna_tiny", "smallthinker_tiny", "ouro_tiny", "glm_tiny"):
+    if name in ("laguna_tiny", "smallthinker_tiny", "ouro_tiny", "glm_tiny",
+                "xing_tiny"):
         from benchmark.drivers import (glm_train, laguna_train, ouro_train,
-                                       smallthinker_train)
+                                       smallthinker_train, xing_train)
         driver, twin, seq = {
             "laguna_tiny": (laguna_train, "laguna-tiny.json", 32),
             "smallthinker_tiny": (smallthinker_train,
                                   "smallthinker-tiny.json", 64),
             "ouro_tiny": (ouro_train, "ouro-tiny.json", 32),
-            "glm_tiny": (glm_train, "glm-tiny.json", 32)}[name]
+            "glm_tiny": (glm_train, "glm-tiny.json", 32),
+            "xing_tiny": (xing_train, "xing-tiny.json", 32)}[name]
         with open(os.path.join(ROOT, "benchmark", "rehearsal", "configs",
                                twin)) as f:
             conf, rows = driver.program_config(json.load(f), seq, 7), 2

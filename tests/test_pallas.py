@@ -133,6 +133,56 @@ class TestFlashBackwardKernels:
             np.testing.assert_allclose(np.asarray(g1), np.asarray(g2),
                                        atol=2e-4)
 
+    # (q shape, k / v heads, v width, block): latent attention's q / k of
+    # 192 (128 + 64 rope) against v of 128, the cell's widths, one head a
+    # row on rows of four blocks, and a K/V group of 2 folded into a step
+    OWN_WIDTHS = {"latent": ((1, 2, 256, 192), 2, 128, 64),
+                  "grouped": ((1, 4, 256, 192), 2, 128, 64)}
+
+    @pytest.mark.parametrize("case", sorted(OWN_WIDTHS))
+    def test_v_of_its_own_width_and_a_scale_match_dense(self, rng,
+                                                        interpret_pallas,
+                                                        monkeypatch, case):
+        """v narrower than q and k, and a softmax scale that is not
+        1 / sqrt(d) (YaRN's mscale² over sqrt(192)): the forward and dQ, dK,
+        dV against ``dense_attention`` at that scale, with the pallas
+        backward and with the scan escape hatch; the output and dV take v's
+        width, and nothing of v is padded to q's."""
+        import jax
+        from deeplearning4j_tpu.ops.pallas_kernels import flash_attention
+        q_shape, kv_heads, dv, block = self.OWN_WIDTHS[case]
+        B, H, T, d = q_shape
+        scale = 2.00474 / np.sqrt(d)
+        q = jnp.asarray(rng.randn(*q_shape), jnp.float32)
+        k = jnp.asarray(rng.randn(B, kv_heads, T, d), jnp.float32)
+        v = jnp.asarray(rng.randn(B, kv_heads, T, dv), jnp.float32)
+        cot = jnp.asarray(np.random.RandomState(42).randn(B, H, T, dv),
+                          jnp.float32)
+        rep = lambda x: jnp.repeat(x, H // kv_heads, axis=1)
+
+        def grads(fn):
+            return jax.value_and_grad(lambda a, b, c: (fn(a, b, c) * cot).sum(),
+                                      argnums=(0, 1, 2))(q, k, v)
+        flash = lambda a, b, c: flash_attention(
+            a, b, c, causal=True, block_q=block, block_k=block, scale=scale)
+        assert flash(q, k, v).shape == (B, H, T, dv)
+        want = grads(lambda a, b, c: dense_attention(
+            a, rep(b), rep(c), causal=True, scale=scale))
+        for hatch in ("pallas", "scan"):
+            if hatch == "scan":
+                monkeypatch.setenv("DL4J_TPU_FLASH_BWD", "scan")
+            out, got = grads(flash)
+            np.testing.assert_allclose(out, want[0], rtol=1e-5)
+            for g1, g2, name in zip(got, want[1], "qkv"):
+                assert g1.shape == g2.shape
+                np.testing.assert_allclose(np.asarray(g1), np.asarray(g2),
+                                           atol=2e-4, err_msg=f"d{name}")
+        # the default scale is 1 / sqrt(d) of q's width, not of v's
+        np.testing.assert_allclose(
+            flash_attention(q, k, v, causal=True, block_q=block,
+                            block_k=block),
+            dense_attention(q, rep(k), rep(v), causal=True), atol=1e-5)
+
     def test_bf16_inputs_grads_finite_and_close(self, rng, interpret_pallas):
         from deeplearning4j_tpu.ops.pallas_kernels import flash_attention
         q = jnp.asarray(rng.randn(1, 32, 8), jnp.bfloat16)

@@ -118,6 +118,36 @@ def latent_stacks():
         return name_stacks(lowered.as_text(debug_info=True))
 
 
+@pytest.fixture(scope="module")
+def hyper_stacks():
+    """The name stacks of a tiny step with hyper-connections around latent
+    attention whose v is narrower than q and k, a dense and a routed layer
+    and the multi-token-prediction module, under remat."""
+    from deeplearning4j_tpu.models.transformer import (Experts,
+                                                       HyperConnections,
+                                                       LayerSpec, Latent)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("DL4J_TPU_PALLAS_INTERPRET", "1")
+        mp.setenv("DL4J_TPU_LM_ATTN", "pallas")
+        lm = transformer.TransformerLM(transformer.TransformerConfig(
+            vocab_size=64, max_len=32, d_model=32, n_heads=2, head_dim=8,
+            n_layers=2, d_ff=64, block_size=16, pos_embed="rope",
+            norm="rmsnorm", bias=False, ffn="swiglu", tie_embeddings=False,
+            remat=True, compute_dtype="bfloat16",
+            latent=Latent(q_rank=12, kv_rank=8, d_nope=12, d_rope=4, d_v=8,
+                          scale=2.0),
+            layers=(LayerSpec(), LayerSpec(ffn="experts")),
+            experts=Experts(n_experts=4, top_k=2, d_expert=16, held=(0, 2),
+                            d_shared=16, bias_rate=1e-3),
+            mtp=LayerSpec(ffn="experts"),
+            hyper=HyperConnections(streams=4))).init()
+        tokens = jnp.zeros((2, 32), jnp.int32)
+        lowered = lm._build_step().lower(
+            lm.params, lm.opt_state, jnp.int32(0), jax.random.PRNGKey(0),
+            tokens, tokens, None)
+        return name_stacks(lowered.as_text(debug_info=True))
+
+
 # what a model with a per-layer list enters in ``attn``'s and ``mlp``'s place
 PER_LAYER_SCOPES = ("attn_full", "attn_window", "attn_gate", "router",
                     "moe_dispatch", "experts", "shared_expert")
@@ -125,23 +155,27 @@ PER_LAYER_SCOPES = ("attn_full", "attn_window", "attn_gate", "router",
 LOOPED_SCOPES = ("attn_norm", "mlp_norm", "exit_gate")
 # ... latent attention and the multi-token-prediction module (PR 39)
 LATENT_SCOPES = ("q_latent", "kv_latent", "attn_latent", "mtp", "join")
+# ... hyper-connections
+HC_SCOPES = ("hc_pre", "hc_post")
 
 
 def test_the_benchmark_reads_the_programs_vocabulary():
     """``scope_reduce.LM_SCOPES`` is the benchmark's accepted copy (its
     ``unscoped_share`` finds ``block`` in every layer's scope either way);
-    what PR 28, PR 32 and PR 39 added comes after it."""
+    what PR 28, PR 32, PR 39 and hyper-connections added comes after it."""
     assert transformer.SCOPES == scope_reduce.LM_SCOPES + PER_LAYER_SCOPES \
-        + LOOPED_SCOPES + LATENT_SCOPES
+        + LOOPED_SCOPES + LATENT_SCOPES + HC_SCOPES
 
 
 @pytest.mark.parametrize("scope", transformer.SCOPES)
 def test_lm_step_enters_every_scope_of_the_vocabulary(lm_stacks, mixed_stacks,
                                                       looped_stacks,
-                                                      latent_stacks, scope):
+                                                      latent_stacks,
+                                                      hyper_stacks, scope):
     stacks = (mixed_stacks if scope in PER_LAYER_SCOPES else
               looped_stacks if scope in LOOPED_SCOPES else
-              latent_stacks if scope in LATENT_SCOPES else lm_stacks)
+              latent_stacks if scope in LATENT_SCOPES else
+              hyper_stacks if scope in HC_SCOPES else lm_stacks)
     assert any(scope in scope_reduce.tokens(s) for s in stacks)
 
 
@@ -170,6 +204,28 @@ def test_latent_scopes_are_one_element_each_forward_and_backward(
     assert any("transpose" in scope_reduce.tokens(s) for s in kernels)
     every = set().union(*(scope_reduce.tokens(s) for s in latent_stacks))
     assert not {"qkv", "attn"} & every
+
+
+def test_hyper_connection_scopes_are_one_element_each_forward_and_backward(
+        hyper_stacks):
+    """``block.hc_pre`` and ``block.hc_post`` are entered beside the block's
+    other scopes, never inside them, forward and backward (what ``hc_ms``
+    reads); the sublayers' norms stay under ``ln1`` / ``ln2``, the flash
+    kernels under ``block.attn_latent``, and the streams' expansion and sum
+    ride in ``embed``, ``mtp.join`` and ``final_ln``."""
+    for scope in HC_SCOPES:
+        mine = [s for s in hyper_stacks if scope in scope_reduce.tokens(s)]
+        assert any("transpose" in scope_reduce.tokens(s) for s in mine), scope
+        assert any("transpose" not in scope_reduce.tokens(s) for s in mine)
+        others = set(transformer.SCOPES) - {"block", scope}
+        for s in mine:
+            assert not others & scope_reduce.tokens(s), s
+    for scope in ("ln1", "ln2", "attn_latent"):
+        assert any(scope in scope_reduce.tokens(s) for s in hyper_stacks)
+    kernels = {s for s in hyper_stacks if s.endswith("/pallas_call")
+               and not {"experts", "moe_dispatch"} & scope_reduce.tokens(s)}
+    assert kernels and all("attn_latent" in scope_reduce.tokens(s)
+                           for s in kernels)
 
 
 def test_looped_scopes_are_one_element_each_forward_and_backward(
