@@ -31,7 +31,9 @@ rather than as layer-zoo glue:
 - manifold-constrained hyper-connections (``hyper``) widen the residual
   stream to n streams, mixed around each sublayer by maps computed from the
   streams themselves, the stream-to-stream one projected onto the doubly
-  stochastic matrices by Sinkhorn-Knopp (``_hc_pre`` / ``_hc_post``).
+  stochastic matrices by Sinkhorn-Knopp (``_hc_pre`` / ``_hc_post``); on
+  the TPU the passes over the streams are Pallas kernels that read and
+  write them once a pass (``_hc_junction`` / ``_hc_last``).
 """
 
 from __future__ import annotations
@@ -51,9 +53,9 @@ from deeplearning4j_tpu.config import env_int, env_str
 from deeplearning4j_tpu.models.expert_layer import (Experts, decide,
                                                     expert_ffn, glu,
                                                     moved_bias, stat_names)
-from deeplearning4j_tpu.ops.pallas_kernels import (FLASH_RESIDUALS,
-                                                   flash_attention,
-                                                   pallas_supported)
+from deeplearning4j_tpu.ops.pallas_kernels import (
+    FLASH_RESIDUALS, flash_attention, hc_bwd, hc_dpre, hc_fwd, hc_maps_width,
+    hc_mix, hc_pack, hc_supported, pallas_supported)
 from deeplearning4j_tpu.parallel.sequence_parallel import (
     blockwise_attention, dense_attention)
 
@@ -626,29 +628,43 @@ def _sinkhorn(m, iters, eps):
     return m
 
 
-def _hc_pre(c, bp, x, name):
-    """mHC's maps of one sublayer (``HyperConnections``) from the streams
-    ``x`` [B, T, n, C], and the sublayer's input ``H_pre x`` [B, T, C]:
-    ``(input, H_post [B, T, n], H_res [B, T, n, n], the worst |row or column
-    sum - 1| of H_res)``. The norm over n·C is a scalar a token, so it is
-    applied to the product ``vec(x) phi`` (n(n + 2) columns) and not to x;
-    the maps, Sinkhorn and the mixes are float32. Leaves of ``bp``: ``name``
-    (phi: [n·C, n | n | n²]), ``name_alpha`` [3], ``name_b`` [n(n + 2)]."""
-    hc = c.hyper
-    B, T, n, C = x.shape
-    flat = x.reshape(B, T, n * C)
-    ms = jnp.mean(jnp.square(flat.astype(jnp.float32)), -1, keepdims=True)
-    z = jnp.matmul(flat, bp[name], preferred_element_type=jnp.float32) \
-        * jax.lax.rsqrt(ms + c.norm_eps)
-    alpha = bp[name + "_alpha"].astype(jnp.float32)
-    b = bp[name + "_b"].astype(jnp.float32)
+def _hc_maps(hc, norm_eps, z, ms, alpha, b):
+    """mHC's three maps of one sublayer (``hc``, a ``HyperConnections``)
+    from the product ``z = vec(x) phi`` [..., n(n + 2)] and the mean square
+    ``ms`` [..., 1] of the streams over n·C (the norm is a scalar a token,
+    so it is applied to the product and not to x; ``norm_eps`` is the
+    model's): ``(H_pre [..., n], H_post [..., n], H_res [..., n, n], the
+    worst |row or column sum - 1| of H_res)``, float32; ``alpha`` [3] and
+    ``b`` [n(n + 2)] are the sublayer's leaves."""
+    n = hc.streams
+    z = z * jax.lax.rsqrt(ms + norm_eps)
+    alpha = alpha.astype(jnp.float32)
+    b = b.astype(jnp.float32)
     pre = jax.nn.sigmoid(alpha[0] * z[..., :n] + b[:n])
     post = 2.0 * jax.nn.sigmoid(alpha[1] * z[..., n:2 * n] + b[n:2 * n])
-    res = (alpha[2] * z[..., 2 * n:] + b[2 * n:]).reshape(B, T, n, n)
+    res = (alpha[2] * z[..., 2 * n:] + b[2 * n:]).reshape(z.shape[:-1]
+                                                          + (n, n))
     res = _sinkhorn(jnp.exp(jnp.clip(res, -hc.clamp, hc.clamp)),
                     hc.sinkhorn_iters, hc.eps)
     err = jnp.maximum(jnp.abs(res.sum(-1) - 1.0).max(),
                       jnp.abs(res.sum(-2) - 1.0).max())
+    return pre, post, res, err
+
+
+def _hc_pre(c, bp, x, name):
+    """mHC's maps of one sublayer (``HyperConnections``, ``_hc_maps``) from
+    the streams ``x`` [B, T, n, C], and the sublayer's input ``H_pre x`` [B,
+    T, C]: ``(input, H_post [B, T, n], H_res [B, T, n, n], the worst |row or
+    column sum - 1| of H_res)``; the maps, Sinkhorn and the mixes are
+    float32. Leaves of ``bp``: ``name`` (phi: [n·C, n | n | n²]),
+    ``name_alpha`` [3], ``name_b`` [n(n + 2)]."""
+    B, T, n, C = x.shape
+    flat = x.reshape(B, T, n * C)
+    ms = jnp.mean(jnp.square(flat.astype(jnp.float32)), -1, keepdims=True)
+    pre, post, res, err = _hc_maps(
+        c.hyper, c.norm_eps,
+        jnp.matmul(flat, bp[name], preferred_element_type=jnp.float32), ms,
+        bp[name + "_alpha"], bp[name + "_b"])
     inp = (pre[..., None] * x.astype(jnp.float32)).sum(-2)
     return inp.astype(x.dtype), post, res, err
 
@@ -661,20 +677,166 @@ def _hc_post(x, y, post, res):
         .astype(x.dtype)
 
 
+# Sublayers whose hyper-connection stream passes are the Pallas kernels
+# (``_hc_junction``, ``_hc_last``) or XLA's fusions (``_hc_pre``,
+# ``_hc_post``), counted where a block is traced, as the flash gauges are:
+# the route depends on the backend and the width alone (``_hc_kernels``).
+_HC_KERNEL_SUBLAYERS = obs.gauge(
+    "hc.kernel_sublayers", "Sublayers of the blocks traced so far whose "
+    "hyper-connection stream passes are the Pallas kernels")
+_HC_XLA_SUBLAYERS = obs.gauge(
+    "hc.xla_sublayers", "Sublayers of the blocks traced so far whose "
+    "hyper-connection stream passes are XLA's")
+
+
+def _hc_kernels(c):
+    """The stream passes of a model with hyper-connections run as kernels:
+    on the TPU (or the interpreter), at a width of whole lane tiles, and
+    not under a GSPMD plan (``_block_apply``). There the streams are [B, T,
+    n·C], side by side in the last dim, as the kernels read them (a [B, T,
+    n, C] array between them would be laid out by XLA with n apart from C,
+    and copied at each kernel's door)."""
+    return hc_supported(c.hyper.streams, c.d_model)
+
+
+# The maps on the kernels' route, one program traced and differentiated once
+# for every sublayer of a step: ``_hc_junction_fwd`` takes their vjp at each
+# junction, twice (the primal and the forward), and inline the 20 Sinkhorn
+# rounds and their linearisation each time took longer than the rest of the
+# step's trace
+_hc_maps_once = jax.jit(_hc_maps, static_argnums=(0, 1))
+
+
+def _hc_phi_t(phi, n):
+    """phi [n·C, n(n + 2)] as the kernels take it: transposed, its rows
+    padded to ``hc_maps_width``."""
+    return jnp.pad(phi.T, ((0, hc_maps_width(n) - phi.shape[1]), (0, 0)))
+
+
+def _hc_junction_fwd(c, x, y, post, res, phi, alpha, b):
+    """One pass of the kernels over the streams ``x`` [B, T, n·C] where a
+    sublayer's output meets the next sublayer's input: with the output
+    ``y`` the streams ``x' = H_res x + H_post^T y`` (``post``, ``res``: the
+    sublayer's maps), else x' = x, and in the same pass the next maps'
+    product and mean square; then the maps (``_hc_maps``, XLA) and a pass
+    that mixes the next input ``H_pre x'``. ``(input, x', H_post, H_res,
+    error)`` as ``_hc_pre`` gives them, and the backward's residuals."""
+    B, T = x.shape[:2]
+    n, C = c.hyper.streams, c.d_model
+    tokens = B * T
+    mixes = y is not None
+    with jax.named_scope("block.hc_post" if mixes else "block.hc_pre"):
+        flat = x.reshape(tokens, n * C)
+        phi_t = _hc_phi_t(phi, n)
+        w = hc_pack(n, tokens, res=res, post=post) if mixes else None
+        outs = hc_fwd(flat, y.reshape(tokens, C) if mixes else None, w,
+                      phi_t, n=n)
+    xo, z, s = outs if mixes else (flat, *outs)
+    with jax.named_scope("block.hc_pre"):
+        ms = s[:, n * n + 2 * n:n * n + 2 * n + 1] / (n * C)
+        (pre, post2, res2, err), maps_vjp = jax.vjp(
+            functools.partial(_hc_maps_once, c.hyper, c.norm_eps),
+            z[:, :n * (n + 2)], ms, alpha, b)
+        inp = hc_mix(xo, hc_pack(n, tokens, pre=pre), n=n)
+        out = (inp.reshape(B, T, C), xo.reshape(x.shape),
+               post2.reshape(B, T, n), res2.reshape(B, T, n, n), err)
+    return out, (flat, xo, y, post, res, phi_t, pre, maps_vjp)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _hc_junction(c, x, y, post, res, phi, alpha, b):
+    """``_hc_pre`` (after ``_hc_post`` where the sublayer output ``y`` is
+    given) on the kernels (``_hc_junction_fwd``), returning x' beside the
+    maps so that its cotangent from the next pass comes back here: the
+    backward is one pass over the streams besides H_pre's."""
+    return _hc_junction_fwd(c, x, y, post, res, phi, alpha, b)[0]
+
+
+def _hc_junction_bwd(c, residuals, cts):
+    flat, xo, y, post, res, phi_t, pre, maps_vjp = residuals
+    g_inp, g_xo, g_post, g_res, g_err = cts
+    n = c.hyper.streams
+    tokens, width = flat.shape
+    C = width // n
+    g = g_inp.reshape(tokens, C)
+    with jax.named_scope("block.hc_pre"):
+        at = n * n + n
+        dpre = hc_dpre(xo, g, n=n)[:, at:at + n]
+        dz, dms, dalpha, db = maps_vjp((dpre, g_post.reshape(tokens, n),
+                                        g_res.reshape(tokens, n, n), g_err))
+        dz = jnp.pad(dz, ((0, 0), (0, phi_t.shape[0] - dz.shape[1])))
+        w = hc_pack(n, tokens, res=res, post=post, pre=pre,
+                    scalar=2.0 * dms / width)
+    mixes = y is not None
+    with jax.named_scope("block.hc_post" if mixes else "block.hc_pre"):
+        outs = hc_bwd(g_xo.reshape(tokens, width), w, g=g, xo=xo, dz=dz,
+                      phi_t=phi_t, x=flat if mixes else None,
+                      y=y.reshape(tokens, C) if mixes else None, n=n)
+        dphi = outs[-1][:n * (n + 2)].T.astype(phi_t.dtype)
+        dx = outs[0].reshape(g_xo.shape)
+        if not mixes:
+            return dx, None, None, None, dphi, dalpha, db
+        dy, s = outs[1:3]
+        return (dx, dy.reshape(y.shape), s[:, n * n:at].reshape(post.shape),
+                s[:, :n * n].reshape(res.shape), dphi, dalpha, db)
+
+
+_hc_junction.defvjp(_hc_junction_fwd, _hc_junction_bwd)
+
+
+@jax.custom_vjp
+def _hc_last(x, y, post, res):
+    """``_hc_post`` on the kernels, for a block's last sublayer (the next
+    maps are the next block's): one pass forward, one backward."""
+    return _hc_last_fwd(x, y, post, res)[0]
+
+
+def _hc_last_fwd(x, y, post, res):
+    (B, T, C), n = y.shape, post.shape[-1]
+    with jax.named_scope("block.hc_post"):
+        flat, y = x.reshape(B * T, n * C), y.reshape(B * T, C)
+        w = hc_pack(n, B * T, res=res, post=post)
+        xo, = hc_fwd(flat, y, w, n=n)
+        return xo.reshape(x.shape), (flat, y, w)
+
+
+def _hc_last_bwd(residuals, g):
+    flat, y, w = residuals
+    (B, T), (tokens, C) = g.shape[:2], y.shape
+    n = flat.shape[1] // C
+    with jax.named_scope("block.hc_post"):
+        dx, dy, s = hc_bwd(g.reshape(flat.shape), w, x=flat, y=y, n=n)
+        return (dx.reshape(g.shape), dy.reshape(B, T, C),
+                s[:, n * n:n * n + n].reshape(B, T, n),
+                s[:, :n * n].reshape(B, T, n, n))
+
+
+_hc_last.defvjp(_hc_last_fwd, _hc_last_bwd)
+
+
 def _streams(c, x):
     """The residual stream of a model with hyper-connections: n copies of
-    ``x`` [B, T, C], [B, T, n, C]; ``x`` itself where there are none."""
+    ``x`` [B, T, C], [B, T, n, C] ([B, T, n·C] on the kernels' route:
+    ``_hc_kernels``); ``x`` itself where there are none."""
     if c.hyper is None:
         return x
     B, T, C = x.shape
+    if _hc_kernels(c):
+        return jnp.concatenate([x] * c.hyper.streams, axis=-1)
     return jnp.broadcast_to(x[:, :, None], (B, T, c.hyper.streams, C))
 
 
 def _collapse(c, x):
     """The streams summed back to one before a final norm (Zhu et al. 2024,
-    arXiv:2409.19606); ``x`` itself where there are none."""
+    arXiv:2409.19606), in float32; ``x`` itself where there are none."""
     if c.hyper is None:
         return x
+    if _hc_kernels(c):
+        C = c.d_model
+        total = x[..., :C].astype(jnp.float32)
+        for i in range(1, c.hyper.streams):
+            total = total + x[..., i * C:(i + 1) * C].astype(jnp.float32)
+        return total.astype(x.dtype)
     return x.astype(jnp.float32).sum(-2).astype(x.dtype)
 
 
@@ -701,10 +863,13 @@ def _block_apply(c, bp, x, spec, drop=None, rng=None, attend=None, ffn=None,
     [B, H, T, hd]. A layer with experts returns ``(x, statistics)``.
 
     With hyper-connections (``c.hyper``) ``x`` is the n streams [B, T, n,
-    C], each sublayer reads ``H_pre x`` and writes back through ``_hc_post``,
-    and every layer returns ``(x, statistics)`` with ``hc_err`` among them:
-    the worst row or column sum error of its two H_res after Sinkhorn."""
-    B, T, d = x.shape[0], x.shape[1], x.shape[-1]
+    C], each sublayer reads ``H_pre x`` and writes back through ``_hc_post``
+    (where the kernels run, ``_hc_kernels``: the streams [B, T, n·C],
+    ``_hc_junction`` for a sublayer's maps with the previous output's add,
+    ``_hc_last`` for the block's last add), and every layer returns ``(x,
+    statistics)`` with ``hc_err`` among them: the worst row or column sum
+    error of its two H_res after Sinkhorn."""
+    B, T = x.shape[0], x.shape[1]
     hd, H = c.hd, spec.n_heads
     hc = c.hyper
     r1 = r2 = None
@@ -717,8 +882,22 @@ def _block_apply(c, bp, x, spec, drop=None, rng=None, attend=None, ffn=None,
     scope = jax.named_scope
     inp = x
     if hc is not None:
-        with scope("block.hc_pre"):
-            inp, post1, res1, err1 = _hc_pre(c, bp, x, "hc1")
+        # the kernels' route holds the streams side by side; under a GSPMD
+        # plan (a Mosaic kernel cannot be partitioned) XLA's passes take
+        # them apart and the block puts them back side by side
+        side = _hc_kernels(c)
+        fused = side and plan is None
+        gauge = _HC_KERNEL_SUBLAYERS if fused else _HC_XLA_SUBLAYERS
+        gauge.set(gauge.value + 2)
+        if side and not fused:
+            x = x.reshape(B, T, hc.streams, -1)
+        if fused:
+            inp, x, post1, res1, err1 = _hc_junction(
+                c, x, None, None, None, bp["hc1"], bp["hc1_alpha"],
+                bp["hc1_b"])
+        else:
+            with scope("block.hc_pre"):
+                inp, post1, res1, err1 = _hc_pre(c, bp, x, "hc1")
     with scope("block.ln1"):
         hloc = _norm(c, bp, "ln1", inp)
     routing = None
@@ -726,7 +905,8 @@ def _block_apply(c, bp, x, spec, drop=None, rng=None, attend=None, ffn=None,
             and c.experts.router_input == "block":
         # the router reads what attention reads: the decision is made here,
         # crosses the attention sublayer and is applied after ``ln2``
-        routing = decide(c.experts, bp["router"], hloc.reshape(B * T, d),
+        routing = decide(c.experts, bp["router"],
+                         hloc.reshape(B * T, hloc.shape[-1]),
                          bp.get("router_bias"))
     qd = H * hd
     if c.latent is not None:
@@ -768,11 +948,26 @@ def _block_apply(c, bp, x, spec, drop=None, rng=None, attend=None, ffn=None,
         if not c.post_norm and hc is None:
             x = add(x, a, r1)
     if hc is not None:
-        with scope("block.hc_post"):
-            x = _hc_post(x, drop(a, r1) if drop else a, post1, res1)
+        if fused:
+            inp, x, post2, res2, err2 = _hc_junction(
+                c, x, drop(a, r1) if drop else a, post1, res1, bp["hc2"],
+                bp["hc2_alpha"], bp["hc2_b"])
+        else:
+            with scope("block.hc_post"):
+                x = _hc_post(x, drop(a, r1) if drop else a, post1, res1)
         with scope("block.hc_pre"):
-            inp, post2, res2, err2 = _hc_pre(c, bp, x, "hc2")
+            if not fused:
+                inp, post2, res2, err2 = _hc_pre(c, bp, x, "hc2")
             hc_stats = {"hc_err": jnp.maximum(err1, err2)}
+
+        def hc_out(m):
+            """The last sublayer's output added back (the next maps are the
+            next block's): the kernels open their own scopes."""
+            if fused:
+                return _hc_last(x, drop(m, r2) if drop else m, post2, res2)
+            with scope("block.hc_post"):
+                out = _hc_post(x, drop(m, r2) if drop else m, post2, res2)
+            return out.reshape(B, T, -1) if side else out
     if c.post_norm:   # sandwich: the sublayer's output is normed, then summed
         with scope("block.attn_norm"):
             x = add(x, _norm(c, bp, "attn_norm", a), r1)
@@ -784,9 +979,7 @@ def _block_apply(c, bp, x, spec, drop=None, rng=None, attend=None, ffn=None,
             with scope("block.mlp_norm"):
                 m = _norm(c, bp, "mlp_norm", m)
         if hc is not None:
-            with scope("block.hc_post"):
-                return _hc_post(x, drop(m, r2) if drop else m, post2,
-                                res2), dict(stats, **hc_stats)
+            return hc_out(m), dict(stats, **hc_stats)
         return add(x, m, r2), stats
     with scope("block.mlp"):
         if ffn is not None:
@@ -799,9 +992,7 @@ def _block_apply(c, bp, x, spec, drop=None, rng=None, attend=None, ffn=None,
         if not c.post_norm and hc is None:
             return add(x, m, r2)
     if hc is not None:
-        with scope("block.hc_post"):
-            return _hc_post(x, drop(m, r2) if drop else m, post2,
-                            res2), hc_stats
+        return hc_out(m), hc_stats
     with scope("block.mlp_norm"):
         return add(x, _norm(c, bp, "mlp_norm", m), r2)
 

@@ -2005,6 +2005,523 @@ combine_rows.defvjp(_combine_rows_fwd, _combine_rows_bwd)
 
 
 # ---------------------------------------------------------------------------
+# the streams of manifold-constrained hyper-connections
+# (``models/transformer._hc_junction`` / ``_hc_last``): n streams of C a
+# token side by side, ``[tokens, n C]`` (the model's ``[B, T, n C]`` on this
+# route), crossed once a pass. A grid step holds a tile of tokens, every
+# stream and the whole width; inside it the vector work walks chunks of rows
+# and lanes, and the products with phi (n(n + 2) columns) take the whole
+# tile's rows at once. The streams cross the boundary in their own dtype,
+# everything inside is float32. A token's maps ride in one float32 row of
+# ``_LANES`` (``_hc_slots``), laid across a lane tile once a step; its sums
+# are kept as lane tiles of partial sums and summed across the lanes once a
+# step.
+# ---------------------------------------------------------------------------
+
+# what a call's blocks (double-buffered) and scratch may take of a core's
+# VMEM; the compiler is told ``_GROUP_VMEM``, the rest is its temporaries'
+_HC_VMEM = 40 * 2 ** 20
+_HC_MAX_TILE = 128
+# rows of a tile worked at a time by the vector code: one bfloat16 tile
+_HC_ROWS = 16
+
+
+def _hc_slots(n):
+    """Lanes of a token's float32 row in and out of the stream passes:
+    H_res (row-major, n²), H_post (n), H_pre (n), then one scalar (in: the
+    mean square's cotangent times 2 / (n C); out: the sum of squares)."""
+    return 0, n * n, n * n + n, n * n + 2 * n
+
+
+def hc_supported(n, width):
+    """The stream passes run as kernels: where ``pallas_supported``, the
+    width is whole lane tiles and a token's maps fit one row of lanes."""
+    return (pallas_supported() and width % _LANES == 0
+            and n * n + 2 * n < _LANES)
+
+
+def hc_pack(n, tokens, res=None, post=None, pre=None, scalar=None):
+    """The float32 rows ``[tokens, _LANES]`` of a pass's maps, zeros in the
+    slots it does not read (``_hc_slots``)."""
+    pieces = [(res, n * n), (post, n), (pre, n), (scalar, 1)]
+    cols = [jnp.zeros((tokens, k), jnp.float32) if a is None
+            else a.reshape(tokens, k).astype(jnp.float32) for a, k in pieces]
+    return jnp.pad(jnp.concatenate(cols, axis=1),
+                   ((0, 0), (0, _LANES - n * n - 2 * n - 1)))
+
+
+def hc_maps_width(n):
+    """Columns of phi a sublayer, n(n + 2), padded for the products' tiles."""
+    return -(-n * (n + 2) // 16) * 16
+
+
+def _hc_lanes(width):
+    return next(w for w in (512, 256, _LANES) if width % w == 0)
+
+
+def _hc_tile(tokens, row_bytes, fixed_bytes=0, scratch_row_bytes=0):
+    """Tokens a grid step: the most (up to ``_HC_MAX_TILE``) whose blocks,
+    double-buffered, and scratch fit ``_HC_VMEM``; whole chunks of
+    ``_HC_ROWS`` (a last tile past the end is cut), or all of a shorter
+    call."""
+    if tokens < _HC_ROWS:
+        return tokens
+    tm = _HC_MAX_TILE
+    while tm > _HC_ROWS and 2 * (tm * row_bytes + fixed_bytes) \
+            + tm * scratch_row_bytes > _HC_VMEM:
+        tm //= 2
+    return min(tm, tokens // _HC_ROWS * _HC_ROWS)
+
+
+def _hc_loop(count, size, body):
+    """``body(start)`` for ``count`` chunks of ``size``: one traced body in
+    a loop (a step's code does not grow with the width or the tile)."""
+    from jax.experimental import pallas as pl
+
+    if count == 1:
+        body(0)
+        return
+
+    def step(i, carry):
+        body(pl.multiple_of(i * size, size))
+        return carry
+
+    jax.lax.fori_loop(0, count, step, 0)
+
+
+class _HcWalk(NamedTuple):
+    """Where a step's vector code is: ``stream(j)`` slices stream j's lanes
+    of the current lane chunk, ``lanes`` the chunk of a one-stream array,
+    ``col(k, rows)`` slot k of the rows' maps across the chunk's lanes."""
+    stream: object
+    lanes: object
+    col: object
+
+
+def _hc_walk(n, tm, width, body_rows, before=None, after=None, w_ref=None,
+             cols_ref=None):
+    """Lane chunks of each stream, outer, and row chunks, inner:
+    ``before(walk)`` and ``after(walk)`` around the rows of a lane chunk
+    (the products with phi, on the whole tile), ``body_rows(walk, rows)``
+    on ``_HC_ROWS`` rows of it. With the maps ``w_ref``, each slot is laid
+    across a lane tile once a step (``cols_ref`` [slots, tile, _LANES]):
+    the rows' code reads it as it reads the streams."""
+    from jax.experimental import pallas as pl
+
+    lanes = _hc_lanes(width)
+    rows = min(_HC_ROWS, tm)
+    if w_ref is not None:
+        for k in range(cols_ref.shape[0]):
+            cols_ref[k] = jnp.broadcast_to(w_ref[:, k:k + 1],
+                                           cols_ref.shape[1:])
+
+    def col(k, r):
+        tile = cols_ref[k, r, :]
+        return jnp.concatenate([tile] * (lanes // _LANES), axis=1)
+
+    def lane_chunk(c0):
+        walk = _HcWalk(
+            lambda j: pl.ds(pl.multiple_of(j * width + c0, lanes)
+                            if not isinstance(c0, int) else j * width + c0,
+                            lanes),
+            pl.ds(c0, lanes), col)
+        if before is not None:
+            before(walk)
+        _hc_loop(tm // rows, rows,
+                 lambda r0: body_rows(walk, pl.ds(r0, rows)))
+        if after is not None:
+            after(walk)
+
+    _hc_loop(width // lanes, lanes, lane_chunk)
+
+
+def _hc_put(acc, k, v):
+    """``acc`` [rows, _LANES] with ``v``'s row sums added in lane ``k``."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, acc.shape, 1)
+    return acc + jnp.where(lane == k, v.sum(axis=1, keepdims=True), 0.0)
+
+
+def _hc_fold(v):
+    """``v`` [rows, lanes] summed lane tile onto lane tile: [rows, _LANES]
+    partial sums whose own sum is the row's."""
+    return sum(v[:, c:c + _LANES] for c in range(0, v.shape[1], _LANES))
+
+
+def _hc_sums(s_ref, part_ref, slots, rows):
+    """Each slot's partial sums (``part_ref`` [slots, tile, _LANES]) summed
+    across the lanes into its lane of ``s_ref``, ``rows`` at a time."""
+    from jax.experimental import pallas as pl
+
+    tm = s_ref.shape[0]
+    rows = min(rows, tm)
+
+    def chunk(r0):
+        r = pl.ds(r0, rows)
+        acc = s_ref[r, :]
+        for i, k in enumerate(slots):
+            acc = _hc_put(acc, k, part_ref[i, r, :])
+        s_ref[r, :] = acc
+
+    _hc_loop(tm // rows, rows, chunk)
+
+
+def _hc_dot(a, b, dims):
+    """Float32 ``a`` against ``b`` to float32, to a's precision: two
+    bfloat16 passes where ``b`` is bfloat16 (exact in one: a's high and low
+    halves, 16 bits of its mantissa), one at the highest precision where
+    it is float32."""
+    if b.dtype == jnp.bfloat16:
+        hi = a.astype(jnp.bfloat16)
+        lo = (a - hi.astype(jnp.float32)).astype(jnp.bfloat16)
+        return _dot(hi, b, dims) + _dot(lo, b, dims)
+    return jax.lax.dot_general(a, b, dims, precision=jax.lax.Precision.HIGHEST,
+                               preferred_element_type=jnp.float32)
+
+
+def _hc_product(a, b, dims):
+    """``a`` (the streams) against phi, each in its own dtype: bfloat16
+    products are exact, float32 ones at the highest precision."""
+    precision = (jax.lax.Precision.HIGHEST if a.dtype == jnp.float32
+                 else None)
+    return jax.lax.dot_general(a, b, dims, precision=precision,
+                               preferred_element_type=jnp.float32)
+
+
+def _hc_fwd_kernel(*refs, n, post, stats):
+    """Forward of a junction: with ``post`` the streams mixed and the
+    sublayer's output added, ``x' = H_res x + H_post^T y`` (one cast to the
+    streams' dtype), else x' = x; with ``stats`` the sum of x''s squares
+    over n C (the last slot of ``s``) and ``z = vec(x') phi`` [tile, maps]."""
+    refs = list(refs)
+    x_ref = refs.pop(0)
+    w_ref = cols_ref = None
+    if post:
+        y_ref, w_ref = refs.pop(0), refs.pop(0)
+    if stats:
+        phi_ref = refs.pop(0)
+    xo_ref = refs.pop(0) if post else x_ref
+    if stats:
+        z_ref, s_ref, part_ref = refs.pop(0), refs.pop(0), refs.pop(0)
+        z_ref[...] = jnp.zeros(z_ref.shape, jnp.float32)
+        part_ref[...] = jnp.zeros(part_ref.shape, jnp.float32)
+        s_ref[...] = jnp.zeros(s_ref.shape, jnp.float32)
+    if post:
+        cols_ref = refs.pop(0)
+    tm, width = x_ref.shape[0], x_ref.shape[1] // n
+    res, post_at, _, last = _hc_slots(n)
+
+    def rows(walk, r):
+        if post:
+            xs = [x_ref[r, walk.stream(i)].astype(jnp.float32)
+                  for i in range(n)]
+            y = y_ref[r, walk.lanes].astype(jnp.float32)
+        squares = 0.0
+        for j in range(n):
+            if post:
+                o = walk.col(res + j * n, r) * xs[0]
+                for i in range(1, n):
+                    o = o + walk.col(res + j * n + i, r) * xs[i]
+                o = (o + walk.col(post_at + j, r) * y).astype(xo_ref.dtype)
+                xo_ref[r, walk.stream(j)] = o
+            else:
+                o = x_ref[r, walk.stream(j)]
+            if stats:
+                o = o.astype(jnp.float32)
+                squares = squares + o * o
+        if stats:
+            part_ref[0, r, :] += _hc_fold(squares)
+
+    def products(walk):
+        z = z_ref[...]
+        for j in range(n):
+            z = z + _hc_product(xo_ref[:, walk.stream(j)],
+                                phi_ref[:, walk.stream(j)], _NT)
+        z_ref[...] = z
+
+    _hc_walk(n, tm, width, rows, after=products if stats else None,
+             w_ref=w_ref, cols_ref=cols_ref)
+    if stats:
+        _hc_sums(s_ref, part_ref, [last], _HC_ROWS)
+
+
+def _hc_mix_kernel(x_ref, w_ref, o_ref, cols_ref, *, n):
+    """The sublayer's input ``H_pre x'``: the streams' float32 sum weighted
+    by H_pre, one cast."""
+    tm, width = x_ref.shape[0], x_ref.shape[1] // n
+    pre = _hc_slots(n)[2]
+
+    def rows(walk, r):
+        o = walk.col(pre, r) * x_ref[r, walk.stream(0)].astype(jnp.float32)
+        for i in range(1, n):
+            o = o + walk.col(pre + i, r) * x_ref[r, walk.stream(i)].astype(
+                jnp.float32)
+        o_ref[r, walk.lanes] = o.astype(o_ref.dtype)
+
+    _hc_walk(n, tm, width, rows, w_ref=w_ref, cols_ref=cols_ref)
+
+
+def _hc_dpre_kernel(x_ref, g_ref, s_ref, part_ref, *, n):
+    """H_pre's cotangent a token: each stream of x' against the input's
+    cotangent ``g``, summed over C (slots of H_pre in ``s``)."""
+    tm, width = x_ref.shape[0], x_ref.shape[1] // n
+    pre = _hc_slots(n)[2]
+    s_ref[...] = jnp.zeros(s_ref.shape, jnp.float32)
+    part_ref[...] = jnp.zeros(part_ref.shape, jnp.float32)
+
+    def rows(walk, r):
+        g = g_ref[r, walk.lanes].astype(jnp.float32)
+        for i in range(n):
+            part_ref[i, r, :] += _hc_fold(
+                x_ref[r, walk.stream(i)].astype(jnp.float32) * g)
+
+    _hc_walk(n, tm, width, rows)
+    _hc_sums(s_ref, part_ref, [pre + i for i in range(n)], _HC_ROWS)
+
+
+def _hc_bwd_kernel(*refs, n, post, stats, tokens):
+    """Backward of a junction (``_hc_fwd_kernel``) in one pass: the
+    cotangent of each stream of x', float32, ``G_j = dx'_j`` plus, with
+    ``stats``, the mix's ``H_pre_j g``, the product's ``dz phi_j^T`` and
+    the mean square's ``2 dms x'_j / (n C)``; then with ``post`` ``dx_i =
+    sum_j H_res[j, i] G_j``, ``dy = sum_j H_post_j G_j`` and the maps'
+    cotangents a token, ``<G_j, x_i>`` and ``<G_j, y>`` (slots of ``s``),
+    else dx = G; with ``stats`` phi's cotangent, summed over the grid."""
+    from jax.experimental import pallas as pl
+
+    refs = list(refs)
+    gxo_ref = refs.pop(0)
+    if stats:
+        g_ref, xo_ref, dz_ref, phi_ref = (refs.pop(0) for _ in range(4))
+    if post:
+        x_ref, y_ref = refs.pop(0), refs.pop(0)
+    w_ref, dx_ref = refs.pop(0), refs.pop(0)
+    if post:
+        dy_ref, s_ref = refs.pop(0), refs.pop(0)
+        s_ref[...] = jnp.zeros(s_ref.shape, jnp.float32)
+    if stats:
+        dphi_ref, t_ref = refs.pop(0), refs.pop(0)
+    if post:
+        part_ref = refs.pop(0)
+        part_ref[...] = jnp.zeros(part_ref.shape, jnp.float32)
+    cols_ref, = refs
+    tm, width = gxo_ref.shape[0], gxo_ref.shape[1] // n
+    res, post_at, pre, last = _hc_slots(n)
+    if stats:
+        # a last tile past the end holds rows of no token: no sum over the
+        # grid may read them
+        if tokens % tm:
+            valid = pl.program_id(0) * tm + jax.lax.broadcasted_iota(
+                jnp.int32, (tm, 1), 0) < tokens
+            masked = lambda a: jnp.where(valid, a.astype(jnp.float32), 0.0)
+        else:
+            masked = lambda a: a
+        dz = masked(dz_ref[...])
+
+        @pl.when(pl.program_id(0) == 0)
+        def _():
+            dphi_ref[...] = jnp.zeros(dphi_ref.shape, jnp.float32)
+
+    def terms(walk):
+        for j in range(n):
+            t_ref[j] = _hc_dot(dz, phi_ref[:, walk.stream(j)], _NN)
+
+    def rows(walk, r):
+        cot = []
+        for j in range(n):
+            gj = gxo_ref[r, walk.stream(j)].astype(jnp.float32)
+            if stats:
+                gj = (gj + walk.col(pre + j, r)
+                      * g_ref[r, walk.lanes].astype(jnp.float32)
+                      + t_ref[j, r, :] + walk.col(last, r)
+                      * xo_ref[r, walk.stream(j)].astype(jnp.float32))
+            cot.append(gj)
+        if not post:
+            for j in range(n):
+                dx_ref[r, walk.stream(j)] = cot[j].astype(dx_ref.dtype)
+            return
+        y = y_ref[r, walk.lanes].astype(jnp.float32)
+        dy = walk.col(post_at, r) * cot[0]
+        for j in range(1, n):
+            dy = dy + walk.col(post_at + j, r) * cot[j]
+        dy_ref[r, walk.lanes] = dy.astype(dy_ref.dtype)
+        for j in range(n):
+            part_ref[n * n + j, r, :] += _hc_fold(cot[j] * y)
+        for i in range(n):
+            x = x_ref[r, walk.stream(i)].astype(jnp.float32)
+            dx = walk.col(res + i, r) * cot[0]
+            for j in range(1, n):
+                dx = dx + walk.col(res + j * n + i, r) * cot[j]
+            dx_ref[r, walk.stream(i)] = dx.astype(dx_ref.dtype)
+            for j in range(n):
+                part_ref[j * n + i, r, :] += _hc_fold(cot[j] * x)
+
+    def gradient(walk):
+        for j in range(n):
+            xo = masked(xo_ref[:, walk.stream(j)])
+            dphi_ref[:, walk.stream(j)] += _hc_dot(
+                dz, xo.astype(xo_ref.dtype), _TN)
+
+    _hc_walk(n, tm, width, rows, before=terms if stats else None,
+             after=gradient if stats else None, w_ref=w_ref,
+             cols_ref=cols_ref)
+    if post:
+        _hc_sums(s_ref, part_ref, range(n * n + n), _HC_ROWS)
+
+
+def _hc_call(kernel, tokens, tm, ins, outs, scratch=(), arbitrary=False,
+             interpret=False):
+    """``kernel`` over the token tiles: ``ins`` and ``outs`` are ``(array
+    or shape-dtype, whole)``, a block of ``tm`` tokens (rows) of it or, if
+    ``whole``, all of it in every step."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    def spec(a, whole):
+        if whole:
+            return pl.BlockSpec(a.shape, lambda t: (0,) * len(a.shape))
+        return pl.BlockSpec((tm, a.shape[1]), lambda t: (t, 0))
+
+    return pl.pallas_call(
+        kernel,
+        out_shape=[jax.ShapeDtypeStruct(a.shape, a.dtype) for a, _ in outs],
+        grid=(-(-tokens // tm),),
+        in_specs=[spec(a, whole) for a, whole in ins],
+        out_specs=[spec(a, whole) for a, whole in outs],
+        scratch_shapes=list(scratch),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary" if arbitrary else "parallel",),
+            vmem_limit_bytes=_GROUP_VMEM),
+        interpret=interpret)(*(a for a, _ in ins))
+
+
+def _hc_planes(planes, tm):
+    """VMEM scratch of ``[count, tm, _LANES]`` float32 for each count: a
+    token's slots laid across a lane tile, or its sums' partial sums."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    return [pltpu.VMEM((k, tm, _LANES), jnp.float32) for k in planes]
+
+
+def _row_bytes(*arrays):
+    return sum(a.shape[1] * jnp.dtype(a.dtype).itemsize for a in arrays)
+
+
+def _whole_bytes(*arrays):
+    return sum(int(np.prod(a.shape)) * jnp.dtype(a.dtype).itemsize
+               for a in arrays)
+
+
+# Traced once for each shape and inlined where it is called, as the flash
+# kernels are (``_trace_once``): a step's sublayers and their rematerialised
+# copies share one trace of each pass.
+_hc_once = functools.partial(jax.jit, inline=True,
+                             static_argnames=("n", "interpret"))
+
+
+@_hc_once
+def _hc_fwd(x, y, w, phi_t, *, n, interpret):
+    tokens = x.shape[0]
+    post, stats = y is not None, phi_t is not None
+    ins = [(x, False)] + ([(y, False), (w, False)] if post else []) \
+        + ([(phi_t, True)] if stats else [])
+    outs = ([(x, False)] if post else []) + ([
+        (jax.ShapeDtypeStruct((tokens, phi_t.shape[0]), jnp.float32), False),
+        (jax.ShapeDtypeStruct((tokens, _LANES), jnp.float32), False)]
+        if stats else [])
+    streams = [a for a, whole in ins + outs if not whole]
+    planes = ([1] if stats else []) + ([_hc_slots(n)[3] + 1] if post else [])
+    tm = _hc_tile(tokens, _row_bytes(*streams),
+                  _whole_bytes(*(a for a, whole in ins if whole)),
+                  sum(planes) * _LANES * 4)
+    return _hc_call(
+        functools.partial(_hc_fwd_kernel, n=n, post=post, stats=stats),
+        tokens, tm, ins, outs, _hc_planes(planes, tm), interpret=interpret)
+
+
+def hc_fwd(x, y=None, w=None, phi_t=None, *, n):
+    """A junction's forward pass over the streams ``x`` [tokens, n C]:
+    ``[x']`` (with ``y`` [tokens, C] and the maps ``w`` (``hc_pack``: H_res
+    and H_post): ``H_res x + H_post^T y``) ``+ [z, s]`` (with ``phi_t``
+    [maps, n C]: ``vec(x') phi`` [tokens, maps] float32 and the rows whose
+    last slot is x''s sum of squares)."""
+    return _hc_fwd(x, y, w, phi_t, n=n, interpret=_interpret_mode())
+
+
+@_hc_once
+def _hc_mix(x, w, *, n, interpret):
+    tokens, width = x.shape[0], x.shape[1] // n
+    out = jax.ShapeDtypeStruct((tokens, width), x.dtype)
+    planes = [_hc_slots(n)[3] + 1]
+    tm = _hc_tile(tokens, _row_bytes(x, w, out), 0, planes[0] * _LANES * 4)
+    return _hc_call(functools.partial(_hc_mix_kernel, n=n), tokens, tm,
+                    [(x, False), (w, False)], [(out, False)],
+                    _hc_planes(planes, tm), interpret=interpret)[0]
+
+
+def hc_mix(x, w, *, n):
+    """The sublayer's input ``H_pre x`` [tokens, C] from the streams ``x``
+    [tokens, n C] and the maps ``w`` (H_pre's slots)."""
+    return _hc_mix(x, w, n=n, interpret=_interpret_mode())
+
+
+@_hc_once
+def _hc_dpre(x, g, *, n, interpret):
+    tokens = x.shape[0]
+    out = jax.ShapeDtypeStruct((tokens, _LANES), jnp.float32)
+    tm = _hc_tile(tokens, _row_bytes(x, g, out), 0, n * _LANES * 4)
+    return _hc_call(functools.partial(_hc_dpre_kernel, n=n), tokens, tm,
+                    [(x, False), (g, False)], [(out, False)],
+                    _hc_planes([n], tm), interpret=interpret)[0]
+
+
+def hc_dpre(x, g, *, n):
+    """H_pre's cotangent: ``<x_i, g>`` a token, in H_pre's slots of rows
+    [tokens, _LANES], from the streams ``x`` and the input's cotangent
+    ``g`` [tokens, C]."""
+    return _hc_dpre(x, g, n=n, interpret=_interpret_mode())
+
+
+@_hc_once
+def _hc_bwd(gxo, g, xo, dz, phi_t, x, y, w, *, n, interpret):
+    from jax.experimental.pallas import tpu as pltpu
+
+    tokens, width = gxo.shape[0], gxo.shape[1] // n
+    post, stats = x is not None, xo is not None
+    ins = [(gxo, False)] \
+        + ([(g, False), (xo, False), (dz, False), (phi_t, True)]
+           if stats else []) \
+        + ([(x, False), (y, False)] if post else []) + [(w, False)]
+    outs = [(gxo, False)] + ([(y, False), (
+        jax.ShapeDtypeStruct((tokens, _LANES), jnp.float32), False)]
+        if post else []) + ([(jax.ShapeDtypeStruct(
+            phi_t.shape, jnp.float32), True)] if stats else [])
+    streams = [a for a, whole in ins + outs if not whole]
+    lanes = _hc_lanes(width)
+    planes = ([n * n + n] if post else []) + [_hc_slots(n)[3] + 1]
+    tm = _hc_tile(tokens, _row_bytes(*streams),
+                  _whole_bytes(*(a for a, whole in ins + outs if whole)),
+                  4 * ((n * lanes if stats else 0) + sum(planes) * _LANES))
+    scratch = [pltpu.VMEM((n, tm, lanes), jnp.float32)] if stats else []
+    return _hc_call(
+        functools.partial(_hc_bwd_kernel, n=n, post=post, stats=stats,
+                          tokens=tokens),
+        tokens, tm, ins, outs, scratch + _hc_planes(planes, tm),
+        arbitrary=stats, interpret=interpret)
+
+
+def hc_bwd(gxo, w, g=None, xo=None, dz=None, phi_t=None, x=None, y=None, *,
+           n):
+    """A junction's backward pass (``hc_fwd``'s) from x''s cotangent
+    ``gxo`` and the maps ``w`` (H_res, H_post where the junction mixed;
+    H_pre and the mean square's term where it had ``stats``): ``[dx] +
+    [dy, s]`` (with ``x``, ``y``: the maps' cotangents in H_res's and
+    H_post's slots of ``s``) ``+ [dphi_t]`` (with the input's cotangent
+    ``g``, x' ``xo``, z's cotangent ``dz`` and ``phi_t``)."""
+    return _hc_bwd(gxo, g, xo, dz, phi_t, x, y, w, n=n,
+                   interpret=_interpret_mode())
+
+
+# ---------------------------------------------------------------------------
 # fused LSTM cell ("Optimizing Performance of Recurrent Neural Networks on
 # GPUs", arxiv 1604.01946; the cuDNN RNN fusion strategy, arxiv 1410.0759):
 # one kernel per time step fusing the recurrent matmul epilogue

@@ -244,6 +244,38 @@ def test_row_movement_compiles_for_v5e(v5e, shape):
         sds((rows, d)), sds((tokens, d))) == 8
 
 
+# the streams of hyper-connections: (streams, C, tokens a step, dtype) --
+# the seventh cell's, and its width in float32 (the tile a quarter)
+HC_SHAPES = {"xing": (4, 3584, 4096, jnp.bfloat16),
+             "xing_f32": (4, 3584, 1024, jnp.float32)}
+
+
+@pytest.mark.parametrize("shape", sorted(HC_SHAPES))
+def test_stream_passes_compile_for_v5e(v5e, shape):
+    """The three passes of ``transformer._hc_junction`` / ``_hc_last``,
+    forward (with and without the mix, with and without the maps' sums) and
+    backward, H_pre's mix and its cotangent: eight Mosaic kernels, each
+    inside the VMEM it asks for (``_GROUP_VMEM``) at the tile
+    ``_hc_tile`` gives."""
+    n, C, tokens, dtype = HC_SHAPES[shape]
+    sds = lambda shape, dt=dtype: jax.ShapeDtypeStruct(shape, dt,
+                                                       sharding=v5e)
+    x, y = sds((tokens, n * C)), sds((tokens, C))
+    w = sds((tokens, pk._LANES), jnp.float32)
+    phi_t = sds((pk.hc_maps_width(n), n * C))
+    dz = sds((tokens, pk.hc_maps_width(n)), jnp.float32)
+
+    def passes(x, y, w, phi_t, dz):
+        return (pk.hc_fwd(x, y, w, phi_t, n=n),
+                pk.hc_fwd(x, phi_t=phi_t, n=n), pk.hc_fwd(x, y, w, n=n),
+                pk.hc_mix(x, w, n=n), pk.hc_dpre(x, y, n=n),
+                pk.hc_bwd(x, w, y, x, dz, phi_t, x, y, n=n),
+                pk.hc_bwd(x, w, y, x, dz, phi_t, n=n),
+                pk.hc_bwd(x, w, x=x, y=y, n=n))
+
+    assert _compile(passes, x, y, w, phi_t, dz) == 8
+
+
 def test_flash_walk_at_its_bound_compiles_for_v5e(v5e):
     """The walk's table is a scalar-prefetch operand and lives in the 1 MiB
     of SMEM for the whole call, 12 bytes a step: a row that walks exactly
@@ -324,6 +356,50 @@ def test_sharded_lm_step_compiles_for_2x2_mesh(v5e_devices, monkeypatch):
     text = compiled.as_text()
     assert text.count("tpu_custom_call") == 3
     assert "all-gather" in text   # level 3: params gathered just in time
+
+
+def test_sharded_hyper_step_takes_xla_passes_for_2x2_mesh(v5e_devices,
+                                                          monkeypatch):
+    """A ``shard()``-ed model with hyper-connections at a width the
+    streams' kernels take: GSPMD cannot partition them, so under the plan
+    the block takes XLA's passes on the streams it holds side by side,
+    and the step compiles for four chips with the flash kernels alone."""
+    from jax.sharding import PartitionSpec as P
+
+    from deeplearning4j_tpu import obs
+    from deeplearning4j_tpu.models import transformer
+    from deeplearning4j_tpu.parallel.sharding_core import (ShardingCore,
+                                                           build_mesh)
+
+    monkeypatch.setenv("DL4J_TPU_LM_ATTN", "pallas")
+    monkeypatch.setattr(transformer, "hc_supported",
+                        lambda n, width: width % 128 == 0)
+    mesh = build_mesh(devices=v5e_devices)
+    lm = transformer.TransformerLM(transformer.TransformerConfig(
+        vocab_size=1024, max_len=256, d_model=256, n_heads=4, n_layers=1,
+        d_ff=512, compute_dtype="bfloat16", block_size=128,
+        hyper=transformer.HyperConnections()))
+    params, opt = jax.eval_shape(lambda: (lm.init().params, lm.opt_state))
+    lm.params = lm.opt_state = None
+    core = lm._shard_plan = ShardingCore(mesh, level=3)
+
+    def sds(a, spec):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                    sharding=core.sharding(spec))
+
+    tokens = jax.ShapeDtypeStruct((8, 256), jnp.int32,
+                                  sharding=core.data_sharding())
+    obs.reset_metrics()
+    compiled = lm._build_step().lower(
+        jax.tree.map(lambda a: sds(a, core.param_spec(a)), params),
+        jax.tree.map(lambda a: sds(a, core.updater_spec(a)), opt),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=core.sharding(P())),
+        jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=core.sharding(P())),
+        tokens, tokens, None).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 3
+    assert (obs.metrics.value("hc.kernel_sublayers"),
+            obs.metrics.value("hc.xla_sublayers")) == (0, 2)
+    obs.reset_metrics()
 
 
 def test_lm_step_names_reach_the_chips_program(v5e, monkeypatch):

@@ -498,7 +498,9 @@ def test_save_and_load_round_trip_the_gate_and_the_counters(seeded, tmp_path):
 # it stands with the other steps whose flash calls are ungrouped
 # (``gpt2_remat_rope`` takes no flash route). Hyper-connections, a v of its
 # own width and a softmax scale at the flash kernels changed none of the seven
-# and added the Xing cell's twin, read on its own tree.
+# and added the Xing cell's twin, read on its own tree. The streams' passes as
+# Pallas kernels changed none of the eight: the Xing twin's width, 64, is no
+# whole lane tile, and it takes XLA's passes as before.
 LOWERED_BEFORE = {
     "gpt2": "f19a640cb05302ba6b1a96247726095553a0daa69a62f3ba0adb18d9229d8743",
     "gpt2_flash_bf16":

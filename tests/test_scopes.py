@@ -148,6 +148,29 @@ def hyper_stacks():
         return name_stacks(lowered.as_text(debug_info=True))
 
 
+@pytest.fixture(scope="module")
+def hyper_kernel_stacks():
+    """``hyper_stacks``' model at a width of one lane tile, where the
+    streams' passes are the Pallas kernels (interpret mode)."""
+    from deeplearning4j_tpu.models.transformer import (HyperConnections,
+                                                       Latent)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("DL4J_TPU_PALLAS_INTERPRET", "1")
+        mp.setenv("DL4J_TPU_LM_ATTN", "pallas")
+        lm = transformer.TransformerLM(transformer.TransformerConfig(
+            vocab_size=64, max_len=32, d_model=128, n_heads=2, head_dim=8,
+            n_layers=2, d_ff=64, block_size=16, pos_embed="rope",
+            norm="rmsnorm", bias=False, ffn="swiglu", tie_embeddings=False,
+            remat=True, compute_dtype="bfloat16",
+            latent=Latent(q_rank=12, kv_rank=8, d_nope=12, d_rope=4, d_v=8),
+            hyper=HyperConnections(streams=4))).init()
+        tokens = jnp.zeros((2, 32), jnp.int32)
+        lowered = lm._build_step().lower(
+            lm.params, lm.opt_state, jnp.int32(0), jax.random.PRNGKey(0),
+            tokens, tokens, None)
+        return name_stacks(lowered.as_text(debug_info=True))
+
+
 # what a model with a per-layer list enters in ``attn``'s and ``mlp``'s place
 PER_LAYER_SCOPES = ("attn_full", "attn_window", "attn_gate", "router",
                     "moe_dispatch", "experts", "shared_expert")
@@ -226,6 +249,34 @@ def test_hyper_connection_scopes_are_one_element_each_forward_and_backward(
                and not {"experts", "moe_dispatch"} & scope_reduce.tokens(s)}
     assert kernels and all("attn_latent" in scope_reduce.tokens(s)
                            for s in kernels)
+
+
+def test_hyper_connection_kernels_sit_under_their_scopes_under_remat(
+        hyper_kernel_stacks):
+    """On the kernels' route every Pallas call outside attention carries
+    ``block.hc_pre`` or ``block.hc_post`` as ONE element, in the forward,
+    the rematerialised forward and the backward (``transpose``): what
+    ``hc_ms`` and ``hc_roofline`` read; nothing of the maps sits outside
+    the two scopes."""
+    calls = {s for s in hyper_kernel_stacks if s.endswith("/pallas_call")}
+    kernels = {s for s in calls
+               if "attn_latent" not in scope_reduce.tokens(s)}
+    assert kernels
+    for s in kernels:
+        assert len(set(HC_SCOPES) & scope_reduce.tokens(s)) == 1, s
+        assert re.search(r"[/(]block\.hc_(pre|post)\)?/pallas_call$", s), s
+    for scope in HC_SCOPES:
+        mine = [s for s in kernels if scope in scope_reduce.tokens(s)]
+        assert any("transpose" not in scope_reduce.tokens(s) for s in mine)
+        assert any("rematted_computation" in scope_reduce.tokens(s)
+                   for s in mine), scope
+        assert any("transpose" in scope_reduce.tokens(s)
+                   and "rematted_computation" not in scope_reduce.tokens(s)
+                   for s in mine), scope
+        others = set(transformer.SCOPES) - {"block", scope}
+        for s in (s for s in hyper_kernel_stacks
+                  if scope in scope_reduce.tokens(s)):
+            assert not others & scope_reduce.tokens(s), s
 
 
 def test_looped_scopes_are_one_element_each_forward_and_backward(

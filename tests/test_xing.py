@@ -22,6 +22,7 @@ import pytest
 from benchmark.drivers import xing_train as driver
 from benchmark.references import glm
 from benchmark.references import xing as ref
+from deeplearning4j_tpu import obs
 from deeplearning4j_tpu.models.expert_layer import expert_ffn
 from deeplearning4j_tpu.models.transformer import (
     HyperConnections, Latent, LayerSpec, TransformerConfig, TransformerLM,
@@ -368,19 +369,64 @@ def test_the_eight_shares_add_up_to_the_uncut_layer(who):
 
 
 def test_the_kernel_route_trains_the_same_model(seeded, monkeypatch):
-    """Flash kernels at q / k 16 against v 8 under YaRN's scale
-    (interpret mode), remat and the bfloat16 the twin states, against the
-    float32 reference within bfloat16's reach."""
-    config, weights, (tokens, *_) = seeded
+    """Flash kernels at q / k 16 against v 8 under YaRN's scale and the
+    streams' passes as kernels (interpret mode; the twin widened to one
+    lane tile, 128, where they run), remat and the bfloat16 the twin
+    states, against the float32 reference within bfloat16's reach; every
+    sublayer of the stack and the module took the kernels."""
+    _, _, (tokens, *_) = seeded
+    config = tiny_config(hidden_size=128)
+    weights = ref.init_weights(config, SEED)
     want = float(ref.loss_fn(config, "float32", weights, ref.init_bias(config),
                              jnp.asarray(tokens))[0])
     monkeypatch.setenv("DL4J_TPU_PALLAS_INTERPRET", "1")
     monkeypatch.setenv("DL4J_TPU_LM_ATTN", "pallas")
-    lm = program(tiny_config(compute_dtype="bfloat16", block_size=16),
-                 weights)
+    obs.reset_metrics()
+    lm = program(tiny_config(hidden_size=128, compute_dtype="bfloat16",
+                             block_size=16), weights)
     got = float(lm.fit_batch(tokens))
     assert abs(got - want) / want < 2e-3   # bfloat16: 8 mantissa bits
     assert lm.moe_counters()["moe.rows_over_buffer"] == 0
+    assert obs.metrics.value("hc.kernel_sublayers") > 0
+    assert obs.metrics.value("hc.xla_sublayers") == 0
+
+
+def sublayers_by_route(conf, seq=32):
+    """``(hc.kernel_sublayers, hc.xla_sublayers)`` once the training step
+    of ``conf`` is traced (interpret mode, shapes only)."""
+    lm = TransformerLM(conf)
+    params, opt = jax.eval_shape(lambda: (lm.init().params, lm.opt_state))
+    lm.params = lm.opt_state = None
+    tokens = jax.ShapeDtypeStruct((ROWS, seq), jnp.int32)
+    obs.reset_metrics()
+    lm._build_step().trace(
+        params, opt, jax.ShapeDtypeStruct((), jnp.int32),
+        jax.ShapeDtypeStruct((2,), jnp.uint32), tokens, tokens, None)
+    read = (obs.metrics.value("hc.kernel_sublayers"),
+            obs.metrics.value("hc.xla_sublayers"))
+    obs.reset_metrics()
+    return read
+
+
+def test_a_width_of_no_whole_lane_tiles_takes_xla_passes(monkeypatch):
+    """The twin as it stands (d 64) takes XLA's passes where the kernels
+    could run: all eight sublayers of its three layers and the module;
+    at 128 the kernels take all eight."""
+    monkeypatch.setenv("DL4J_TPU_PALLAS_INTERPRET", "1")
+    for width, want in ((64, (0, 8)), (128, (8, 0))):
+        conf = driver.program_config(tiny_config(hidden_size=width), SEQ,
+                                     SEED)
+        assert sublayers_by_route(conf) == want, width
+
+
+def test_a_model_without_hyper_connections_counts_no_sublayer(monkeypatch):
+    """Without ``hyper`` neither pass is traced: both gauges stay at 0 (the
+    other models' steps lower to their former text:
+    tests/test_ouro.py::LOWERED_BEFORE)."""
+    monkeypatch.setenv("DL4J_TPU_PALLAS_INTERPRET", "1")
+    conf = TransformerConfig(vocab_size=64, max_len=32, d_model=128,
+                             n_heads=2, n_layers=2, d_ff=64)
+    assert sublayers_by_route(conf) == (0, 0)
 
 
 # --- refusals by name ----------------------------------------------------------
