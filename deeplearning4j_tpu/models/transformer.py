@@ -22,7 +22,7 @@ rather than as layer-zoo glue:
 - a looped model (``loops``: the whole stack run several times over the
   same weights; ``post_norm``, ``exit_gate``) is the same walk
   (``_stack_runs``) for training and serving, a KV cache entry a run and
-  layer, and a loss over every run's exit (``_exit_terms``);
+  layer, and a loss over every run's exit (``_exits_loss``);
 - latent attention (``latent``: queries, keys and values up-projected from
   two normed low-rank latents, one rotated key shared by every head) builds
   q, k and v of one width and enters the same attention route; a
@@ -1216,32 +1216,195 @@ def _head_nll(c, ep, h, targets, scope="logits_loss"):
         return nll
 
 
-def _head_sum(c, scope, ep, h, targets, m):
-    """``sum_i m_i nll_i`` of one head (``_head_nll``); the logits live
-    inside: rematerialised (``_mtp_loss``), one head's are alive at a time."""
-    return (_head_nll(c, ep, h, targets, scope) * m).sum()
+# One chunk of a training head's rows holds at most this many bytes of
+# float32 logits: a head whose whole [tokens, vocab] array fits is one
+# chunk. 2 GiB keeps the GPT-2 cells' 1.65 GB head whole and cuts the fifth
+# cell's 2.49 GB into two (PERF.md section 6).
+HEAD_CHUNK_BYTES = 2 << 30
+
+_HEAD_FUSED_ROWS = obs.gauge(
+    "head.fused_rows", "Rows (tokens, over every head or exit) that went "
+    "through head_loss in the last traced training loss")
+_HEAD_CHUNKS = obs.gauge(
+    "head.chunks", "Row chunks head_loss walked in the last traced training "
+    "loss")
 
 
-def _exit_terms(c, last, ep, h, targets, m, log_surv):
-    """One exit of a looped model, from its run's final-normed state ``h``
-    and the exit's leaves ``ep`` (the head or the tied embedding, the gate):
-    with ``lam = sigmoid(h . w + b)`` a token leaves here with probability
-    ``p = lam * surv``, ``surv`` the probability that it left at no earlier
-    exit (``log_surv`` [B, T], f32), and at the ``last`` exit with all that
-    is left, ``p = surv``. Returns this exit's summand of the loss,
-    ``sum_i m_i p_i (L_i + exit_entropy * log p_i)`` (the expected loss less
-    ``exit_entropy`` times the exit distribution's entropy, ``-sum p log p``,
-    exit by exit), the next exit's ``log_surv`` and the mass ``sum_i m_i
-    p_i``. The logits live inside: rematerialised (``_exits_loss``), one
-    exit's are alive at a time."""
-    nll = _head_nll(c, ep, h, targets)
+def _head_spans(shape, vocab):
+    """The position spans ``[a, b)`` of a head's ``[B, T]`` rows that
+    ``head_loss`` takes one chunk at a time: as few as keep a chunk's
+    float32 logits within ``HEAD_CHUNK_BYTES``, all B rows a chunk (a
+    batch's sharding is left whole), the last one shorter where T does not
+    divide."""
+    B, T = shape
+    k = max(1, -(-B * T * vocab * 4 // HEAD_CHUNK_BYTES))
+    step = -(-T // k)
+    return [(a, min(a + step, T)) for a in range(0, T, step)]
+
+
+def _nll_terms(c, logits, targets, w=None):
+    """Each token's loss [B, T] from its f32 ``logits`` (label smoothing and
+    the z-loss included), and where ``w`` [B, T] is given its gradient in
+    the logits, ``w`` times it: ``w (softmax (1 + 2 z lse) - (1 - a) onehot
+    - a / V)``. Three passes over the logits: their max, the sum of the
+    exponentials with the target's logit and the mean, the gradient."""
+    V = logits.shape[-1]
+    a, zl = c.label_smoothing, c.z_loss
+    lse = jax.nn.logsumexp(logits, axis=-1, keepdims=True)
+    hit = jnp.arange(V) == targets[..., None]
+    nll = lse[..., 0] - jnp.where(hit, logits, 0.0).sum(-1)
+    if a > 0.0:
+        nll = (1.0 - a) * nll + a * (lse[..., 0] - logits.mean(-1))
+    if zl > 0.0:
+        nll = nll + zl * lse[..., 0] ** 2
+    if w is None:
+        return nll, None
+    p = jnp.exp(logits - lse)
+    if zl > 0.0:
+        p = p * (1.0 + 2.0 * zl * lse)
+    dl = p - (1.0 - a) * hit
+    if a > 0.0:
+        dl = dl - a / V
+    return nll, w[..., None] * dl
+
+
+def head_loss(c, ep, hs, targets, ws, scopes=None):
+    """``sum_i sum_r ws[i]_r nll(hs[i]_r W, targets[i]_r)``: the training
+    loss of one or more heads through one leaf ``W`` (``ep``: ``{"head":
+    [d, V]}``, or ``{"wte": [V, d]}`` where the embedding is tied), head i
+    from its final-normed states ``hs[i]`` [B, T, d] under ``scopes[i]``
+    (``logits_loss`` each by default); label smoothing and the z-loss
+    included, as ``_head_nll`` has them.
+
+    Its backward is formed in its forward (``jax.custom_vjp``): a chunk of
+    rows (``_head_spans``) at a time it forms the logits in f32 from
+    compute-dtype operands, the loss and its gradient in the logits
+    (``_nll_terms``) cast to the compute dtype as autodiff casts it, the
+    rows' gradient ``dl W^T``, and adds ``h^T dl`` to W's gradient in f32,
+    rounded once at the end to the leaf's dtype. The residuals are those
+    gradients and each token's loss (the gradient in its weight), which the
+    backward only scales: three products a head, no float32 [tokens,
+    vocab] array larger than a chunk, and nothing to rematerialise. Called
+    without a gradient it runs the chunks' forward alone."""
+    scopes = scopes or ("logits_loss",) * len(hs)
+    (name, leaf), = ep.items()
+    tied = name == "wte"
+    vocab = leaf.shape[0 if tied else 1]
+    spans = [_head_spans(t.shape, vocab) for t in targets]
+    chunks = sum(map(len, spans))
+    _HEAD_FUSED_ROWS.set(sum(t.size for t in targets))
+    _HEAD_CHUNKS.set(chunks)
+    # the products, contracting d (logits), V (the rows' gradient) and the
+    # rows (W's: in f32 where chunks are summed, else in the product alone)
+    logits_of = (lambda W, h: jnp.einsum("btd,vd->btv", h, W)) if tied else \
+        (lambda W, h: jnp.einsum("btd,dv->btv", h, W))
+    rows_of = (lambda W, dl: jnp.einsum("btv,vd->btd", dl, W)) if tied else \
+        (lambda W, dl: jnp.einsum("btv,dv->btd", dl, W))
+    summed = jnp.float32 if chunks > 1 else leaf.dtype
+    leaf_of = (lambda h, dl: jnp.einsum(
+        "btv,btd->vd", dl, h, preferred_element_type=summed)) if tied \
+        else (lambda h, dl: jnp.einsum("btd,btv->dv", h, dl,
+                                       preferred_element_type=summed))
+
+    def walk(W, hs, targets, ws, grad):
+        # each head's chunks' (nll, rows' gradient); the head of the last one
+        total, d_leaf, out, last = 0.0, None, [[] for _ in hs], None
+        for i, (h, t, w, scope, cut) in enumerate(
+                zip(hs, targets, ws, scopes, spans)):
+            for a, b in cut:
+                hc = h[:, a:b]
+                with jax.named_scope(scope):
+                    if last is not None:
+                        # one chunk at a time: the next chunk's logits wait
+                        # for all the last one made (XLA would otherwise
+                        # hold several chunks' [rows, V] arrays at once)
+                        hc, total, d_leaf, out[last][-1] = \
+                            jax.lax.optimization_barrier(
+                                (hc, total, d_leaf, out[last][-1]))
+                    logits = logits_of(W, hc).astype(jnp.float32)
+                    n, dl = _nll_terms(c, logits, t[:, a:b],
+                                       w[:, a:b] if grad else None)
+                    total = total + (w[:, a:b] * n).sum()
+                    d_h = None
+                    if grad:
+                        dl = dl.astype(jnp.result_type(hc, W))
+                        d_h = rows_of(W, dl)
+                        part = leaf_of(hc, dl)
+                        d_leaf = part if d_leaf is None else d_leaf + part
+                    out[i].append((n, d_h))
+                    last = i
+        if not grad:
+            return total, None, None, None
+        join = lambda xs: xs[0] if len(xs) == 1 else \
+            jnp.concatenate(xs, axis=1)
+        return (total, d_leaf, [join([d for _, d in o]) for o in out],
+                [join([n for n, _ in o]) for o in out])
+
+    @jax.custom_vjp
+    def loss(W, hs, targets, ws):
+        return walk(W, hs, targets, ws, False)[0]
+
+    def fwd(W, hs, targets, ws):
+        total, d_leaf, d_hs, nlls = walk(W, hs, targets, ws, True)
+        with jax.named_scope(scopes[0]):
+            # kept in the leaf's dtype until the backward: without the
+            # barrier XLA folds the rounding into the master's f32 gradient
+            # and holds twice the bytes through the whole backward
+            d_leaf = jax.lax.optimization_barrier(d_leaf.astype(W.dtype))
+        return total, (d_leaf, d_hs, nlls)
+
+    def bwd(res, g):
+        d_leaf, d_hs, nlls = res
+        scale = lambda x: (g * x).astype(x.dtype)
+        with jax.named_scope(scopes[0]):
+            d_w = scale(d_leaf)
+        outs = []
+        for scope, d_h, nll in zip(scopes, d_hs, nlls):
+            with jax.named_scope(scope):
+                outs.append((scale(d_h), scale(nll)))
+        return (d_w, tuple(o[0] for o in outs), tuple(None for _ in outs),
+                tuple(o[1] for o in outs))
+
+    loss.defvjp(fwd, bwd)
+    return loss(leaf, tuple(hs), tuple(targets), tuple(ws))
+
+
+def _exit_weights(c, ep, hs, m):
+    """The exits of a looped model, from each run's final-normed state in
+    ``hs`` and the gate's leaves in ``ep``: with ``lam = sigmoid(h . w + b)``
+    a token leaves at exit t with probability ``p_t = lam_t * surv_t``,
+    ``surv_t`` the probability that it left at no earlier exit, and at the
+    last exit with all that is left, ``p = surv``. Returns each exit's
+    ``m p_t`` [B, T] (``m`` the mask: the weights of the exits' losses
+    and their masses) and ``exit_entropy * sum_t sum_i m_i p_t log p_t``
+    (the loss less ``exit_entropy`` times the exit distribution's entropy,
+    ``-sum p log p``)."""
+    ps, ent = [], 0.0
+    log_surv = jnp.zeros(m.shape, jnp.float32)
     with jax.named_scope("exit_gate"):
-        z = (h @ ep["exit_gate"] + ep["exit_gate_b"])[..., 0] \
-            .astype(jnp.float32)
-        log_p = log_surv if last else log_surv + jax.nn.log_sigmoid(z)
-        p = jnp.exp(log_p) * m
-        return ((p * (nll + c.exit_entropy * log_p)).sum(),
-                log_surv + jax.nn.log_sigmoid(-z), p.sum())
+        for t, h in enumerate(hs):
+            z = (h @ ep["exit_gate"] + ep["exit_gate_b"])[..., 0] \
+                .astype(jnp.float32)
+            log_p = log_surv if t == len(hs) - 1 \
+                else log_surv + jax.nn.log_sigmoid(z)
+            p = jnp.exp(log_p) * m
+            ent = ent + c.exit_entropy * (p * log_p).sum()
+            ps.append(p)
+            log_surv = log_surv + jax.nn.log_sigmoid(-z)
+    return ps, ent
+
+
+def _head_leaf(c, params):
+    """The head's one leaf of ``params``, as ``head_loss`` takes it: the
+    embedding where it is tied, else the head."""
+    name = "wte" if c.tie_embeddings else "head"
+    return {name: params[name]}
+
+
+def _weights(targets, mask):
+    """The loss's per-token mask [B, T] in f32: ``mask``, or ones."""
+    return (jnp.ones(targets.shape, jnp.float32) if mask is None
+            else mask.astype(jnp.float32))
 
 
 # what ``init`` starts mHC's three gating factors at: 1, so that the maps'
@@ -1674,21 +1837,24 @@ class TransformerLM:
         """The training loss: the last exit's (every model's only one), or
         with an exit gate the loss over all the exits (``_exits_loss``:
         ``exits``, a list, then takes each exit's mass), or with a
-        multi-token-prediction module both heads' (``_mtp_loss``)."""
-        if self.conf.exit_gate:
+        multi-token-prediction module both heads' (``_mtp_loss``); each
+        head through ``head_loss``, whose forward forms its gradient."""
+        c = self.conf
+        if c.exit_gate:
             return self._exits_loss(params, tokens, targets, mask, rng, stats,
                                     exits)
-        if self.conf.mtp is not None:
+        if c.mtp is not None:
             return self._mtp_loss(params, tokens, targets, mask, rng, stats,
                                   bias)
-        return self._last_exit_loss(params, tokens, targets, mask, rng, stats,
-                                    bias)
+        m = _weights(targets, mask)
+        cast, h = self._states(params, tokens, rng, stats, bias=bias)
+        return head_loss(c, _head_leaf(c, cast), [h], [targets],
+                         [m / jnp.maximum(m.sum(), 1.0)])
 
     def _last_exit_loss(self, params, tokens, targets, mask, rng=None,
                         stats=None, bias=None):
         """Mean next-token loss of the last exit's logits: the model as it is
-        served (``eval_loss``), and the training loss where there is no exit
-        gate and no second head."""
+        served (``eval_loss``), the plain head with no gradient work."""
         c = self.conf
         logits = self._logits(params, tokens, rng, stats, bias)
         with jax.named_scope("logits_loss"):
@@ -1705,30 +1871,21 @@ class TransformerLM:
     def _exits_loss(self, params, tokens, targets, mask, rng, stats, exits):
         """``_loss`` of a model with an exit gate: the expectation of the
         exits' losses under the gate's exit distribution less
-        ``exit_entropy`` times its entropy, every run's exit through
-        ``_exit_terms``, rematerialised where the blocks are, so that the
-        backward holds one exit's vocabulary-wide logits at a time and the
-        forward none."""
+        ``exit_entropy`` times its entropy (``_exit_weights``); the exits'
+        heads in one ``head_loss`` over every run's final-normed state,
+        weighted by ``p / denom``, whose gradient in the weights (each
+        token's loss) carries the gate's."""
         c = self.conf
-        m = (jnp.ones(targets.shape, jnp.float32) if mask is None
-             else mask.astype(jnp.float32))
-        total, log_surv = 0.0, jnp.zeros(targets.shape, jnp.float32)
-        leaves = ("wte" if c.tie_embeddings else "head", "exit_gate",
-                  "exit_gate_b")
-
-        def after_run(run, cast, h):
-            nonlocal total, log_surv
-            terms = functools.partial(_exit_terms, c, run == c.loops - 1)
-            if c.remat:
-                terms = jax.checkpoint(terms)
-            term, log_surv, mass = terms({k: cast[k] for k in leaves}, h,
-                                         targets, m, log_surv)
-            total = total + term
-            if exits is not None:
-                exits.append(mass)
-
-        self._states(params, tokens, rng, stats, after_run)
-        return total / jnp.maximum(m.sum(), 1.0)
+        m = _weights(targets, mask)
+        hs = []
+        cast, _ = self._states(params, tokens, rng, stats,
+                               lambda run, cast, h: hs.append(h))
+        ps, entropy = _exit_weights(c, cast, hs, m)
+        if exits is not None:
+            exits.extend(p.sum() for p in ps)
+        denom = jnp.maximum(m.sum(), 1.0)
+        return head_loss(c, _head_leaf(c, cast), hs, [targets] * len(hs),
+                         [p / denom for p in ps]) + entropy / denom
 
     def _mtp_loss(self, params, tokens, targets, mask, rng, stats, bias):
         """``_loss`` of a model with a multi-token-prediction module (DeepSeek-V3
@@ -1743,21 +1900,16 @@ class TransformerLM:
         norm and the shared head, and predicts t_{i+2}: the main target of
         position i + 1. The last position
         has no such target and is masked; ``L_mtp`` is the mean over the
-        positions that have one. Each head and its loss is rematerialised
-        where the blocks are, so that one head's vocabulary-wide logits live
-        at a time."""
+        positions that have one. Both heads go through one ``head_loss``
+        (one gradient of the shared leaf), the module's under
+        ``mtp.logits_loss``."""
         c = self.conf
         bias = self._bias(bias) or {}
-        m = (jnp.ones(targets.shape, jnp.float32) if mask is None
-             else mask.astype(jnp.float32))
+        m = _weights(targets, mask)
         later = jnp.concatenate([targets[:, 1:], targets[:, -1:]], axis=1)
         m_later = jnp.concatenate([m[:, :-1] * m[:, 1:],
                                    jnp.zeros_like(m[:, :1])], axis=1)
         cast, h = self._states(params, tokens, rng, stats, bias=bias)
-        heads = {k: cast[k] for k in ("wte" if c.tie_embeddings else "head",)}
-        terms = lambda scope: (jax.checkpoint if c.remat else lambda f: f)(
-            functools.partial(_head_sum, c, scope))
-        main = terms("logits_loss")(heads, h, targets, m)
         mp = cast["mtp"]
         emb = _embed(c, params, targets)          # Emb(t_{i+1})
         with jax.named_scope("mtp.join"):
@@ -1768,9 +1920,11 @@ class TransformerLM:
                               bias.get("mtp"))
         with jax.named_scope("final_ln"):
             x = _norm(c, mp, "lnf", _collapse(c, x))
-        extra = terms("mtp.logits_loss")(heads, x, later, m_later)
-        return main / jnp.maximum(m.sum(), 1.0) \
-            + c.mtp_weight * extra / jnp.maximum(m_later.sum(), 1.0)
+        return head_loss(
+            c, _head_leaf(c, cast), [h, x], [targets, later],
+            [m / jnp.maximum(m.sum(), 1.0),
+             c.mtp_weight * m_later / jnp.maximum(m_later.sum(), 1.0)],
+            ("logits_loss", "mtp.logits_loss"))
 
     # ---- training ------------------------------------------------------
     def _build_step(self):

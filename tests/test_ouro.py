@@ -20,8 +20,9 @@ from benchmark.references import ouro as ref
 from deeplearning4j_tpu import obs
 from deeplearning4j_tpu.models.transformer import (TransformerConfig,
                                                    TransformerLM, _cast_params,
-                                                   _embed, _exit_terms, _head,
-                                                   _stack_runs)
+                                                   _embed, _exit_weights,
+                                                   _head, _stack_runs,
+                                                   head_loss)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SEQ, ROWS, SEED = 32, 2, 11
@@ -128,19 +129,15 @@ def program_readings(config, weights, tokens):
         lambda p: lm._loss(p, inputs, targets, None)))(lm.params)
 
     def exits(params):
-        logits, survived = [], [jnp.zeros(targets.shape, jnp.float32)]
+        logits, hs = [], []
 
         def after_run(run, cast, h):
             logits.append(_head(c, cast, h))
-            survived.append(_exit_terms(
-                c, run == c.loops - 1, cast, h, targets,
-                jnp.ones(targets.shape), survived[-1])[1])
+            hs.append(h)
 
-        lm._states(params, inputs, after_run=after_run)
-        # p(t) = surv(t) - surv(t+1), and the last exit takes what is left
-        surv = jnp.exp(jnp.stack(survived[:c.loops]))
-        return jnp.stack(logits), jnp.concatenate(
-            [surv[:-1] - surv[1:], surv[-1:]])
+        cast, _ = lm._states(params, inputs, after_run=after_run)
+        ps, _ = _exit_weights(c, cast, hs, jnp.ones(targets.shape))
+        return jnp.stack(logits), jnp.stack(ps)
 
     logits, p = jax.jit(exits)(lm.params)
     return float(loss), dict(driver.leaves(grads)), logits, p
@@ -254,7 +251,8 @@ def test_the_loop_is_the_plain_walk_chained_and_shared_gradients_add_up(seeded):
 
 def test_exit_distribution_sums_to_one_and_the_loss_is_the_hand_computed_one():
     """Three exits of one row of two tokens over a vocabulary of three, d 2:
-    ``sum_t p(t) = 1`` a token, and the summands of ``_exit_terms`` add up to
+    ``sum_t p(t) = 1`` a token, and the exits' losses weighted by
+    ``_exit_weights`` (``head_loss``) and its entropy term add up to
     ``sum_i [sum_t p_i(t) L_i^t - beta H(p_i)]`` written out in numpy."""
     c = TransformerConfig(vocab_size=3, d_model=2, n_heads=1, n_layers=1,
                           tie_embeddings=False, loops=3, exit_gate=True,
@@ -267,14 +265,11 @@ def test_exit_distribution_sums_to_one_and_the_loss_is_the_hand_computed_one():
     targets = np.array([[2, 0]])
     ep = {"head": jnp.asarray(head), "exit_gate": jnp.asarray(gate_w),
           "exit_gate_b": jnp.asarray([gate_b])}
-    total, masses = 0.0, []
-    log_surv, ones = jnp.zeros((1, 2)), jnp.ones((1, 2))
-    for t in range(3):
-        term, log_surv, mass = _exit_terms(c, t == 2, ep, jnp.asarray(hs[t]),
-                                           jnp.asarray(targets), ones,
-                                           log_surv)
-        total += float(term)
-        masses.append(float(mass))
+    exits = [jnp.asarray(h) for h in hs]
+    ps, entropy = _exit_weights(c, ep, exits, jnp.ones((1, 2)))
+    total = float(head_loss(c, {"head": ep["head"]}, exits,
+                            [jnp.asarray(targets)] * 3, ps) + entropy)
+    masses = [float(p.sum()) for p in ps]
     # by hand
     logits = hs @ head                                         # [R, 1, 2, V]
     logp = logits - np.log(np.exp(logits).sum(-1, keepdims=True))
@@ -501,22 +496,31 @@ def test_save_and_load_round_trip_the_gate_and_the_counters(seeded, tmp_path):
 # and added the Xing cell's twin, read on its own tree. The streams' passes as
 # Pallas kernels changed none of the eight: the Xing twin's width, 64, is no
 # whole lane tile, and it takes XLA's passes as before.
+# All eight were renewed once when every training head came to go through
+# ``head_loss``: its forward forms the loss's gradient in the logits, the
+# rows' and the leaf's gradients (a ``custom_vjp``, a chunk of rows at a
+# time, the leaf's gradient held in its dtype behind an optimization
+# barrier), its backward only scales them, and no head sits under
+# ``jax.checkpoint`` any more; the exits' gates are computed after the runs,
+# and ``log_softmax`` / ``take_along_axis`` gave way to one log-sum-exp and a
+# masked sum (CHANGES.md shows the GPT-2 step's vocabulary-wide ops before
+# and after).
 LOWERED_BEFORE = {
-    "gpt2": "f19a640cb05302ba6b1a96247726095553a0daa69a62f3ba0adb18d9229d8743",
+    "gpt2": "58175c9725e5c230be9288e5188bd717c28d5ac603e6a712331a6abdb9ff1961",
     "gpt2_flash_bf16":
-        "3aff487a9faed63983d356db3d834591bcd013d21cb428b0790eae0b5be26ced",
+        "74eac1a64de789ba98793bf9058a241afdd6f20346f0b3b07364343b62d1d9f3",
     "gpt2_remat_rope":
-        "8e7c5ef5fab6e2288fcbbc65e11159aa928b9b4301d789f30c51f345a9493539",
+        "4b16d968df7c732e52b058ee084f4c95f6192cfeec84ceca14199aa08c87ab32",
     "laguna_tiny":
-        "4195244a8fefcdc30d59f9ab5d689dd18f6ac18b5c3c5936f70e937ae9b716d8",
+        "565bc3675719a6269ed0c76ccbae4ee32c0ea76b6be30e63518eb21d8f87eb35",
     "smallthinker_tiny":
-        "8020963b18c47c5964c13d27fc3adac5df68f3d32fed4b107ace44adb91be7b8",
+        "6e7286b130f26abed4f42db00e25255a7bdbc1991d7d128d211f9512f5cc0b84",
     "ouro_tiny":
-        "0c35df2a221db3d80d2a57ba5f85206a8f318de4595372618704e2fc58257e03",
+        "71f6dbe06d75ea078517448fb11c8d4140c3ccd1811ff64e728e832120276c09",
     "glm_tiny":
-        "edd858b649e1fdaa19ba62a6b2a6a77e25b97b21c5f44d505a6ae9b8a03fd4d0",
+        "6f38c6b3e0c9f7be0e377bed31f923f0a6fcdf892d1cafb42bae980f0f5236e7",
     "xing_tiny":
-        "ded9b37a4d14990802ab9d810a6acddb11f08cb99b718fe956c2bcb01d1e610d",
+        "8bc22e2eb7f875a4c3757a22f755f06ad7a4ac149dd41a05e3aaae1ddc71659c",
 }
 
 
@@ -530,7 +534,8 @@ def test_the_defaults_leave_the_gpt2_and_laguna_steps_as_they_were(
     by PR 35 (the expert layer's groups, masks and product), both by PR 37
     (the expert layer's rows move in Pallas calls); ``ouro_tiny`` is held
     since PR 39, ``glm_tiny`` since PR 41's parent, ``xing_tiny`` since
-    hyper-connections came."""
+    hyper-connections came; all eight when the training heads came to go
+    through ``head_loss``."""
     import hashlib
     import re
     monkeypatch.setenv("DL4J_TPU_PALLAS_INTERPRET", "1")
